@@ -41,20 +41,13 @@ BASELINE_NAME = "lint_baseline.json"
 
 # jax-ish module roots whose members mark device entry (see module doc)
 _JAX_ROOTS = ("jax", "jax.numpy", "jax.lax", "jax.experimental",
-              "jax.experimental.pallas", "jax.experimental.shard_map",
-              "numpy", "functools")
-
-# repo-local compat shims that re-export jax transforms under the same
-# terminal names (mesh/compat.py `shard_map`): members resolve exactly
-# like the native jax ones, so a function passed to the compat-wrapped
-# shard_map is still device code
-_COMPAT_ROOTS = ("lightgbm_tpu.mesh", "lightgbm_tpu.mesh.compat")
+              "jax.experimental.pallas", "numpy", "functools")
 
 
 def _jaxish_module(mod: Optional[str]) -> bool:
     if not mod:
         return False
-    return mod == "jax" or mod.startswith("jax.") or mod in _COMPAT_ROOTS
+    return mod == "jax" or mod.startswith("jax.")
 
 # callee terminal name -> positions of function-valued arguments
 _DEVICE_WRAPPERS: Dict[str, Tuple[object, ...]] = {

@@ -434,4 +434,9 @@ def run(argv: List[str]) -> int:
 
 
 def main() -> None:
+    # the process entry point places the persistent compile cache (a
+    # process-global JAX setting); `run` stays free of it for in-process
+    # callers
+    from .utils.env import setup_compile_cache
+    setup_compile_cache()
     sys.exit(run(sys.argv[1:]))

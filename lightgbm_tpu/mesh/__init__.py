@@ -1,8 +1,6 @@
 """Mesh runtime: the shared device-topology layer under training AND
 serving.
 
- - ``compat``   — version-spanning ``shard_map`` / sharding-symbol shim
-                  (jax 0.4.x experimental spelling vs the promoted one).
  - ``topology`` — discovery + normalization of 1-D, 2-level (dcn×ici)
                   and virtual-CPU meshes; the ``mesh_shape`` param.
  - ``placement``— mesh-divisible padding math, per-device placement
@@ -12,8 +10,6 @@ serving.
 striped serving plane) both build on this package; ``parallel/mesh.py``
 remains as a thin re-export shim for older imports.
 """
-from .compat import (Mesh, NamedSharding, PartitionSpec,  # noqa: F401
-                     SHARD_MAP_IS_NATIVE, shard_map)
 from .placement import (collective_span, padded_feature_count,  # noqa: F401
                         padded_row_count, place_from_datastore,
                         record_placement)
@@ -21,8 +17,6 @@ from .topology import (build_mesh, describe, get_mesh,  # noqa: F401
                        get_mesh_2level, init, parse_mesh_shape)
 
 __all__ = [
-    "Mesh", "NamedSharding", "PartitionSpec", "shard_map",
-    "SHARD_MAP_IS_NATIVE",
     "build_mesh", "describe", "get_mesh", "get_mesh_2level", "init",
     "parse_mesh_shape",
     "collective_span", "padded_feature_count", "padded_row_count",

@@ -27,7 +27,7 @@ import numpy as np
 
 from ..resilience import FAULTS, Supervisor
 from ..utils.log import LightGBMError
-from .compat import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["padded_feature_count", "padded_row_count",
            "record_placement", "collective_span", "emit_collective_round",

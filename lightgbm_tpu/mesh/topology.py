@@ -27,7 +27,7 @@ import jax
 import numpy as np
 
 from ..utils import log
-from .compat import Mesh
+from jax.sharding import Mesh
 
 _initialized = False
 
@@ -42,15 +42,6 @@ def init(coordinator_address: Optional[str] = None,
     if _initialized:
         return
     if coordinator_address is not None or num_processes is not None:
-        # CPU clusters need an explicit cross-process collective backend
-        # on this jax (0.4.x defaults to "none", so any multi-process
-        # computation is rejected at compile time); later versions turn
-        # gloo on by default, hence the tolerant update.  Must land
-        # before the first backend init.
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except (AttributeError, ValueError):  # renamed/absent upstream
-            pass
         jax.distributed.initialize(coordinator_address=coordinator_address,
                                    num_processes=num_processes,
                                    process_id=process_id)

@@ -39,7 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis.contracts import contract
-from .histogram import leaf_histogram
+from .histogram import leaf_histogram, ring_ordered_sum
 from .split import NEG_INF, SplitResult, find_best_split, leaf_output, \
     smooth_output
 
@@ -589,19 +589,35 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
                                              debug=spec.debug_checks)
         one_slot = jnp.zeros((1,), jnp.int32)
 
+        def kernel_hist(mask_rows):
+            """This shard's rows through the Pallas kernel."""
+            lid = jnp.where(mask_rows, 0, -1).astype(jnp.int32)
+            if spec.hist_impl == "pallas":
+                return pallas_histogram_multi_rows(
+                    hist_bins, pw_prep, lid, one_slot, HB,
+                    interpret=spec.hist_interpret)[0]
+            return pallas_histogram_multi_quantized_rows(
+                hist_bins, pw_prep, lid, one_slot, HB,
+                feat["qscales"][0], feat["qscales"][1],
+                interpret=spec.hist_interpret)[0]
+
         if det:
             # ring-chained deterministic histogram: shard t folds its
             # local rows onto the carry received from shard t-1, so the
             # scatter-add sequence is exactly the serial one-pass order
             # over rows 0..num_data.  Pad rows (weight 0, absent from the
             # serial program) key to a dropped extra column instead of
-            # adding a bit-flipping +0.0 to live cells.
+            # adding a bit-flipping +0.0 to live cells.  On the Pallas
+            # families the kernel stays the histogram: its per-shard
+            # partials are chained in shard order instead
+            # (`ring_ordered_sum`) — fixed order, not bitwise serial.
             row0_g = jax.lax.axis_index(axis_last) * N
             det_valid = row0_g + jnp.arange(N) < num_data
             det_cols = jnp.where(det_valid[None, :],
                                  hist_bins.astype(jnp.int32), HB)
             det_perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
-            packed_fam = spec.hist_impl in ("packed", "pallas_q")
+            kernel_fam = spec.hist_impl in ("pallas", "pallas_q")
+            packed_fam = spec.hist_impl == "packed"
             if packed_fam:
                 from .histogram import (hist_stream_packed_finalize,
                                         hist_stream_packed_init,
@@ -609,7 +625,11 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
 
             def det_hist(mask_rows):
                 Fh = hist_bins.shape[0]
-                if packed_fam:
+                if kernel_fam:
+                    with jax.named_scope("ring_fold"):
+                        h = ring_ordered_sum(kernel_hist(mask_rows),
+                                             axis_last, n_shards)
+                elif packed_fam:
                     chl = spec.packed_const_hess_level
                     lid = jnp.where(mask_rows & det_valid, 0, -1)\
                         .astype(jnp.int32)
@@ -675,17 +695,8 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
             with jax.named_scope("histogram"):
                 if det:
                     return det_hist(mask_rows)
-                if spec.hist_impl == "pallas":
-                    lid = jnp.where(mask_rows, 0, -1).astype(jnp.int32)
-                    h = pallas_histogram_multi_rows(
-                        hist_bins, pw_prep, lid, one_slot, HB,
-                        interpret=spec.hist_interpret)[0]
-                elif spec.hist_impl == "pallas_q":
-                    lid = jnp.where(mask_rows, 0, -1).astype(jnp.int32)
-                    h = pallas_histogram_multi_quantized_rows(
-                        hist_bins, pw_prep, lid, one_slot, HB,
-                        feat["qscales"][0], feat["qscales"][1],
-                        interpret=spec.hist_interpret)[0]
+                if spec.hist_impl in ("pallas", "pallas_q"):
+                    h = kernel_hist(mask_rows)
                 elif spec.hist_impl == "packed":
                     # quantized-gradient packed-int scatter (2 sweeps);
                     # scales ride in feat["qscales"] (booster/fused set

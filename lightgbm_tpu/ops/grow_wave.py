@@ -62,7 +62,8 @@ from .histogram import (hist_stream_finalize, hist_stream_init,
                         hist_stream_packed_finalize,
                         hist_stream_packed_init,
                         hist_stream_packed_update, hist_stream_update,
-                        leaf_histogram_multi, leaf_histogram_packed_multi)
+                        leaf_histogram_multi, leaf_histogram_packed_multi,
+                        ring_ordered_sum)
 from .split import (NEG_INF, decide_from_candidates, find_best_split,
                     leaf_output, merge_split_results, smooth_output)
 
@@ -241,17 +242,35 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         else:
             bfeat, bmono = feat, mono
 
+        def kernel_hist_multi(leaf_id, slots):
+            """This shard's rows through the Pallas kernel."""
+            if hist_fam == "pallas":
+                return pallas_histogram_multi_rows(
+                    bins_fm, pw_prep, leaf_id, slots, HB,
+                    interpret=spec.hist_interpret)
+            return pallas_histogram_multi_quantized_rows(
+                bins_fm, pw_prep, leaf_id, slots, HB,
+                feat["qscales"][0], feat["qscales"][1],
+                interpret=spec.hist_interpret)
+
         if det:
             det_perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
-            det_packed = hist_fam in ("packed", "pallas_q")
 
             def det_hist_multi(leaf_id, slots):
-                """Ring-chained deterministic wave histogram: bitwise the
-                serial `hist_multi` (pad rows carry leaf_id -1 and match
-                no slot, so they never touch live cells)."""
+                """Ring-chained deterministic wave histogram.  On the XLA
+                families it is bitwise the serial `hist_multi` (pad rows
+                carry leaf_id -1 and match no slot, so they never touch
+                live cells); on the Pallas families the kernel stays the
+                histogram and its per-shard partials are chained in
+                shard order (`ring_ordered_sum`)."""
                 Fh = bins_fm.shape[0]
                 S = slots.shape[0]
-                if det_packed:
+                if hist_fam in ("pallas", "pallas_q"):
+                    with jax.named_scope("ring_fold"):
+                        h = ring_ordered_sum(
+                            kernel_hist_multi(leaf_id, slots), axis_last,
+                            n_shards)
+                elif hist_fam == "packed":
                     chl = spec.packed_const_hess_level
 
                     def fold(acc):
@@ -309,15 +328,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             with jax.named_scope("histogram_wave"):
                 if det:
                     return det_hist_multi(leaf_id, slots)
-                if hist_fam == "pallas":
-                    h = pallas_histogram_multi_rows(
-                        bins_fm, pw_prep, leaf_id, slots, HB,
-                        interpret=spec.hist_interpret)
-                elif hist_fam == "pallas_q":
-                    h = pallas_histogram_multi_quantized_rows(
-                        bins_fm, pw_prep, leaf_id, slots, HB,
-                        feat["qscales"][0], feat["qscales"][1],
-                        interpret=spec.hist_interpret)
+                if hist_fam in ("pallas", "pallas_q"):
+                    h = kernel_hist_multi(leaf_id, slots)
                 elif hist_fam == "packed":
                     h = leaf_histogram_packed_multi(
                         bins_fm, payload, leaf_id, slots, HB,

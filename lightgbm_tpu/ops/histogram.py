@@ -281,6 +281,22 @@ def leaf_histogram_packed_multi(bins_fm: Array, payload: Array,
 # equal carries produce bit-equal [S, F, max_bin, 3] histograms.
 
 
+def ring_ordered_sum(local: Array, axis_name, n_shards: int) -> Array:
+    """Every shard's `local`, summed in ASCENDING shard order —
+    ((x0 + x1) + x2) + ... — and returned on every shard: a ppermute
+    chain in place of psum's reduction tree, so the float result does not
+    depend on how the backend shapes that tree.  The deterministic
+    reduction of the Pallas histogram family: each shard's kernel runs
+    over its own rows at once and only the S partial histograms are
+    chained (the XLA families instead chain the scatter-add itself,
+    `hist_stream_*`, which keeps them bitwise equal to one shard)."""
+    perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
+    carry = local
+    for _ in range(n_shards - 1):
+        carry = jax.lax.ppermute(carry, axis_name, perm) + local
+    return jax.lax.all_gather(carry, axis_name)[n_shards - 1]
+
+
 def hist_stream_init(F: int, slots_n: int, max_bin: int) -> Array:
     """Zero f32 carry for the segment_sum family: [3, F, (S+1)*max_bin]."""
     return jnp.zeros((3, F, (slots_n + 1) * max_bin), jnp.float32)

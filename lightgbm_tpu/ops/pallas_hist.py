@@ -42,6 +42,7 @@ Layouts (all chosen for the (sublane, lane=128) tiling):
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -49,6 +50,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ..analysis.contracts import contract
+from ..utils.log import LightGBMError
 from .split import (FUSED_CAND_COLS, FUSED_CASES, fused_numerical_candidates)
 
 Array = jax.Array
@@ -832,25 +834,56 @@ def pallas_split_scan(hist: Array, feat_nb: Array, feat_missing: Array,
         .transpose(1, 2, 0, 3)                       # [S, 2, F, 8]
 
 
+@dataclasses.dataclass(frozen=True)
+class ProbeResult:
+    """Verdict of a kernel probe; truthy iff the kernel may be used.
+    `cause` is "" (ok), "compile" (the kernel raised — lowering, Mosaic
+    or execution) or "mismatch" (it ran and disagreed with the
+    reference); `detail` carries the compiler's message or the numbers,
+    so a degradation event can say WHY."""
+    ok: bool
+    cause: str = ""
+    detail: str = ""
+
+    def __bool__(self) -> bool:
+        return self.ok
+
+
+_PROBE_OK = ProbeResult(True)
 _PROBE_CACHE = {}
 
 
 def probe_cached(max_bin: int = 256, num_feature: int = 28,
                  multi: bool = False, width: int = None,
                  quantized: bool = None, fused: bool = False,
-                 interpret: bool = False) -> bool:
+                 interpret: bool = False) -> ProbeResult:
     """probe(), memoised per (backend platform, shape, multi params)."""
-    try:
-        key = (jax.devices()[0].platform, max_bin, num_feature, multi,
-               width, quantized, fused, interpret)
-    except RuntimeError:
-        return False
+    key = (jax.devices()[0].platform, max_bin, num_feature, multi,
+           width, quantized, fused, interpret)
     if key not in _PROBE_CACHE:
         _PROBE_CACHE[key] = probe(interpret=interpret, max_bin=max_bin,
                                   num_feature=num_feature, multi=multi,
                                   width=width, quantized=quantized,
                                   fused=fused)
     return _PROBE_CACHE[key]
+
+
+def _refused(e: Exception) -> ProbeResult:
+    return ProbeResult(False, "compile", f"{type(e).__name__}: {e}")
+
+
+def _mismatch(what: str, got, want) -> ProbeResult:
+    import numpy as np
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    if got.shape != want.shape:
+        return ProbeResult(False, "mismatch",
+                           f"{what}: shape {got.shape} != {want.shape}")
+    diff = np.abs(got - want)
+    return ProbeResult(
+        False, "mismatch",
+        f"{what}: max |diff| {np.nanmax(diff):.6g} at "
+        f"{np.unravel_index(np.nanargmax(diff), diff.shape)}, "
+        f"{int(np.count_nonzero(got != want))}/{got.size} elements differ")
 
 
 # the fused probe's static scan parameters are placeholders — the gate is
@@ -861,14 +894,17 @@ _PROBE_SCAN_KW = dict(l1=0.0, l2=1.0, min_data_in_leaf=1.0,
 
 
 def _probe_fused(interpret: bool, max_bin: int, num_feature: int,
-                 width: int, quantized: bool) -> bool:
+                 width: int, quantized: bool) -> ProbeResult:
     """EXACT-parity gate for the fused path: the fused kernel's histogram
     must be bitwise the multi kernel's, and `decide_from_candidates` over
     its candidate tensor must reproduce `find_best_split` field-for-field
     (gain, feature, threshold, missing direction, child sums).  Bitwise —
     not allclose — because byte-identical models are the fused path's
     whole contract; any backend where Mosaic lowers the scan differently
-    (cumsum association, lane gathers) degrades to the base impl here."""
+    (cumsum association, lane gathers) degrades to the base impl here.
+    The fused kernel is an UPGRADE over a working base, so a refusal
+    degrades on every platform — but the compiler's message rides in the
+    result (and from there in `fallback.fused_split`)."""
     import numpy as np
 
     from .split import decide_from_candidates, find_best_split
@@ -921,7 +957,8 @@ def _probe_fused(interpret: bool, max_bin: int, num_feature: int,
                 **_PROBE_SCAN_KW)
         got_h, want_h, cand = jax.device_get((got_h, want_h, cand))
         if not np.array_equal(got_h, want_h):
-            return False
+            return _mismatch("fused histogram vs multi kernel", got_h,
+                             want_h)
         allowed = jnp.ones((num_feature,), bool)
         iscat = jnp.zeros((num_feature,), bool)
         for sl in range(min(3, wdt)):
@@ -935,19 +972,24 @@ def _probe_fused(interpret: bool, max_bin: int, num_feature: int,
                 jnp.asarray(cand[sl]), pg, ph, pc, miss, fdef, allowed,
                 max_bin)
             ref, got = jax.device_get((ref, got))
-            for a, b in zip(ref, got):
+            for i, (a, b) in enumerate(zip(ref, got)):
                 if not np.array_equal(a, b):
-                    return False
-        return True
-    except Exception:  # pragma: no cover - backend-specific failures
-        return False
+                    return _mismatch(f"slot {sl} decision field {i}", b, a)
+        return _PROBE_OK
+    except Exception as e:  # the compiler's verdict, kept verbatim
+        return _refused(e)
 
 
 def probe(interpret: bool = False, max_bin: int = 256,
           num_feature: int = 28, multi: bool = False, width: int = None,
-          quantized: bool = None, fused: bool = False) -> bool:
+          quantized: bool = None, fused: bool = False) -> ProbeResult:
     """Runtime check that the kernel compiles and matches segment-sum on
     the current backend — used by Booster to gate the TPU histogram path.
+    On a real TPU (`interpret=False`) a base kernel that RAISES is an
+    error, not a degradation: `LightGBMError` carries Mosaic's message
+    instead of training ~60x slower on segment-sum behind a log line.  A
+    kernel that runs but disagrees numerically still returns a falsy
+    result, with the numbers in `detail`.
     Probes at the PRODUCTION bin count / feature count / ROW_TILE (Mosaic
     regressions are usually shape-specific, so a toy-shape probe would
     pass and the real call would still crash), with a single row tile to
@@ -965,11 +1007,27 @@ def probe(interpret: bool = False, max_bin: int = 256,
     `fused=True` gates `hist_impl='pallas_fused'`/`'pallas_fused_q'`:
     a stricter, EXACT-equality probe (`_probe_fused`) over the fused
     kernel's histogram AND its in-kernel split candidates — see there."""
-    import numpy as np
-
     if fused:
         return _probe_fused(interpret, max_bin, num_feature, width,
                             bool(quantized))
+    try:
+        return _probe_base(interpret, max_bin, num_feature, multi, width,
+                           quantized)
+    except Exception as e:
+        if not interpret and jax.devices()[0].platform == "tpu":
+            raise LightGBMError(
+                f"the Pallas histogram kernel (max_bin={max_bin}, "
+                f"num_feature={num_feature}, multi={multi}, width={width}, "
+                f"quantized={quantized}) failed on this TPU — "
+                f"{type(e).__name__}: {e}") from e
+        return _refused(e)
+
+
+def _probe_base(interpret: bool, max_bin: int, num_feature: int,
+                multi: bool, width: int, quantized: bool) -> ProbeResult:
+    """`probe`'s compile-and-compare body for the unfused kernels; any
+    exception the kernel raises propagates to `probe`."""
+    import numpy as np
 
     from .histogram import leaf_histogram
     rng = np.random.RandomState(0)
@@ -984,60 +1042,61 @@ def probe(interpret: bool = False, max_bin: int = 256,
     pq = jnp.stack([jnp.round(payload[:, 0] * 8) * s,
                     jnp.abs(jnp.round(payload[:, 1] * 8)) * s,
                     jnp.ones((n,), jnp.float32)], axis=1)
-    try:
-        if multi:
-            # the wave grower's multi-leaf block shapes, at the exact
-            # production width when the caller supplies one
-            if quantized is None:
-                fams = [(False, width or MULTI_CHUNK),
-                        (True, width or MULTI_CHUNK_Q)]
+
+    def close(what, got, want):
+        # explicit sync (device_get) — the probe compares on host by
+        # design; bool(jnp.allclose(...)) would hide the same transfer
+        # as an implicit block (graft-lint R001)
+        got, want = jax.device_get((got, want))
+        if np.allclose(got, want, rtol=1e-4, atol=1e-4):
+            return _PROBE_OK
+        return _mismatch(what, got, want)
+
+    if multi:
+        # the wave grower's multi-leaf block shapes, at the exact
+        # production width when the caller supplies one
+        if quantized is None:
+            fams = [(False, width or MULTI_CHUNK),
+                    (True, width or MULTI_CHUNK_Q)]
+        else:
+            fams = [(quantized,
+                     width or (MULTI_CHUNK_Q if quantized
+                               else MULTI_CHUNK))]
+        for quant_f, wdt in fams:
+            lid = jnp.asarray(
+                rng.randint(0, wdt + 2, (n,)).astype(np.int32))
+            slots = jnp.arange(wdt, dtype=jnp.int32)
+            if quant_f:
+                got = pallas_histogram_multi_quantized(
+                    bins, pq, lid, slots, max_bin, s, s,
+                    row_tile=min(n, ROW_TILE), interpret=interpret)
+                ref_payload = pq
             else:
-                fams = [(quantized,
-                         width or (MULTI_CHUNK_Q if quantized
-                                   else MULTI_CHUNK))]
-            for quant_f, wdt in fams:
-                lid = jnp.asarray(
-                    rng.randint(0, wdt + 2, (n,)).astype(np.int32))
-                slots = jnp.arange(wdt, dtype=jnp.int32)
-                if quant_f:
-                    got = pallas_histogram_multi_quantized(
-                        bins, pq, lid, slots, max_bin, s, s,
-                        row_tile=min(n, ROW_TILE), interpret=interpret)
-                    ref_payload = pq
-                else:
-                    got = pallas_histogram_multi(
-                        bins, payload, lid, slots, max_bin,
-                        row_tile=min(n, ROW_TILE), interpret=interpret)
-                    ref_payload = payload
-                k = min(3, wdt)
-                want = jnp.stack([leaf_histogram(bins, ref_payload,
-                                                 lid == sl, max_bin)
-                                  for sl in range(k)])
-                # explicit sync (device_get) — the probe compares on
-                # host by design; bool(jnp.allclose(...)) would hide
-                # the same transfer as an implicit block (graft-lint
-                # R001)
-                if not np.allclose(jax.device_get(got[:k]),
-                                   jax.device_get(want),
-                                   rtol=1e-4, atol=1e-4):
-                    return False
-            return True
-        got = pallas_histogram(bins, payload, mask, max_bin,
-                               row_tile=min(n, ROW_TILE),
-                               interpret=interpret)
-        want = leaf_histogram(bins, payload, mask, max_bin)
-        if not np.allclose(jax.device_get(got), jax.device_get(want),
-                           rtol=1e-4, atol=1e-4):
-            return False
-        # the quantized kernel runs DIFFERENT block shapes (3-row payload)
-        # — probe it too, or a Mosaic regression there would crash the
-        # pallas_q path that this probe is supposed to gate
-        gotq = pallas_histogram_quantized(bins, pq, mask, max_bin, s, s,
-                                          row_tile=min(n, ROW_TILE),
-                                          interpret=interpret)
-        wantq = leaf_histogram(bins, pq, mask, max_bin)
-        return bool(np.allclose(jax.device_get(gotq),
-                                jax.device_get(wantq),
-                                rtol=1e-4, atol=1e-4))
-    except Exception:  # pragma: no cover - backend-specific failures
-        return False
+                got = pallas_histogram_multi(
+                    bins, payload, lid, slots, max_bin,
+                    row_tile=min(n, ROW_TILE), interpret=interpret)
+                ref_payload = payload
+            k = min(3, wdt)
+            want = jnp.stack([leaf_histogram(bins, ref_payload,
+                                             lid == sl, max_bin)
+                              for sl in range(k)])
+            res = close(f"multi-leaf kernel (quantized={quant_f}, "
+                        f"width={wdt}) vs segment-sum", got[:k], want)
+            if not res:
+                return res
+        return _PROBE_OK
+    got = pallas_histogram(bins, payload, mask, max_bin,
+                           row_tile=min(n, ROW_TILE),
+                           interpret=interpret)
+    res = close("single-leaf f32 kernel vs segment-sum", got,
+                leaf_histogram(bins, payload, mask, max_bin))
+    if not res:
+        return res
+    # the quantized kernel runs DIFFERENT block shapes (3-row payload)
+    # — probe it too, or a Mosaic regression there would crash the
+    # pallas_q path that this probe is supposed to gate
+    gotq = pallas_histogram_quantized(bins, pq, mask, max_bin, s, s,
+                                      row_tile=min(n, ROW_TILE),
+                                      interpret=interpret)
+    return close("single-leaf int8 kernel vs segment-sum", gotq,
+                 leaf_histogram(bins, pq, mask, max_bin))

@@ -27,8 +27,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..mesh.compat import Mesh, NamedSharding, PartitionSpec as P, \
-    shard_map
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
 from ..mesh.placement import emit_collective_round, local_device_ids
 from ..ops.grow import DeviceTree, GrowerSpec, make_grower
 

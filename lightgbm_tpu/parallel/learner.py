@@ -38,8 +38,9 @@ import jax.numpy as jnp
 
 import itertools
 
-from ..mesh.compat import Mesh, NamedSharding, PartitionSpec as P, \
-    shard_map
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
 from ..mesh.placement import emit_collective_round, local_device_ids, \
     padded_feature_count, padded_row_count, record_placement
 from ..ops.grow import DeviceTree, GrowerSpec, make_grower

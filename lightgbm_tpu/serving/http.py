@@ -137,7 +137,8 @@ class ServingHTTPHandler(BaseHTTPRequestHandler):
                        "models": models,
                        "stale": st["stale"],
                        "demoted": st["demoted"],
-                       "device_bytes": st["device_bytes"]}
+                       "device_bytes": st["device_bytes"],
+                       "rungs": st["rungs"]}
             if "bounded" in st:
                 payload["bounded"] = st["bounded"]
             if "latency_ms" in st:

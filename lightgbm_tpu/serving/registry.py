@@ -364,8 +364,9 @@ class ModelRegistry:
     def status(self) -> Dict:
         """Registry health snapshot (the `/healthz` payload body):
         model names, entries whose booster mutated since export
-        (`stale`), demoted entries, per-entry device bytes, and — once
-        any request has completed — all-rung server-side latency
+        (`stale`), demoted entries, per-entry device bytes, per-entry
+        rung status (`rungs`: live rungs + why the others are off), and
+        — once any request has completed — all-rung server-side latency
         percentiles from the `serve.stage.e2e` histograms
         (`latency_ms`: count/p50/p90/p99/p999).  Also refreshes the
         `serve.stale` gauge."""
@@ -379,7 +380,12 @@ class ModelRegistry:
                "demoted": sorted(n for n, e in entries.items()
                                  if e.runtime.demoted),
                "device_bytes": {n: e.runtime.device_bytes()
-                                for n, e in sorted(entries.items())}}
+                                for n, e in sorted(entries.items())},
+               # which device rungs each model serves from and, for a
+               # rung that is off, why (cause + the compiler's message
+               # or the probe's numbers)
+               "rungs": {n: e.runtime.rung_status()
+                         for n, e in sorted(entries.items())}}
         # bounded precision tier: publish each bounded-tier model's
         # contract (the worst-case bound) next to what the probe actually
         # measured, so /healthz is where operators audit the promise

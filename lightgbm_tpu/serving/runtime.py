@@ -71,7 +71,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -148,6 +148,18 @@ class _ServeState:
         for f in self.__slots__:
             setattr(new, f, getattr(self, f))
         return new
+
+
+def _compile_refusal(e: BaseException) -> bool:
+    """Whether `e` is the compiler refusing a program — Pallas has no
+    lowering rule (NotImplementedError / LoweringException) or Mosaic
+    rejects the kernel (MosaicError) — rather than a device failure a
+    retry could clear.  A refusal is permanent for this model on this
+    installation, so it is published as `cause="compile"` with the
+    compiler's message instead of opening a breaker that re-probes."""
+    names = {c.__name__ for c in type(e).__mro__}
+    return bool(names & {"MosaicError", "LoweringException",
+                         "NotImplementedError"})
 
 
 def bucket_rows(n: int, max_rows: int = DEFAULT_MAX_BATCH_ROWS) -> int:
@@ -234,6 +246,12 @@ class ServingRuntime:
                                  backoff_max_s=breaker_backoff_max_s)
             for rung in ("bounded", "compiled", "device_sum",
                          "slot_path")}
+        #: rung -> {"cause", "detail"} of the last time it was switched
+        #: off; an entry goes when the rung next comes up.  Read by
+        #: `rung_status()` (`/healthz`), so WHY a rung is off is visible
+        #: without the event stream.
+        self._rung_lock = make_lock("serving.runtime._rung_lock")
+        self._rung_off: Dict[str, Dict[str, str]] = {}  # guarded-by: _rung_lock
         self._reprobe_lock = make_lock("serving.runtime._reprobe_lock")
         self._reprobe_threads: Dict[str, threading.Thread] = {}  # guarded-by: _reprobe_lock
         #: pin every device array (export planes + staged inputs) to one
@@ -349,6 +367,29 @@ class ServingRuntime:
     def compiled_active(self) -> bool:
         """Is the compiled tile rung serving (plan built, probe passed)?"""
         return self._state.compiled_ok
+
+    def rung_status(self) -> Dict:
+        """Which device rungs the published bundle serves from and, for
+        each rung that is off, the cause and detail of its last
+        disable."""
+        st = self._state
+        with self._rung_lock:
+            off = {r: dict(v) for r, v in self._rung_off.items()}
+        return {"bounded": bool(st.bounded_ok),
+                "compiled": bool(st.compiled_ok),
+                "device_sum": bool(st.device_sum_ok),
+                "disabled": off}
+
+    def _note_rung(self, rung: str, cause: str = "",
+                   detail: str = "") -> None:
+        """Record why `rung` went off (or, with no cause, that it is
+        back) for `rung_status()`."""
+        with self._rung_lock:
+            if cause:
+                self._rung_off[rung] = {"cause": cause,
+                                        "detail": detail[:500]}
+            else:
+                self._rung_off.pop(rung, None)
 
     @property
     def precision(self) -> str:
@@ -532,34 +573,45 @@ class ServingRuntime:
             return False
         if self._device_sum_mode == "force":
             self._breakers["device_sum"].record_success()
+            self._note_rung("device_sum")
             return True
-        verdict = self._probe_device_sum(ex)
+        verdict, detail = self._probe_device_sum(ex)
         if verdict == "ok":
             self._breakers["device_sum"].record_success()
+            self._note_rung("device_sum")
             return True
         if verdict == "mismatch":
             # wrong CONTENT: permanent until a refresh re-probes a new
             # export — no amount of waiting fixes wrong bits
             st.probe_failed = True
             self._breakers["device_sum"].record_mismatch()
+        elif verdict == "compile":
+            # the compiler refuses the program: waiting fixes nothing
+            self._breakers["device_sum"].record_mismatch()
         else:
             # transient device exception: the breaker's half-open
             # re-probe can recover the rung without a manual refresh
             self._breakers["device_sum"].record_failure()
-        telemetry.REGISTRY.counter("serve.device_sum_disabled").inc()
-        telemetry.event("serve.device_sum_disabled", model=self.name,
-                        cause=verdict)
+        self._disable_device_sum(verdict, detail)
         return False
 
-    def _probe_device_sum(self, ex: Dict) -> str:
+    def _disable_device_sum(self, cause: str, detail: str = "") -> None:
+        telemetry.REGISTRY.counter("serve.device_sum_disabled").inc()
+        telemetry.event("serve.device_sum_disabled", model=self.name,
+                        cause=cause, detail=detail[:200])
+        self._note_rung("device_sum", cause, detail)
+
+    def _probe_device_sum(self, ex: Dict) -> Tuple[str, str]:
         """Export-time exact-parity gate (the `_probe_fused` pattern
         from ops/pallas_hist.py): the device-sum program must
         bit-match the host f64 gather/sum over the SAME device slots —
         raw and converted — on a threshold-clustered probe batch, or
-        the model degrades to the slot path.  Verdict: "ok",
-        "mismatch" (wrong bits — permanent) or "error" (device
-        exception — breaker-recoverable); a broken rung always
-        degrades, never raises."""
+        the model degrades to the slot path.  Returns (verdict,
+        detail); verdict is "ok", "mismatch" (wrong bits — permanent),
+        "compile" (the compiler refused the program — permanent, detail
+        is its message) or "error" (device exception —
+        breaker-recoverable); a broken rung always degrades, never
+        raises."""
         try:
             # single-chunk probe: stay within the bucket cap so the
             # staging buffer fits (small-bucket runtimes probe small)
@@ -575,7 +627,7 @@ class ServingRuntime:
             got = self._device_sum_chunk(X, ex, want_raw=True)
             if got.shape != want.shape or not np.array_equal(
                     got.view(np.uint64), want.view(np.uint64)):
-                return "mismatch"
+                return "mismatch", "raw scores differ"
             obj = self._booster.objective_
             if obj is not None:
                 got_c = self._device_sum_chunk(X, ex, want_raw=False)
@@ -584,12 +636,22 @@ class ServingRuntime:
                         or got_c.dtype != want_c.dtype \
                         or not np.array_equal(got_c.view(np.uint32),
                                               want_c.view(np.uint32)):
-                    return "mismatch"
-            return "ok"
+                    return "mismatch", "converted scores differ"
+            return "ok", ""
         except Exception as e:
-            telemetry.event("serve.device_sum_probe_error",
-                            model=self.name, error=str(e)[:200])
-            return "error"
+            return self._probe_exception("device_sum", e)
+
+    def _probe_exception(self, rung: str, e: Exception) -> Tuple[str, str]:
+        """(verdict, detail) for an exception out of a rung's probe:
+        "compile" when the compiler refused the program, else the
+        transient "error" (with its `serve.<rung>_probe_error`
+        event)."""
+        detail = f"{type(e).__name__}: {e}"
+        if _compile_refusal(e):
+            return "compile", detail
+        telemetry.event(f"serve.{rung}_probe_error", model=self.name,
+                        error=str(e)[:200])
+        return "error", detail
 
     def _probe_batch(self, ex: Dict, rows: int = 256) -> np.ndarray:
         """Deterministic adversarial probe batch: feature values
@@ -624,6 +686,7 @@ class ServingRuntime:
                                    cause=cause).inc()
         telemetry.event("serve.compiled_disabled", model=self.name,
                         cause=cause, detail=detail[:200])
+        self._note_rung("compiled", cause, detail)
 
     def _compiled_enable(self, ex: Dict, st: _ServeState) -> bool:
         """Decide the compiled tile rung for this export (refresh-time):
@@ -680,15 +743,20 @@ class ServingRuntime:
         st.plan_gidx = gidx
         if mode == "force":
             self._breakers["compiled"].record_success()
+            self._note_rung("compiled")
             return True
-        verdict = self._probe_compiled(st)
+        verdict, detail = self._probe_compiled(st)
         if verdict == "ok":
             self._breakers["compiled"].record_success()
+            self._note_rung("compiled")
             return True
-        if verdict == "mismatch":
-            st.probe_failed = True
+        if verdict in ("mismatch", "compile"):
+            # wrong bits, or a kernel the compiler refuses: permanent
+            # until a refresh — only wrong bits taint `probe_failed`
+            st.probe_failed = st.probe_failed or verdict == "mismatch"
             self._breakers["compiled"].record_mismatch()
-            self._disable_compiled("probe")
+            self._disable_compiled(
+                "probe" if verdict == "mismatch" else "compile", detail)
             st.plan = None
             st.plan_planes = None
             st.plan_meta = None
@@ -698,16 +766,17 @@ class ServingRuntime:
             # half-open re-probe can retry without a rebuild — the open
             # breaker (plus compiled_ok=False) gates serving meanwhile
             self._breakers["compiled"].record_failure()
-            self._disable_compiled("probe_error")
+            self._disable_compiled("probe_error", detail)
         return False
 
-    def _probe_compiled(self, st: _ServeState) -> str:
+    def _probe_compiled(self, st: _ServeState) -> Tuple[str, str]:
         """Refresh-time exact-parity gate for the compiled rung: the
         tiled kernel's accumulated bits — raw AND converted — must
         match the host f64 gather/sum over the slot program's device
         slots on the threshold-clustered probe batch (the same
         reference `_probe_device_sum` holds the device-sum rung to).
-        Same verdict split: "ok" | "mismatch" | "error"."""
+        Same (verdict, detail) split: "ok" | "mismatch" | "compile" |
+        "error"."""
         try:
             ex = st.export
             X = self._probe_batch(ex, rows=min(256, self.max_batch_rows))
@@ -722,7 +791,7 @@ class ServingRuntime:
             got = self._compiled_chunk(X, st, want_raw=True)
             if got.shape != want.shape or not np.array_equal(
                     got.view(np.uint64), want.view(np.uint64)):
-                return "mismatch"
+                return "mismatch", "raw scores differ"
             obj = self._booster.objective_
             if obj is not None:
                 got_c = self._compiled_chunk(X, st, want_raw=False)
@@ -731,12 +800,10 @@ class ServingRuntime:
                         or got_c.dtype != want_c.dtype \
                         or not np.array_equal(got_c.view(np.uint32),
                                               want_c.view(np.uint32)):
-                    return "mismatch"
-            return "ok"
+                    return "mismatch", "converted scores differ"
+            return "ok", ""
         except Exception as e:
-            telemetry.event("serve.compiled_probe_error",
-                            model=self.name, error=str(e)[:200])
-            return "error"
+            return self._probe_exception("compiled", e)
 
     # ----------------------------------------------------- bounded gate
     def _disable_bounded(self, cause: str, detail: str = "") -> None:
@@ -746,6 +813,7 @@ class ServingRuntime:
                         cause=cause, detail=detail[:200])
         telemetry.REGISTRY.gauge("serve.bounded.active",
                                  model=self.name).set(0)
+        self._note_rung("bounded", cause, detail)
 
     def _bounded_gauges(self, st: _ServeState, active: bool) -> None:
         """Publish the per-model bound/measured gauges the fleet
@@ -798,12 +866,20 @@ class ServingRuntime:
             arrs = [jax.device_put(a, self.device) for a in arrs]
         st.bounded_planes = tuple(arrs)
         st.bounded_bound = float(packed["bound"])
-        verdict = self._probe_bounded(st)
+        verdict, detail = self._probe_bounded(st)
         if verdict == "ok":
             self._breakers["bounded"].record_success()
             self._bounded_gauges(st, True)
+            self._note_rung("bounded")
             return True
-        if verdict == "bound":
+        if verdict == "compile":
+            # the compiler refuses the program: permanent until a
+            # refresh, like a bound breach, and nothing to re-probe
+            self._breakers["bounded"].record_mismatch()
+            self._disable_bounded("compile", detail)
+            st.bounded_planes = None
+            st.bounded_bound = None
+        elif verdict == "bound":
             # measured error past the published bound is wrong CONTENT
             # (a doctored/rotted plane, not a transient): permanent
             # until a refresh re-quantizes — same class as a parity
@@ -819,18 +895,19 @@ class ServingRuntime:
             # transient device exception: KEEP the quantized planes so
             # the half-open re-probe can retry without a repack
             self._breakers["bounded"].record_failure()
-            self._disable_bounded("probe_error")
+            self._disable_bounded("probe_error", detail)
         return False
 
-    def _probe_bounded(self, st: _ServeState) -> str:
+    def _probe_bounded(self, st: _ServeState) -> Tuple[str, str]:
         """Refresh-time bound-enforcement gate: measure the bounded
         program's max-abs error against the host f64 gather/sum over
         the slot program's device slots (the same exact reference the
         parity probes use) on the threshold-clustered probe batch.
-        Verdict: "ok" (measured <= published bound, measurement stored
-        for publication), "bound" (measured exceeds the bound — the
-        contract would be violated, permanent) or "error" (device
-        exception — breaker-recoverable)."""
+        Returns (verdict, detail): "ok" (measured <= published bound,
+        measurement stored for publication), "bound" (measured exceeds
+        the bound — the contract would be violated, permanent),
+        "compile" (the compiler refused the program — permanent) or
+        "error" (device exception — breaker-recoverable)."""
         try:
             ex = st.export
             X = self._probe_batch(ex, rows=min(256, self.max_batch_rows))
@@ -845,16 +922,14 @@ class ServingRuntime:
             got = self._bounded_chunk(X, st, want_raw=True)
             if got.shape != want.shape:
                 st.bounded_measured = float("inf")
-                return "bound"
+                return "bound", f"shape {got.shape} != {want.shape}"
             err = float(np.max(np.abs(got.astype(np.float64) - want)))
             st.bounded_measured = err
             if not np.isfinite(err) or err > st.bounded_bound:
-                return "bound"
-            return "ok"
+                return "bound", f"measured {err!r}"
+            return "ok", ""
         except Exception as e:
-            telemetry.event("serve.bounded_probe_error",
-                            model=self.name, error=str(e)[:200])
-            return "error"
+            return self._probe_exception("bounded", e)
 
     def _drop_bounded(self, st: _ServeState, cause: str,
                       detail: str = "") -> None:
@@ -918,7 +993,9 @@ class ServingRuntime:
                         # the bounded rung degrades to the exact ladder
                         # exactly like a compiled warmup failure
                         bounded_warm = False
-                        self._drop_bounded(st, "warmup_error", str(e))
+                        self._drop_bounded(
+                            st, "compile" if _compile_refusal(e)
+                            else "warmup_error", str(e))
                 if slot_warm:
                     try:
                         self._device_slots_chunk(Z, ex["stacked"])
@@ -946,7 +1023,9 @@ class ServingRuntime:
                         # model load — retire it and keep warming the
                         # surviving ladder
                         compiled_ok = False
-                        self._drop_compiled(st, "warmup_error", str(e))
+                        self._drop_compiled(
+                            st, "compile" if _compile_refusal(e)
+                            else "warmup_error", str(e))
                 if device_sum_warm:
                     try:
                         self._device_sum_chunk(Z, ex, want_raw=True)
@@ -1028,27 +1107,25 @@ class ServingRuntime:
                         or not ex.get("trees"):
                     br.record_failure()
                     return
+                verdict, detail = "error", ""
                 if rung == "device_sum":
-                    verdict = ("ok" if self._device_sum_mode == "force"
-                               else self._probe_device_sum(ex))
+                    verdict, detail = (
+                        ("ok", "") if self._device_sum_mode == "force"
+                        else self._probe_device_sum(ex))
                 elif rung == "compiled":
-                    if cur.plan_planes is None:
-                        # mismatch dropped the planes (permanent) or a
-                        # demote did — only a refresh rebuilds them
-                        verdict = "error"
-                    elif self._compiled_mode == "force":
-                        verdict = "ok"
-                    else:
-                        verdict = self._probe_compiled(cur)
+                    # no planes: a mismatch dropped them (permanent) or
+                    # a demote did — only a refresh rebuilds them
+                    if cur.plan_planes is not None:
+                        verdict, detail = (
+                            ("ok", "") if self._compiled_mode == "force"
+                            else self._probe_compiled(cur))
                 elif rung == "bounded":
-                    if cur.bounded_planes is None:
-                        # bound breach dropped the planes (permanent)
-                        # or a demote did — only a refresh repacks
-                        verdict = "error"
-                    else:
-                        verdict = self._probe_bounded(cur)
+                    # no planes: a bound breach dropped them (permanent)
+                    # or a demote did — only a refresh repacks
+                    if cur.bounded_planes is not None:
+                        verdict, detail = self._probe_bounded(cur)
                 else:
-                    verdict = self._probe_slot_path(ex)
+                    verdict, detail = self._probe_slot_path(ex)
                 if verdict == "ok":
                     br.record_success()
                     telemetry.REGISTRY.counter("serve.breaker.recovered",
@@ -1057,19 +1134,21 @@ class ServingRuntime:
                                     model=self.name, rung=rung)
                     if rung == "bounded":
                         self._bounded_gauges(cur, True)
+                    self._note_rung(rung)
                     self._publish_rung(cur, rung, True)
-                elif verdict in ("mismatch", "bound"):
+                elif verdict in ("mismatch", "bound", "compile"):
                     br.record_mismatch()
                     if rung == "compiled":
-                        self._disable_compiled("probe")
+                        self._disable_compiled(
+                            "probe" if verdict == "mismatch" else verdict,
+                            detail)
                     elif rung == "bounded":
-                        self._disable_bounded(verdict)
-                    else:
-                        telemetry.REGISTRY.counter(
-                            "serve.device_sum_disabled").inc()
-                        telemetry.event("serve.device_sum_disabled",
-                                        model=self.name, cause="mismatch")
-                    self._publish_rung(cur, rung, False, mismatch=True)
+                        self._disable_bounded(verdict, detail)
+                    elif rung == "device_sum":
+                        self._disable_device_sum(verdict, detail)
+                    # only wrong BITS label the ladder `probe_fail`
+                    self._publish_rung(cur, rung, False, mismatch=True,
+                                       taint=verdict != "compile")
                 else:
                     br.record_failure()
         except Exception as e:  # a failed re-probe must never propagate
@@ -1078,26 +1157,29 @@ class ServingRuntime:
                             model=self.name, rung=rung,
                             error=str(e)[:200])
 
-    def _probe_slot_path(self, ex: Dict) -> str:
+    def _probe_slot_path(self, ex: Dict) -> Tuple[str, str]:
         """Re-probe gate for the slot rung: the slot path is exact by
         construction (device slots + host f64 gather), so recovery only
         needs the device program to answer again."""
         try:
             X = self._probe_batch(ex, rows=min(64, self.max_batch_rows))
             self._device_slots_chunk(X, ex["stacked"])
-            return "ok"
+            return "ok", ""
         except Exception as e:
             telemetry.event("serve.slot_probe_error", model=self.name,
                             error=str(e)[:200])
-            return "error"
+            return "error", f"{type(e).__name__}: {e}"
 
     def _publish_rung(self, cur: _ServeState, rung: str, ok: bool,
-                      mismatch: bool = False) -> None:
+                      mismatch: bool = False, taint: bool = True) -> None:
         """Republish the live bundle with one rung verdict flipped
         (caller holds `_refresh_lock`).  The slot rung has no state
         flag — its breaker is the only gate — and a no-op flip is not
         republished (predict-time failures leave the flag True; the
-        breaker alone gated the rung, so closing it suffices)."""
+        breaker alone gated the rung, so closing it suffices).
+        `mismatch` marks a permanent verdict (the planes go even when
+        the flag is already down); `taint=False` keeps it from labelling
+        the ladder `probe_fail` (a compile refusal is not wrong bits)."""
         if rung == "slot_path":
             return
         flag = {"device_sum": "device_sum_ok", "compiled": "compiled_ok",
@@ -1108,7 +1190,7 @@ class ServingRuntime:
         # a bounded bound-breach does NOT taint probe_failed: that flag
         # labels the EXACT ladder's host-walk cause, and the exact
         # rungs beneath the bounded tier are untouched by its verdict
-        new.probe_failed = cur.probe_failed or (mismatch
+        new.probe_failed = cur.probe_failed or (mismatch and taint
                                                 and rung != "bounded")
         setattr(new, flag, ok)
         if rung == "compiled" and not ok:
@@ -1205,6 +1287,12 @@ class ServingRuntime:
                         clock)
                     for lo in range(0, X.shape[0], self.max_batch_rows)]
         except Exception as e:
+            if _compile_refusal(e):
+                # a bucket the probe did not compile: retire the rung
+                # with the compiler's message, nothing to re-probe
+                self._breakers["bounded"].record_mismatch()
+                self._drop_bounded(st, "compile", str(e))
+                return None
             self._breakers["bounded"].record_failure()
             telemetry.REGISTRY.counter("serve.device_errors").inc()
             telemetry.event("serve.device_error", model=self.name,
@@ -1283,6 +1371,12 @@ class ServingRuntime:
                         clock)
                     for lo in range(0, X.shape[0], self.max_batch_rows)]
         except Exception as e:
+            if _compile_refusal(e):
+                # a bucket the probe did not compile: retire the rung
+                # with the compiler's message, nothing to re-probe
+                self._breakers["compiled"].record_mismatch()
+                self._drop_compiled(st, "compile", str(e))
+                return None
             self._breakers["compiled"].record_failure()
             telemetry.REGISTRY.counter("serve.device_errors").inc()
             telemetry.event("serve.device_error", model=self.name,

@@ -35,7 +35,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import List, Optional
+from typing import Dict, List, Optional
 
 import numpy as np
 
@@ -151,6 +151,20 @@ class ShardedServingRuntime:
     @property
     def compiled_active(self) -> bool:
         return self._replicas[0].compiled_active
+
+    def rung_status(self) -> Dict:
+        """A rung is live only when EVERY replica serves it; `disabled`
+        merges the replicas' causes (first replica to report a rung
+        wins — the replicas share one export and one compiler)."""
+        per = [r.rung_status() for r in self._replicas]
+        out = {k: all(p[k] for p in per)
+               for k in ("bounded", "compiled", "device_sum")}
+        off: Dict = {}
+        for p in per:
+            for rung, why in p["disabled"].items():
+                off.setdefault(rung, why)
+        out["disabled"] = off
+        return out
 
     @property
     def precision(self) -> str:

@@ -9,12 +9,11 @@ Three always-available pieces (see ISSUE: observability tentpole):
  - sinks — JSONL event log + in-memory capture (sinks.py), summarized
    by `python -m lightgbm_tpu telemetry-report` (report.py).
 
-This package NEVER imports jax, so `bench.py`'s orchestrator and
-`scripts/probe_tpu.py` can load the submodules by file path from
-jax-free processes.  (Importing it as `lightgbm_tpu.telemetry` runs
+This package NEVER imports jax, so jax-free processes can load the
+submodules by file path.  (Importing it as `lightgbm_tpu.telemetry` runs
 `lightgbm_tpu/__init__.py`, which does pull jax — jax-free callers must
-use `importlib.util.spec_from_file_location` on the submodule files, as
-bench.py already does for utils/env.py.)
+use `importlib.util.spec_from_file_location` on the submodule files
+(tests/test_telemetry.py::test_jax_free_import shows how).)
 """
 from .metrics import (Counter, Gauge, Histogram, HISTOGRAM_BOUNDS,
                       MetricsRegistry, REGISTRY, Timing, write_prometheus)

@@ -13,12 +13,11 @@ rules and prints a machine-readable verdict:
  - a delta beyond tolerance in the bad direction is a **violation**
    (exit 1); beyond tolerance in the good direction is reported as
    *improved* (exit 0); `--warn-timings` downgrades timing-class
-   violations to warnings (CI runs on the CPU fallback, where absolute
+   violations to warnings (CI runs on the CPU backend, where absolute
    wall-clock is noise but counter/shape regressions are still real).
 
 STDLIB-ONLY and self-contained (no imports from the sibling telemetry
-modules): `scripts/run_ci.sh` and the bench orchestrator load this file
-by path in processes that must never import jax.
+modules), so a jax-free process can load this file by path.
 """
 from __future__ import annotations
 
@@ -71,7 +70,7 @@ RULES: List[Tuple[str, str, str]] = [
     ("*cache_entries", "up_is_bad", "counter"),
     ("*compile_total_s", "up_is_bad", "timing"),
     # device-memory ledger (ISSUE 18): unattributed bytes growing means
-    # allocations escaped the owner taxonomy (an attribution leak);
+    # allocations escaped the owner classes (an attribution leak);
     # budget-violation counts and the leak-sentinel slope fail hard on
     # growth (slope is wall-clock-derived — timing tolerance); the
     # reconcile walk is background work, and the per-device per-owner

@@ -384,12 +384,10 @@ _COMPILE_EVENT_MARKERS = ("backend_compile", "compilation_cache_miss")
 
 
 def install_compile_listener() -> bool:
-    """Hook `jax.monitoring` (when jax is loaded and exposes it) so every
-    backend compile increments `jit.recompiles` and accumulates
-    `jit.compile_total_s`.  Idempotent; returns whether the hook is (now)
-    installed.  Callers that find it unavailable fall back to
-    `poll_jit_caches` — counting cache entries instead of compile events.
-    """
+    """Hook `jax.monitoring` so every backend compile increments
+    `jit.recompiles` and accumulates `jit.compile_total_s`.  Idempotent;
+    returns False only when jax is not loaded in this process (this
+    module never imports it)."""
     global _compile_listener_installed
     with _compile_lock:
         if _compile_listener_installed:
@@ -397,37 +395,29 @@ def install_compile_listener() -> bool:
         jax = sys.modules.get("jax")
         if jax is None:
             return False
-        try:
-            monitoring = jax.monitoring
 
-            def _on_duration(name: str, secs: float, **kw) -> None:
-                if any(m in name for m in _COMPILE_EVENT_MARKERS):
-                    REGISTRY.counter("jit.recompiles").inc()
-                    g = REGISTRY.gauge("jit.compile_total_s")
-                    g.set(g.value + float(secs))
+        def _on_duration(name: str, secs: float, **kw) -> None:
+            if any(m in name for m in _COMPILE_EVENT_MARKERS):
+                REGISTRY.counter("jit.recompiles").inc()
+                g = REGISTRY.gauge("jit.compile_total_s")
+                g.set(g.value + float(secs))
 
-            monitoring.register_event_duration_secs_listener(_on_duration)
-            _compile_listener_installed = True
-            return True
-        except Exception:
-            return False
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _compile_listener_installed = True
+        return True
 
 
 def poll_jit_caches(fns: Sequence[Any]) -> int:
-    """Degraded compile accounting: sum the jit cache entry counts of the
-    given jitted callables (`_cache_size()` on PjitFunction) into the
-    `jit.cache_entries` gauge.  Used when `jax.monitoring` is missing and
-    at summary time either way (cache entries ≠ compiles: a cache that
-    keeps growing between summaries is the recompile-trap signal)."""
+    """Sum the jit cache entry counts of the given jitted callables
+    (`_cache_size()` on PjitFunction) into the `jit.cache_entries` gauge
+    at summary time (cache entries ≠ compiles: a cache that keeps
+    growing between summaries is the recompile-trap signal)."""
     total = 0
     for fn in fns:
         size = getattr(fn, "_cache_size", None)
         if size is None:
             continue
-        try:
-            total += int(size())
-        except Exception:
-            pass
+        total += int(size())
     REGISTRY.gauge("jit.cache_entries").set(total)
     return total
 

@@ -30,9 +30,9 @@ merge as Chrome-trace (catapult) JSON for chrome://tracing / Perfetto.
 Both back `python -m lightgbm_tpu timeline <spool_dir>` and the spool
 block in `/debug/fleet` (telemetry/ops.py).
 
-STDLIB-ONLY by design (see metrics.py): the bench orchestrator loads
-this file by path from a jax-free process to spool its own header, and
-`aggregate()`/`main()` never need the package.  `attach_spool()` is the
+STDLIB-ONLY by design (see metrics.py): a jax-free process can load
+this file by path to spool its own header, and `aggregate()`/`main()`
+never need the package.  `attach_spool()` is the
 one in-package helper (it touches the process-global TRACER); file-path
 loaders construct `SpoolSink` directly instead.  jax is mirrored via
 `sys.modules.get("jax")`, never imported.
@@ -92,9 +92,8 @@ def _safe(token: str) -> str:
 
 def _jax_identity() -> Tuple[Optional[int], Optional[List[int]]]:
     """(process_index, visible device ids) from an ALREADY-LOADED jax —
-    mirrored via sys.modules, never imported, so a jax-free process (or
-    one whose remote-TPU tunnel would wedge backend init) is never
-    dragged into it."""
+    mirrored via sys.modules, never imported, so a jax-free process is
+    never dragged into it."""
     jax = sys.modules.get("jax")
     if jax is None:
         return None, None

@@ -178,8 +178,13 @@ _PARAMS: Dict[str, Tuple[Any, str, Tuple[str, ...]]] = {
     "num_machines": (1, "int", ("num_machine",)),
     # deterministic fixed-order histogram/score reduction for data-parallel
     # training: chains per-shard partial sums in shard order (ring
-    # ppermute) instead of psum, so multi-round sharded models are
-    # byte-identical to serial; false restores the faster tree-psum
+    # ppermute) instead of psum.  On the XLA histogram paths
+    # (segment_sum/packed) the scatter-add itself is chained, so
+    # multi-round sharded models are byte-identical to serial; on the
+    # Pallas paths (every TPU) each shard runs the kernel over its rows
+    # and the partial histograms are chained — fixed order, not bitwise
+    # serial (one kernel sums its row tiles in another order than four).
+    # false restores the tree-psum
     "deterministic_reduce": (True, "bool", ()),
     "local_listen_port": (12400, "int", ("local_port", "port")),
     "time_out": (120, "int", ()),

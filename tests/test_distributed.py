@@ -117,6 +117,43 @@ class TestShardedGrower:
         np.testing.assert_array_equal(np.asarray(score),
                                       np.asarray(score_ref))
 
+    @pytest.mark.parametrize("wave", [True, False])
+    def test_det_reduce_keeps_the_pallas_kernel(self, wave, monkeypatch):
+        """With `hist_impl=pallas` the deterministic reduction must chain
+        the per-shard KERNEL histograms (`ring_ordered_sum`), never swap
+        the kernel for the streamed XLA scatter-add: on four real v5e
+        chips that swap cost 1308.7 s instead of 27.9 s for 48 rounds
+        (PR 21).  Trace only — nothing is compiled or run."""
+        from lightgbm_tpu.ops import grow_wave
+        from lightgbm_tpu.parallel.learner import make_distributed_grower
+
+        def scatter_path(*a, **k):
+            raise AssertionError("det reduce left the Pallas kernel for "
+                                 "the streamed scatter-add")
+
+        monkeypatch.setattr(grow_wave, "hist_stream_update", scatter_path)
+        n, f, mb = 1024, 8, 32
+        spec = GrowerSpec(num_leaves=9, max_depth=-1, max_bin=mb,
+                          lambda_l1=0.0, lambda_l2=0.0,
+                          min_data_in_leaf=5.0,
+                          min_sum_hessian_in_leaf=1e-3,
+                          min_gain_to_split=0.0, max_delta_step=0.0,
+                          hist_impl="pallas", hist_interpret=True,
+                          wave_width=4)
+        grow = make_distributed_grower(spec, get_mesh(4), "data", f, n,
+                                       wave=wave, det_reduce=True)
+        feat = dict(nb=jnp.full((f,), mb, jnp.int32),
+                    missing=jnp.zeros((f,), jnp.int32),
+                    default=jnp.zeros((f,), jnp.int32),
+                    is_cat=jnp.zeros((f,), bool),
+                    mono=jnp.zeros((f,), jnp.int32))
+        ones = jnp.ones((n,), jnp.float32)
+        text = str(jax.make_jaxpr(grow)(
+            jnp.zeros((f, n), jnp.uint8), ones, ones, ones, feat,
+            jnp.ones((f,), bool)))
+        # the old det path never traced the kernel at all
+        assert "pallas_call" in text and "ppermute" in text
+
     @pytest.mark.slow
     def test_multi_iteration_sharded_training(self):
         X, y = make_data(1600)

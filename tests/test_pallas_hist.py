@@ -67,6 +67,30 @@ class TestPallasHistogram:
         # the wave-policy gate: full-M multi-leaf block shapes
         assert probe(interpret=True, multi=True)
 
+    def test_refused_kernel_raises_on_tpu_degrades_elsewhere(
+            self, monkeypatch):
+        # off-TPU a kernel the backend refuses is a falsy result that
+        # carries the message (compiled Pallas on CPU is refused); on a
+        # TPU the BASE probes raise instead of letting training fall to
+        # segment-sum, while the fused probe — an upgrade over a working
+        # base — still degrades and keeps the message
+        from lightgbm_tpu.ops import pallas_hist as ph
+        from lightgbm_tpu.utils.log import LightGBMError
+        res = probe(interpret=False)
+        assert not res and res.cause == "compile"
+        assert "interpret mode" in res.detail
+
+        class _Tpu:
+            platform = "tpu"
+
+        monkeypatch.setattr(ph.jax, "devices", lambda *a: [_Tpu()])
+        for kw in ({}, {"multi": True, "width": 4, "quantized": False}):
+            with pytest.raises(LightGBMError, match="interpret mode"):
+                probe(interpret=False, **kw)
+        res = probe(interpret=False, fused=True, width=4, quantized=False)
+        assert not res and res.cause == "compile"
+        assert "interpret mode" in res.detail
+
     def test_multi_matches_per_leaf_interpret(self):
         rng = np.random.RandomState(21)
         n, f, mb = 512, 4, 16

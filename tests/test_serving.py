@@ -47,9 +47,8 @@ def _golden(name):
 
 
 def _recompiles():
-    """Process-wide compile counter; skips when jax.monitoring is out."""
-    if not telemetry.install_compile_listener():
-        pytest.skip("jax.monitoring unavailable — no compile accounting")
+    """Process-wide compile counter."""
+    assert telemetry.install_compile_listener()
     return telemetry.REGISTRY.counter("jit.recompiles").value
 
 

@@ -39,8 +39,7 @@ def _golden(name):
 
 
 def _recompiles():
-    if not telemetry.install_compile_listener():
-        pytest.skip("jax.monitoring unavailable — no compile accounting")
+    assert telemetry.install_compile_listener()
     return telemetry.REGISTRY.counter("jit.recompiles").value
 
 
@@ -242,6 +241,47 @@ def test_compiled_probe_gate_corrupted_node_word(monkeypatch):
         assert np.array_equal(rt.predict(X[:100], raw_score=raw),
                               bst.predict(X[:100], raw_score=raw))
     assert cc.value == before_cc, "doctored plan must never serve"
+
+
+def test_compiler_refusal_is_cause_compile(monkeypatch):
+    # a kernel the compiler refuses (Pallas lowering / Mosaic) must land
+    # in serve.compiled_disabled{cause=compile} WITH the compiler's
+    # message — at the refresh probe and at a bucket the probe did not
+    # compile — permanently (no breaker re-probe), without tainting the
+    # ladder as `probe_fail`, and `rung_status()` must say why
+    from lightgbm_tpu.resilience.breaker import PERMANENT
+    bst, X = _golden("binary")
+    msg = ("Unimplemented primitive in Pallas TPU lowering for "
+           "KernelType.TC: cumsum")
+    orig = srt.compiled_predict
+
+    def refuse(*a, **k):
+        raise NotImplementedError(msg)
+
+    dis = telemetry.REGISTRY.counter("serve.compiled_disabled",
+                                     cause="compile")
+    before = dis.value
+    monkeypatch.setattr(srt, "compiled_predict", refuse)
+    rt = ServingRuntime(bst, compiled="on")     # probe runs here
+    assert not rt.compiled_active
+    assert dis.value == before + 1
+    why = rt.rung_status()["disabled"]["compiled"]
+    assert why["cause"] == "compile" and msg in why["detail"]
+    assert rt._breakers["compiled"].state == PERMANENT
+    assert not rt._state.probe_failed
+    assert np.array_equal(rt.predict(X[:100]), bst.predict(X[:100]))
+
+    # refused only at serve time: the probe's bucket compiles, a later
+    # one does not — the rung retires with the same cause
+    monkeypatch.setattr(srt, "compiled_predict", orig)
+    rt = ServingRuntime(bst, compiled="on")
+    assert rt.compiled_active and "compiled" not in \
+        rt.rung_status()["disabled"]
+    monkeypatch.setattr(srt, "compiled_predict", refuse)
+    assert np.array_equal(rt.predict(X[:100]), bst.predict(X[:100]))
+    assert not rt.compiled_active
+    assert dis.value == before + 2
+    assert rt.rung_status()["disabled"]["compiled"]["cause"] == "compile"
 
 
 def test_compiled_auto_stays_off_on_cpu():

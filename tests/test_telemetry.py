@@ -326,8 +326,8 @@ class TestMetricsRegistry:
         assert "lgbm_tpu_span_eval_seconds_dup2_count 1" in text
 
     def test_jax_free_import(self):
-        """bench.py / probe_tpu.py load these modules by file path in
-        processes that must never import jax — prove the modules don't."""
+        """jax-free processes load these modules by file path — prove
+        the modules don't import jax."""
         import subprocess
         import sys
         code = (

@@ -1,12 +1,10 @@
 """Perf-regression sentinel (telemetry/diff.py): rule matching, verdict
-semantics, CLI exit codes, and the probe-stage parsers that feed the
-snapshots it compares.
+semantics and CLI exit codes.
 
 The diff module is stdlib-only and jax-free, so everything here runs
 in-process with synthetic snapshots — no training required.
 """
 import json
-import sys
 
 import pytest
 
@@ -178,56 +176,3 @@ class TestCli:
         a = self._write(tmp_path, "a.json", {"value": 5.0, "auc": 0.90})
         b = self._write(tmp_path, "b.json", {"value": 5.0, "auc": 0.40})
         assert diff_main([a, b]) == 1
-
-
-class TestProbeStageParsers:
-    """Both jax-free probe parents grow per-stage timing parsers; the
-    format contract (`@stage <name> <secs>`) is shared."""
-
-    SAMPLE = ("[noise]\n@stage import_jax 1.250\n"
-              "@stage client_init 0.310\n@stage device_enumerate 0.020\n"
-              "@stage broken nan_oops extra\n"
-              "cpu 1\n")
-
-    def test_bench_parser(self):
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "_bench_mod", "/root/repo/bench.py")
-        bench = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(bench)
-        stages = bench._parse_stages(self.SAMPLE)
-        assert stages == {"import_jax": 1.25, "client_init": 0.31,
-                          "device_enumerate": 0.02}
-        assert bench._parse_stages(self.SAMPLE.encode()) == stages
-        assert bench._parse_stages(None) == {}
-
-    def test_probe_tpu_parser(self):
-        import importlib.util
-        spec = importlib.util.spec_from_file_location(
-            "_probe_mod", "/root/repo/scripts/probe_tpu.py")
-        probe = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(probe)
-        stages = probe.parse_stages(self.SAMPLE)
-        assert stages["client_init"] == 0.31
-        assert "broken" not in stages
-
-    def test_child_code_emits_ordered_stages(self):
-        """Run the real probe child on the CPU backend: every stage line
-        must appear, in bring-up order, before the @ok line."""
-        import importlib.util
-        import os
-        import subprocess
-        spec = importlib.util.spec_from_file_location(
-            "_probe_mod2", "/root/repo/scripts/probe_tpu.py")
-        probe = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(probe)
-        env = dict(os.environ, JAX_PLATFORMS="cpu")
-        r = subprocess.run([sys.executable, "-c", probe.CHILD_CODE],
-                           capture_output=True, text=True, timeout=120,
-                           env=env)
-        assert r.returncode == 0, r.stderr[-1000:]
-        stages = probe.parse_stages(r.stdout)
-        assert list(stages) == ["import_jax", "client_init",
-                                "device_enumerate", "compile_and_run"]
-        assert all(v >= 0 for v in stages.values())
-        assert any(l.startswith("@ok ") for l in r.stdout.splitlines())

@@ -1460,14 +1460,16 @@ class Booster:
     def update(self, train_set: Optional[Dataset] = None, fobj=None) -> bool:
         """One boosting iteration (ref: basic.py Booster.update →
         LGBM_BoosterUpdateOneIter → GBDT::TrainOneIter)."""
-        with telemetry.span("train.chunk", rounds=1, fused=False), \
-                self._nan_check_ctx():
-            out = self._update_impl(train_set, fobj)
-        telemetry.REGISTRY.counter("train.rounds").inc()
-        self._ledger_round()
-        if self._flight is not None:
-            from .telemetry.recorder import sample_memory
-            sample_memory("train")
+        with telemetry.span("train.chunk", rounds=1, fused=False,
+                            round=self.cur_iter):
+            with self._nan_check_ctx():
+                out = self._update_impl(train_set, fobj)
+            telemetry.REGISTRY.counter("train.rounds").inc()
+            with telemetry.span("train.bookkeeping"):
+                self._ledger_round()
+                if self._flight is not None:
+                    from .telemetry.recorder import sample_memory
+                    sample_memory("train")
         return out
 
     def _ledger_round(self) -> None:
@@ -1544,30 +1546,31 @@ class Booster:
         K = self.num_tree_per_iteration
         if self._boost_mode == "dart":
             return self._update_dart(fobj)
-        if fobj is None:
-            if self.objective_ is None:
-                raise LightGBMError(
-                    "Custom objective function (fobj) is required when "
-                    "objective is none/custom")
-            self._boost_from_average()
-            score = self._train_score
-            if self._boost_mode == "rf":
-                # RF trees are independent: gradients always taken at the
-                # constant base score (ref: rf.hpp RF::Boosting)
-                score = jnp.zeros_like(self._train_score)
-            grad, hess = self._grad_fn(score)
-        else:
-            preds = np.asarray(self._train_score, dtype=np.float64)
-            if K > 1:
-                preds = preds.reshape(-1, order="F")
-            g, h = fobj(preds, self.train_set)
-            grad = jnp.asarray(np.asarray(g, dtype=np.float32)
-                               .reshape((-1, K), order="F").squeeze())
-            hess = jnp.asarray(np.asarray(h, dtype=np.float32)
-                               .reshape((-1, K), order="F").squeeze())
-            if K > 1:
-                grad = grad.reshape((-1, K))
-                hess = hess.reshape((-1, K))
+        if fobj is None and self.objective_ is None:
+            raise LightGBMError(
+                "Custom objective function (fobj) is required when "
+                "objective is none/custom")
+        with telemetry.span("train.gradients"):
+            if fobj is None:
+                self._boost_from_average()
+                score = self._train_score
+                if self._boost_mode == "rf":
+                    # RF trees are independent: gradients always taken at
+                    # the constant base score (ref: rf.hpp RF::Boosting)
+                    score = jnp.zeros_like(self._train_score)
+                grad, hess = self._grad_fn(score)
+            else:
+                preds = np.asarray(self._train_score, dtype=np.float64)
+                if K > 1:
+                    preds = preds.reshape(-1, order="F")
+                g, h = fobj(preds, self.train_set)
+                grad = jnp.asarray(np.asarray(g, dtype=np.float32)
+                                   .reshape((-1, K), order="F").squeeze())
+                hess = jnp.asarray(np.asarray(h, dtype=np.float32)
+                                   .reshape((-1, K), order="F").squeeze())
+                if K > 1:
+                    grad = grad.reshape((-1, K))
+                    hess = hess.reshape((-1, K))
         return self.__boost(grad, hess)
 
     def _goss_weights(self, iteration: int, grad, hess) -> jax.Array:
@@ -1592,13 +1595,14 @@ class Booster:
         cfg = self.config
         K = self.num_tree_per_iteration
         it = self.cur_iter
-        if self._use_goss:
-            # GOSS ranks the EXACT gradients; discretization happens after
-            # sampling, like the reference (sample_strategy before the
-            # tree learner's gradient discretizer)
-            sw = self._goss_weights(it, grad, hess)
-        else:
-            sw = self._sample_weights(it)
+        with telemetry.span("train.sample"):
+            if self._use_goss:
+                # GOSS ranks the EXACT gradients; discretization happens
+                # after sampling, like the reference (sample_strategy
+                # before the tree learner's gradient discretizer)
+                sw = self._goss_weights(it, grad, hess)
+            else:
+                sw = self._sample_weights(it)
         qscales = None
         if cfg.use_quantized_grad and cfg.num_grad_quant_bins > 0:
             # ref: v4 quantized training (cuda_gradient_discretizer.cu);
@@ -1626,14 +1630,16 @@ class Booster:
         for k in range(K):
             gk = grad if K == 1 else grad[:, k]
             hk = hess if K == 1 else hess[:, k]
-            allowed = self._feature_mask(it, k)
-            feat = self._feat
-            if "ff_key" in feat:
-                # fresh per-node sampling stream for each tree
-                # (ref: ColSampler per-tree reseed); same derivation as
-                # ops/fused.py chunk_step
-                feat = {**feat, "ff_key": jax.random.fold_in(
-                    jax.random.fold_in(self._ff_key0, 2 ** 20 + it), k)}
+            with telemetry.span("train.sample", k=k):
+                allowed = self._feature_mask(it, k)
+                feat = self._feat
+                if "ff_key" in feat:
+                    # fresh per-node sampling stream for each tree
+                    # (ref: ColSampler per-tree reseed); same derivation
+                    # as ops/fused.py chunk_step
+                    feat = {**feat, "ff_key": jax.random.fold_in(
+                        jax.random.fold_in(self._ff_key0, 2 ** 20 + it),
+                        k)}
             if qscales is not None:
                 feat = {**feat, "qscales": qscales}
             # first dispatch of a (re)built grower traces + compiles
@@ -1647,10 +1653,18 @@ class Booster:
                                        hk.astype(jnp.float32), sw,
                                        feat, allowed)
             self._grower_warmed = self._grower
-            # the device_get inside from_device is where the dispatch is
-            # actually waited on — train.decode carries that wall-clock
+            # the grower was only dispatched: the host waits for it in the
+            # first read of its outputs.  A recorded round takes that wait
+            # into a span of its own (the outputs of one program are ready
+            # together), so train.decode holds the host decode alone;
+            # with no recording the device_get in from_device waits as ever
+            with telemetry.span("train.wait") as wait:
+                if wait is not telemetry.NOOP:
+                    jax.block_until_ready(dev.n_splits)
             with telemetry.span("train.decode"):
                 tree = Tree.from_device(dev, self.train_set.bin_mappers, lr)
+            telemetry.REGISTRY.counter("grow.hist_rows_needed").inc(
+                tree.hist_rows_needed())
             if "cegb_used" in self._feat and tree.num_leaves > 1:
                 # coupled penalties charge a feature once per MODEL
                 used = np.array(jax.device_get(self._feat["cegb_used"]))
@@ -1661,49 +1675,58 @@ class Booster:
                     self._feat["cegb_used"] = jnp.asarray(used)
             if tree.num_leaves > 1:
                 all_const = False
-            # L1-family leaf refit (ref: ObjectiveFunction::RenewTreeOutput →
-            # serial_tree_learner.cpp RenewTreeOutput; applied pre-shrinkage)
-            renew_alpha = getattr(self.objective_, "renew_percentile", None) \
-                if self.objective_ is not None else None
-            if cfg.linear_tree and tree.num_leaves > 1:
-                # ridge-fit linear leaves on raw values (ref:
-                # linear_tree_learner.cpp `LinearTreeLearner::Train`)
-                contrib = jnp.asarray(self._fit_linear_tree(
-                    tree, dev, gk, hk, sw, lr).astype(np.float32))
-            else:
-                if renew_alpha is not None and tree.num_leaves > 1:
-                    scaled = self._renew_tree_output(tree, dev, sw,
-                                                     float(renew_alpha), lr)
+            with telemetry.span("train.score", k=k):
+                contrib = self._tree_contribution(tree, dev, gk, hk, sw, lr)
+                if K == 1:
+                    new_train = self._train_score + contrib
                 else:
-                    scaled = dev.leaf_value * lr
-                # train score: final leaf_id from growth → direct gather
-                contrib = scaled[dev.leaf_id]
-            if K == 1:
-                new_train = self._train_score + contrib
-            else:
-                new_train = self._train_score.at[:, k].add(contrib)
-            self._last_contribs.append(("train", k, contrib))
-            self._train_score = new_train
-            # valid scores: bin-level traversal (ref: ScoreUpdater::AddScore)
-            for vi, vdd in enumerate(self._valid_dd):
-                self._valid_scores[vi] = self._apply_tree_to_score(
-                    self._valid_scores[vi], tree, vdd, k,
-                    bias_included=False, record=vi)
-            # fold init score into the stored model's first tree
-            # (ref: gbdt.cpp TrainOneIter → Tree::AddBias after UpdateScore)
-            if it == 0 and abs(self._init_scores[k]) > 1e-35:
-                tree.add_bias(self._init_scores[k])
-            self.trees.append(tree)
-            self._bump_model_version()
-            if round_trees is not None:
-                round_trees.append(telemetry.tree_stats(tree))
+                    new_train = self._train_score.at[:, k].add(contrib)
+                self._last_contribs.append(("train", k, contrib))
+                self._train_score = new_train
+                # valid scores: bin-level traversal
+                # (ref: ScoreUpdater::AddScore)
+                for vi, vdd in enumerate(self._valid_dd):
+                    self._valid_scores[vi] = self._apply_tree_to_score(
+                        self._valid_scores[vi], tree, vdd, k,
+                        bias_included=False, record=vi)
+            with telemetry.span("train.bookkeeping", k=k):
+                # fold init score into the stored model's first tree (ref:
+                # gbdt.cpp TrainOneIter → Tree::AddBias after UpdateScore)
+                if it == 0 and abs(self._init_scores[k]) > 1e-35:
+                    tree.add_bias(self._init_scores[k])
+                self.trees.append(tree)
+                self._bump_model_version()
+                if round_trees is not None:
+                    round_trees.append(telemetry.tree_stats(tree))
         if round_trees is not None:
-            self._flight.record_round(it, round_trees)
+            with telemetry.span("train.bookkeeping"):
+                self._flight.record_round(it, round_trees)
         self.cur_iter += 1
         if all_const:
             log.warning("Stopped training because there are no more leaves "
                         "that meet the split requirements")
         return all_const
+
+    def _tree_contribution(self, tree: Tree, dev: DeviceTree, gk, hk, sw,
+                           lr: float) -> jax.Array:
+        """The new tree's per-row addition to the training score."""
+        cfg = self.config
+        if cfg.linear_tree and tree.num_leaves > 1:
+            # ridge-fit linear leaves on raw values (ref:
+            # linear_tree_learner.cpp `LinearTreeLearner::Train`)
+            return jnp.asarray(self._fit_linear_tree(
+                tree, dev, gk, hk, sw, lr).astype(np.float32))
+        # L1-family leaf refit (ref: ObjectiveFunction::RenewTreeOutput →
+        # serial_tree_learner.cpp RenewTreeOutput; applied pre-shrinkage)
+        renew_alpha = getattr(self.objective_, "renew_percentile", None) \
+            if self.objective_ is not None else None
+        if renew_alpha is not None and tree.num_leaves > 1:
+            scaled = self._renew_tree_output(tree, dev, sw,
+                                             float(renew_alpha), lr)
+        else:
+            scaled = dev.leaf_value * lr
+        # train score: final leaf_id from growth → direct gather
+        return scaled[dev.leaf_id]
 
     def _renew_tree_output(self, tree: Tree, dev: DeviceTree, sw,
                            alpha: float, lr: float) -> jax.Array:
@@ -2062,7 +2085,8 @@ class Booster:
         it0 = self.cur_iter + self._pending_iters
         telemetry.REGISTRY.gauge("train.pipeline.depth").set(
             self._pipeline_depth())
-        with telemetry.span("train.chunk", rounds=spec.chunk, fused=True):
+        with telemetry.span("train.chunk", rounds=spec.chunk, fused=True,
+                            round=it0):
             self._ensure_train_bins()
             with telemetry.span("compile_warmup", kind="bulk_trainer") \
                     if not warm else telemetry.NOOP, self._nan_check_ctx():
@@ -2096,7 +2120,8 @@ class Booster:
             raise LightGBMError("pipeline harvest out of dispatch order")
         self._inflight.popleft()
         spec = pending.spec
-        with telemetry.span("train.harvest", rounds=spec.chunk):
+        with telemetry.span("train.harvest", rounds=spec.chunk,
+                            round=pending.it0):
             # ONE device→host transfer for the trees AND every score
             # snapshot — each separate device_get pays a full
             # host<->device round trip (same batching Tree.from_device
@@ -2218,6 +2243,8 @@ class Booster:
                 else:
                     dev = DeviceTree(*[np.asarray(f[c, k]) for f in host])
                 tree = Tree.from_device(dev, self.train_set.bin_mappers, lr)
+                telemetry.REGISTRY.counter("grow.hist_rows_needed").inc(
+                    tree.hist_rows_needed())
                 if tree.num_leaves > 1:
                     all_const = False
                 if self.cur_iter == 0 and abs(self._init_scores[k]) > 1e-35:

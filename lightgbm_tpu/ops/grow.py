@@ -970,10 +970,14 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
             (gain_s, f, t, dl, lg, lh, lc, rg, rh, rc, node_cat,
              node_mask) = chosen
 
-            # ---- partition: dense leaf_id update (no row movement) ----
-            go_left = split_go_left(spec, feat, bins_fm, decode_bins,
-                                    f, t, dl, node_cat, node_mask)
-            leaf_id = jnp.where(in_leaf & ~go_left, new, st["leaf_id"])
+            # ---- partition: dense leaf_id update (no row movement);
+            # the scope name is the wave grower's (one vocabulary for the
+            # trace readers) ----
+            with jax.named_scope("partition"):
+                go_left = split_go_left(spec, feat, bins_fm, decode_bins,
+                                        f, t, dl, node_cat, node_mask)
+                leaf_id = jnp.where(in_leaf & ~go_left, new,
+                                    st["leaf_id"])
 
             # ---- record the internal node ----
             nodes = st["nodes"]

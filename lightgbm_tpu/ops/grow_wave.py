@@ -41,6 +41,19 @@ allreduce-max; features block-padded), or full-histogram psum under EFB
 width-1 waves — strict order by construction — then free growth resumes
 at full width).  Monotone intermediate and the bounded histogram pool
 keep the strict grower (priced downgrade warning in the booster).
+
+Device phases: the work of one tree runs under `jax.named_scope`s whose
+names the trace readers select by (`perfbench/program_readers.py`), so
+they are fixed: `payload` (kernel payload carrier), `init` (root sums,
+empty node and leaf tables), `histogram_wave` (every histogram pass),
+`find_split` (the root's search, the per-child fan-out and its scatter),
+`partition` (the pick loop: choice, `split_go_left`, `leaf_id` rewrite,
+node and leaf records), `hist_cache` (sibling subtraction and the two
+cache scatters), `prune` (`prune_wave_tail`, only with overgrow).  An
+op's phase is the innermost of these on its name stack; what is under
+none (loop control, the tree's final selects) is the readers'
+"unattributed".  Scopes change HLO metadata only, never the program
+(tests/test_wave.py holds that).
 """
 from __future__ import annotations
 
@@ -203,8 +216,10 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
              ) -> DeviceTree:
         N = bins_fm.shape[1]
         F = feat["nb"].shape[0]
-        payload = jnp.stack([grad * sample_weight, hess * sample_weight,
-                             sample_weight], axis=1)  # [N, 3]
+        with jax.named_scope("payload"):
+            payload = jnp.stack([grad * sample_weight,
+                                 hess * sample_weight,
+                                 sample_weight], axis=1)  # [N, 3]
         mono = feat.get("mono")
         if mono is None:
             mono = jnp.zeros((F,), jnp.int32)
@@ -221,14 +236,16 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         if hist_fam == "pallas":
             from .pallas_hist import (_split_payload9,
                                       pallas_histogram_multi_rows)
-            pw_prep = _split_payload9(payload)
+            with jax.named_scope("payload"):
+                pw_prep = _split_payload9(payload)
         elif hist_fam == "pallas_q":
             from .pallas_hist import (
                 pallas_histogram_multi_quantized_rows,
                 quantized_lattice_rows)
-            pw_prep = quantized_lattice_rows(payload, feat["qscales"][0],
-                                             feat["qscales"][1],
-                                             debug=spec.debug_checks)
+            with jax.named_scope("payload"):
+                pw_prep = quantized_lattice_rows(
+                    payload, feat["qscales"][0], feat["qscales"][1],
+                    debug=spec.debug_checks)
         if fused:
             from .pallas_hist import (
                 pallas_fused_hist_split_quantized_rows,
@@ -461,101 +478,110 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         # [W, N] slot compare + reduce at COMPILE time (observed: 10.3 s
         # fold stall per chunk program at N=100k — BENCH_r03 tail); the
         # barrier trades that for a trivial runtime zeros-fill
-        if det:
-            # pad rows (beyond num_data) start at leaf -1: they match no
-            # histogram slot and no partition descriptor, so the det
-            # chain never replays a +0.0 the serial program doesn't have
-            row0_g = jax.lax.axis_index(axis_last) * N
-            det_valid = row0_g + jnp.arange(N) < num_data
-            leaf_id0 = jax.lax.optimization_barrier(
-                jnp.where(det_valid, 0, -1).astype(jnp.int32))
-        else:
-            leaf_id0 = jax.lax.optimization_barrier(
-                jnp.zeros((N,), jnp.int32))
-        root_slots = jnp.full((W,), LB, jnp.int32).at[0].set(0)
-        if det:
-            # deterministic root stats: gather the rows back into storage
-            # order (pad tail sliced off) and reduce with the serial
-            # grower's own expression — no psum of per-shard partials
-            gp = jax.lax.all_gather(payload, axis_last, axis=0,
-                                    tiled=True)[:num_data]
-            root_g = gp[:, 0].sum()
-            root_h = gp[:, 1].sum()
-            root_c = gp[:, 2].sum()
-        else:
-            root_g = payload[:, 0].sum()
-            root_h = payload[:, 1].sum()
-            root_c = payload[:, 2].sum()
-            if axes_all is not None:
-                root_g = jax.lax.psum(root_g, axes_all)
-                root_h = jax.lax.psum(root_h, axes_all)
-                root_c = jax.lax.psum(root_c, axes_all)
-        root_out = clamp_output(root_g, root_h)
-        if spec.n_ic_groups:
-            # only features inside some constraint group may ever split
-            allowed = allowed & jnp.any(feat["ic_groups"], axis=0)
-        root_pen = cegb_penalty(root_c, jnp.zeros((F,), bool))
+        with jax.named_scope("init"):
+            if det:
+                # pad rows (beyond num_data) start at leaf -1: they match
+                # no histogram slot and no partition descriptor, so the
+                # det chain never replays a +0.0 the serial program
+                # doesn't have
+                row0_g = jax.lax.axis_index(axis_last) * N
+                det_valid = row0_g + jnp.arange(N) < num_data
+                leaf_id0 = jax.lax.optimization_barrier(
+                    jnp.where(det_valid, 0, -1).astype(jnp.int32))
+            else:
+                leaf_id0 = jax.lax.optimization_barrier(
+                    jnp.zeros((N,), jnp.int32))
+            root_slots = jnp.full((W,), LB, jnp.int32).at[0].set(0)
+            if det:
+                # deterministic root stats: gather the rows back into
+                # storage order (pad tail sliced off) and reduce with the
+                # serial grower's own expression — no psum of per-shard
+                # partials
+                gp = jax.lax.all_gather(payload, axis_last, axis=0,
+                                        tiled=True)[:num_data]
+                root_g = gp[:, 0].sum()
+                root_h = gp[:, 1].sum()
+                root_c = gp[:, 2].sum()
+            else:
+                root_g = payload[:, 0].sum()
+                root_h = payload[:, 1].sum()
+                root_c = payload[:, 2].sum()
+                if axes_all is not None:
+                    root_g = jax.lax.psum(root_g, axes_all)
+                    root_h = jax.lax.psum(root_h, axes_all)
+                    root_c = jax.lax.psum(root_c, axes_all)
+            root_out = clamp_output(root_g, root_h)
+            if spec.n_ic_groups:
+                # only features inside a constraint group may ever split
+                allowed = allowed & jnp.any(feat["ic_groups"], axis=0)
+            root_pen = cegb_penalty(root_c, jnp.zeros((F,), bool))
         if fused:
-            root_parent = jnp.zeros((W, 3), jnp.float32).at[0].set(
-                jnp.stack([root_g, root_h, root_c]))
+            with jax.named_scope("init"):
+                root_parent = jnp.zeros((W, 3), jnp.float32).at[0].set(
+                    jnp.stack([root_g, root_h, root_c]))
             hist0, cand0 = hist_cand_multi(leaf_id0, root_slots,
                                            root_parent)
             hist0 = hist0[0]
-            s0 = split_of_fused(hist0, cand0[0], root_g, root_h, root_c,
-                                allowed, jnp.float32(-INF),
-                                jnp.float32(INF), root_out, 0,
-                                penalty=root_pen)
+            with jax.named_scope("find_split"):
+                s0 = split_of_fused(hist0, cand0[0], root_g, root_h,
+                                    root_c, allowed, jnp.float32(-INF),
+                                    jnp.float32(INF), root_out, 0,
+                                    penalty=root_pen)
         else:
             hist0 = hist_multi(leaf_id0, root_slots)[0]
-            s0 = split_of(hist0, root_g, root_h, root_c, allowed,
-                          jnp.float32(-INF), jnp.float32(INF), root_out,
-                          0, penalty=root_pen)
+            with jax.named_scope("find_split"):
+                s0 = split_of(hist0, root_g, root_h, root_c, allowed,
+                              jnp.float32(-INF), jnp.float32(INF),
+                              root_out, 0, penalty=root_pen)
 
-        hist = jnp.zeros((LB,) + hist0.shape, dtype=jnp.float32)\
-            .at[0].set(hist0)
-        leaf_best = [jnp.zeros((LB,) + a.shape, dtype=a.dtype)
-                     .at[0].set(a) for a in _split_to_arrays(s0)]
-        leaf_best[0] = jnp.full((LB,), NEG_INF, dtype=jnp.float32).at[0]\
-            .set(s0.gain)
+        with jax.named_scope("init"):
+            hist = jnp.zeros((LB,) + hist0.shape, dtype=jnp.float32)\
+                .at[0].set(hist0)
+            leaf_best = [jnp.zeros((LB,) + a.shape, dtype=a.dtype)
+                         .at[0].set(a) for a in _split_to_arrays(s0)]
+            leaf_best[0] = jnp.full((LB,), NEG_INF, dtype=jnp.float32)\
+                .at[0].set(s0.gain)
 
-        nodes = dict(
-            split_leaf=jnp.zeros((LB - 1,), jnp.int32),
-            split_feature=jnp.zeros((LB - 1,), jnp.int32),
-            threshold_bin=jnp.zeros((LB - 1,), jnp.int32),
-            default_left=jnp.zeros((LB - 1,), bool),
-            split_is_cat=jnp.zeros((LB - 1,), bool),
-            split_cat_mask=jnp.zeros((LB - 1, MB), bool),
-            split_gain=jnp.zeros((LB - 1,), jnp.float32),
-            internal_g=jnp.zeros((LB - 1,), jnp.float32),
-            internal_h=jnp.zeros((LB - 1,), jnp.float32),
-            internal_cnt=jnp.zeros((LB - 1,), jnp.float32),
-        )
+            nodes = dict(
+                split_leaf=jnp.zeros((LB - 1,), jnp.int32),
+                split_feature=jnp.zeros((LB - 1,), jnp.int32),
+                threshold_bin=jnp.zeros((LB - 1,), jnp.int32),
+                default_left=jnp.zeros((LB - 1,), bool),
+                split_is_cat=jnp.zeros((LB - 1,), bool),
+                split_cat_mask=jnp.zeros((LB - 1, MB), bool),
+                split_gain=jnp.zeros((LB - 1,), jnp.float32),
+                internal_g=jnp.zeros((LB - 1,), jnp.float32),
+                internal_h=jnp.zeros((LB - 1,), jnp.float32),
+                internal_cnt=jnp.zeros((LB - 1,), jnp.float32),
+            )
 
-        state = dict(
-            step=jnp.int32(0), nl=jnp.int32(1),
-            leaf_id=leaf_id0, hist=hist,
-            leaf_gain=leaf_best[0], leaf_feat=leaf_best[1],
-            leaf_thr=leaf_best[2], leaf_dl=leaf_best[3],
-            leaf_lg=leaf_best[4], leaf_lh=leaf_best[5],
-            leaf_lc=leaf_best[6], leaf_rg=leaf_best[7],
-            leaf_rh=leaf_best[8], leaf_rc=leaf_best[9],
-            leaf_iscat=leaf_best[10], leaf_catmask=leaf_best[11],
-            leaf_g=jnp.zeros((LB,), jnp.float32).at[0].set(root_g),
-            leaf_h=jnp.zeros((LB,), jnp.float32).at[0].set(root_h),
-            leaf_c=jnp.zeros((LB,), jnp.float32).at[0].set(root_c),
-            leaf_lb=jnp.full((LB,), -INF, jnp.float32),
-            leaf_ub=jnp.full((LB,), INF, jnp.float32),
-            leaf_out=jnp.zeros((LB,), jnp.float32).at[0].set(root_out),
-            leaf_depth=jnp.zeros((LB,), jnp.int32),
-            nodes=nodes,
-        )
-        if track_used:
-            state["leaf_used"] = jnp.zeros((LB, F), bool)
-        if n_forced:
-            # shrinks to `step` if a forced split proves infeasible —
-            # abandoning the rest of the prefix (its BFS leaf numbering
-            # no longer matches the tree), same as the strict grower
-            state["forced_n"] = jnp.int32(n_forced)
+            state = dict(
+                step=jnp.int32(0), nl=jnp.int32(1),
+                leaf_id=leaf_id0, hist=hist,
+                leaf_gain=leaf_best[0], leaf_feat=leaf_best[1],
+                leaf_thr=leaf_best[2], leaf_dl=leaf_best[3],
+                leaf_lg=leaf_best[4], leaf_lh=leaf_best[5],
+                leaf_lc=leaf_best[6], leaf_rg=leaf_best[7],
+                leaf_rh=leaf_best[8], leaf_rc=leaf_best[9],
+                leaf_iscat=leaf_best[10], leaf_catmask=leaf_best[11],
+                leaf_g=jnp.zeros((LB,), jnp.float32).at[0].set(root_g),
+                leaf_h=jnp.zeros((LB,), jnp.float32).at[0].set(root_h),
+                leaf_c=jnp.zeros((LB,), jnp.float32).at[0].set(root_c),
+                leaf_lb=jnp.full((LB,), -INF, jnp.float32),
+                leaf_ub=jnp.full((LB,), INF, jnp.float32),
+                leaf_out=jnp.zeros((LB,), jnp.float32).at[0]
+                .set(root_out),
+                leaf_depth=jnp.zeros((LB,), jnp.int32),
+                nodes=nodes,
+            )
+            if track_used:
+                state["leaf_used"] = jnp.zeros((LB, F), bool)
+            if n_forced:
+                # shrinks to `step` if a forced split proves infeasible —
+                # abandoning the rest of the prefix (its BFS leaf
+                # numbering no longer matches the tree), same as the
+                # strict grower
+                state["forced_n"] = jnp.int32(n_forced)
 
         LEAF_KEYS = ("leaf_gain", "leaf_feat", "leaf_thr", "leaf_dl",
                      "leaf_lg", "leaf_lh", "leaf_lc", "leaf_rg", "leaf_rh",
@@ -657,12 +683,13 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         cand = jnp.zeros((F, MB), bool)\
                             .at[forced_feat[idx], forced_bin[idx]]\
                             .set(True)
-                        fs = split_of(
-                            s["hist"][fl], s["leaf_g"][fl],
-                            s["leaf_h"][fl], s["leaf_c"][fl],
-                            allowed.at[forced_feat[idx]].set(True),
-                            s["leaf_lb"][fl], s["leaf_ub"][fl],
-                            s["leaf_out"][fl], 0, cand=cand)
+                        with jax.named_scope("find_split"):
+                            fs = split_of(
+                                s["hist"][fl], s["leaf_g"][fl],
+                                s["leaf_h"][fl], s["leaf_c"][fl],
+                                allowed.at[forced_feat[idx]].set(True),
+                                s["leaf_lb"][fl], s["leaf_ub"][fl],
+                                s["leaf_out"][fl], 0, cand=cand)
                         return _split_to_arrays(fs)
 
                     fa = jax.lax.cond(active_forced, eval_forced,
@@ -785,7 +812,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     out["hist"] = hist_ride
                 return out
 
-            s1 = jax.lax.while_loop(icond, ibody, istate)
+            with jax.named_scope("partition"):
+                s1 = jax.lax.while_loop(icond, ibody, istate)
 
             def hist_and_find(_):
                 # ---- histogram phase: ONE batched pass for all smaller
@@ -802,68 +830,73 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         s1["leaf_id"], s1["p_small"], par_small)
                 else:
                     small_h = hist_multi(s1["leaf_id"], s1["p_small"])
-                parents = st["hist"][jnp.clip(s1["p_left"], 0, LB - 1)]
-                large_h = parents - small_h
-                p_large = jnp.where(s1["p_small"] == s1["p_left"],
-                                    s1["p_new"], s1["p_left"])
-                hist = st["hist"].at[s1["p_small"]]\
-                    .set(small_h, mode="drop")
-                hist = hist.at[p_large].set(large_h, mode="drop")
+                with jax.named_scope("hist_cache"):
+                    parents = st["hist"][jnp.clip(s1["p_left"], 0, LB - 1)]
+                    large_h = parents - small_h
+                    p_large = jnp.where(s1["p_small"] == s1["p_left"],
+                                        s1["p_new"], s1["p_left"])
+                    hist = st["hist"].at[s1["p_small"]]\
+                        .set(small_h, mode="drop")
+                    hist = hist.at[p_large].set(large_h, mode="drop")
 
                 # ---- find phase: best splits of the new children ----
-                child_slots = jnp.concatenate([s1["p_left"], s1["p_new"]])
-                node_ids = jnp.concatenate([2 * s1["p_step"] + 1,
-                                            2 * s1["p_step"] + 2])
+                with jax.named_scope("find_split"):
+                    child_slots = jnp.concatenate([s1["p_left"],
+                                                   s1["p_new"]])
+                    node_ids = jnp.concatenate([2 * s1["p_step"] + 1,
+                                                2 * s1["p_step"] + 2])
 
-                if fused:
-                    # larger children's histograms came from subtraction,
-                    # not the kernel — scan them with the scan-only
-                    # kernel (same in-VMEM code path, no HBM gain grids),
-                    # then route each (left, new) pair's candidates to
-                    # whichever of (small, large) it actually is
-                    par_large = stats[jnp.clip(p_large, 0, LB - 1)]
-                    cand_large = pallas_split_scan(
-                        large_h, feat["nb"], feat["missing"], par_large,
-                        interpret=spec.hist_interpret, **scan_kw)
-                    small_is_left = (s1["p_small"] == s1["p_left"])[
-                        :, None, None, None]
-                    cand_left = jnp.where(small_is_left, cand_small,
-                                          cand_large)
-                    cand_new = jnp.where(small_is_left, cand_large,
-                                         cand_small)
-                    cand_all = jnp.concatenate([cand_left, cand_new])
-
-                def eval_child(slot, nid, *cand_sl):
-                    sl = jnp.clip(slot, 0, LB - 1)
-                    g, h, c = s1["leaf_g"][sl], s1["leaf_h"][sl], \
-                        s1["leaf_c"][sl]
-                    deep_ok = (spec.max_depth <= 0) | \
-                        (s1["leaf_depth"][sl] < spec.max_depth)
-                    lu = s1["leaf_used"][sl] if track_used \
-                        else jnp.zeros((F,), bool)
-                    a = allowed & deep_ok
-                    if spec.n_ic_groups:
-                        a = a & ic_allowed_from_used(feat, lu)
                     if fused:
-                        sr = split_of_fused(hist[sl], cand_sl[0], g, h, c,
-                                            a, s1["leaf_lb"][sl],
-                                            s1["leaf_ub"][sl],
-                                            s1["leaf_out"][sl], nid,
-                                            penalty=cegb_penalty(c, lu))
-                    else:
-                        sr = split_of(hist[sl], g, h, c, a,
-                                      s1["leaf_lb"][sl],
-                                      s1["leaf_ub"][sl],
-                                      s1["leaf_out"][sl], nid,
-                                      penalty=cegb_penalty(c, lu))
-                    return _split_to_arrays(sr)
+                        # larger children's histograms came from
+                        # subtraction, not the kernel — scan them with the
+                        # scan-only kernel (same in-VMEM code path, no HBM
+                        # gain grids), then route each (left, new) pair's
+                        # candidates to whichever of (small, large) it
+                        # actually is
+                        par_large = stats[jnp.clip(p_large, 0, LB - 1)]
+                        cand_large = pallas_split_scan(
+                            large_h, feat["nb"], feat["missing"],
+                            par_large, interpret=spec.hist_interpret,
+                            **scan_kw)
+                        small_is_left = (s1["p_small"] == s1["p_left"])[
+                            :, None, None, None]
+                        cand_left = jnp.where(small_is_left, cand_small,
+                                              cand_large)
+                        cand_new = jnp.where(small_is_left, cand_large,
+                                             cand_small)
+                        cand_all = jnp.concatenate([cand_left, cand_new])
 
-                args = (child_slots, node_ids) + \
-                    ((cand_all,) if fused else ())
-                res = jax.vmap(eval_child)(*args)
-                return hist, tuple(
-                    s1[k].at[child_slots].set(r, mode="drop")
-                    for k, r in zip(LEAF_KEYS, res))
+                    def eval_child(slot, nid, *cand_sl):
+                        sl = jnp.clip(slot, 0, LB - 1)
+                        g, h, c = s1["leaf_g"][sl], s1["leaf_h"][sl], \
+                            s1["leaf_c"][sl]
+                        deep_ok = (spec.max_depth <= 0) | \
+                            (s1["leaf_depth"][sl] < spec.max_depth)
+                        lu = s1["leaf_used"][sl] if track_used \
+                            else jnp.zeros((F,), bool)
+                        a = allowed & deep_ok
+                        if spec.n_ic_groups:
+                            a = a & ic_allowed_from_used(feat, lu)
+                        if fused:
+                            sr = split_of_fused(
+                                hist[sl], cand_sl[0], g, h, c, a,
+                                s1["leaf_lb"][sl], s1["leaf_ub"][sl],
+                                s1["leaf_out"][sl], nid,
+                                penalty=cegb_penalty(c, lu))
+                        else:
+                            sr = split_of(hist[sl], g, h, c, a,
+                                          s1["leaf_lb"][sl],
+                                          s1["leaf_ub"][sl],
+                                          s1["leaf_out"][sl], nid,
+                                          penalty=cegb_penalty(c, lu))
+                        return _split_to_arrays(sr)
+
+                    args = (child_slots, node_ids) + \
+                        ((cand_all,) if fused else ())
+                    res = jax.vmap(eval_child)(*args)
+                    return hist, tuple(
+                        s1[k].at[child_slots].set(r, mode="drop")
+                        for k, r in zip(LEAF_KEYS, res))
 
             def tree_full(_):
                 # capacity reached mid-wave: the children can never be
@@ -883,9 +916,10 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         st = jax.lax.while_loop(cond, body, state)
 
         if LB > L:
-            nodes_f, leaves_f, leaf_id_f, n_splits = prune_wave_tail(
-                st, LB=LB, L=L, n_forced=n_forced,
-                clamp_output=clamp_output)
+            with jax.named_scope("prune"):
+                nodes_f, leaves_f, leaf_id_f, n_splits = prune_wave_tail(
+                    st, LB=LB, L=L, n_forced=n_forced,
+                    clamp_output=clamp_output)
             nl_f = n_splits + 1
             slot = jnp.arange(L)
             active = slot < nl_f

@@ -378,14 +378,19 @@ def throughput_report(rounds: int, seconds: float, num_data: int,
 _compile_listener_installed = False
 _compile_lock = threading.Lock()
 
-#: jax.monitoring keys that mark one XLA computation compile.  The
-#: trace/lowering durations fire alongside but must not double-count.
-_COMPILE_EVENT_MARKERS = ("backend_compile", "compilation_cache_miss")
+#: the jax.monitoring duration that marks one XLA computation compile (or
+#: its retrieval from the persistent cache, which the same event times).
+#: The trace/lowering durations fire alongside but must not double-count.
+_COMPILE_EVENT_MARKER = "backend_compile"
+#: the jax.monitoring event (not a duration) of one persistent-cache miss:
+#: a program compiled and written because no entry had its key.
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
 
 def install_compile_listener() -> bool:
     """Hook `jax.monitoring` so every backend compile increments
-    `jit.recompiles` and accumulates `jit.compile_total_s`.  Idempotent;
+    `jit.recompiles` and accumulates `jit.compile_total_s`, and every
+    persistent-cache miss increments `jit.cache_misses`.  Idempotent;
     returns False only when jax is not loaded in this process (this
     module never imports it)."""
     global _compile_listener_installed
@@ -397,12 +402,18 @@ def install_compile_listener() -> bool:
             return False
 
         def _on_duration(name: str, secs: float, **kw) -> None:
-            if any(m in name for m in _COMPILE_EVENT_MARKERS):
+            if _COMPILE_EVENT_MARKER in name:
                 REGISTRY.counter("jit.recompiles").inc()
                 g = REGISTRY.gauge("jit.compile_total_s")
                 g.set(g.value + float(secs))
 
+        def _on_event(name: str, **kw) -> None:
+            if name == _CACHE_MISS_EVENT:
+                REGISTRY.counter("jit.cache_misses").inc()
+
         jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        REGISTRY.counter("jit.cache_misses")    # reads 0, not absent
         _compile_listener_installed = True
         return True
 

@@ -5,6 +5,14 @@ a wall-clock interval, maintains per-thread nesting (depth + parent name),
 and on exit emits one `{"ev": "span", ...}` event to every attached sink
 and one observation into the timing registry (`span.<name>`).
 
+A span event carries what it takes to rebuild the tree it came from: `id`
+(unique in the process), `parent_id` (beside the parent's `name`),
+`start_ns` / `end_ns` on ONE monotonic clock (`time.perf_counter_ns`;
+`dur_s` is their difference, `ts` stays the wall-clock stamp of the start
+for merging across processes) and `round`: the boosting iteration, given
+by the span that opens a round (`train.chunk`, `train.harvest`) and
+inherited by every span under it, so the spans of one round share it.
+
 Two cost regimes, chosen per `span()` call:
 
  - **inactive** (no sink attached, not force-enabled): `span()` returns a
@@ -14,10 +22,32 @@ Two cost regimes, chosen per `span()` call:
  - **active**: wall time via `perf_counter`, and the body additionally
    runs under `jax.profiler.TraceAnnotation(name)` when jax is already
    loaded, so the host-side record and the XProf/Perfetto device timeline
-   carry the SAME phase names and can be cross-read (the device-side
-   analogs are the `jax.named_scope`s inside the jitted programs —
-   ops/grow.py `histogram`/`find_split`, ops/fused.py `grad_hess`/
-   `grow_tree`/`update_scores`).
+   carry the SAME phase names and can be cross-read.
+
+The spans of one boosting round (`Booster.update`, booster.py):
+
+    train.chunk                 the round (attrs: rounds, fused; `round`)
+      train.gradients           objective gradients dispatched
+      train.sample              bagging / GOSS weights, feature mask
+      compile_warmup            first dispatch of a (re)built grower
+        train.grow              the grower's dispatch (returns at once)
+      train.wait                dispatch end -> the device tree is ready:
+                                the round's DEVICE time, seen from the host
+      train.decode              device_get of the ready tree + host Tree
+      train.score               leaf-value gather, score add, valid scores
+      train.bookkeeping         model version, flight recorder, ledger
+
+The fused chunk path keeps `train.chunk` (dispatch) and `train.harvest` >
+`train.decode` (readback, decode), each with the chunk's first `round`.
+
+Device-side, the phases of a tree are `jax.named_scope`s inside the jitted
+growers; they reach the profiler's trace as the `tf_op` stat of a device
+event's METADATA (`jax.profiler.ProfileData` does not show it;
+`perfbench/xplane_meta.py` reads it).  The wave grower (ops/grow_wave.py)
+carries `init`, `payload`, `partition`, `histogram_wave`, `hist_cache`,
+`find_split`, `prune`; the strict grower (ops/grow.py) `histogram`,
+`find_split`, `partition`; the fused chunk (ops/fused.py) `grad_hess`,
+`grow_tree`, `update_scores` around them.
 
 jax is mirrored via `sys.modules.get("jax")`, NEVER imported: jax-free
 supervising processes load telemetry too, and a parent that touched jax
@@ -25,6 +55,7 @@ would hold the chip its child needs.
 """
 from __future__ import annotations
 
+import itertools
 import os
 import sys
 import threading
@@ -59,17 +90,25 @@ class Span:
     """One named wall-clock phase; records itself on exit."""
 
     __slots__ = ("tracer", "name", "attrs", "t0", "wall0", "depth",
-                 "parent", "_annot")
+                 "parent", "id", "parent_id", "round", "_annot")
+
+    _ids = itertools.count(1)
 
     def __init__(self, tracer: "Tracer", name: str, attrs: Dict[str, Any]):
         self.tracer = tracer
         self.name = name
+        self.round = attrs.pop("round", None)
         self.attrs = attrs
+        self.id = next(Span._ids)
         self._annot = None
 
     def __enter__(self) -> "Span":
         stack = self.tracer._stack()
-        self.parent = stack[-1].name if stack else None
+        above = stack[-1] if stack else None
+        self.parent = above.name if above else None
+        self.parent_id = above.id if above else None
+        if self.round is None and above is not None:
+            self.round = above.round
         self.depth = len(stack)
         stack.append(self)
         jax = sys.modules.get("jax")
@@ -80,7 +119,7 @@ class Span:
             except Exception:
                 self._annot = None
         self.wall0 = time.time()
-        self.t0 = time.perf_counter()
+        self.t0 = time.perf_counter_ns()
         return self
 
     def set(self, **attrs) -> "Span":
@@ -90,7 +129,8 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        dur = time.perf_counter() - self.t0
+        end = time.perf_counter_ns()
+        dur = (end - self.t0) / 1e9
         if self._annot is not None:
             try:
                 self._annot.__exit__(exc_type, exc, tb)
@@ -103,10 +143,14 @@ class Span:
             stack.remove(self)
         REGISTRY.timing(f"span.{self.name}").observe(dur)
         ev = make_event("span", self.name, dur_s=round(dur, 6),
-                        depth=self.depth, pid=os.getpid())
+                        depth=self.depth, pid=os.getpid(), id=self.id,
+                        start_ns=self.t0, end_ns=end)
         ev["ts"] = round(self.wall0, 6)  # span events stamp their START
         if self.parent is not None:
             ev["parent"] = self.parent
+            ev["parent_id"] = self.parent_id
+        if self.round is not None:
+            ev["round"] = self.round
         if self.attrs:
             ev["attrs"] = self.attrs
         if exc_type is not None:

@@ -182,6 +182,23 @@ class Tree:
         t.leaf_count = np.asarray(leaf_cnt_h)[:nl].astype(np.float64)
         return t
 
+    def hist_rows_needed(self) -> float:
+        """Rows whose histograms growing this tree had to build: the
+        root's, and at every split the SMALLER child's (its sibling is
+        parent minus it).  What a histogram pass over all rows did that
+        was needed, from the counts the tree already carries."""
+        ns = self.num_internal()
+        if not ns:
+            return float(self.leaf_count[0])
+
+        def count(child: int) -> float:
+            return self.internal_count[child] if child >= 0 \
+                else self.leaf_count[~child]
+
+        return float(self.internal_count[0]) + float(sum(
+            min(count(int(self.left_child[i])),
+                count(int(self.right_child[i]))) for i in range(ns)))
+
     def leaf_path_features(self) -> list:
         """Per-leaf NUMERICAL features on the root path, in path order
         (ref: linear_tree_learner.cpp gathers the branch features)."""

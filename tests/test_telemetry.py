@@ -367,3 +367,155 @@ class TestMetricsRegistry:
                            capture_output=True, text=True, timeout=60)
         assert r.returncode == 0, r.stderr
         assert "CLEAN" in r.stdout
+
+
+# --------------------------------------------------------------------------
+# ISSUE 25: one span tree per round, the no-op path, the needed-rows counter
+# --------------------------------------------------------------------------
+ROUND_CHILDREN = {"train.gradients", "train.sample", "compile_warmup",
+                  "train.grow", "train.wait", "train.decode", "train.score",
+                  "train.bookkeeping"}
+
+
+@pytest.fixture(scope="module")
+def recorded_rounds():
+    """Three `Booster.update()` rounds of a 7-leaf wave booster into a
+    MemorySink: (span events, booster, the needed-rows counter's growth)."""
+    X, y = make_binary(600)
+    sink = telemetry.TRACER.add_sink(MemorySink())
+    before = telemetry.REGISTRY.counter("grow.hist_rows_needed").value
+    try:
+        bst = lgb.Booster(params={"objective": "binary", "verbosity": -1,
+                                  "num_leaves": 7, "min_data_in_leaf": 5,
+                                  "tree_grow_policy": "wave"},
+                          train_set=lgb.Dataset(X, label=y))
+        for _ in range(3):
+            bst.update()
+    finally:
+        telemetry.TRACER.remove_sink(sink)
+    grown = telemetry.REGISTRY.counter("grow.hist_rows_needed").value - before
+    return [e for e in sink.events if e["ev"] == "span"], bst, grown
+
+
+class TestRoundSpanTree:
+    def test_every_round_is_one_tree_under_its_chunk(self, recorded_rounds):
+        spans, _, _ = recorded_rounds
+        chunks = [s for s in spans if s["name"] == "train.chunk"]
+        assert [c["round"] for c in chunks] == [0, 1, 2]
+        assert len({s["id"] for s in spans}) == len(spans)
+        by_id = {s["id"]: s for s in spans}
+        for c in chunks:
+            below = [s for s in spans if s.get("parent_id") == c["id"]]
+            names = {s["name"] for s in below}
+            assert names <= ROUND_CHILDREN
+            assert names >= ROUND_CHILDREN - {"compile_warmup",
+                                              "train.grow"}
+            # the spans of one round share its identifier ...
+            assert {s["round"] for s in below} == {c["round"]}
+            # ... lie inside it, and do not overlap one another
+            below.sort(key=lambda s: s["start_ns"])
+            assert c["start_ns"] <= below[0]["start_ns"]
+            assert below[-1]["end_ns"] <= c["end_ns"]
+            for a, b in zip(below, below[1:]):
+                assert a["end_ns"] <= b["start_ns"], (a["name"], b["name"])
+        # train.grow hangs from the chunk, or from compile_warmup in the
+        # round that compiles; either way its parent is in the same round
+        for g in (s for s in spans if s["name"] == "train.grow"):
+            up = by_id[g["parent_id"]]
+            assert up["name"] in ("train.chunk", "compile_warmup")
+            assert up["round"] == g["round"]
+        assert [s["name"] for s in spans
+                if s["name"] == "compile_warmup"] == ["compile_warmup"]
+
+    def test_start_and_end_are_one_clock(self, recorded_rounds):
+        spans, _, _ = recorded_rounds
+        for s in spans:
+            assert s["end_ns"] >= s["start_ns"]
+            assert s["dur_s"] == pytest.approx(
+                (s["end_ns"] - s["start_ns"]) / 1e9, abs=1e-6)
+
+    def test_wait_and_decode_split_the_old_decode_interval(
+            self, recorded_rounds):
+        """`train.decode` used to run from the end of the dispatch to the
+        decoded tree; `train.wait` now takes the head of that interval."""
+        spans, _, _ = recorded_rounds
+        for rnd in (0, 1, 2):
+            mine = {s["name"]: s for s in spans if s.get("round") == rnd}
+            grow, wait, dec = (mine[n] for n in ("train.grow", "train.wait",
+                                                 "train.decode"))
+            assert grow["end_ns"] <= wait["start_ns"] <= wait["end_ns"] \
+                <= dec["start_ns"] <= dec["end_ns"]
+            # nothing but the two span boundaries lies between them
+            assert wait["start_ns"] - grow["end_ns"] < 5e6
+            assert dec["start_ns"] - wait["end_ns"] < 5e6
+
+    def test_needed_rows_against_a_hand_count(self, recorded_rounds):
+        _, bst, grown = recorded_rounds
+        total = 0.0
+        for tree in bst.trees:
+            assert tree.num_leaves == 7
+            count = {}                  # node ref -> rows, by walking down
+
+            def rows(ref):
+                if ref < 0:
+                    return float(tree.leaf_count[~ref])
+                if ref not in count:
+                    count[ref] = rows(int(tree.left_child[ref])) \
+                        + rows(int(tree.right_child[ref]))
+                return count[ref]
+
+            needed = rows(0)            # the root's histogram: every row
+            assert needed == 600
+            for i in range(6):
+                needed += min(rows(int(tree.left_child[i])),
+                              rows(int(tree.right_child[i])))
+            assert tree.hist_rows_needed() == needed
+            total += needed
+        assert grown == total
+        assert 600 * 3 < total < 600 * 3 * 4    # root + at most N/2 a level
+
+    def test_single_leaf_tree_needs_its_root_only(self):
+        from lightgbm_tpu.tree import Tree
+        t = Tree(1)
+        t.leaf_count = np.array([42.0])
+        assert t.hist_rows_needed() == 42.0
+
+
+class TestNoSpanWithoutASink:
+    def test_update_creates_no_span_object(self, monkeypatch):
+        """With no sink and no `enable`, a round goes through the shared
+        no-op: not one `Span` is built."""
+        from lightgbm_tpu.telemetry import spans as spans_mod
+        assert not telemetry.TRACER.active
+        X, y = make_binary(300)
+        bst = lgb.Booster(params={"objective": "binary", "verbosity": -1,
+                                  "num_leaves": 4},
+                          train_set=lgb.Dataset(X, label=y))
+        made = []
+        real = spans_mod.Span.__init__
+
+        def counting(self, *a, **kw):
+            made.append(a[1] if len(a) > 1 else kw.get("name"))
+            real(self, *a, **kw)
+
+        monkeypatch.setattr(spans_mod.Span, "__init__", counting)
+        bst.update()
+        bst.update()
+        assert made == []
+        assert bst.current_iteration() == 2
+
+
+class TestCompileListener:
+    def test_cache_misses_are_counted_from_jaxs_event(self):
+        import jax
+        from lightgbm_tpu.telemetry.recorder import install_compile_listener
+        assert install_compile_listener()
+        misses = telemetry.REGISTRY.counter("jit.cache_misses")
+        compiles = telemetry.REGISTRY.counter("jit.recompiles")
+        m0, c0 = misses.value, compiles.value
+        jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
+        jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
+        assert (misses.value, compiles.value) == (m0 + 1, c0)
+        jax.monitoring.record_event_duration_secs(
+            "/jax/core/compile/backend_compile_duration", 0.25)
+        assert (misses.value, compiles.value) == (m0 + 1, c0 + 1)

@@ -696,3 +696,54 @@ class TestWaveDistributed:
             preds[learner] = bst.predict(X, raw_score=True)
         np.testing.assert_allclose(preds["serial"], preds["data"],
                                    rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.quick
+class TestPhaseScopes:
+    """The device phase scopes of ops/grow_wave.py name operations; they
+    must not change the program (ISSUE 25: metric selectors are scope
+    names, and a scope that moved a fusion would move what it measures)."""
+
+    PHASES = ("payload", "init", "histogram_wave", "find_split",
+              "partition", "hist_cache")
+
+    @staticmethod
+    def _compiled(params):
+        import re
+        from lightgbm_tpu.ops.grow_wave import make_wave_grower
+        make_wave_grower.cache_clear()
+        X, y = make_binary(1500, 6)
+        bst = lgb.Booster(params={"objective": "binary", "verbosity": -1,
+                                  "tree_grow_policy": "wave", **params},
+                          train_set=lgb.Dataset(X, label=y))
+        assert bst._grow_policy == "wave"
+        n, f = X.shape
+        ones = jnp.ones((n,), jnp.float32)
+        text = bst._make_serial_grower().lower(
+            bst._train_bins, ones, ones, ones, bst._feat,
+            jnp.ones((f,), bool)).compile().as_text()
+        # the program without its names: no `metadata={...}` on an
+        # instruction, and none of the module's source-location tables
+        tables = ("FileNames", "FunctionNames", "FileLocations",
+                  "StackFrames")
+        blocks = [b for b in re.sub(r",? ?metadata=\{[^}]*\}", "", text)
+                  .split("\n\n") if not b.lstrip().startswith(tables)]
+        return text, "\n\n".join(blocks)
+
+    @pytest.mark.parametrize("params", [
+        {"num_leaves": 7},
+        {"num_leaves": 7, "tpu_wave_overgrow": 1.5},
+    ], ids=["plain", "overgrow"])
+    def test_scopes_change_names_only(self, params, monkeypatch):
+        import contextlib
+        with_names, with_scopes = self._compiled(params)
+        for phase in self.PHASES + (("prune",) if "tpu_wave_overgrow"
+                                    in params else ()):
+            assert f"/{phase}/" in with_names, phase
+        monkeypatch.setattr(jax, "named_scope",
+                            lambda name: contextlib.nullcontext())
+        without_names, without_scopes = self._compiled(params)
+        assert "/partition/" not in without_names
+        assert with_scopes == without_scopes
+        from lightgbm_tpu.ops.grow_wave import make_wave_grower
+        make_wave_grower.cache_clear()     # no unnamed grower left cached

@@ -234,6 +234,19 @@ def _probe_why(res) -> str:
     return f" ({res.cause}: {detail[:600]})" if detail else ""
 
 
+def _count_growth(tree: Tree) -> None:
+    """What growing `tree` cost, onto the always-on counters: the rows its
+    histograms needed, and (wave grower) its strict tail's passes, splits
+    served from a speculated histogram, and speculated ones left unused."""
+    counter = telemetry.REGISTRY.counter
+    counter("grow.hist_rows_needed").inc(tree.hist_rows_needed())
+    if tree.tail_stats is not None:
+        passes, hits, unused, _ = tree.tail_stats
+        counter("grow.tail_passes").inc(passes)
+        counter("grow.tail_spec_hits").inc(hits)
+        counter("grow.tail_spec_unused").inc(unused)
+
+
 @jax.jit
 def _add_leaf_values(score, leaf_idx, values):
     return score + values[leaf_idx]
@@ -1663,8 +1676,7 @@ class Booster:
                     jax.block_until_ready(dev.n_splits)
             with telemetry.span("train.decode"):
                 tree = Tree.from_device(dev, self.train_set.bin_mappers, lr)
-            telemetry.REGISTRY.counter("grow.hist_rows_needed").inc(
-                tree.hist_rows_needed())
+            _count_growth(tree)
             if "cegb_used" in self._feat and tree.num_leaves > 1:
                 # coupled penalties charge a feature once per MODEL
                 used = np.array(jax.device_get(self._feat["cegb_used"]))
@@ -2238,13 +2250,11 @@ class Booster:
         for c in range(chunk):
             round_trees = [] if self._flight is not None else None
             for k in range(K):
-                if K == 1:
-                    dev = DeviceTree(*[np.asarray(f[c]) for f in host])
-                else:
-                    dev = DeviceTree(*[np.asarray(f[c, k]) for f in host])
+                at = c if K == 1 else (c, k)
+                dev = DeviceTree(*[None if f is None else np.asarray(f[at])
+                                   for f in host])
                 tree = Tree.from_device(dev, self.train_set.bin_mappers, lr)
-                telemetry.REGISTRY.counter("grow.hist_rows_needed").inc(
-                    tree.hist_rows_needed())
+                _count_growth(tree)
                 if tree.num_leaves > 1:
                     all_const = False
                 if self.cur_iter == 0 and abs(self._init_scores[k]) > 1e-35:

@@ -180,6 +180,10 @@ class DeviceTree(NamedTuple):
     leaf_h: Array         # [L] f32
     leaf_cnt: Array       # [L] f32
     leaf_id: Array        # [N] i32 — final row→leaf assignment (train rows)
+    # [4] i32, wave grower only (None elsewhere) — its strict tail's
+    # histogram passes, splits served from a speculated histogram, and
+    # speculated histograms never used / made (ops/grow_wave.py)
+    tail_stats: Array = None
 
 
 def _split_to_arrays(s: SplitResult):

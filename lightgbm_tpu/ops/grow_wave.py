@@ -4,17 +4,38 @@ Motivation (PROFILE.md round 3c): the strict best-first loop in
 `ops/grow.py` needs ONE new histogram per split, and each histogram is a
 full pass over the bin matrix whose MXU cost is IDENTICAL whether the LHS
 carries one leaf's payload (9 rows) or fourteen (126 rows) — the MXU pads
-the M axis to 128 either way.  Strict order therefore wastes ~93% of every
-pass, and its serial chain (the next split depends on the previous split's
-child histograms) cannot be batched without changing the growth order.
+the M axis to 128 either way.  A pass that builds one histogram wastes
+~93% of itself.  Two schedules fill the pass; neither touches the split
+math.
 
-The wave policy changes the order, not the split math: each wave splits
-EVERY current leaf whose cached best gain is positive (best-first within
-the wave, up to the `wave_width` batch capacity), then computes all the
-new smaller-children histograms in ONE batched kernel pass
+The wave policy changes the growth order: each wave splits EVERY current
+leaf whose cached best gain is positive (best-first within the wave, up
+to the `wave_width` batch capacity), then computes all the new
+smaller-children histograms in ONE batched kernel pass
 (`pallas_histogram_multi`), derives the larger children by subtraction,
-and re-searches the new leaves' best splits vmapped.  A 31-leaf tree costs
-~7 histogram passes instead of 30.
+and re-searches the new leaves' best splits vmapped.  31 leaves grown in
+waves alone cost ~7 passes instead of 30.
+
+The strict tail (`wave_strict_tail`: the last splits of a tree are made
+in strict best-first order, which allocates the remaining capacity the
+way the reference does) keeps the order and changes WHEN a histogram is
+built.  Every frontier leaf already carries its best split, so the rows
+of its would-be smaller child are known before it is picked, and they do
+not change until it is.  A tail pass is therefore made BEFORE the split
+it is for, and fills the kernel's other slots with the would-be smaller
+children of the next-best leaves (`speculate`); the histograms wait in a
+per-leaf cache.  Best-first then commits split after split with no pass
+for as long as the best leaf's histogram is already there, and pays a
+pass only when the best leaf is one created since the last pass.  A
+slot's sums depend on its own rows alone, so every histogram is bit for
+bit the one a pass after the split would have built: the trees are those
+of one pass per split (tests/data/wave_tail_goldens.json), at about a
+third of the passes where the frontier is wide (a 16-split tail: 15
+passes before, about 5 measured, PERF.md section 6, PR 26); a chain-shaped
+tree, whose next best leaf is always the newest, still pays one per
+split.  `DeviceTree.tail_stats` counts passes, hits and unused
+histograms.  The cache is one histogram per leaf slot, the size of
+`hist`.
 
 Relation to the reference: LightGBM grows strictly best-first
 (ref: serial_tree_learner.cpp `SerialTreeLearner::Train` — one
@@ -23,9 +44,11 @@ Relation to the reference: LightGBM grows strictly best-first
 is best-first over the frontier but fills each level before descending,
 so trees are more balanced than strict leaf-wise on skewed data and
 identical on data where the frontier's gains dominate the children's
-(always identical for num_leaves <= 3).  Accuracy on benchmark-scale data
-matches strict to within noise (tests/test_wave.py); the default policy
-remains `leafwise` for stock-exact trees.
+(always identical for num_leaves <= 3); with `wave_strict_tail >=
+num_leaves - 1` every split is a tail split and the trees are the strict
+grower's byte for byte.  Accuracy on benchmark-scale data matches strict
+to within noise (tests/test_wave.py); the default policy remains
+`leafwise` for stock-exact trees.
 
 Feature scope (the booster downgrades to the strict grower otherwise):
 numerical + categorical splits, missing handling, monotone basic,
@@ -48,8 +71,10 @@ they are fixed: `payload` (kernel payload carrier), `init` (root sums,
 empty node and leaf tables), `histogram_wave` (every histogram pass),
 `find_split` (the root's search, the per-child fan-out and its scatter),
 `partition` (the pick loop: choice, `split_go_left`, `leaf_id` rewrite,
-node and leaf records), `hist_cache` (sibling subtraction and the two
-cache scatters), `prune` (`prune_wave_tail`, only with overgrow).  An
+node and leaf records; in the tail also the choice of the leaves to
+speculate and their rows' slot ids), `hist_cache` (sibling subtraction,
+the two cache scatters, the speculated histograms' reads and scatters),
+`prune` (`prune_wave_tail`, only with overgrow).  An
 op's phase is the innermost of these on its name stack; what is under
 none (loop control, the tree's final selects) is the readers'
 "unattributed".  Scopes change HLO metadata only, never the program
@@ -139,6 +164,10 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
     REGISTRY.gauge("wave.grow_leaves").set(LB)
     REGISTRY.gauge("wave.shards").set(n_shards)
     n_forced = len(spec.forced_splits)
+    # splits of the strict tail (0: no tail); capped against LB-1 (not
+    # num_leaves) so that under overgrow the tail is the endgame of the
+    # GROW phase (pruning then trims by gain)
+    tail = min(max(spec.wave_strict_tail, 0), LB - 1)
     find = functools.partial(
         find_best_split,
         l1=spec.lambda_l1, l2=spec.lambda_l2,
@@ -582,6 +611,14 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 # numbering no longer matches the tree), same as the
                 # strict grower
                 state["forced_n"] = jnp.int32(n_forced)
+            if tail:
+                # the strict tail's speculated smaller-child histograms,
+                # one per leaf slot (the size of `hist`), valid while the
+                # leaf stands unsplit; tail_stats = passes, hits, unused,
+                # speculated (DeviceTree.tail_stats)
+                state["spec_hist"] = jnp.zeros_like(hist)
+                state["spec_ok"] = jnp.zeros((LB,), bool)
+                state["tail_stats"] = jnp.zeros((4,), jnp.int32)
 
         LEAF_KEYS = ("leaf_gain", "leaf_feat", "leaf_thr", "leaf_dl",
                      "leaf_lg", "leaf_lh", "leaf_lc", "leaf_rg", "leaf_rh",
@@ -593,7 +630,26 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 go = go | (st["step"] < st["forced_n"])
             return (st["step"] < LB - 1) & go
 
-        def body(st):
+        def in_tail(st):
+            """The strict tail has begun: at most `tail` splits of
+            capacity are left and no forced split is pending (the forced
+            prefix keeps the wave body, at width 1)."""
+            strict = LB - st["nl"] <= tail
+            if n_forced:
+                strict = strict & (st["step"] >= st["forced_n"])
+            return strict
+
+        def body(st, small_hists=None):
+            """One wave: the pick loop, one histogram pass for the
+            picks' smaller children, the children's searches.  With
+            `small_hists` [LB, ...] (the strict tail, `tail_body`) it is
+            ONE pick whose smaller child's histogram is read from there
+            instead of built."""
+            strict = small_hists is not None
+            # the tail's children are searched from the carried histogram
+            # by `split_of`, whose result the fused candidates equal byte
+            # for byte by construction: decided from the static spec
+            fused_here = fused and not strict
             # ---- split phase: best-first among READY leaves (leaves
             # created this wave have no histogram yet and wait for the
             # next wave), up to the batch capacity W ----
@@ -609,23 +665,19 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 istate["hist"] = st["hist"]
             istate["ready"] = jnp.arange(LB) < st["nl"]
             istate["w"] = jnp.int32(0)
-            # hybrid wave/strict schedule (spec.wave_strict_tail): with
-            # at most `tail` splits of capacity left, cap the wave at
-            # width 1 — strict best-first order (children re-searched
-            # before the next pick), still on the one [W]-slot kernel
-            # shape (pad slots) at ~1.1x a single-leaf pass.  The wave
+            # hybrid wave/strict schedule (spec.wave_strict_tail): the
+            # last `tail` splits of capacity are made in strict
+            # best-first order, one pick at a time with the children
+            # re-searched before the next pick (`tail_body`).  The wave
             # that CROSSES the boundary is clipped to `remaining - tail`
             # so the promised strict endgame is never consumed by a wide
-            # boundary wave; the cap against LB-1 (not num_leaves) keeps
-            # the semantics under overgrow: the tail is the endgame of
-            # the GROW phase (pruning then trims by gain).
-            if spec.wave_strict_tail > 0:
-                tail = min(spec.wave_strict_tail, LB - 1)
-                remaining = LB - st["nl"]
-                istate["wcap"] = jnp.where(
-                    remaining <= tail, jnp.int32(1),
-                    jnp.minimum(jnp.int32(W),
-                                (remaining - tail).astype(jnp.int32)))
+            # boundary wave.  A forced split still pending at the
+            # boundary is made here at width 1: one pick, then its pass.
+            if strict:
+                istate["wcap"] = jnp.int32(1)
+            elif tail:
+                istate["wcap"] = jnp.clip(LB - st["nl"] - tail, 1, W)\
+                    .astype(jnp.int32)
             else:
                 istate["wcap"] = jnp.int32(W)
             # (forced prefix: no wcap pinning here — a pending forced
@@ -813,13 +865,22 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 return out
 
             with jax.named_scope("partition"):
-                s1 = jax.lax.while_loop(icond, ibody, istate)
+                # the tail's loop condition IS icond at w == 0: one pick
+                s1 = ibody(istate) if strict else \
+                    jax.lax.while_loop(icond, ibody, istate)
 
             def hist_and_find(_):
                 # ---- histogram phase: ONE batched pass for all smaller
                 # children; larger children by subtraction (the parent
                 # histogram still lives in the left child's slot) ----
-                if fused:
+                if strict:
+                    # speculated for the leaf that was just split, under
+                    # the very split it was split by; pad slots gather
+                    # junk that every scatter below drops
+                    with jax.named_scope("hist_cache"):
+                        small_h = small_hists[
+                            jnp.clip(s1["p_left"], 0, LB - 1)]
+                elif fused:
                     # per-slot (g, h, cnt) sums = the in-kernel scan's
                     # gain shift; pad slots clip to junk stats whose
                     # candidates are dropped by the scatter below
@@ -846,7 +907,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     node_ids = jnp.concatenate([2 * s1["p_step"] + 1,
                                                 2 * s1["p_step"] + 2])
 
-                    if fused:
+                    if fused_here:
                         # larger children's histograms came from
                         # subtraction, not the kernel — scan them with the
                         # scan-only kernel (same in-VMEM code path, no HBM
@@ -877,7 +938,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         a = allowed & deep_ok
                         if spec.n_ic_groups:
                             a = a & ic_allowed_from_used(feat, lu)
-                        if fused:
+                        if fused_here:
                             sr = split_of_fused(
                                 hist[sl], cand_sl[0], g, h, c, a,
                                 s1["leaf_lb"][sl], s1["leaf_ub"][sl],
@@ -892,7 +953,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         return _split_to_arrays(sr)
 
                     args = (child_slots, node_ids) + \
-                        ((cand_all,) if fused else ())
+                        ((cand_all,) if fused_here else ())
                     res = jax.vmap(eval_child)(*args)
                     return hist, tuple(
                         s1[k].at[child_slots].set(r, mode="drop")
@@ -907,13 +968,96 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             hist, leaf_upd = jax.lax.cond(s1["step"] >= LB - 1, tree_full,
                                           hist_and_find, None)
 
-            new_state = {k: s1[k] for k in carry_keys}
+            new_state = {**st, **{k: s1[k] for k in carry_keys}}
             new_state["hist"] = hist
             for k, v in zip(LEAF_KEYS, leaf_upd):
                 new_state[k] = v
             return new_state
 
-        st = jax.lax.while_loop(cond, body, state)
+        def speculate(st):
+            """The tail's histogram pass, made BEFORE the split it is
+            for.  Every leaf carries its best split, so the rows of its
+            would-be smaller child are known before it is picked, and
+            they stay the same until it is: the pass the best leaf needs
+            also fills the kernel's other W-1 slots with the smaller
+            children of the next-best leaves that have no histogram yet.
+            A slot's sums depend on its own rows only, so each is bit
+            for bit the histogram a pass after the split would build."""
+            with jax.named_scope("partition"):
+                # (slots no leaf has reached yet carry gain -inf; every
+                # leaf of the tail is ready.)  Ties go to the lower leaf,
+                # as in the pick's argmax: the best leaf is always chosen
+                top_gain, top_leaf = jax.lax.top_k(
+                    jnp.where(st["spec_ok"], NEG_INF, st["leaf_gain"]), W)
+                chosen = top_gain > 0.0
+
+                def fill_slot(k, slot_of_row):
+                    # the partition's own routing and the pick's own
+                    # smaller-side rule, under the leaf's stored split
+                    lf = top_leaf[k]
+                    go_left = split_go_left(
+                        spec, feat, bins_fm, decode_bins,
+                        st["leaf_feat"][lf], st["leaf_thr"][lf],
+                        st["leaf_dl"][lf], st["leaf_iscat"][lf],
+                        st["leaf_catmask"][lf])
+                    small_is_left = st["leaf_lc"][lf] <= st["leaf_rc"][lf]
+                    return jnp.where(
+                        chosen[k] & (st["leaf_id"] == lf)
+                        & (go_left == small_is_left), k, slot_of_row)
+
+                # one slot at a time: one [N] routing mask alive, not W
+                slot_of_row = jax.lax.fori_loop(
+                    0, W, fill_slot, jnp.full((N,), -1, jnp.int32))
+            small_h = hist_multi(slot_of_row,
+                                 jnp.arange(W, dtype=jnp.int32))
+            with jax.named_scope("hist_cache"):
+                dst = jnp.where(chosen, top_leaf, LB)
+                return (st["spec_hist"].at[dst].set(small_h, mode="drop"),
+                        st["spec_ok"].at[dst].set(True, mode="drop"),
+                        jnp.sum(chosen, dtype=jnp.int32))
+
+        def tail_body(st):
+            """One split of the strict tail: a histogram pass only if
+            the best leaf's smaller child has no speculated histogram
+            yet (and its children could still be split), then the pick
+            and the children's searches from the cache."""
+            with jax.named_scope("partition"):
+                best = jnp.argmax(st["leaf_gain"])   # the pick's choice
+                # this split fills the tree: its children need no
+                # histogram, whatever the cache holds
+                fills = st["step"] + 1 >= LB - 1
+                held = st["spec_ok"][best]
+                miss = ~held & ~fills
+            spec_hist, spec_ok, n_spec = jax.lax.cond(
+                miss, speculate,
+                lambda st: (st["spec_hist"], st["spec_ok"], jnp.int32(0)),
+                st)
+            # the pick rewrites `leaf_id`, which the pass reads: tie the
+            # pick behind the pass, or XLA keeps both orders open and
+            # copies the [N] ids every split
+            leaf_id, spec_hist = jax.lax.optimization_barrier(
+                (st["leaf_id"], spec_hist))
+            new_state = body({**st, "leaf_id": leaf_id},
+                             small_hists=spec_hist)
+            # the split leaf's two children are new leaves with no entry
+            new_state["spec_hist"] = spec_hist
+            new_state["spec_ok"] = spec_ok.at[best].set(False)
+            new_state["tail_stats"] = st["tail_stats"] + jnp.stack([
+                miss, held & ~fills, held & fills, n_spec])\
+                .astype(jnp.int32)
+            return new_state
+
+        # the waves stop where the strict tail begins
+        st = jax.lax.while_loop(
+            (lambda st: cond(st) & ~in_tail(st)) if tail else cond,
+            body, state)
+        if tail:
+            st = jax.lax.while_loop(cond, tail_body, st)
+            # entries still standing were never used
+            tail_stats = st["tail_stats"].at[2].add(
+                jnp.sum(st["spec_ok"], dtype=jnp.int32))
+        else:
+            tail_stats = jnp.zeros((4,), jnp.int32)
 
         if LB > L:
             with jax.named_scope("prune"):
@@ -930,6 +1074,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 leaf_g=leaves_f["g"], leaf_h=leaves_f["h"],
                 leaf_cnt=leaves_f["c"],
                 leaf_id=leaf_id_f,
+                tail_stats=tail_stats,
                 **nodes_f,
             )
 
@@ -954,6 +1099,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             leaf_g=st["leaf_g"], leaf_h=st["leaf_h"],
             leaf_cnt=st["leaf_c"],
             leaf_id=st["leaf_id"],
+            tail_stats=tail_stats,
         )
 
     return jax.jit(grow)

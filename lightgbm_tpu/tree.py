@@ -61,6 +61,10 @@ class Tree:
         self.leaf_weight = np.zeros(num_leaves, dtype=np.float64)
         self.leaf_count = np.zeros(num_leaves, dtype=np.float64)
         self.shrinkage = 1.0
+        # growth record of a wave-grown tree (DeviceTree.tail_stats):
+        # the strict tail's histogram passes, splits served from a
+        # speculated histogram, speculated histograms unused and made
+        self.tail_stats = None
         self.num_cat = 0
         # categorical split storage (ref: tree.h cat_boundaries_/cat_threshold_)
         self.cat_boundaries: np.ndarray = np.zeros(1, dtype=np.int64)
@@ -94,17 +98,20 @@ class Tree:
         # must never ride along (it is N-sized)
         import jax
         (n_splits_h, split_leaf, feat, thr_bin, dl, is_cat, cat_masks,
-         gains, ig, ih, ic, leaf_value_h, leaf_h_h, leaf_cnt_h) = \
+         gains, ig, ih, ic, leaf_value_h, leaf_h_h, leaf_cnt_h,
+         tail_stats) = \
             jax.device_get((dev.n_splits, dev.split_leaf, dev.split_feature,
                             dev.threshold_bin, dev.default_left,
                             dev.split_is_cat, dev.split_cat_mask,
                             dev.split_gain, dev.internal_g, dev.internal_h,
                             dev.internal_cnt, dev.leaf_value, dev.leaf_h,
-                            dev.leaf_cnt))
+                            dev.leaf_cnt, dev.tail_stats))
         ns = int(n_splits_h)
         nl = ns + 1
         t = cls(nl)
         t.shrinkage = shrinkage
+        if tail_stats is not None:
+            t.tail_stats = tuple(int(v) for v in tail_stats)
         split_leaf = split_leaf[:ns]
         feat = feat[:ns]
         thr_bin = thr_bin[:ns]

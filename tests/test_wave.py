@@ -747,3 +747,175 @@ class TestPhaseScopes:
         assert with_scopes == without_scopes
         from lightgbm_tpu.ops.grow_wave import make_wave_grower
         make_wave_grower.cache_clear()     # no unnamed grower left cached
+
+
+# ------------------------------------------------ the strict tail's schedule
+def _golden_script():
+    """`tests/data/make_wave_tail_goldens.py`: the cases and how each is
+    trained."""
+    import importlib.util
+    import os
+    path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                        "data", "make_wave_tail_goldens.py")
+    spec = importlib.util.spec_from_file_location("wave_tail_goldens", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+_GOLD = _golden_script()
+
+
+@pytest.fixture(scope="module")
+def golden_models():
+    """The models the PARENT of ISSUE 26 dumped for `_GOLD.CASES`."""
+    import json
+    with open(_GOLD.GOLDENS) as fh:
+        return json.load(fh)
+
+
+def _grow_once(X, y, **params):
+    """One tree of the wave grower on a fresh booster: (DeviceTree, LB)."""
+    from lightgbm_tpu.booster import Booster
+    from lightgbm_tpu.ops.grow_wave import wave_sizes
+    bst = Booster(params={"verbosity": -1, "tree_grow_policy": "wave",
+                          "tpu_wave_gain_ratio": 0, "tpu_wave_overgrow": 0,
+                          **params},
+                  train_set=lgb.Dataset(X, label=y))
+    assert bst._grow_policy == "wave"
+    g, h = bst._grad_fn(bst._train_score)
+    dev = bst._grower(bst._train_bins, g.astype(jnp.float32),
+                      h.astype(jnp.float32), bst._ones, bst._feat,
+                      jnp.asarray(bst._dd.base_allowed))
+    return dev, wave_sizes(bst._grower_spec)[0], bst
+
+
+def _tail_splits(dev, LB, tail):
+    """(splits the tree made inside its strict tail, whether the last of
+    them filled the tree) from the schedule's own arithmetic."""
+    n = int(dev.n_splits)
+    before = LB - 1 - min(tail, LB - 1)
+    return max(n - before, 0), n == LB - 1
+
+
+@pytest.mark.quick
+class TestSpeculativeTail:
+    """ISSUE 26: a tail pass speculates the smaller child of up to W
+    frontier leaves, and a split whose child histogram is cached costs no
+    pass.  The schedule changed; the trees must not have."""
+
+    @pytest.mark.parametrize("case", _GOLD.CASES, ids=lambda c: c[0])
+    def test_golden_models(self, case, golden_models):
+        cid, data, leaves, tail, extra = case
+        assert _GOLD.train_case(data, leaves, tail, extra) \
+            == golden_models[cid]
+
+    @pytest.mark.parametrize("leaves,tail,width", [
+        (31, 16, 8), (31, 16, 2), (31, 4, 6), (15, 1000, 4), (8, 1, 6),
+        (63, 32, 8)], ids=lambda v: str(v))
+    def test_every_speculated_histogram_is_accounted(self, leaves, tail,
+                                                     width):
+        X, y = make_binary(2500)
+        dev, LB, _ = _grow_once(X, y, objective="binary", num_leaves=leaves,
+                                tpu_wave_strict_tail=tail,
+                                tpu_wave_width=width, min_data_in_leaf=5)
+        passes, hits, unused, speculated = (int(v) for v in dev.tail_stats)
+        # a speculated slot is the missed leaf its pass was made for, a
+        # later hit, or never used
+        assert speculated == passes + hits + unused
+        assert passes <= speculated <= passes * min(width, LB - 1)
+        # every tail split whose children needed histograms got them from
+        # a pass of its own or from the cache; the split that fills the
+        # tree needs none
+        made, filled = _tail_splits(dev, LB, tail)
+        assert made > 0
+        assert passes + hits == made - int(filled)
+
+    def test_fewer_passes_than_one_per_split(self):
+        """The 31-leaf numerical case: the parent made 15 passes for its
+        16 tail splits."""
+        X, y = make_binary(3000)
+        dev, LB, _ = _grow_once(X, y, objective="binary", num_leaves=31,
+                                tpu_wave_strict_tail=16, tpu_wave_width=8,
+                                min_data_in_leaf=5)
+        passes, hits, _, _ = (int(v) for v in dev.tail_stats)
+        assert _tail_splits(dev, LB, 16) == (16, True)
+        assert passes + hits == 15
+        assert passes < 15 and hits > 0
+
+    def test_chain_tree_never_hits(self):
+        """One feature of 16 values, a target that quadruples from each
+        to the next: the best split always cuts the top value off and
+        only the rest can be split again, so each split's leaf is a
+        child of the split before.  No speculated histogram is ever for
+        another leaf than the missed one, and the passes are the
+        parent's: one per split but the one that fills the tree."""
+        x = (np.arange(4096) % 16).astype(np.float32)
+        dev, LB, _ = _grow_once(x[:, None], 4.0 ** x,
+                                objective="regression", num_leaves=8,
+                                tpu_wave_strict_tail=1000,
+                                min_data_in_leaf=5)
+        n_splits = int(dev.n_splits)
+        assert n_splits == LB - 1 == 7
+        split_leaf = np.asarray(dev.split_leaf)
+        for i in range(1, n_splits):
+            assert split_leaf[i] in (split_leaf[i - 1], i)
+        passes, hits, unused, speculated = (int(v) for v in dev.tail_stats)
+        assert hits == 0
+        assert passes == n_splits - 1
+        assert speculated == passes + unused
+
+    def test_early_stop_drains_the_cache(self):
+        """A tree that runs out of positive gains before it is full
+        decodes as the strict grower's, and leaves nothing speculated
+        behind: a histogram is speculated only for a leaf of positive
+        gain, and the tree stops when no such leaf is left."""
+        X, y = make_binary(400)
+        dumps = {}
+        strip = ("[tree_grow_policy", "[tpu_wave")
+        params = {"objective": "binary", "num_leaves": 31, "verbosity": -1,
+                  "min_data_in_leaf": 40, "tpu_wave_overgrow": 0}
+        for pol, extra in (("leafwise", {}),
+                           ("wave", {"tpu_wave_strict_tail": 1000,
+                                     "tpu_wave_gain_ratio": 0})):
+            bst = lgb.train({**params, "tree_grow_policy": pol, **extra},
+                            lgb.Dataset(X, label=y), num_boost_round=4)
+            assert bst._grow_policy == pol
+            assert all(1 < t.num_leaves < 31 for t in bst.trees)
+            dumps[pol] = ("\n".join(
+                ln for ln in bst.model_to_string().splitlines()
+                if not ln.startswith(strip)), bst.predict(X), bst)
+        assert dumps["leafwise"][0] == dumps["wave"][0]
+        np.testing.assert_array_equal(dumps["leafwise"][1],
+                                      dumps["wave"][1])
+        for t in dumps["wave"][2].trees:
+            passes, hits, unused, speculated = t.tail_stats
+            assert unused == 0 and hits > 0
+            # no split filled the tree: each got its children's histograms
+            assert passes + hits == speculated == t.num_internal()
+        assert all(t.tail_stats is None for t in dumps["leafwise"][2].trees)
+
+    def test_counters_follow_the_trees(self):
+        """`grow.tail_passes` / `grow.tail_spec_hits` /
+        `grow.tail_spec_unused` grow at decode by what each tree
+        reports, on the per-round path and the fused chunk's alike."""
+        from lightgbm_tpu import telemetry
+        names = ("grow.tail_passes", "grow.tail_spec_hits",
+                 "grow.tail_spec_unused")
+
+        def read():
+            return [telemetry.REGISTRY.counter(k).value for k in names]
+
+        X, y = make_binary(1500)
+        bst = lgb.Booster(params={"objective": "binary", "num_leaves": 15,
+                                  "verbosity": -1,
+                                  "tree_grow_policy": "wave"},
+                          train_set=lgb.Dataset(X, label=y))
+        before = read()
+        for _ in range(2):
+            bst.update()
+        bst.update_many(bst._BULK_CHUNK)
+        assert len(bst.trees) == 2 + bst._BULK_CHUNK
+        want = np.sum([t.tail_stats[:3] for t in bst.trees], axis=0)
+        assert want[0] > 0
+        np.testing.assert_array_equal(np.subtract(read(), before), want)

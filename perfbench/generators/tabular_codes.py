@@ -1,24 +1,35 @@
-"""Rows of a tabular data set, generated in code space from a seed.
+"""Rows of a tabular data set, generated in code space from two seeds.
 
 A column is a small integer code `0 .. cardinality-1` (a calendar field, a
 carrier id, a binned time), drawn uniformly.  The label is Bernoulli with
 log-odds `scale * z(row) + offset`, where `z` is a fixed non-linear
 function of the codes: one effect table per column plus products of two
 tables for the listed pairs.  The tables depend on the configuration's
-`function_seed` only, so every `--seed` trains against the SAME function
-on different rows: runs of different seeds do the same work and reach the
-same quality to sampling noise.
+`function_seed` only.
+
+The TRAINING rows are the configuration's, not the run's: they are the
+stream of `population_seed`, so every run of a configuration trains on the
+same rows against the same function, grows the same trees and does the
+same work (NVIDIA/gbm-bench trains on one airline file).  How many
+histogram passes a tree costs depends on the order its leaves split in;
+rows that changed with `--seed` made the rate a property of the seed.
+What `--seed` draws is the HOLD-OUT rows (and, in the reference, the nodes
+that are checked): the quality is scored on rows that differ from run to
+run and that no run has trained on.
 
 Rows come in fixed chunks of `CHUNK` rows, each from its own generator
 keyed by (seed, chunk index); the stream does not depend on how many
-threads fill it.  Hold-out rows are the chunks that follow the training
-rows, so they are generated with the training rows and never trained on.
+threads fill it.  Training rows are chunks `0 ..` of the population's
+stream; hold-out rows are the chunks of the `--seed` stream AFTER as many
+chunks as the training rows take, so a hold-out chunk is never a training
+chunk, also where the two seeds are equal.
 
 Parameters (the configuration file's `data` object):
-  columns        [{"name", "cardinality", "effect": "smooth"|"iid", "weight"}]
-  pairs          [[column, column, weight], ...]
-  function_seed  seed of the effect tables
-  scale, offset  log-odds = scale * z + offset
+  columns          [{"name", "cardinality", "effect": "smooth"|"iid", "weight"}]
+  pairs            [[column, column, weight], ...]
+  function_seed    seed of the effect tables
+  population_seed  seed of the training rows
+  scale, offset    log-odds = scale * z + offset
 """
 from __future__ import annotations
 
@@ -131,9 +142,10 @@ def generate(seed: int, data: dict, first_row: int, n_rows: int,
 
 def make(seed: int, data: dict, train_rows: int, holdout_rows: int
          ) -> Dict[str, np.ndarray]:
-    """The cell's training and hold-out rows."""
+    """The cell's training rows, from `data["population_seed"]`, and the
+    run's hold-out rows, from `seed`."""
     hold_first = -(-train_rows // CHUNK) * CHUNK
-    codes, label = generate(seed, data, 0, train_rows)
+    codes, label = generate(int(data["population_seed"]), data, 0, train_rows)
     hcodes, hlabel = generate(seed, data, hold_first, holdout_rows)
     return {"codes": codes, "label": label,
             "holdout_codes": hcodes, "holdout_label": hlabel}

@@ -1,6 +1,8 @@
 """The `train` job: closed-loop boosting rounds through `Booster.update()`.
 
-Set-up (counted as `setup_s`): rows from the seed in code space, bin
+Set-up (counted as `setup_s`): the configuration's training rows
+(`data.population_seed`: the same in every run, so every run grows the same
+trees) and the hold-out rows of `--seed`, both in code space, bin
 mappers fitted through the public `Dataset(...).construct()` on a sample,
 the whole matrix handed to a constructed `Dataset` the way
 `Dataset.load_binary` builds one, the booster, `warmup_rounds` rounds.
@@ -14,6 +16,7 @@ the plain reference follows the first `check_rounds` trees
 from __future__ import annotations
 
 import gc
+import hashlib
 import json
 import math
 import time
@@ -84,15 +87,17 @@ def build_dataset(lgb, codes: np.ndarray, label: np.ndarray, params: dict,
 
 def make_inputs(lgb, config: dict, seed: int, holdout_rows: int,
                 say: Callable[[str], None] = lambda msg: None):
-    """The cell's rows from the seed and the constructed data set over the
-    training rows: (rows, data set, the program's parameters)."""
+    """The configuration's training rows, the hold-out rows of `seed`, and
+    the constructed data set over the training rows: (rows, data set, the
+    program's parameters)."""
     data = config["data"]
     gen = load_module("generators", config["generator"])
     t = time.perf_counter()
     rows = gen.make(seed, data, int(config["train_rows"]), holdout_rows)
     say(f"setup: rows made in {time.perf_counter() - t:.2f} s "
-        f"({rows['codes'].shape[1]} x {rows['codes'].shape[0]}, label mean "
-        f"{rows['label'].mean():.4f})")
+        f"({rows['codes'].shape[1]} x {rows['codes'].shape[0]} of population "
+        f"{data['population_seed']}, label mean {rows['label'].mean():.4f}; "
+        f"{rows['holdout_codes'].shape[1]} hold-out rows of seed {seed})")
     params = dict(config["params"])
     t = time.perf_counter()
     ds = build_dataset(lgb, rows["codes"], rows["label"], params,
@@ -246,6 +251,9 @@ def run(ctx) -> Dict[str, Any]:
     check_rounds = int(traffic["check_rounds"])
     ref = load_module("reference", config["reference"])
     dump = booster.dump_model(num_iteration=check_rounds)
+    say(f"check: the first {len(dump['tree_info'])} tree(s) as dumped, sha256 "
+        + hashlib.sha256(json.dumps(dump["tree_info"], sort_keys=True)
+                         .encode()).hexdigest())
     trees = [ref.tree_from_dump(t) for t in dump["tree_info"]]
     ctx.alter_trees(trees)
     # free the program's state before the reference takes the device

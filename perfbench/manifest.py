@@ -41,10 +41,31 @@ def workload(name: str, bench_dir: str = HERE) -> dict:
     return _load(os.path.join(bench_dir, "workloads", name + ".json"))
 
 
+def no_population(body: dict) -> str:
+    """Why a configuration's file names no training population, or ""."""
+    seed = body.get("data", {}).get("population_seed")
+    if isinstance(seed, int) and not isinstance(seed, bool) and seed >= 0:
+        return ""
+    return ("data.population_seed is missing or no whole number: the "
+            "training rows are the configuration's, the same in every run, "
+            "and --seed is no fall-back for them")
+
+
 def config(name: str, bench_dir: str = HERE) -> dict:
     if not NAME.match(name):
         raise ValueError(f"not a config name: {name!r}")
-    return _load(os.path.join(bench_dir, "configs", name + ".json"))
+    body = _load(os.path.join(bench_dir, "configs", name + ".json"))
+    why = no_population(body)
+    if why:
+        raise ValueError(f"config {name}: {why}")
+    return body
+
+
+def with_population(config: dict, population_seed: int) -> dict:
+    """The configuration trained on another population's rows (a builder's
+    `--population-seed`; the driver's runs never ask for it)."""
+    data = dict(config["data"], population_seed=int(population_seed))
+    return dict(config, data=data)
 
 
 def layer_metrics(cell: str, bench_dir: str = HERE) -> List[dict]:
@@ -123,6 +144,9 @@ def problems(root: str = ROOT, bench_dir: str = HERE) -> List[str]:
             bad.append(f"config {c['name']}: reduced differs from its file")
         if len(c["reduced"]) > 16:
             bad.append(f"config {c['name']}: more than 16 reduced keys")
+        why = no_population(body)
+        if why:
+            bad.append(f"config {c['name']}: {why}")
 
     e2e = {m["name"]: m for m in b["end_to_end"]}
     cells = {}

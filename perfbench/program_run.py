@@ -2,7 +2,7 @@
 never run by the benchmark.
 
     python3 -m perfbench.program_run --workload <cell> --seed <n> \\
-        --seconds <s> --trace <0|1> [--save DIR]
+        --seconds <s> --trace <0|1> [--save DIR] [--population-seed <n>]
 
 It is `perfbench.run` with three additions, which are what a `benchmark` PR
 would write into `jobs/train.py`, `run.py` and `readers.py` to make the
@@ -27,8 +27,11 @@ those files; PERF.md, Open questions, lists the lines):
 
 `--save DIR` keeps the trace (gzipped) and the program's record
 (`program.json`) for reduction off the chip (`reduce_saved`) and for test
-fixtures.  After the run the rows that `hist.useful_row_pct` counted are
-checked against a count from the dumped trees' leaf counts.
+fixtures.  `--population-seed`, like every other argument of
+`perfbench.run`, is handed on to it.  After the run the rows that
+`hist.useful_row_pct` counted over the window are checked against a count
+from the leaf counts of the window's own trees, every one of them, dumped
+before the program's state is freed.
 """
 from __future__ import annotations
 
@@ -155,16 +158,27 @@ def main(argv: Optional[List[str]] = None,
 
     record = telemetry.TRACER.add_sink(Record())
     install_compile_listener()
-    warmup = int(manifest.workload(own.workload, own.bench_dir)
-                 ["traffic_params"]["warmup_rounds"])
+    cell = manifest.workload(own.workload, own.bench_dir)
+    warmup = int(cell["traffic_params"]["warmup_rounds"])
+    ref = manifest.load_module("reference", manifest.config(
+        cell["config"], own.bench_dir)["reference"])
     by_counts: List[float] = []
-    alter = hooks.alter_trees
+    made: List[Any] = []            # the run's booster, until its trees are read
+    make, alter = hooks.make_booster, hooks.alter_trees
+
+    def make_booster(lgb, params, ds):
+        made.append(make(lgb, params, ds))
+        return made[-1]
 
     def alter_trees(trees):
-        by_counts.extend(rows_needed_by_counts(t) for t in trees)
+        # every tree made so far, not the few the reference follows; the
+        # booster is let go here, before the job frees the program's state
+        dumped = made.pop().dump_model()["tree_info"]
+        by_counts.extend(rows_needed_by_counts(ref.tree_from_dump(t))
+                         for t in dumped)
         alter(trees)
 
-    hooks.alter_trees = alter_trees
+    hooks.make_booster, hooks.alter_trees = make_booster, alter_trees
     inner = run.per_layer
 
     def per_layer(cell_name, result, device_kind, bench_dir):

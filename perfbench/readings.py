@@ -3,7 +3,9 @@
     python3 -m perfbench.readings --workload <cell> --seeds 1,2,3 \
         [--control-seeds 1,2] [--out chiprun_out/readings.jsonl]
 
-For each seed, at the cell's own size: the rows, the booster, the cell's
+For each seed, at the cell's own size: the rows of THAT seed as the training
+population (a limit is set from many populations' trees, not from the one
+the configuration names), the booster, the cell's
 `check_rounds` rounds through `Booster.update()` (no measured window: a
 training cell's readings need none), then with the program's state freed
 the plain reference follows the trees, and the three compared numbers are
@@ -62,7 +64,8 @@ def main(argv=None) -> int:
 
     for seed in seeds:
         t0 = time.perf_counter()
-        rows, ds, params = make_inputs(lgb, config, seed, 1)
+        rows, ds, params = make_inputs(
+            lgb, manifest.with_population(config, seed), seed, 1)
         booster = lgb.Booster(params=params, train_set=ds)
         t_rounds = []
         for _ in range(n_rounds):

@@ -2,7 +2,11 @@
 
     python3 -m perfbench.run --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
-from the root of a checkout.  Finds `perfbench/workloads/<name>.json`, its
+from the root of a checkout.  `--seed` draws the hold-out rows and the
+nodes the reference checks; the training rows are the configuration's
+(`data.population_seed`), so every run grows the same trees.  A builder may
+add `--population-seed <n>` to train on another population; the driver
+never does.  Finds `perfbench/workloads/<name>.json`, its
 configuration and (with `--trace 1`) every per-layer metric that lists the
 cell; exits 2 before measuring anything where JAX finds no TPU or fewer
 chips than the cell asks for.  The last line of standard output is one JSON
@@ -93,6 +97,10 @@ def main(argv: Optional[List[str]] = None, hooks: Optional[SimpleNamespace]
     ap.add_argument("--seed", type=int, required=True)
     ap.add_argument("--seconds", type=float, required=True)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--population-seed", type=int, default=None,
+                    help="train on this population's rows and not on the "
+                         "configuration's (a builder's check that a gain "
+                         "holds on other trees; the driver never passes it)")
     ap.add_argument("--bench-dir", default=bench_dir,
                     help="where workloads/, configs/ and layer_metrics/ "
                          "are (tests and fixtures; default perfbench/)")
@@ -102,6 +110,8 @@ def main(argv: Optional[List[str]] = None, hooks: Optional[SimpleNamespace]
 
     cell = manifest.workload(args.workload, bench_dir)
     config = manifest.config(cell["config"], bench_dir)
+    if args.population_seed is not None:
+        config = manifest.with_population(config, args.population_seed)
     job = manifest.load_module("jobs", cell["job"])
 
     if manifest.ROOT not in sys.path:
@@ -112,9 +122,11 @@ def main(argv: Optional[List[str]] = None, hooks: Optional[SimpleNamespace]
         print(f"perfbench: the program is not in this checkout ({e}); "
               "nothing was run", file=sys.stderr, flush=True)
         return 3
+    t_imported = time.perf_counter()
     import jax
     import jaxlib
     devs = jax.devices()
+    t_devices = time.perf_counter()
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs)}
     if hooks.require_chip and (device["platform"] != "tpu"
@@ -133,7 +145,10 @@ def main(argv: Optional[List[str]] = None, hooks: Optional[SimpleNamespace]
         f"count={device['count']}")
     say(f"versions: jax={jax.__version__} jaxlib={jaxlib.__version__}")
     say(f"compile cache: {cache_dir} entries_before={n_cache}")
+    say(f"setup: the program imported in {t_imported - T0:.2f} s, the "
+        f"device found in {t_devices - t_imported:.2f} s more")
     say(f"cell: {args.workload} config={cell['config']} seed={args.seed} "
+        f"population_seed={config['data']['population_seed']} "
         f"seconds={args.seconds} trace={args.trace}")
 
     ctx = SimpleNamespace(

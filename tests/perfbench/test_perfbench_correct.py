@@ -23,11 +23,12 @@ BENCH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 CELL = "tiny13-l31.train"
 
 
-def drive(capsys, hooks, seed=20260930):
+def drive(capsys, hooks, seed=20260930, more=()):
     hooks.require_chip = False
     hooks.compile_cache = False
     rc = run.main(["--workload", CELL, "--seed", str(seed), "--seconds",
-                   "0.1", "--trace", "0", "--bench-dir", BENCH], hooks=hooks)
+                   "0.1", "--trace", "0", "--bench-dir", BENCH, *more],
+                  hooks=hooks)
     out = capsys.readouterr()
     assert rc == 0
     line = json.loads(out.out.strip().splitlines()[-1])
@@ -48,6 +49,50 @@ def test_the_program_as_it_is_is_correct(capsys):
     assert 0.5 < line["metrics"]["holdout_auc"]["value"] < 1.0
     assert set(line["device"]) >= {"platform", "kind", "count",
                                    "memory_peak_bytes"}
+
+
+def drive_and_keep_trees(capsys, seed, more=()):
+    """(the result's line, the trees that were compared, as bytes)."""
+    hooks, kept = run.default_hooks(), []
+    hooks.alter_trees = kept.extend
+    line = drive(capsys, hooks, seed, more)
+    assert line["correct"] is True and len(kept) == 3
+    return line, [tuple(np.asarray(x).tobytes() for x in t) for t in kept]
+
+
+@pytest.fixture(scope="module")
+def population_of_the_config():
+    config = manifest.config(manifest.workload(CELL, BENCH)["config"], BENCH)
+    return config["data"]["population_seed"]
+
+
+def test_two_seeds_grow_the_same_trees_and_score_other_rows(
+        capsys, population_of_the_config):
+    """`--seed` draws the hold-out rows and the checked nodes; the trees,
+    and so the work of a round, are the configuration's."""
+    a, trees_a = drive_and_keep_trees(capsys, population_of_the_config)
+    b, trees_b = drive_and_keep_trees(capsys, 2 ** 31 + 777)
+    assert trees_a == trees_b
+    assert a["metrics"]["holdout_auc"]["value"] \
+        != b["metrics"]["holdout_auc"]["value"]
+    assert abs(a["metrics"]["holdout_auc"]["value"]
+               - b["metrics"]["holdout_auc"]["value"]) < 0.05
+
+
+def test_population_seed_overrides_the_configs(capsys,
+                                               population_of_the_config):
+    """A builder's `--population-seed`: other trees on the same hold-out
+    rows; given the configuration's own value it changes nothing."""
+    seed = 2 ** 31 + 777
+    own, trees = drive_and_keep_trees(capsys, seed)
+    same, trees_same = drive_and_keep_trees(
+        capsys, seed, ["--population-seed", str(population_of_the_config)])
+    other, trees_other = drive_and_keep_trees(
+        capsys, seed, ["--population-seed", "77"])
+    assert trees_same == trees and trees_other != trees
+    assert same["metrics"]["holdout_auc"] == own["metrics"]["holdout_auc"]
+    assert same["compared"] == own["compared"]
+    assert other["metrics"]["holdout_auc"] != own["metrics"]["holdout_auc"]
 
 
 def test_fault_a_step_that_leaves_its_state_unchanged(capsys):

@@ -103,6 +103,52 @@ def test_a_broken_manifest_is_caught(checkout, what):
     assert manifest.problems(str(root), str(bench)) != [], what
 
 
+NO_POPULATION = {
+    "left out": lambda data: data.pop("population_seed"),
+    "a string": lambda data: data.update(population_seed="20260930"),
+    "negative": lambda data: data.update(population_seed=-1),
+    "a fraction": lambda data: data.update(population_seed=1.5),
+    "true": lambda data: data.update(population_seed=True),
+}
+
+
+@pytest.mark.parametrize("what", sorted(NO_POPULATION))
+def test_a_config_without_a_population_is_refused_by_name(checkout, what):
+    """The training rows are the configuration's: a file that names no
+    `data.population_seed` is an error, never a fall-back to `--seed`."""
+    root, bench = checkout
+    name = manifest.benchmark()["configs"][0]["name"]
+    assert manifest.config(name, str(bench))["data"]["population_seed"] >= 0
+    path = bench / "configs" / (name + ".json")
+    body = json.load(open(path))
+    NO_POPULATION[what](body["data"])
+    json.dump(body, open(path, "w"))
+    with pytest.raises(ValueError, match=f"config {name}: "
+                                         "data.population_seed"):
+        manifest.config(name, str(bench))
+    assert any(name in p and "population_seed" in p
+               for p in manifest.problems(str(root), str(bench)))
+
+
+def test_every_config_file_names_its_population():
+    """The parked configurations too: the PR that brings one in inherits it."""
+    d = os.path.join(manifest.HERE, "configs")
+    names = [fn[:-5] for fn in sorted(os.listdir(d)) if fn.endswith(".json")]
+    assert len(names) >= 2
+    for name in names:
+        assert manifest.no_population(manifest.config(name)) == ""
+
+
+def test_with_population_replaces_the_seed_and_nothing_else():
+    config = manifest.config(manifest.benchmark()["configs"][0]["name"])
+    kept = json.dumps(config, sort_keys=True)
+    other = manifest.with_population(config, 77)
+    assert other["data"]["population_seed"] == 77
+    assert json.dumps(config, sort_keys=True) == kept       # not in place
+    other["data"]["population_seed"] = config["data"]["population_seed"]
+    assert json.dumps(other, sort_keys=True) == kept
+
+
 def test_a_second_four_chip_cell_of_two_is_caught(checkout):
     """At most a quarter of the cells, and one always, may ask for four."""
     root, bench = checkout

@@ -294,3 +294,45 @@ def test_rows_needed_by_counts_on_a_tree_worked_by_hand():
     stump = gbdt.TreeArrays(*(np.zeros(0),) * 4, np.zeros(1),
                             np.array([7.0]), np.zeros(0), np.zeros(1))
     assert program_run.rows_needed_by_counts(stump) == 7.0
+
+
+def test_the_row_check_counts_every_tree_of_the_window(capsys, tmp_path):
+    """`program_run` on the CPU, the fixture cell, a window of more rounds
+    than the reference follows: the rows the program counted over the
+    window equal the count from the leaf counts of ALL the window's trees
+    (the check used to see the followed trees only: 2 of a 4-round window)."""
+    import re
+    import jax
+    from perfbench import run
+    bench = str(tmp_path / "bench")
+    shutil.copytree(os.path.join(HERE, "fixtures", "bench"), bench)
+    cell = "tiny13-l31.train"
+    path = os.path.join(bench, "workloads", cell + ".json")
+    with open(path) as f:
+        body = json.load(f)
+    followed = body["traffic_params"]["check_rounds"]
+    body["traffic_params"]["min_window_rounds"] = 5
+    with open(path, "w") as f:
+        json.dump(body, f)
+    hooks = run.default_hooks()
+    hooks.require_chip = hooks.compile_cache = False
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, flag)
+    try:
+        rc = program_run.main(
+            ["--workload", cell, "--seed", "5", "--seconds", "0.1",
+             "--trace", "1", "--bench-dir", bench],
+            hooks=hooks)
+    finally:
+        jax.config.update(flag, before)
+    out = capsys.readouterr().out
+    assert rc == 0
+    line = json.loads(out.strip().splitlines()[-1])
+    assert line["correct"] is True and line["attempted"] >= 5 > followed
+    said = re.search(r"program: grow\.hist_rows_needed over the window = "
+                     r"(\d+); from the dumped leaf counts of (\d+) tree\(s\) "
+                     r"= (\d+)", out)
+    assert said, out[-2000:]
+    counted, trees, by_counts = map(int, said.groups())
+    assert trees == line["attempted"]
+    assert counted == by_counts > 0

@@ -768,7 +768,7 @@ def main_kernel() -> None:
     devs = _require_tpu()
 
     from lightgbm_tpu.ops import pallas_hist as ph
-    from lightgbm_tpu.ops.histogram import (leaf_histogram_multi,
+    from lightgbm_tpu.ops.histogram import (hist_value, leaf_histogram_multi,
                                             leaf_histogram_packed_multi)
     from lightgbm_tpu.ops.split import (decide_from_candidates,
                                         find_best_split)
@@ -823,7 +823,7 @@ def main_kernel() -> None:
 
     def p_pallas(b, p, l, par):
         h = ph.pallas_histogram_multi_rows(b, p, l, slots, mb)
-        return h, scan_of(h, par)
+        return h, scan_of(hist_value(h), par)
 
     def p_pallas_q(b, p, l, par):
         h = ph.pallas_histogram_multi_quantized_rows(

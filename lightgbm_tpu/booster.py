@@ -236,15 +236,18 @@ def _probe_why(res) -> str:
 
 def _count_growth(tree: Tree) -> None:
     """What growing `tree` cost, onto the always-on counters: the rows its
-    histograms needed, and (wave grower) its strict tail's passes, splits
-    served from a speculated histogram, and speculated ones left unused."""
+    histograms needed, its leaves, and (wave grower) the waves' passes and
+    its strict tail's passes, splits served from a speculated histogram,
+    and speculated ones left unused: passes a tree = 1 + waves + tail."""
     counter = telemetry.REGISTRY.counter
     counter("grow.hist_rows_needed").inc(tree.hist_rows_needed())
+    counter("grow.leaves").inc(tree.num_leaves)
     if tree.tail_stats is not None:
-        passes, hits, unused, _ = tree.tail_stats
+        passes, hits, unused, _, waves = tree.tail_stats
         counter("grow.tail_passes").inc(passes)
         counter("grow.tail_spec_hits").inc(hits)
         counter("grow.tail_spec_unused").inc(unused)
+        counter("grow.wave_passes").inc(waves)
 
 
 @jax.jit

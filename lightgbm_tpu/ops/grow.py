@@ -39,9 +39,11 @@ import jax
 import jax.numpy as jnp
 
 from ..analysis.contracts import contract
-from .histogram import leaf_histogram, ring_ordered_sum
-from .split import NEG_INF, SplitResult, find_best_split, leaf_output, \
-    smooth_output
+from .histogram import (hist_stream_finalize, hist_stream_init,
+                        hist_stream_update, hist_sub, hist_value,
+                        leaf_histogram_limbs, ring_fold, ring_ordered_sum)
+from .split import NEG_INF, SplitResult, bin_goes_left, find_best_split, \
+    leaf_output, refine_child_sums, smooth_output
 
 Array = jax.Array
 
@@ -180,9 +182,10 @@ class DeviceTree(NamedTuple):
     leaf_h: Array         # [L] f32
     leaf_cnt: Array       # [L] f32
     leaf_id: Array        # [N] i32 — final row→leaf assignment (train rows)
-    # [4] i32, wave grower only (None elsewhere) — its strict tail's
-    # histogram passes, splits served from a speculated histogram, and
-    # speculated histograms never used / made (ops/grow_wave.py)
+    # [5] i32, wave grower only (None elsewhere) — its strict tail's
+    # histogram passes, splits served from a speculated histogram,
+    # speculated histograms never used / made, and the histogram passes
+    # of the waves before the tail (ops/grow_wave.py)
     tail_stats: Array = None
 
 
@@ -337,20 +340,16 @@ def make_node_samplers(spec: GrowerSpec, feat: Dict[str, Array], F: int):
 
 def split_go_left(spec: GrowerSpec, feat: Dict[str, Array], bins_fm: Array,
                   decode_bins, f, t, dl, node_cat, node_mask) -> Array:
-    """[N] left/right routing of one applied split (bundled decode +
-    missing handling + the categorical mask gather, which is gated behind
-    `lax.cond` — the [MB]-table gather at N indices is ~7 ms per split at
-    1M rows on TPU, VMEM-read bound, so it only runs for cat splits)."""
+    """[N] left/right routing of one applied split: the bundled decode,
+    then `split.bin_goes_left` on the rows' bins of the split column."""
     if spec.bundled:
         fbins = decode_bins(bins_fm, f)
     else:
         fbins = jnp.take(bins_fm, f, axis=0).astype(jnp.int32)
-    is_nan_bin = (feat["missing"][f] == 2) & (fbins == feat["nb"][f] - 1)
-    go_left_num = jnp.where(is_nan_bin, dl, fbins <= t)
     if spec.has_cat:
-        return jax.lax.cond(node_cat, lambda: node_mask[fbins],
-                            lambda: go_left_num)
-    return go_left_num
+        return bin_goes_left(fbins, feat["nb"][f], feat["missing"][f], t, dl,
+                             node_cat, node_mask)
+    return bin_goes_left(fbins, feat["nb"][f], feat["missing"][f], t, dl)
 
 
 def child_bounds_basic(mono_f, l_sm, r_sm, lb, ub):
@@ -610,16 +609,13 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
             # local rows onto the carry received from shard t-1, so the
             # scatter-add sequence is exactly the serial one-pass order
             # over rows 0..num_data.  Pad rows (weight 0, absent from the
-            # serial program) key to a dropped extra column instead of
-            # adding a bit-flipping +0.0 to live cells.  On the Pallas
-            # families the kernel stays the histogram: its per-shard
+            # serial program) carry leaf -1 and key to the dropped slot
+            # instead of adding a bit-flipping +0.0 to live cells.  On the
+            # Pallas families the kernel stays the histogram: its per-shard
             # partials are chained in shard order instead
             # (`ring_ordered_sum`) — fixed order, not bitwise serial.
             row0_g = jax.lax.axis_index(axis_last) * N
             det_valid = row0_g + jnp.arange(N) < num_data
-            det_cols = jnp.where(det_valid[None, :],
-                                 hist_bins.astype(jnp.int32), HB)
-            det_perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
             kernel_fam = spec.hist_impl in ("pallas", "pallas_q")
             packed_fam = spec.hist_impl == "packed"
             if packed_fam:
@@ -644,48 +640,23 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
                             feat["qscales"][0], feat["qscales"][1],
                             const_hess_level=chl)
 
-                    recv = hist_stream_packed_init(Fh, 1, HB, chl)
-                    mine = recv
-                    # ring_fold scope: the hop-by-hop ppermute chain is
-                    # what the host-side mesh.collective.ring_fold events
-                    # (parallel/learner.py dispatch) attribute — same
-                    # name on both timelines (ISSUE 16)
-                    with jax.named_scope("ring_fold"):
-                        for t in range(n_shards):
-                            mine = fold(recv)
-                            if t < n_shards - 1:
-                                recv = {k: jax.lax.ppermute(v, axis_last,
-                                                            det_perm)
-                                        for k, v in mine.items()}
-                        full = {k: jax.lax.all_gather(
-                                    v, axis_last)[n_shards - 1]
-                                for k, v in mine.items()}
+                    full = ring_fold(
+                        fold, hist_stream_packed_init(Fh, 1, HB, chl),
+                        axis_last, n_shards)
                     h = hist_stream_packed_finalize(
                         full, Fh, 1, HB, feat["qscales"][0],
                         feat["qscales"][1], const_hess_level=chl)[0]
                 else:
-                    d_full = jnp.where(mask_rows[:, None], payload, 0.0)
+                    lid = jnp.where(mask_rows & det_valid, 0, -1)\
+                        .astype(jnp.int32)
 
                     def fold(acc):
-                        def channel(a_c, vals):
-                            return jax.vmap(
-                                lambda a_f, col: a_f.at[col].add(vals))(
-                                    a_c, det_cols)
-                        return jnp.stack([channel(acc[c], d_full[:, c])
-                                          for c in range(3)])
+                        return hist_stream_update(acc, hist_bins, payload,
+                                                  lid, one_slot, HB)
 
-                    recv = jnp.zeros((3, Fh, HB + 1), jnp.float32)
-                    mine = recv
-                    with jax.named_scope("ring_fold"):
-                        for t in range(n_shards):
-                            mine = fold(recv)
-                            if t < n_shards - 1:
-                                recv = jax.lax.ppermute(mine, axis_last,
-                                                        det_perm)
-                        full = jax.lax.all_gather(
-                            mine, axis_last)[n_shards - 1]
-                    h = jnp.stack([full[0], full[1], full[2]],
-                                  axis=-1)[:, :HB]
+                    full = ring_fold(fold, hist_stream_init(Fh, 1, HB),
+                                     axis_last, n_shards)
+                    h = hist_stream_finalize(full, Fh, 1, HB)[0]
                 if mode == "data_rs":
                     Fb_h = h.shape[0] // n_shards
                     h = jax.lax.dynamic_slice_in_dim(
@@ -711,7 +682,8 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
                         feat["qscales"][0], feat["qscales"][1],
                         const_hess_level=spec.packed_const_hess_level)
                 else:
-                    h = leaf_histogram(hist_bins, payload, mask_rows, HB)
+                    h = leaf_histogram_limbs(hist_bins, payload, mask_rows,
+                                             HB)
                 if axis_name is not None:
                     if mode == "data":
                         h = jax.lax.psum(h, axes_all)
@@ -748,8 +720,9 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
                 # by votes are elected, and only THOSE histograms are
                 # summed across shards — O(2k·MB) traffic instead of
                 # O(F·MB)
-                ltot = hist.sum(axis=1)[0]            # local (g, h, cnt)
-                fg = find_local_vote(hist, ltot[0], ltot[1], ltot[2],
+                local = hist_value(hist)
+                ltot = local.sum(axis=1)[0]           # local (g, h, cnt)
+                fg = find_local_vote(local, ltot[0], ltot[1], ltot[2],
                                      bfeat["nb"], bfeat["missing"],
                                      bfeat["default"], node_allowed,
                                      bfeat["is_cat"], mono=bmono)
@@ -766,7 +739,8 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
                 node_allowed = node_allowed & \
                     jnp.zeros((F,), bool).at[elected].set(True)
             if spec.bundled:
-                hist = expand_bundled(hist, g, h, c)
+                # bundle columns expand to features by value: no limbs
+                hist = expand_bundled(hist_value(hist), g, h, c)
             if block:
                 node_allowed = jax.lax.dynamic_slice_in_dim(
                     node_allowed, offset, Fb, axis=0)
@@ -776,11 +750,12 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
                 if penalty is not None:
                     penalty = jax.lax.dynamic_slice_in_dim(
                         penalty, offset, Fb, axis=0)
-            s = find(hist, g, h, c, bfeat["nb"], bfeat["missing"],
-                     bfeat["default"], node_allowed, bfeat["is_cat"],
-                     mono=bmono, out_lb=lb, out_ub=ub,
+            s = find(hist_value(hist), g, h, c, bfeat["nb"],
+                     bfeat["missing"], bfeat["default"], node_allowed,
+                     bfeat["is_cat"], mono=bmono, out_lb=lb, out_ub=ub,
                      parent_output=p_out, cand_mask=cand_mask,
                      gain_penalty=penalty)
+            s = refine_child_sums(s, hist, bfeat["nb"], bfeat["missing"])
             if block:
                 s = rebase_and_merge_block_split(s, offset, axis_last,
                                                  n_shards)
@@ -1097,7 +1072,7 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
             left_smaller = lc <= rc
             small_leaf = jnp.where(left_smaller, best, new)
             small_hist = hist_of(leaf_id == small_leaf)
-            large_hist = parent_hist - small_hist
+            large_hist = hist_sub(parent_hist, small_hist)
             lhist = jnp.where(left_smaller, small_hist, large_hist)
             rhist = jnp.where(left_smaller, large_hist, small_hist)
             if pooled:

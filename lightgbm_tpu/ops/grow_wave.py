@@ -100,9 +100,11 @@ from .histogram import (hist_stream_finalize, hist_stream_init,
                         hist_stream_packed_finalize,
                         hist_stream_packed_init,
                         hist_stream_packed_update, hist_stream_update,
-                        leaf_histogram_multi, leaf_histogram_packed_multi,
+                        hist_sub, hist_value, leaf_histogram_multi_limbs,
+                        leaf_histogram_packed_multi, ring_fold,
                         ring_ordered_sum)
 from .split import (NEG_INF, decide_from_candidates, find_best_split,
+                    refine_child_sums,
                     leaf_output, merge_split_results, smooth_output)
 
 Array = jax.Array
@@ -300,8 +302,6 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 interpret=spec.hist_interpret)
 
         if det:
-            det_perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
-
             def det_hist_multi(leaf_id, slots):
                 """Ring-chained deterministic wave histogram.  On the XLA
                 families it is bitwise the serial `hist_multi` (pad rows
@@ -325,21 +325,9 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                             feat["qscales"][0], feat["qscales"][1],
                             const_hess_level=chl)
 
-                    recv = hist_stream_packed_init(Fh, S, HB, chl)
-                    mine = recv
-                    # ring_fold scope pairs the device trace with the
-                    # host-side mesh.collective.ring_fold dispatch events
-                    # (ISSUE 16 per-device collective timeline)
-                    with jax.named_scope("ring_fold"):
-                        for t in range(n_shards):
-                            mine = fold(recv)
-                            if t < n_shards - 1:
-                                recv = {k: jax.lax.ppermute(v, axis_last,
-                                                            det_perm)
-                                        for k, v in mine.items()}
-                        full = {k: jax.lax.all_gather(
-                                    v, axis_last)[n_shards - 1]
-                                for k, v in mine.items()}
+                    full = ring_fold(
+                        fold, hist_stream_packed_init(Fh, S, HB, chl),
+                        axis_last, n_shards)
                     h = hist_stream_packed_finalize(
                         full, Fh, S, HB, feat["qscales"][0],
                         feat["qscales"][1], const_hess_level=chl)
@@ -348,16 +336,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         return hist_stream_update(acc, bins_fm, payload,
                                                   leaf_id, slots, HB)
 
-                    recv = hist_stream_init(Fh, S, HB)
-                    mine = recv
-                    with jax.named_scope("ring_fold"):
-                        for t in range(n_shards):
-                            mine = fold(recv)
-                            if t < n_shards - 1:
-                                recv = jax.lax.ppermute(mine, axis_last,
-                                                        det_perm)
-                        full = jax.lax.all_gather(
-                            mine, axis_last)[n_shards - 1]
+                    full = ring_fold(fold, hist_stream_init(Fh, S, HB),
+                                     axis_last, n_shards)
                     h = hist_stream_finalize(full, Fh, S, HB)
                 if block:
                     Fb_h = h.shape[1] // n_shards
@@ -367,8 +347,9 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 return h
 
         def hist_multi(leaf_id, slots):
-            """[S, F|G|Fb, HB, 3] histograms of the listed leaf slots in
-            one batched sweep; pad slots (value LB) yield zeros.  Under
+            """[S, F|G|Fb, HB, 6] histograms of the listed leaf slots in
+            one batched sweep (both limbs of every sum; [.., 3] from the
+            quantized families); pad slots (value LB) yield zeros.  Under
             data_rs the returned feature axis is this shard's summed
             block (psum_scatter over ICI + psum over DCN)."""
             with jax.named_scope("histogram_wave"):
@@ -382,8 +363,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         feat["qscales"][0], feat["qscales"][1],
                         const_hess_level=spec.packed_const_hess_level)
                 else:
-                    h = leaf_histogram_multi(bins_fm, payload, leaf_id,
-                                             slots, HB)
+                    h = leaf_histogram_multi_limbs(bins_fm, payload,
+                                                   leaf_id, slots, HB)
                 if block:
                     # ref: Network::ReduceScatter of histogram buffers —
                     # each shard receives the summed feature block it
@@ -401,7 +382,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             def hist_cand_multi(leaf_id, slots, parent):
                 """Fused wave pass: one kernel builds the listed slots'
                 histograms in VMEM and scans them in place, returning
-                (hist [S, F, MB, 3], cand [S, 2, F, 8]) — the hist is
+                (hist [S, F, MB, 6|3], cand [S, 2, F, 8]) — the hist is
                 bitwise `hist_multi`'s (carried as state for sibling
                 subtraction / categorical fallback), the candidates feed
                 `split_of_fused`.  `parent` [S, 3] = each slot's own
@@ -461,19 +442,23 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 if penalty is not None:
                     penalty = jax.lax.dynamic_slice_in_dim(
                         penalty, offset, Fb, axis=0)
-                s = find(hist, g, h, c, bfeat["nb"], bfeat["missing"],
-                         bfeat["default"], na, bfeat["is_cat"],
-                         mono=bmono, out_lb=lb, out_ub=ub,
+                s = find(hist_value(hist), g, h, c, bfeat["nb"],
+                         bfeat["missing"], bfeat["default"], na,
+                         bfeat["is_cat"], mono=bmono, out_lb=lb, out_ub=ub,
                          parent_output=p_out, cand_mask=cm,
                          gain_penalty=penalty)
+                s = refine_child_sums(s, hist, bfeat["nb"],
+                                      bfeat["missing"])
                 return rebase_and_merge_block_split(s, offset, axis_last,
                                                     n_shards)
             if spec.bundled:
-                hist = expand_bundled(hist, g, h, c)
-            return find(hist, g, h, c, feat["nb"], feat["missing"],
-                        feat["default"], na, feat["is_cat"], mono=mono,
-                        out_lb=lb, out_ub=ub, parent_output=p_out,
-                        cand_mask=cm, gain_penalty=penalty)
+                # bundle columns expand to features by value: no limbs
+                hist = expand_bundled(hist_value(hist), g, h, c)
+            s = find(hist_value(hist), g, h, c, feat["nb"],
+                     feat["missing"], feat["default"], na, feat["is_cat"],
+                     mono=mono, out_lb=lb, out_ub=ub, parent_output=p_out,
+                     cand_mask=cm, gain_penalty=penalty)
+            return refine_child_sums(s, hist, feat["nb"], feat["missing"])
 
         if fused:
             def split_of_fused(hist_sl, cand_sl, g, h, c, node_allowed,
@@ -489,14 +474,15 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 num = decide_from_candidates(
                     cand_sl, g, h, c, feat["missing"], feat["default"],
                     na & ~feat["is_cat"], MB, gain_penalty=penalty)
-                if not spec.has_cat:
-                    return num
-                cat = find(hist_sl, g, h, c, feat["nb"], feat["missing"],
-                           feat["default"], na & feat["is_cat"],
-                           feat["is_cat"], mono=mono, out_lb=lb,
-                           out_ub=ub, parent_output=p_out,
-                           gain_penalty=penalty)
-                return merge_split_results(num, cat)
+                if spec.has_cat:
+                    cat = find(hist_value(hist_sl), g, h, c, feat["nb"],
+                               feat["missing"], feat["default"],
+                               na & feat["is_cat"], feat["is_cat"],
+                               mono=mono, out_lb=lb, out_ub=ub,
+                               parent_output=p_out, gain_penalty=penalty)
+                    num = merge_split_results(num, cat)
+                return refine_child_sums(num, hist_sl, feat["nb"],
+                                         feat["missing"])
 
         # ---- root ----
         # the root pass uses the SAME [W]-slot call shape as every wave
@@ -602,6 +588,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 .set(root_out),
                 leaf_depth=jnp.zeros((LB,), jnp.int32),
                 nodes=nodes,
+                # histogram passes the waves made (DeviceTree.tail_stats)
+                wave_passes=jnp.int32(0),
             )
             if track_used:
                 state["leaf_used"] = jnp.zeros((LB, F), bool)
@@ -615,7 +603,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 # the strict tail's speculated smaller-child histograms,
                 # one per leaf slot (the size of `hist`), valid while the
                 # leaf stands unsplit; tail_stats = passes, hits, unused,
-                # speculated (DeviceTree.tail_stats)
+                # speculated (the first four of DeviceTree.tail_stats)
                 state["spec_hist"] = jnp.zeros_like(hist)
                 state["spec_ok"] = jnp.zeros((LB,), bool)
                 state["tail_stats"] = jnp.zeros((4,), jnp.int32)
@@ -893,7 +881,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     small_h = hist_multi(s1["leaf_id"], s1["p_small"])
                 with jax.named_scope("hist_cache"):
                     parents = st["hist"][jnp.clip(s1["p_left"], 0, LB - 1)]
-                    large_h = parents - small_h
+                    large_h = hist_sub(parents, small_h)
                     p_large = jnp.where(s1["p_small"] == s1["p_left"],
                                         s1["p_new"], s1["p_left"])
                     hist = st["hist"].at[s1["p_small"]]\
@@ -916,7 +904,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         # actually is
                         par_large = stats[jnp.clip(p_large, 0, LB - 1)]
                         cand_large = pallas_split_scan(
-                            large_h, feat["nb"], feat["missing"],
+                            hist_value(large_h), feat["nb"], feat["missing"],
                             par_large, interpret=spec.hist_interpret,
                             **scan_kw)
                         small_is_left = (s1["p_small"] == s1["p_left"])[
@@ -969,6 +957,9 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                                           hist_and_find, None)
 
             new_state = {**st, **{k: s1[k] for k in carry_keys}}
+            if not strict:
+                new_state["wave_passes"] = st["wave_passes"] + \
+                    (s1["step"] < LB - 1).astype(jnp.int32)
             new_state["hist"] = hist
             for k, v in zip(LEAF_KEYS, leaf_upd):
                 new_state[k] = v
@@ -1058,6 +1049,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 jnp.sum(st["spec_ok"], dtype=jnp.int32))
         else:
             tail_stats = jnp.zeros((4,), jnp.int32)
+        tail_stats = jnp.concatenate([tail_stats, st["wave_passes"][None]])
 
         if LB > L:
             with jax.named_scope("prune"):

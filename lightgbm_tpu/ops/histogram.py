@@ -29,6 +29,58 @@ from ..analysis.contracts import contract
 Array = jax.Array
 
 
+# ---------------------------------------------------------------------------
+# Two-limb sums
+# ---------------------------------------------------------------------------
+# A float32 running sum over N near-equal addends drifts by up to N/2 ulps
+# (equal addends round the same way every time), and sibling subtraction
+# hands the drift of a 100M-row root down to a 100-row leaf unchanged.  So
+# the f32 histogram families carry every (feature, bin) sum as TWO f32
+# limbs, hi + lo, with |lo| <= ulp(hi)/2: channels-last [.., MB, 6] =
+# (g_hi, h_hi, c_hi, g_lo, h_lo, c_lo).  Limbs are added and subtracted
+# error-free (TwoSum); a consumer reads `hist_value`.  The quantized
+# families keep [.., MB, 3]: their integer sums are exact already.
+
+HIST_TILE = 8192        # rows summed plainly between two-limb folds
+
+
+def _two_sum(a: Array, b: Array):
+    """s = fl(a + b) and the rounding error e, so that a + b == s + e."""
+    s = a + b
+    bb = s - a
+    return s, (a - (s - bb)) + (b - bb)
+
+
+def limb_add(hi: Array, lo: Array, x_hi: Array, x_lo=None):
+    """(hi, lo) + (x_hi, x_lo), renormalised so the new lo stays under an
+    ulp of the new hi."""
+    s, e = _two_sum(hi, x_hi)
+    e = e + (lo if x_lo is None else lo + x_lo)
+    hi2 = s + e
+    return hi2, e - (hi2 - s)
+
+
+def hist_value(hist: Array) -> Array:
+    """[.., 3] float32 value of a histogram ([.., 6] limbs, or [.., 3])."""
+    if hist.shape[-1] == 6:
+        return hist[..., :3] + hist[..., 3:]
+    return hist
+
+
+def hist_add(a: Array, b: Array) -> Array:
+    """a + b, limb-wise where the histograms carry limbs."""
+    if a.shape[-1] != 6:
+        return a + b
+    hi, lo = limb_add(a[..., :3], a[..., 3:], b[..., :3], b[..., 3:])
+    return jnp.concatenate([hi, lo], axis=-1)
+
+
+def hist_sub(a: Array, b: Array) -> Array:
+    """a - b: the larger child from its parent and the smaller child,
+    keeping both limbs, so that no sum is rounded at the parent's size."""
+    return hist_add(a, -b)
+
+
 @contract(bins_fm="[F, N] int", payload="[N, 3] f32",
           row_mask="[N] bool", max_bin="static:MB",
           ret="[F, MB, 3] f32")
@@ -42,21 +94,20 @@ def leaf_histogram(bins_fm: Array, payload: Array, row_mask: Array,
       row_mask: [N] bool — leaf membership.
       max_bin: padded bin-axis size MB.
 
-    Returns: [F, MB, 3] float32.
+    Returns: [F, MB, 3] float32, each sum right to the round-off of the
+    SUM (`leaf_histogram_limbs` keeps both limbs).
     """
-    d = jnp.where(row_mask[:, None], payload, 0.0)
-    cols = bins_fm.astype(jnp.int32)
+    return hist_value(leaf_histogram_limbs(bins_fm, payload, row_mask,
+                                           max_bin))
 
-    # one segment-sum sweep per channel, channels unrolled in PYTHON: any
-    # batched-channel formulation makes XLA place the 3-sized channel dim
-    # minor-most in the broadcast operand, where TPU tiled layout pads it
-    # to 128 lanes — a 40x HBM blow-up ([F, N, 3] -> [F, N, 128])
-    def per_channel(vals: Array) -> Array:           # vals [N]
-        def per_feature(col: Array) -> Array:
-            return jax.ops.segment_sum(vals, col, num_segments=max_bin)
-        return jax.vmap(per_feature)(cols)           # [F, MB]
 
-    return jnp.stack([per_channel(d[:, c]) for c in range(3)], axis=-1)
+def leaf_histogram_limbs(bins_fm: Array, payload: Array, row_mask: Array,
+                         max_bin: int) -> Array:
+    """`leaf_histogram` as [F, MB, 6] limbs: the multi-leaf builder on a
+    mask-derived leaf id (slot 0 = in the leaf, -1 = dropped)."""
+    lid = jnp.where(row_mask, 0, -1).astype(jnp.int32)
+    return leaf_histogram_multi_limbs(
+        bins_fm, payload, lid, jnp.zeros((1,), jnp.int32), max_bin)[0]
 
 
 @contract(bins_fm="[F, N] int", payload="[N, 3] f32",
@@ -85,7 +136,7 @@ def leaf_histogram_multi(bins_fm: Array, payload: Array, leaf_id: Array,
     """Histograms of SEVERAL leaves in one sweep over the bin matrix.
 
     The wave grower's batched analog of `leaf_histogram`: rows are keyed by
-    `slot_index * MB + bin` and one segment-sum per (feature, channel)
+    `slot_index * MB + bin` and one scatter-add per (feature, channel part)
     accumulates every listed leaf at once — the bin matrix is read ONCE for
     the whole wave instead of once per leaf (ref: the reference's
     `ConstructHistograms` loops leaves serially; on TPU one sweep is the
@@ -99,23 +150,44 @@ def leaf_histogram_multi(bins_fm: Array, payload: Array, leaf_id: Array,
         (e.g. the pad value num_leaves) yield all-zero histograms.
       max_bin: padded bin-axis size MB.
 
-    Returns: [S, F, MB, 3] f32.
+    Returns: [S, F, MB, 3] f32 (`leaf_histogram_multi_limbs`: both limbs).
     """
+    return hist_value(leaf_histogram_multi_limbs(bins_fm, payload, leaf_id,
+                                                 slots, max_bin))
+
+
+# a one-pass build folds at most this many bytes of open tiles at a time
+_ONE_PASS_TILE_BYTES = 64 << 20
+
+
+def leaf_histogram_multi_limbs(bins_fm: Array, payload: Array,
+                               leaf_id: Array, slots: Array,
+                               max_bin: int) -> Array:
+    """`leaf_histogram_multi` as [S, F, MB, 6] limbs: the streamed carry
+    (`hist_stream_*`, below) folded over all rows at once, in chunks of
+    whole tiles so that the open tiles stay a bounded array.  Chunking
+    cannot change a bit: the carry's sequence of additions is fixed by
+    the rows' positions alone."""
+    F, N = bins_fm.shape
     S = slots.shape[0]
-    F = bins_fm.shape[0]
-    pos = slot_positions(leaf_id, slots)             # [N] in [0, S]
-    cols = bins_fm.astype(jnp.int32) + (pos * max_bin)[None, :]
+    acc = hist_stream_init(F, S, max_bin)
+    plane = 5 * F * (S + 1) * max_bin * 4
+    chunk = HIST_TILE * max(1, min(32, _ONE_PASS_TILE_BYTES // plane - 1))
 
-    def per_channel(vals: Array) -> Array:           # vals [N]
-        def per_feature(col: Array) -> Array:
-            return jax.ops.segment_sum(vals, col,
-                                       num_segments=(S + 1) * max_bin)
-        return jax.vmap(per_feature)(cols)           # [F, (S+1)*MB]
+    def fold(acc, lo, n):
+        return hist_stream_update(
+            acc, jax.lax.dynamic_slice_in_dim(bins_fm, lo, n, axis=1),
+            jax.lax.dynamic_slice_in_dim(payload, lo, n, axis=0),
+            jax.lax.dynamic_slice_in_dim(leaf_id, lo, n, axis=0),
+            slots, max_bin)
 
-    out = jnp.stack([per_channel(payload[:, c]) for c in range(3)],
-                    axis=-1)                         # [F, (S+1)*MB, 3]
-    return out.reshape(F, S + 1, max_bin, 3)[:, :S]\
-        .transpose(1, 0, 2, 3)                       # [S, F, MB, 3]
+    whole = N // chunk if N > chunk else 0
+    if whole:
+        acc = jax.lax.fori_loop(
+            0, whole, lambda i, a: fold(a, i * chunk, chunk), acc)
+    if N - whole * chunk:
+        acc = fold(acc, whole * chunk, N - whole * chunk)
+    return hist_stream_finalize(acc, F, S, max_bin)
 
 
 PACKED_TILE = 2048  # rows per int16-field accumulation tile
@@ -268,17 +340,22 @@ def leaf_histogram_packed_multi(bins_fm: Array, payload: Array,
 # datastore shards into a wave histogram without materialising the full
 # [F, N] bin matrix.  Bitwise contract with the one-pass builders:
 #
-#   * f32 family — `segment_sum` lowers to an in-order scatter-add, so a
-#     per-shard `carry.at[cols].add(vals)` applied in pinned shard order
-#     performs the exact same sequence of float adds per (leaf, bin)
-#     cell as `leaf_histogram_multi` over the concatenated rows.
+#   * f32 family — the carry is (sum, cur, n): `cur` takes the rows of
+#     the open tile by in-order scatter-add (plain f32, at most HIST_TILE
+#     rows, and those split into an 11-bit head whose sums stay exact and
+#     a tail 2^-11 as large), and whenever the rows seen so far pass a
+#     multiple of HIST_TILE it is folded into the two-limb `sum` and
+#     cleared.  Every addition is fixed by a row's POSITION in the stream,
+#     so shards of any size, folded in pinned order, perform exactly the
+#     additions of `leaf_histogram_multi_limbs` over the concatenated rows
+#     (which is this fold over one shard).
 #   * packed family — carries are int32; modular integer addition is
 #     fully associative, so any shard/tile grouping yields identical
 #     totals as long as each tile keeps the 16-bit hessian lane from
 #     overflowing (the same PACKED_TILE bound the one-pass builder uses).
 #
 # `finalize` applies the identical trailing conversion expressions, so
-# equal carries produce bit-equal [S, F, max_bin, 3] histograms.
+# equal carries produce bit-equal [S, F, max_bin, .] histograms.
 
 
 def ring_ordered_sum(local: Array, axis_name, n_shards: int) -> Array:
@@ -288,45 +365,103 @@ def ring_ordered_sum(local: Array, axis_name, n_shards: int) -> Array:
     depend on how the backend shapes that tree.  The deterministic
     reduction of the Pallas histogram family: each shard's kernel runs
     over its own rows at once and only the S partial histograms are
-    chained (the XLA families instead chain the scatter-add itself,
-    `hist_stream_*`, which keeps them bitwise equal to one shard)."""
+    chained, limb-wise (the XLA families instead chain the scatter-add
+    itself, `hist_stream_*`, which keeps them bitwise equal to one
+    shard)."""
     perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
     carry = local
     for _ in range(n_shards - 1):
-        carry = jax.lax.ppermute(carry, axis_name, perm) + local
+        carry = hist_add(jax.lax.ppermute(carry, axis_name, perm), local)
     return jax.lax.all_gather(carry, axis_name)[n_shards - 1]
 
 
-def hist_stream_init(F: int, slots_n: int, max_bin: int) -> Array:
-    """Zero f32 carry for the segment_sum family: [3, F, (S+1)*max_bin]."""
-    return jnp.zeros((3, F, (slots_n + 1) * max_bin), jnp.float32)
+def ring_fold(fold, carry: dict, axis_name, n_shards: int) -> dict:
+    """A streamed carry folded shard by shard around the ring, in ascending
+    shard order: shard t applies `fold` (its own rows) to what shard t-1
+    hands it, and every shard gets the last shard's carry back.  The
+    `ring_fold` scope pairs the device trace with the host-side
+    mesh.collective.ring_fold dispatch events (per-device collective
+    timeline)."""
+    perm = [(i, (i + 1) % n_shards) for i in range(n_shards)]
+    with jax.named_scope("ring_fold"):
+        for t in range(n_shards):
+            carry = fold(carry)
+            if t < n_shards - 1:
+                carry = {k: jax.lax.ppermute(v, axis_name, perm)
+                         for k, v in carry.items()}
+        return {k: jax.lax.all_gather(v, axis_name)[n_shards - 1]
+                for k, v in carry.items()}
 
 
-def hist_stream_update(acc: Array, bins_fm: Array, payload: Array,
-                       leaf_id: Array, slots: Array, max_bin: int) -> Array:
+def hist_stream_init(F: int, slots_n: int, max_bin: int) -> dict:
+    """Zero carry of the f32 family over NS = (S+1)*max_bin cells a
+    feature: `sum` [2, 3, F, NS] (limb, channel), `cur` [5, F, NS] (the
+    open tile: g head, g tail, h head, h tail, count), `n` rows seen."""
+    NS = (slots_n + 1) * max_bin
+    return {"sum": jnp.zeros((2, 3, F, NS), jnp.float32),
+            "cur": jnp.zeros((5, F, NS), jnp.float32),
+            "n": jnp.int32(0)}
+
+
+def _fold_tile(total: Array, tile: Array) -> Array:
+    """One closed tile [5, F, NS] into the two-limb sums [2, 3, F, NS]."""
+    heads = jnp.stack([tile[0], tile[2], tile[4]])
+    tails = jnp.stack([tile[1], tile[3], jnp.zeros_like(tile[4])])
+    hi, lo = limb_add(total[0], total[1], heads)
+    hi, lo = limb_add(hi, lo, tails)
+    return jnp.stack([hi, lo])
+
+
+def hist_stream_update(acc: dict, bins_fm: Array, payload: Array,
+                       leaf_id: Array, slots: Array, max_bin: int) -> dict:
     """Fold one shard's rows into the f32 carry.
 
     ``bins_fm``/``payload``/``leaf_id`` hold the shard's rows only; the
     shard's internal row order plus the caller's pinned shard order
-    reproduce the accumulation order of ``leaf_histogram_multi``.
+    reproduce the additions of ``leaf_histogram_multi_limbs``.
     """
+    F, n = bins_fm.shape
+    NS = (slots.shape[0] + 1) * max_bin
+    K = -(-n // HIST_TILE) + 1          # tiles a shard of n rows can touch
     pos = slot_positions(leaf_id, slots)               # [n] in [0, S]
-    cols = bins_fm.astype(jnp.int32) + (pos * max_bin)[None, :]
+    off = acc["n"] % HIST_TILE
+    tile_of_row = (off + jnp.arange(n, dtype=jnp.int32)) // HIST_TILE
+    cols = bins_fm.astype(jnp.int32) \
+        + (pos * max_bin + tile_of_row * NS)[None, :]  # [F, n] in [0, K*NS)
 
-    def channel(acc_c: Array, vals: Array) -> Array:
-        def per_feature(a_f, col):
-            return a_f.at[col].add(vals)
-        return jax.vmap(per_feature)(acc_c, cols)
+    def head_tail(v: Array):
+        # 11 significant bits: HIST_TILE of them add up exactly in f32
+        head = jax.lax.reduce_precision(v, 8, 10)
+        return head, v - head
 
-    return jnp.stack([channel(acc[c], payload[:, c]) for c in range(3)])
+    parts = head_tail(payload[:, 0]) + head_tail(payload[:, 1]) \
+        + (payload[:, 2],)
+
+    def scatter(cur_p: Array, vals: Array) -> Array:
+        # the open tile goes on from where the last shard left it
+        start = jnp.pad(cur_p, ((0, 0), (0, (K - 1) * NS)))
+        return jax.vmap(lambda a_f, col: a_f.at[col].add(vals))(start, cols)
+
+    tiles = jnp.stack([scatter(acc["cur"][p], v)
+                       for p, v in enumerate(parts)]).reshape(5, F, K, NS)
+    closed = (off + n) // HIST_TILE                    # in [0, K - 1]
+    total = jax.lax.fori_loop(
+        0, closed,
+        lambda j, t: _fold_tile(t, jax.lax.dynamic_index_in_dim(
+            tiles, j, axis=2, keepdims=False)),
+        acc["sum"])
+    cur = jax.lax.dynamic_index_in_dim(tiles, closed, axis=2,
+                                       keepdims=False)
+    return {"sum": total, "cur": cur, "n": acc["n"] + n}
 
 
-def hist_stream_finalize(acc: Array, F: int, slots_n: int,
+def hist_stream_finalize(acc: dict, F: int, slots_n: int,
                          max_bin: int) -> Array:
-    """Carry -> [S, F, max_bin, 3], matching leaf_histogram_multi."""
+    """Carry -> [S, F, max_bin, 6], matching leaf_histogram_multi_limbs."""
     S = slots_n
-    out = jnp.stack([acc[0], acc[1], acc[2]], axis=-1)  # [F, NS, 3]
-    return out.reshape(F, S + 1, max_bin, 3)[:, :S].transpose(1, 0, 2, 3)
+    total = _fold_tile(acc["sum"], acc["cur"])          # [2, 3, F, NS]
+    out = jnp.moveaxis(total.reshape(6, F, S + 1, max_bin), 0, -1)
+    return out[:, :S].transpose(1, 0, 2, 3)
 
 
 def hist_stream_packed_init(F: int, slots_n: int, max_bin: int,

@@ -22,8 +22,14 @@ precision — and each f32 payload channel is split into THREE bf16 terms
 accuracy from a single matmul: the LHS is
 [9, N_t] = (g1, g2, g3, h1, h2, h3, w1, w2, w3), and the MXU processes up
 to 128 LHS rows per pass, so the 3-way splits cost nothing over an
-unsplit payload.  Counts accumulate exactly below 2^24 rows, same as the
-segment-sum path.
+unsplit payload.
+
+Across row tiles the f32 kernels keep TWO limbs a cell (hi + lo): a plain
+VMEM accumulator takes `FLUSH_TILES` tiles (8-bit terms over 2048 rows and
+16 tiles add up in 23 bits: no rounding yet), then is folded error-free
+into the (hi, lo) outputs.  One f32 accumulator over all tiles drifted by
+N/2 ulps on near-equal addends (+861 on a 10.4M hessian sum over 40,960
+tiles), which sibling subtraction handed down to the smallest leaves.
 
 The quantized variant (`pallas_histogram_quantized`) feeds the integer
 gradient lattice of `use_quantized_grad` (ref:
@@ -48,9 +54,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..analysis.contracts import contract
 from ..utils.log import LightGBMError
+from .histogram import hist_value, limb_add
 from .split import (FUSED_CAND_COLS, FUSED_CASES, fused_numerical_candidates)
 
 Array = jax.Array
@@ -84,16 +92,24 @@ def _split3(x: Array):
     return x1, x2, x3
 
 
-def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, out_ref, *,
-                       mb: int):
+# row tiles a plain f32 accumulator takes between two-limb folds
+FLUSH_TILES = 16
+
+
+def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
+                       acc_ref, *, mb: int, n_rt: int):
     """Multi-leaf grid cell with IN-KERNEL leaf masking — THE production
     kernel: every public f32 entry point (single-leaf included, via a
     mask-derived leaf id) lowers to this one body, so `probe()` gates
-    exactly the code that training runs.
+    exactly the code that training runs (the fused kernel calls it too,
+    so their sums are bit-equal).
 
-    bins_ref: [F_t, N_t]; pw_ref: [R0, N_t] base payload rows (9 f32-split
-    or 3 quantized-lattice); lid_ref: [1, N_t] i32 row→leaf; slots_ref:
-    [1, S] i32 leaf slots; out_ref: [F_t, S*R0, MB] accumulator.
+    bins_ref: [F_t, N_t]; pw_ref: [R0, N_t] base payload rows (9
+    f32-split); lid_ref: [1, N_t] i32 row→leaf; slots_ref: [1, S] i32 leaf
+    slots; hi_ref, lo_ref: [F_t, S*R0, MB] two-limb sums; acc_ref (VMEM
+    scratch, the same block) takes every tile's one-hot dot plainly:
+    after each `FLUSH_TILES` tiles, and after the last, it is added
+    error-free into (hi_ref, lo_ref) and cleared.
 
     The payload rides f32 refs whose VALUES are bf16-representable:
     DEFAULT precision on TPU truncates f32 operands to bf16 for the MXU
@@ -110,7 +126,9 @@ def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, out_ref, *,
 
     @pl.when(r == 0)
     def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        hi_ref[:] = jnp.zeros_like(hi_ref)
+        lo_ref[:] = jnp.zeros_like(lo_ref)
 
     f_t, n_t = bins_ref.shape
     pw = pw_ref[:]                                   # [R0, N_t]
@@ -123,18 +141,33 @@ def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, out_ref, *,
     for f in range(f_t):                             # static unroll
         b = bins_ref[f, :].astype(jnp.int32)
         onehot = (b[:, None] == bin_ids).astype(jnp.float32)
-        out_ref[f] += jax.lax.dot_general(
+        acc_ref[f] += jax.lax.dot_general(
             lhs, onehot, (((1,), (0,)), ((), ())),
             precision=jax.lax.Precision.DEFAULT,
             preferred_element_type=jnp.float32)
 
+    @pl.when(((r + 1) % FLUSH_TILES == 0) | (r == n_rt - 1))
+    def _flush():
+        for f in range(f_t):                         # a feature at a time
+            hi_ref[f], lo_ref[f] = limb_add(hi_ref[f], lo_ref[f],
+                                            acc_ref[f])
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
+def _combine_terms(hi: Array, lo: Array):
+    """[.., 3 terms, MB] limbs of the three bf16 split terms -> the
+    channel's two limbs [.., MB]: one error-free chain, term 1 first."""
+    h, l = limb_add(hi[..., 0, :], lo[..., 0, :], hi[..., 1, :],
+                    lo[..., 1, :])
+    return limb_add(h, l, hi[..., 2, :], lo[..., 2, :])
+
 
 def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
                       slots: Array, max_bin: int, row_tile: int,
-                      feat_tile: int, interpret: bool) -> Array:
+                      feat_tile: int, interpret: bool):
     """pallas_call driver for the in-kernel-masked multi-leaf kernel:
     [F, N] bins x [R0, N] payload x [N] leaf ids x [S] slots ->
-    [F, S*R0, MB] f32."""
+    (hi, lo), each [F, S*R0, MB] f32."""
     f, n = bins_fm.shape
     r0 = pw0.shape[0]
     s_n = slots.shape[0]
@@ -151,23 +184,25 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
         bins_fm = jnp.pad(bins_fm, ((0, f_pad), (0, 0)))
     n_rt = (n + n_pad) // row_tile
     n_ft = (f + f_pad) // feat_tile
+    block = (feat_tile, s_n * r0, max_bin)
+    sums = jax.ShapeDtypeStruct((f + f_pad, s_n * r0, max_bin), jnp.float32)
 
-    out = pl.pallas_call(
-        functools.partial(_hist_kernel_multi, mb=max_bin),
-        grid=(n_ft, n_rt),  # row tiles iterate fastest -> out revisited
+    hi, lo = pl.pallas_call(
+        functools.partial(_hist_kernel_multi, mb=max_bin, n_rt=n_rt),
+        grid=(n_ft, n_rt),  # row tiles iterate fastest -> sums revisited
         in_specs=[
             pl.BlockSpec((feat_tile, row_tile), lambda j, r: (j, r)),
             pl.BlockSpec((r0, row_tile), lambda j, r: (0, r)),
             pl.BlockSpec((1, row_tile), lambda j, r: (0, r)),
             pl.BlockSpec((1, s_n), lambda j, r: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((feat_tile, s_n * r0, max_bin),
-                               lambda j, r: (j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((f + f_pad, s_n * r0, max_bin),
-                                       jnp.float32),
+        out_specs=[pl.BlockSpec(block, lambda j, r: (j, 0, 0)),
+                   pl.BlockSpec(block, lambda j, r: (j, 0, 0))],
+        out_shape=[sums, sums],
+        scratch_shapes=[pltpu.VMEM(block, jnp.float32)],
         interpret=interpret,
     )(bins_fm, pw0, leaf_id.astype(jnp.int32)[None, :], slots[None, :])
-    return out[:f]
+    return hi[:f], lo[:f]
 
 
 def _hist_kernel_multi_i8(bins_ref, pw_ref, lid_ref, slots_ref, out_ref, *,
@@ -262,14 +297,14 @@ def pallas_histogram(bins_fm: Array, payload: Array, row_mask: Array,
         single-pass split-bf16 multi kernel.
     Returns: [F, MB, 3] f32 — matches the segment-sum path to >= f32
       accuracy (the 3-term bf16 split carries ~27 mantissa bits per
-      payload element; counts are exact below 2^24 rows).
+      payload element, every sum two limbs).
     """
     del impl
     lid = jnp.where(row_mask, 0, -1).astype(jnp.int32)
-    return pallas_histogram_multi_rows(
+    return hist_value(pallas_histogram_multi_rows(
         bins_fm, _split_payload9(payload), lid,
         jnp.zeros((1,), jnp.int32), max_bin, row_tile=row_tile,
-        feat_tile=feat_tile, interpret=interpret)[0]
+        feat_tile=feat_tile, interpret=interpret)[0])
 
 
 # MXU LHS capacity is 128 rows; leaves per kernel pass at 9 / 3 rows each
@@ -312,9 +347,18 @@ def pallas_histogram_multi(bins_fm: Array, payload: Array, leaf_id: Array,
         leaf_id, canonically num_leaves) produce zero histograms.
     Returns: [S, F, MB, 3] f32.
     """
-    return pallas_histogram_multi_rows(
+    return hist_value(pallas_histogram_multi_rows(
         bins_fm, _split_payload9(payload), leaf_id, slots, max_bin,
-        row_tile=row_tile, feat_tile=feat_tile, interpret=interpret)
+        row_tile=row_tile, feat_tile=feat_tile, interpret=interpret))
+
+
+def _limbs_from_terms(hi: Array, lo: Array, c: int, max_bin: int) -> Array:
+    """Kernel sums [F, c*9, MB] (rows (channel, split-term) major) ->
+    [c, F, MB, 6] limbs: the three terms of a channel chained into two."""
+    f = hi.shape[0]
+    h, l = _combine_terms(hi.reshape(f, c, 3, 3, max_bin),
+                          lo.reshape(f, c, 3, 3, max_bin))  # [F, c, 3, MB]
+    return jnp.concatenate([h, l], axis=2).transpose(1, 0, 3, 2)
 
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "row_tile",
@@ -327,18 +371,18 @@ def pallas_histogram_multi_rows(bins_fm: Array, pw9: Array, leaf_id: Array,
     """`pallas_histogram_multi` with the payload ALREADY split to [9, N]
     carrier rows (`_split_payload9`) — the wave grower prepares the rows
     once per tree and reuses them for every wave's call, instead of
-    re-splitting the loop-invariant payload inside the while_loop body."""
+    re-splitting the loop-invariant payload inside the while_loop body.
+    Returns [S, F, MB, 6], both limbs of every sum, for
+    `ops/histogram.hist_sub` to carry through the subtractions
+    (`hist_value` for the [.., 3] sums)."""
     S = slots.shape[0]
     outs = []
     for c0 in range(0, S, MULTI_CHUNK):
         c1 = min(S, c0 + MULTI_CHUNK)
-        out = _run_kernel_multi(bins_fm, pw9, leaf_id, slots[c0:c1],
-                                max_bin, row_tile, feat_tile,
-                                interpret)           # [F, (c1-c0)*9, MB]
-        f = out.shape[0]
-        # rows per leaf are (channel, split-term) major → sum the terms
-        out = out.reshape(f, c1 - c0, 3, 3, max_bin).sum(axis=3)
-        outs.append(out.transpose(1, 0, 3, 2))       # [c, F, MB, 3]
+        hi, lo = _run_kernel_multi(bins_fm, pw9, leaf_id, slots[c0:c1],
+                                   max_bin, row_tile, feat_tile,
+                                   interpret)        # [F, (c1-c0)*9, MB]
+        outs.append(_limbs_from_terms(hi, lo, c1 - c0, max_bin))
     return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
 
@@ -486,40 +530,25 @@ def _fused_scan_tail(acc4, nb_ref, miss_ref, par_ref, cand_ref, *, scan_kw):
 
 
 def _fused_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, nb_ref,
-                        miss_ref, par_ref, out_ref, cand_ref, *,
-                        mb: int, n_rt: int, scan_kw: dict):
+                        miss_ref, par_ref, hi_ref, lo_ref, cand_ref, acc_ref,
+                        *, mb: int, n_rt: int, scan_kw: dict):
     """f32 fused grid cell: `_hist_kernel_multi` accumulation + in-VMEM
     split scan on the last row tile.  Extra refs: nb_ref/miss_ref [1, F_t]
     i32 per-feature bin metadata, par_ref [3, S] f32 parent (g, h, cnt)
     rows; cand_ref [F_t, S*2, 8] candidate output."""
-    r = pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    f_t, n_t = bins_ref.shape
-    pw = pw_ref[:]                                   # [R0, N_t]
-    lid = lid_ref[0, :]                              # [N_t] i32
+    _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
+                       acc_ref, mb=mb, n_rt=n_rt)
+    f_t = bins_ref.shape[0]
     s_n = slots_ref.shape[1]
-    lhs = jnp.concatenate(
-        [jnp.where((lid == slots_ref[0, s])[None, :], pw, 0.0)
-         for s in range(s_n)], axis=0)               # [S*R0, N_t]
-    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (n_t, mb), 1)
-    for f in range(f_t):                             # static unroll
-        b = bins_ref[f, :].astype(jnp.int32)
-        onehot = (b[:, None] == bin_ids).astype(jnp.float32)
-        out_ref[f] += jax.lax.dot_general(
-            lhs, onehot, (((1,), (0,)), ((), ())),
-            precision=jax.lax.Precision.DEFAULT,
-            preferred_element_type=jnp.float32)
 
-    @pl.when(r == n_rt - 1)
+    @pl.when(pl.program_id(1) == n_rt - 1)
     def _scan():
-        # the SAME recombination ops as pallas_histogram_multi_rows, so
-        # the scanned histogram is bitwise the one the state carries
-        acc = out_ref[:].reshape(f_t, s_n, 3, 3, mb).sum(axis=3)
-        _fused_scan_tail(acc.transpose(0, 1, 3, 2), nb_ref, miss_ref,
+        # the SAME recombination ops as pallas_histogram_multi_rows and
+        # `hist_value`, so the scanned histogram is bitwise the value of
+        # the limbs the state carries
+        h, l = _combine_terms(hi_ref[:].reshape(f_t, s_n, 3, 3, mb),
+                              lo_ref[:].reshape(f_t, s_n, 3, 3, mb))
+        _fused_scan_tail((h + l).transpose(0, 1, 3, 2), nb_ref, miss_ref,
                          par_ref, cand_ref, scan_kw=scan_kw)
 
 
@@ -572,7 +601,7 @@ def _run_fused_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
                      slots: Array, feat_nb: Array, feat_missing: Array,
                      parent: Array, max_bin: int, row_tile: int,
                      feat_tile: int, interpret: bool, scan_kw: dict):
-    """Fused f32 driver -> ([F, S*9, MB] f32 accumulator,
+    """Fused f32 driver -> (hi, lo: [F, S*9, MB] f32 two-limb sums,
     [F, S*2, 8] f32 candidates)."""
     f, n = bins_fm.shape
     r0 = pw0.shape[0]
@@ -591,10 +620,12 @@ def _run_fused_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
     n_rt = (n + n_pad) // row_tile
     n_ft = (f + f_pad) // feat_tile
 
-    out, cand = pl.pallas_call(
+    block = (feat_tile, s_n * r0, max_bin)
+    sums = jax.ShapeDtypeStruct((f + f_pad, s_n * r0, max_bin), jnp.float32)
+    hi, lo, cand = pl.pallas_call(
         functools.partial(_fused_kernel_multi, mb=max_bin, n_rt=n_rt,
                           scan_kw=scan_kw),
-        grid=(n_ft, n_rt),  # row tiles iterate fastest -> out revisited
+        grid=(n_ft, n_rt),  # row tiles iterate fastest -> sums revisited
         in_specs=[
             pl.BlockSpec((feat_tile, row_tile), lambda j, r: (j, r)),
             pl.BlockSpec((r0, row_tile), lambda j, r: (0, r)),
@@ -605,21 +636,21 @@ def _run_fused_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
             pl.BlockSpec((3, s_n), lambda j, r: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((feat_tile, s_n * r0, max_bin),
-                         lambda j, r: (j, 0, 0)),
+            pl.BlockSpec(block, lambda j, r: (j, 0, 0)),
+            pl.BlockSpec(block, lambda j, r: (j, 0, 0)),
             pl.BlockSpec((feat_tile, s_n * FUSED_CASES, FUSED_CAND_COLS),
                          lambda j, r: (j, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((f + f_pad, s_n * r0, max_bin),
-                                 jnp.float32),
+            sums, sums,
             jax.ShapeDtypeStruct((f + f_pad, s_n * FUSED_CASES,
                                   FUSED_CAND_COLS), jnp.float32),
         ],
+        scratch_shapes=[pltpu.VMEM(block, jnp.float32)],
         interpret=interpret,
     )(bins_fm, pw0, leaf_id.astype(jnp.int32)[None, :], slots[None, :],
       nb2, miss2, parent.T.astype(jnp.float32))
-    return out[:f], cand[:f]
+    return hi[:f], lo[:f], cand[:f]
 
 
 def _run_fused_multi_i8(bins_fm: Array, pw0: Array, leaf_id: Array,
@@ -703,9 +734,10 @@ def pallas_fused_hist_split_rows(bins_fm: Array, pw9: Array, leaf_id: Array,
     Same batching/chunking economics as `pallas_histogram_multi_rows`;
     `parent` [S, 3] carries each slot's (g, h, cnt) sums for the in-kernel
     gain shift.  Returns `(hist, cand)`:
-      hist: [S, F, MB, 3] f32 — bitwise the `pallas` path's histogram
-        (the wave grower still carries it for sibling subtraction and
-        categorical fallback);
+      hist: [S, F, MB, 6] f32 limbs — bitwise the `pallas` path's
+        histogram (the wave grower still carries it for
+        sibling subtraction and categorical fallback); the in-kernel scan
+        read its `hist_value`;
       cand: [S, FUSED_CASES, F, FUSED_CAND_COLS] f32 — per (slot, case,
         feature) the first-wins best (gain, thr, left_g, left_h, left_cnt),
         decided by `ops/split.py decide_from_candidates`.
@@ -717,12 +749,11 @@ def pallas_fused_hist_split_rows(bins_fm: Array, pw9: Array, leaf_id: Array,
     houts, couts = [], []
     for c0 in range(0, S, MULTI_CHUNK):
         c1 = min(S, c0 + MULTI_CHUNK)
-        out, cand = _run_fused_multi(
+        hi, lo, cand = _run_fused_multi(
             bins_fm, pw9, leaf_id, slots[c0:c1], feat_nb, feat_missing,
             parent[c0:c1], max_bin, row_tile, feat_tile, interpret, scan_kw)
-        f = out.shape[0]
-        h = out.reshape(f, c1 - c0, 3, 3, max_bin).sum(axis=3)
-        houts.append(h.transpose(1, 0, 3, 2))        # [c, F, MB, 3]
+        f = hi.shape[0]
+        houts.append(_limbs_from_terms(hi, lo, c1 - c0, max_bin))
         couts.append(cand.reshape(f, c1 - c0, FUSED_CASES, FUSED_CAND_COLS)
                      .transpose(1, 2, 0, 3))         # [c, 2, F, 8]
     if len(houts) > 1:
@@ -948,8 +979,8 @@ def _probe_fused(interpret: bool, max_bin: int, num_feature: int,
                 row_tile=min(n, ROW_TILE), interpret=interpret,
                 **_PROBE_SCAN_KW)
         else:
-            want_h = pallas_histogram_multi(
-                bins, pj, lid, slots, max_bin,
+            want_h = pallas_histogram_multi_rows(
+                bins, _split_payload9(pj), lid, slots, max_bin,
                 row_tile=min(n, ROW_TILE), interpret=interpret)
             got_h, cand = pallas_fused_hist_split_rows(
                 bins, _split_payload9(pj), lid, slots, nb, miss, pjj,
@@ -964,7 +995,8 @@ def _probe_fused(interpret: bool, max_bin: int, num_feature: int,
         for sl in range(min(3, wdt)):
             pg, ph, pc = (jnp.float32(parent[sl, c]) for c in range(3))
             ref = find_best_split(
-                jnp.asarray(want_h[sl]), pg, ph, pc, nb, miss, fdef,
+                hist_value(jnp.asarray(want_h[sl])), pg, ph, pc, nb, miss,
+                fdef,
                 allowed, iscat, cat_smooth=10.0, cat_l2=10.0,
                 max_cat_threshold=32, max_cat_to_onehot=4, has_cat=False,
                 **_PROBE_SCAN_KW)
@@ -983,7 +1015,7 @@ def _probe_fused(interpret: bool, max_bin: int, num_feature: int,
 def probe(interpret: bool = False, max_bin: int = 256,
           num_feature: int = 28, multi: bool = False, width: int = None,
           quantized: bool = None, fused: bool = False) -> ProbeResult:
-    """Runtime check that the kernel compiles and matches segment-sum on
+    """Runtime check that the kernel compiles and matches a host count on
     the current backend — used by Booster to gate the TPU histogram path.
     On a real TPU (`interpret=False`) a base kernel that RAISES is an
     error, not a degradation: `LightGBMError` carries Mosaic's message
@@ -1026,22 +1058,29 @@ def probe(interpret: bool = False, max_bin: int = 256,
 def _probe_base(interpret: bool, max_bin: int, num_feature: int,
                 multi: bool, width: int, quantized: bool) -> ProbeResult:
     """`probe`'s compile-and-compare body for the unfused kernels; any
-    exception the kernel raises propagates to `probe`."""
+    exception the kernel raises propagates to `probe`.  The reference is
+    a float64 count on the host: nothing but the kernel compiles here."""
     import numpy as np
 
-    from .histogram import leaf_histogram
     rng = np.random.RandomState(0)
     n = ROW_TILE if not interpret else 128
-    bins = jnp.asarray(
-        rng.randint(0, max_bin, (num_feature, n)).astype(np.uint8)
-        if max_bin <= 256 else
-        rng.randint(0, max_bin, (num_feature, n)).astype(np.uint16))
-    payload = jnp.asarray(rng.randn(n, 3).astype(np.float32))
-    mask = jnp.asarray(rng.rand(n) < 0.7)
+    bins_np = rng.randint(0, max_bin, (num_feature, n)).astype(
+        np.uint8 if max_bin <= 256 else np.uint16)
+    payload_np = rng.randn(n, 3).astype(np.float32)
+    mask_np = rng.rand(n) < 0.7
     s = jnp.float32(0.25)
-    pq = jnp.stack([jnp.round(payload[:, 0] * 8) * s,
-                    jnp.abs(jnp.round(payload[:, 1] * 8)) * s,
-                    jnp.ones((n,), jnp.float32)], axis=1)
+    pq_np = np.stack([np.round(payload_np[:, 0] * 8) * 0.25,
+                      np.abs(np.round(payload_np[:, 1] * 8)) * 0.25,
+                      np.ones(n)], axis=1).astype(np.float32)
+    bins, payload, mask, pq = (jnp.asarray(a) for a in (
+        bins_np, payload_np, mask_np, pq_np))
+
+    def host_hist(pay, rows):
+        """[F, MB, 3] sums over `rows` (bool [n]), in float64."""
+        return np.stack([np.stack([np.bincount(
+            col[rows], weights=pay[rows, c].astype(np.float64),
+            minlength=max_bin) for c in range(3)], axis=-1)
+            for col in bins_np])
 
     def close(what, got, want):
         # explicit sync (device_get) — the probe compares on host by
@@ -1063,33 +1102,32 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
                      width or (MULTI_CHUNK_Q if quantized
                                else MULTI_CHUNK))]
         for quant_f, wdt in fams:
-            lid = jnp.asarray(
-                rng.randint(0, wdt + 2, (n,)).astype(np.int32))
+            lid_np = rng.randint(0, wdt + 2, (n,)).astype(np.int32)
+            lid = jnp.asarray(lid_np)
             slots = jnp.arange(wdt, dtype=jnp.int32)
             if quant_f:
                 got = pallas_histogram_multi_quantized(
                     bins, pq, lid, slots, max_bin, s, s,
                     row_tile=min(n, ROW_TILE), interpret=interpret)
-                ref_payload = pq
+                ref_payload = pq_np
             else:
                 got = pallas_histogram_multi(
                     bins, payload, lid, slots, max_bin,
                     row_tile=min(n, ROW_TILE), interpret=interpret)
-                ref_payload = payload
+                ref_payload = payload_np
             k = min(3, wdt)
-            want = jnp.stack([leaf_histogram(bins, ref_payload,
-                                             lid == sl, max_bin)
-                              for sl in range(k)])
+            want = np.stack([host_hist(ref_payload, lid_np == sl)
+                             for sl in range(k)])
             res = close(f"multi-leaf kernel (quantized={quant_f}, "
-                        f"width={wdt}) vs segment-sum", got[:k], want)
+                        f"width={wdt}) vs a float64 count", got[:k], want)
             if not res:
                 return res
         return _PROBE_OK
     got = pallas_histogram(bins, payload, mask, max_bin,
                            row_tile=min(n, ROW_TILE),
                            interpret=interpret)
-    res = close("single-leaf f32 kernel vs segment-sum", got,
-                leaf_histogram(bins, payload, mask, max_bin))
+    res = close("single-leaf f32 kernel vs a float64 count", got,
+                host_hist(payload_np, mask_np))
     if not res:
         return res
     # the quantized kernel runs DIFFERENT block shapes (3-row payload)
@@ -1098,5 +1136,5 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
     gotq = pallas_histogram_quantized(bins, pq, mask, max_bin, s, s,
                                       row_tile=min(n, ROW_TILE),
                                       interpret=interpret)
-    return close("single-leaf int8 kernel vs segment-sum", gotq,
-                 leaf_histogram(bins, pq, mask, max_bin))
+    return close("single-leaf int8 kernel vs a float64 count", gotq,
+                 host_hist(pq_np, mask_np))

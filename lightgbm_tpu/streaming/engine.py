@@ -65,8 +65,10 @@ from ..ops.grow_wave import prune_wave_tail, wave_sizes
 from ..ops.histogram import (hist_stream_finalize, hist_stream_init,
                              hist_stream_packed_finalize,
                              hist_stream_packed_init,
-                             hist_stream_packed_update, hist_stream_update)
-from ..ops.split import NEG_INF, find_best_split, leaf_output, smooth_output
+                             hist_stream_packed_update, hist_stream_update,
+                             hist_sub, hist_value)
+from ..ops.split import (NEG_INF, find_best_split, leaf_output,
+                         refine_child_sums, smooth_output)
 from ..telemetry import REGISTRY
 from .. import telemetry
 
@@ -205,10 +207,11 @@ class StreamingWaveGrower:
                      penalty=None):
             na = node_allowed & bynode_mask(nid)
             cm = extra_mask(nid)
-            return find(hist, g, h, c, feat["nb"], feat["missing"],
-                        feat["default"], na, feat["is_cat"], mono=mono,
-                        out_lb=lb, out_ub=ub, parent_output=p_out,
-                        cand_mask=cm, gain_penalty=penalty)
+            s = find(hist_value(hist), g, h, c, feat["nb"],
+                     feat["missing"], feat["default"], na, feat["is_cat"],
+                     mono=mono, out_lb=lb, out_ub=ub, parent_output=p_out,
+                     cand_mask=cm, gain_penalty=penalty)
+            return refine_child_sums(s, hist, feat["nb"], feat["missing"])
 
         return F, mono, split_of, cegb_penalty
 
@@ -497,7 +500,7 @@ class StreamingWaveGrower:
             streamed smaller-children histograms."""
             F, mono, split_of, cegb_penalty = self._split_ctx(feat)
             parents = hist_st[jnp.clip(s1["p_left"], 0, LB - 1)]
-            large_h = parents - small_h
+            large_h = hist_sub(parents, small_h)
             p_large = jnp.where(s1["p_small"] == s1["p_left"],
                                 s1["p_new"], s1["p_left"])
             hist = hist_st.at[s1["p_small"]].set(small_h, mode="drop")

@@ -63,7 +63,8 @@ class Tree:
         self.shrinkage = 1.0
         # growth record of a wave-grown tree (DeviceTree.tail_stats):
         # the strict tail's histogram passes, splits served from a
-        # speculated histogram, speculated histograms unused and made
+        # speculated histogram, speculated histograms unused and made,
+        # and the waves' histogram passes
         self.tail_stats = None
         self.num_cat = 0
         # categorical split storage (ref: tree.h cat_boundaries_/cat_threshold_)

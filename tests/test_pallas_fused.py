@@ -23,6 +23,7 @@ from lightgbm_tpu import telemetry
 from lightgbm_tpu.ops import pallas_hist as ph
 from lightgbm_tpu.ops.grow import GrowerSpec
 from lightgbm_tpu.ops.grow_wave import make_wave_grower
+from lightgbm_tpu.ops.histogram import hist_value
 from lightgbm_tpu.ops.split import fused_numerical_candidates
 
 pytestmark = pytest.mark.quick
@@ -75,7 +76,14 @@ def test_fused_kernel_hist_and_candidates_exact():
     got_h, cand = ph.pallas_fused_hist_split_rows(
         bins, ph._split_payload9(pj), lid, slots, nb, miss, parent, mb,
         row_tile=256, interpret=True, **SCAN_KW)
-    np.testing.assert_array_equal(np.asarray(got_h), want_h)
+    # the f32 family hands back both limbs of every sum; its scan read
+    # their value, which is the unfused kernel's histogram bit for bit
+    assert got_h.shape[-1] == 6
+    np.testing.assert_array_equal(np.asarray(hist_value(got_h)), want_h)
+    np.testing.assert_array_equal(
+        np.asarray(got_h), np.asarray(ph.pallas_histogram_multi_rows(
+            bins, ph._split_payload9(pj), lid, slots, mb, row_tile=256,
+            interpret=True)))
     np.testing.assert_array_equal(
         np.asarray(cand), _xla_candidates(want_h, nb, miss, parent))
 

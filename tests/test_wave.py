@@ -819,7 +819,7 @@ class TestSpeculativeTail:
         dev, LB, _ = _grow_once(X, y, objective="binary", num_leaves=leaves,
                                 tpu_wave_strict_tail=tail,
                                 tpu_wave_width=width, min_data_in_leaf=5)
-        passes, hits, unused, speculated = (int(v) for v in dev.tail_stats)
+        passes, hits, unused, speculated = (int(v) for v in dev.tail_stats[:4])
         # a speculated slot is the missed leaf its pass was made for, a
         # later hit, or never used
         assert speculated == passes + hits + unused
@@ -838,7 +838,7 @@ class TestSpeculativeTail:
         dev, LB, _ = _grow_once(X, y, objective="binary", num_leaves=31,
                                 tpu_wave_strict_tail=16, tpu_wave_width=8,
                                 min_data_in_leaf=5)
-        passes, hits, _, _ = (int(v) for v in dev.tail_stats)
+        passes, hits, _, _ = (int(v) for v in dev.tail_stats[:4])
         assert _tail_splits(dev, LB, 16) == (16, True)
         assert passes + hits == 15
         assert passes < 15 and hits > 0
@@ -860,7 +860,7 @@ class TestSpeculativeTail:
         split_leaf = np.asarray(dev.split_leaf)
         for i in range(1, n_splits):
             assert split_leaf[i] in (split_leaf[i - 1], i)
-        passes, hits, unused, speculated = (int(v) for v in dev.tail_stats)
+        passes, hits, unused, speculated = (int(v) for v in dev.tail_stats[:4])
         assert hits == 0
         assert passes == n_splits - 1
         assert speculated == passes + unused
@@ -889,7 +889,7 @@ class TestSpeculativeTail:
         np.testing.assert_array_equal(dumps["leafwise"][1],
                                       dumps["wave"][1])
         for t in dumps["wave"][2].trees:
-            passes, hits, unused, speculated = t.tail_stats
+            passes, hits, unused, speculated = t.tail_stats[:4]
             assert unused == 0 and hits > 0
             # no split filled the tree: each got its children's histograms
             assert passes + hits == speculated == t.num_internal()
@@ -897,11 +897,12 @@ class TestSpeculativeTail:
 
     def test_counters_follow_the_trees(self):
         """`grow.tail_passes` / `grow.tail_spec_hits` /
-        `grow.tail_spec_unused` grow at decode by what each tree
-        reports, on the per-round path and the fused chunk's alike."""
+        `grow.tail_spec_unused` / `grow.wave_passes` / `grow.leaves` grow
+        at decode by what each tree reports, on the per-round path and
+        the fused chunk's alike."""
         from lightgbm_tpu import telemetry
         names = ("grow.tail_passes", "grow.tail_spec_hits",
-                 "grow.tail_spec_unused")
+                 "grow.tail_spec_unused", "grow.wave_passes", "grow.leaves")
 
         def read():
             return [telemetry.REGISTRY.counter(k).value for k in names]
@@ -916,6 +917,31 @@ class TestSpeculativeTail:
             bst.update()
         bst.update_many(bst._BULK_CHUNK)
         assert len(bst.trees) == 2 + bst._BULK_CHUNK
-        want = np.sum([t.tail_stats[:3] for t in bst.trees], axis=0)
-        assert want[0] > 0
+        want = np.sum([t.tail_stats[:3] + (t.tail_stats[4], t.num_leaves)
+                       for t in bst.trees], axis=0)
+        assert want[0] > 0 and want[3] > 0
         np.testing.assert_array_equal(np.subtract(read(), before), want)
+
+    @pytest.mark.parametrize("leaves,tail", [(31, 16), (31, 0), (12, 4)])
+    def test_a_trees_passes_are_root_waves_and_tail(self, leaves, tail):
+        """`tail_stats[4]` counts the waves' kernel passes: with the
+        tail's passes and hits it accounts for every split of the tree
+        (a wave of width W takes up to W splits a pass; a tail pass or a
+        hit takes one; the split that fills the tree takes none)."""
+        X, y = make_binary(2000)
+        bst = lgb.train({"objective": "binary", "num_leaves": leaves,
+                         "verbosity": -1, "tree_grow_policy": "wave",
+                         "min_data_in_leaf": 5, "tpu_wave_width": 4,
+                         "tpu_wave_gain_ratio": 0,
+                         "tpu_wave_strict_tail": tail},
+                        lgb.Dataset(X, label=y), num_boost_round=2)
+        for t in bst.trees:
+            passes, hits, _, _, waves = t.tail_stats
+            assert t.num_leaves == leaves
+            in_tail = min(tail, leaves - 1)
+            in_waves = leaves - 1 - in_tail
+            assert -(-in_waves // 4) <= waves <= max(in_waves, 0)
+            if tail:
+                assert passes + hits in (in_tail - 1, in_tail)
+            else:
+                assert passes == hits == 0

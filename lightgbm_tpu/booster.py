@@ -110,6 +110,9 @@ class _DeviceData:
         mappers = ds.bin_mappers
         self.feat_nb = jnp.asarray(
             np.array([m.num_bin for m in mappers], dtype=np.int32))
+        # host copy: static inputs (the histogram kernel's lane plan) are
+        # derived from it without a device read
+        self.num_bins = tuple(int(m.num_bin) for m in mappers)
         self.feat_missing = jnp.asarray(
             np.array([m.missing_type for m in mappers], dtype=np.int32))
         self.feat_default = jnp.asarray(
@@ -507,9 +510,11 @@ class Booster:
             wave_strict_tail=self._wave_strict_tail(),
             has_cat=bool(self._dd.is_cat_np.any()),
             debug_checks=bool(self.config.tpu_debug_nans),
+            hist_lane_plan=self._hist_lane_plan(),
         )
         self._grow_policy = self._resolve_grow_policy()
         self._maybe_fuse_hist_impl()
+        self._record_hist_lanes()
         self._rng_key0 = jax.random.PRNGKey(
             self.config.bagging_seed % (2 ** 31))
         self._ff_key0 = jax.random.PRNGKey(
@@ -900,7 +905,8 @@ class Booster:
                 pc = padded_feature_count(pc, s_last)
             res = probe_cached(pb, pc, multi=True, width=w,
                                quantized=spec.hist_impl == "pallas_q",
-                               interpret=spec.hist_interpret)
+                               interpret=spec.hist_interpret,
+                               plan=spec.hist_lane_plan)
             if not res:
                 reasons.append("a failing multi-leaf Pallas kernel probe "
                                "on this backend"
@@ -939,6 +945,41 @@ class Booster:
         if efb is not None:
             return efb.max_bin, efb.n_cols
         return self._dd.max_bin, self._dd.num_feature
+
+    def _hist_lane_plan(self):
+        """The f32 histogram kernel's static lane plan
+        (ops/pallas_hist.py `lane_plan`), from the bin counts of the
+        columns the kernel will see: the mappers' `num_bin`, under EFB
+        the bundle columns' widths — the columns of `_probe_shape`.
+        None (every column on its own lanes) where a distributed learner
+        pads or slices the column axis (`place_training_data`'s
+        `pad_features`: the kernel then sees other columns than these)."""
+        from .ops.pallas_hist import lane_plan
+        efb = self._dd.efb
+        kind, shards = self._learner_topology()[:2]
+        if efb is None and shards > 1 and kind in ("data", "feature"):
+            return None
+        num_bins = self._dd.num_bins if efb is None \
+            else tuple(int(b) for b in efb.col_num_bin)
+        return lane_plan(num_bins, self._probe_shape()[0])
+
+    def _record_hist_lanes(self) -> None:
+        """Gauges `hist.lanes_per_row` (lanes one histogram pass contracts
+        a row) and `hist.packed_columns` (columns that share a lane
+        group) of the grower this booster runs; 0 where no Pallas
+        histogram kernel runs."""
+        from .ops.pallas_hist import LANE, base_hist_impl, plan_lanes
+        fam = base_hist_impl(self._grower_spec.hist_impl)
+        plan = self._grower_spec.hist_lane_plan if fam == "pallas" else None
+        bins, cols = self._probe_shape()
+        lanes = 0
+        if fam in ("pallas", "pallas_q"):
+            lanes = plan_lanes(plan) if plan is not None \
+                else cols * (-(-bins // LANE) * LANE)
+        packed = sum(len(members) for _, members in plan or ()
+                     if len(members) > 1)
+        telemetry.REGISTRY.gauge("hist.lanes_per_row").set(lanes)
+        telemetry.REGISTRY.gauge("hist.packed_columns").set(packed)
 
     #: legal `hist_impl` requests (fused names resolve to their base
     #: family here; the fusion upgrade stays `_maybe_fuse_hist_impl`'s
@@ -1032,7 +1073,8 @@ class Booster:
                                    "hist_interpret is off)")
                 else:
                     res = probe_cached(*self._probe_shape(),
-                                       interpret=not on_tpu)
+                                       interpret=not on_tpu,
+                                       plan=self._hist_lane_plan())
                     if not res:
                         reasons.append("a failing Pallas histogram probe "
                                        "on this backend" + _probe_why(res))
@@ -1052,7 +1094,8 @@ class Booster:
             # there.  The probe RAISES when the kernel does not compile
             # or run on the TPU; only a numeric mismatch degrades, with
             # the numbers in the event
-            res = probe_cached(*self._probe_shape())
+            res = probe_cached(*self._probe_shape(),
+                               plan=self._hist_lane_plan())
             if res:
                 return "pallas_q" if quant_ok else "pallas"
             telemetry.REGISTRY.counter("fallback.events").inc()

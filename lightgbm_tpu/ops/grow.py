@@ -33,7 +33,7 @@ cached, so repeated boosting iterations reuse one compiled executable.
 from __future__ import annotations
 
 import functools
-from typing import Dict, NamedTuple
+from typing import Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -155,6 +155,12 @@ class GrowerSpec(NamedTuple):
     # pallas/pallas_q/pallas_fused families become runnable — and
     # byte-comparable — off-TPU); never set on real backends
     hist_interpret: bool = False
+    # static lane plan of the f32 Pallas histogram kernel
+    # (ops/pallas_hist.py `lane_plan`): which few-bin columns share one
+    # 128-lane multi-hot group.  Derived by the booster from the bin
+    # counts of the columns the kernel will see; None = every column on
+    # its own max_bin lanes.  Same sums bit for bit either way
+    hist_lane_plan: Optional[tuple] = None
 
 
 class DeviceTree(NamedTuple):
@@ -580,9 +586,11 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
         # (XLA does not reliably hoist the split/lattice encoding out of
         # the while body — same hoisting as the wave grower)
         if spec.hist_impl == "pallas":
-            from .pallas_hist import (_split_payload9,
+            from .pallas_hist import (_split_payload9, assert_bins_in_plan,
                                       pallas_histogram_multi_rows)
             pw_prep = _split_payload9(payload)
+            if spec.debug_checks and spec.hist_lane_plan is not None:
+                assert_bins_in_plan(hist_bins, spec.hist_lane_plan)
         elif spec.hist_impl == "pallas_q":
             from .pallas_hist import (
                 pallas_histogram_multi_quantized_rows,
@@ -598,7 +606,8 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
             if spec.hist_impl == "pallas":
                 return pallas_histogram_multi_rows(
                     hist_bins, pw_prep, lid, one_slot, HB,
-                    interpret=spec.hist_interpret)[0]
+                    interpret=spec.hist_interpret,
+                    plan=spec.hist_lane_plan)[0]
             return pallas_histogram_multi_quantized_rows(
                 hist_bins, pw_prep, lid, one_slot, HB,
                 feat["qscales"][0], feat["qscales"][1],

@@ -265,10 +265,12 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         # loop-invariant code motion does not reliably hoist the f32
         # 3-way split / int8 lattice conversion out of the loop)
         if hist_fam == "pallas":
-            from .pallas_hist import (_split_payload9,
+            from .pallas_hist import (_split_payload9, assert_bins_in_plan,
                                       pallas_histogram_multi_rows)
             with jax.named_scope("payload"):
                 pw_prep = _split_payload9(payload)
+                if spec.debug_checks and spec.hist_lane_plan is not None:
+                    assert_bins_in_plan(bins_fm, spec.hist_lane_plan)
         elif hist_fam == "pallas_q":
             from .pallas_hist import (
                 pallas_histogram_multi_quantized_rows,
@@ -295,7 +297,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             if hist_fam == "pallas":
                 return pallas_histogram_multi_rows(
                     bins_fm, pw_prep, leaf_id, slots, HB,
-                    interpret=spec.hist_interpret)
+                    interpret=spec.hist_interpret,
+                    plan=spec.hist_lane_plan)
             return pallas_histogram_multi_quantized_rows(
                 bins_fm, pw_prep, leaf_id, slots, HB,
                 feat["qscales"][0], feat["qscales"][1],

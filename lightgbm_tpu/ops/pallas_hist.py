@@ -8,12 +8,26 @@ buffers]; src/treelearner/cuda/cuda_histogram_constructor.cu
 TPUs have no atomics, and XLA lowers a 256-segment scatter-add to a SERIAL
 update loop (~750 ms per 1M x 28 histogram on v5e — measured honestly, see
 PROFILE.md "round 3b").  So scatter-add becomes dense compute the MXU can
-chew: for each (row-tile, feature) the kernel materialises a one-hot
+chew: for each (row-tile, lane group) the kernel materialises a one-hot
 comparison of the bin column against the bin axis and contracts it with the
 payload in ONE default-precision bf16 matmul.  Per-tile accumulators live
 in VMEM and revisit across the row-tile grid axis, exactly the role of the
 CUDA kernel's shared-memory histograms (grid-level reduction replaces
 atomicAdd).
+
+What a call costs (PERF.md section 6, PR 30; one TPU v5e).  Not bytes: a
+call ran at 0.49 % of its HBM bound.  Until PR 30 the one-hot was built
+as `bins[:, None] == lane iota`, which spreads every bin row from lanes
+to sublanes with one XLU permute per 8 rows and column: a flat 0.57 ns
+per (row, column) whatever the bin count (128 lanes cost what 256 did).
+The operand is now built TRANSPOSED, `bins[None, :] == sublane iota`
+([lanes, N_t]; a bin row never leaves the lanes it arrives on), and the
+dot contracts both operands' last axis, the MXU loading the 0/1 tile
+transposed.  That left the MXU's slots as the bound — about 1 ps per
+(row, contracted lane) plus 1 us a tile — so the lanes count, and the
+LANE PLAN (`lane_plan`) packs the columns of few bins into shared
+128-lane multi-hot groups: the airline table contracts 1,920 lanes a
+row, not 3,328.  Same products, same order: sums bit-equal throughout.
 
 Precision design (replaces the old Precision.HIGHEST formulation, which
 cost 3-6 MXU passes): the one-hot operand is {0,1} — exact in bf16 at any
@@ -38,13 +52,16 @@ CUDA kernel) directly: LHS [3, N_t] = (gq·w, hq·w, w) — small integers,
 exact in bf16 — one matmul, rescaled to (Σg, Σh, count) afterwards.
 
 Layouts (all chosen for the (sublane, lane=128) tiling):
- - bins stay uint8 [F, N] in HBM — histogramming is bandwidth-bound and
-   bins dominate traffic.
+ - bins stay uint8 [F, N] in HBM, rows on lanes; the kernel compares
+   them where they lie (see above).
  - the payload rows are passed pre-split+masked [R, N] as f32 refs whose
    VALUES are bf16-representable (see the in-kernel comment: real bf16
    refs make Mosaic round the RESULT to bf16).
- - the kernel writes [F, R, MB] (lane dim = bins); the wrapper recombines
-   the split rows to the [F, MB, 3] the split finder expects.
+ - the kernel writes [F, R, MB] (lane dim = bins), or under a lane plan
+   [R, L]: one 128-aligned lane slice a group, a packed column at its
+   lane offset inside its group's slice (`_columns_of_plan` cuts the
+   columns back out to [F, R, MB]); the wrapper recombines the split
+   rows to the [F, MB, 3] the split finder expects.
 """
 from __future__ import annotations
 
@@ -96,8 +113,84 @@ def _split3(x: Array):
 FLUSH_TILES = 16
 
 
+LANE = 128
+
+
+def lane_plan(num_bins, max_bin: int):
+    """The f32 kernel's static lane plan, from the bin count of every
+    column it will see (the mappers' `num_bin`; under EFB the bundle
+    columns' widths): which columns share one one-hot operand.
+
+    The kernel's price is flat per row and CONTRACTED LANE, whatever a
+    column's bin count, so a column of 2 bins on `max_bin` lanes of its
+    own pays for 256.  First-fit in column order: a column of at most
+    128 bins joins the open 128-lane group at the next free lane offset
+    if its bins fit, else opens a new group; a wider column is a group of
+    its own on `max_bin` lanes (rounded up to whole 128-lane tiles), as
+    without a plan.  A group's operand is a multi-hot: one hot lane a
+    member, at disjoint offsets, so every cell is still the sum of the
+    same products in the same order (bit-equal sums).
+
+    Returns a hashable tuple of groups `(lanes, members)`, a member
+    `(column, lane_offset, num_bin)`; or None — today's program, every
+    column on its own `max_bin` lanes — where that saves no lane (no
+    column of 128 bins or fewer) or bins are uint16 (`max_bin` > 256).
+    No parameter: the layout depends only on the observed bin counts."""
+    if max_bin > 2 * LANE:
+        return None
+    wide = -(-max_bin // LANE) * LANE
+    groups, open_members, free = [], None, 0     # free: of the open group
+    for col, nb in enumerate(num_bins):
+        nb = max(int(nb), 1)
+        if nb > LANE:
+            groups.append((wide, ((col, 0, nb),)))
+            continue
+        if nb > free:
+            open_members, free = [], LANE
+            groups.append((LANE, open_members))
+        open_members.append((col, LANE - free, nb))
+        free -= nb
+    plan = tuple((lanes, tuple(members)) for lanes, members in groups)
+    if plan_lanes(plan) == len(num_bins) * wide:     # no lane saved
+        return None
+    return plan
+
+
+def plan_lanes(plan) -> int:
+    """Lanes a row contracted under `plan` (gauge `hist.lanes_per_row`)."""
+    return sum(lanes for lanes, _ in plan)
+
+
+def plan_columns(plan) -> list:
+    """`plan`'s columns in column order: (column, first lane in the
+    kernel's [.., L] sums, num_bin)."""
+    cols, lane0 = [], 0
+    for lanes, members in plan:
+        cols += [(c, lane0 + off, nb) for c, off, nb in members]
+        lane0 += lanes
+    return sorted(cols)
+
+
+def assert_bins_in_plan(bins_fm: Array, plan) -> None:
+    """Debug check (`GrowerSpec.debug_checks`): every bin below its
+    column's `num_bin`.  Without a plan an out-of-range bin lands in its
+    own column's dead lanes; packed it would alias a neighbour's cells.
+    A host callback, like `quantized_lattice_rows`' weight check."""
+    columns = plan_columns(plan)
+
+    def _check(top):
+        bad = [(c, int(top[c]), nb) for c, _, nb in columns if top[c] >= nb]
+        if bad:
+            raise FloatingPointError(
+                "histogram lane plan precondition violated: (column, "
+                f"largest bin, num_bin) {bad} — a bin at or over its "
+                "column's num_bin would be summed into a neighbouring "
+                "column's cells of the packed lane group")
+    jax.debug.callback(_check, bins_fm.max(axis=1))
+
+
 def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
-                       acc_ref, *, mb: int, n_rt: int):
+                       acc_ref, *, mb: int, n_rt: int, plan=None):
     """Multi-leaf grid cell with IN-KERNEL leaf masking — THE production
     kernel: every public f32 entry point (single-leaf included, via a
     mask-derived leaf id) lowers to this one body, so `probe()` gates
@@ -106,10 +199,14 @@ def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
 
     bins_ref: [F_t, N_t]; pw_ref: [R0, N_t] base payload rows (9
     f32-split); lid_ref: [1, N_t] i32 row→leaf; slots_ref: [1, S] i32 leaf
-    slots; hi_ref, lo_ref: [F_t, S*R0, MB] two-limb sums; acc_ref (VMEM
-    scratch, the same block) takes every tile's one-hot dot plainly:
-    after each `FLUSH_TILES` tiles, and after the last, it is added
-    error-free into (hi_ref, lo_ref) and cleared.
+    slots.  One dot a lane GROUP (`lane_plan`): without a plan every
+    column is its own group of `mb` lanes and hi_ref, lo_ref are
+    [F_t, S*R0, MB] two-limb sums; with one they are [S*R0, L], each
+    group a static 128-aligned lane slice, its operand the OR of its
+    members' one-hots at their lane offsets.  acc_ref (VMEM scratch, the
+    same block) takes every tile's dot plainly: after each `FLUSH_TILES`
+    tiles, and after the last, it is added error-free into (hi_ref,
+    lo_ref) and cleared.
 
     The payload rides f32 refs whose VALUES are bf16-representable:
     DEFAULT precision on TPU truncates f32 operands to bf16 for the MXU
@@ -131,26 +228,48 @@ def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
         lo_ref[:] = jnp.zeros_like(lo_ref)
 
     f_t, n_t = bins_ref.shape
+    if plan is None:
+        groups = tuple((mb, ((f, 0, mb),)) for f in range(f_t))
+        cells = tuple(range(f_t))                    # block [F_t, S*R0, MB]
+    else:
+        groups, cells, lane0 = plan, [], 0           # block [S*R0, L]
+        for lanes, _ in plan:
+            cells.append((slice(None), slice(lane0, lane0 + lanes)))
+            lane0 += lanes
     pw = pw_ref[:]                                   # [R0, N_t]
     lid = lid_ref[0, :]                              # [N_t] i32
     s_n = slots_ref.shape[1]
     lhs = jnp.concatenate(
         [jnp.where((lid == slots_ref[0, s])[None, :], pw, 0.0)
          for s in range(s_n)], axis=0)               # [S*R0, N_t]
-    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (n_t, mb), 1)
-    for f in range(f_t):                             # static unroll
-        b = bins_ref[f, :].astype(jnp.int32)
-        onehot = (b[:, None] == bin_ids).astype(jnp.float32)
-        acc_ref[f] += jax.lax.dot_general(
-            lhs, onehot, (((1,), (0,)), ((), ())),
+    bin_ids = {}
+    for (lanes, members), cell in zip(groups, cells):    # static unroll
+        if lanes not in bin_ids:
+            bin_ids[lanes] = jax.lax.broadcasted_iota(
+                jnp.int32, (lanes, n_t), 0)
+        # the operand is built TRANSPOSED, [lanes, N_t]: a bin row stays
+        # on the lanes it arrives on and is compared against a sublane
+        # iota; the dot contracts both operands' last axis (the MXU loads
+        # the transposed tile natively).  `b[:, None] == lane iota` cost
+        # one XLU permute per 8 rows and column to spread the row over
+        # sublanes — the whole price of a call before PR 30
+        hot = None
+        for f, off, _ in members:
+            b = bins_ref[f:f + 1, :].astype(jnp.int32)   # [1, N_t]
+            if off:
+                b = b + off
+            one = b == bin_ids[lanes]
+            hot = one if hot is None else hot | one
+        acc_ref[cell] += jax.lax.dot_general(
+            lhs, hot.astype(jnp.float32), (((1,), (1,)), ((), ())),
             precision=jax.lax.Precision.DEFAULT,
             preferred_element_type=jnp.float32)
 
     @pl.when(((r + 1) % FLUSH_TILES == 0) | (r == n_rt - 1))
     def _flush():
-        for f in range(f_t):                         # a feature at a time
-            hi_ref[f], lo_ref[f] = limb_add(hi_ref[f], lo_ref[f],
-                                            acc_ref[f])
+        for cell in cells:                           # a group at a time
+            hi_ref[cell], lo_ref[cell] = limb_add(hi_ref[cell], lo_ref[cell],
+                                                  acc_ref[cell])
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
 
@@ -164,10 +283,14 @@ def _combine_terms(hi: Array, lo: Array):
 
 def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
                       slots: Array, max_bin: int, row_tile: int,
-                      feat_tile: int, interpret: bool):
+                      feat_tile: int, interpret: bool, plan=None):
     """pallas_call driver for the in-kernel-masked multi-leaf kernel:
     [F, N] bins x [R0, N] payload x [N] leaf ids x [S] slots ->
-    (hi, lo), each [F, S*R0, MB] f32."""
+    (hi, lo), each [F, S*R0, MB] f32.  With a lane `plan` the kernel sums
+    into [S*R0, L] and each column's `[offset, offset + num_bin)` lanes
+    are sliced back out here and zero-padded to MB, so callers see one
+    layout; a `feat_tile` that splits the columns over several feature
+    blocks takes no plan (a group's members must share a block)."""
     f, n = bins_fm.shape
     r0 = pw0.shape[0]
     s_n = slots.shape[0]
@@ -179,16 +302,29 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
         leaf_id = jnp.pad(leaf_id, (0, n_pad), constant_values=-1)
     if feat_tile <= 0 or feat_tile > f:
         feat_tile = f
+    if feat_tile < f:
+        plan = None
     f_pad = (-f) % feat_tile
     if f_pad:
         bins_fm = jnp.pad(bins_fm, ((0, f_pad), (0, 0)))
     n_rt = (n + n_pad) // row_tile
     n_ft = (f + f_pad) // feat_tile
-    block = (feat_tile, s_n * r0, max_bin)
-    sums = jax.ShapeDtypeStruct((f + f_pad, s_n * r0, max_bin), jnp.float32)
+    if plan is None:
+        shape = (f + f_pad, s_n * r0, max_bin)
+        block = (feat_tile,) + shape[1:]
+        sums_spec = pl.BlockSpec(block, lambda j, r: (j, 0, 0))
+    else:
+        planned = [c for c, _, _ in plan_columns(plan)]
+        if planned != list(range(f)):
+            raise ValueError(f"lane plan over columns {planned} for {f} "
+                             "columns")
+        shape = block = (s_n * r0, plan_lanes(plan))
+        sums_spec = pl.BlockSpec(block, lambda j, r: (0, 0))
+    sums = jax.ShapeDtypeStruct(shape, jnp.float32)
 
     hi, lo = pl.pallas_call(
-        functools.partial(_hist_kernel_multi, mb=max_bin, n_rt=n_rt),
+        functools.partial(_hist_kernel_multi, mb=max_bin, n_rt=n_rt,
+                          plan=plan),
         grid=(n_ft, n_rt),  # row tiles iterate fastest -> sums revisited
         in_specs=[
             pl.BlockSpec((feat_tile, row_tile), lambda j, r: (j, r)),
@@ -196,13 +332,29 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
             pl.BlockSpec((1, row_tile), lambda j, r: (0, r)),
             pl.BlockSpec((1, s_n), lambda j, r: (0, 0)),
         ],
-        out_specs=[pl.BlockSpec(block, lambda j, r: (j, 0, 0)),
-                   pl.BlockSpec(block, lambda j, r: (j, 0, 0))],
+        out_specs=[sums_spec, sums_spec],
         out_shape=[sums, sums],
         scratch_shapes=[pltpu.VMEM(block, jnp.float32)],
         interpret=interpret,
     )(bins_fm, pw0, leaf_id.astype(jnp.int32)[None, :], slots[None, :])
-    return hi[:f], lo[:f]
+    if plan is None:
+        return hi[:f], lo[:f]
+    # the barrier hands callers two materialised arrays, as the kernel
+    # does without a plan: XLA then compiles what follows as it did,
+    # whereas fused into the unpacking a grower's f32 reductions over
+    # the bin axis associated differently (an internal node's stated
+    # hessian sum moved by one ulp on the chip)
+    return jax.lax.optimization_barrier(
+        (_columns_of_plan(hi, plan, max_bin),
+         _columns_of_plan(lo, plan, max_bin)))
+
+
+def _columns_of_plan(sums: Array, plan, max_bin: int) -> Array:
+    """Packed kernel sums [S*R0, L] -> [F, S*R0, MB]: each column's own
+    lanes, zero beyond its `num_bin` as its dead lanes are unpacked."""
+    return jnp.stack([jnp.pad(sums[:, lane:lane + nb],
+                              ((0, 0), (0, max_bin - nb)))
+                      for _, lane, nb in plan_columns(plan)])
 
 
 def _hist_kernel_multi_i8(bins_ref, pw_ref, lid_ref, slots_ref, out_ref, *,
@@ -275,11 +427,12 @@ def _run_kernel_multi_i8(bins_fm: Array, pw0: Array, leaf_id: Array,
 
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "impl", "row_tile",
-                                             "feat_tile", "interpret"))
+                                             "feat_tile", "interpret",
+                                             "plan"))
 def pallas_histogram(bins_fm: Array, payload: Array, row_mask: Array,
                      max_bin: int, *, impl: str = "onehot",
                      row_tile: int = ROW_TILE, feat_tile: int = 0,
-                     interpret: bool = False) -> Array:
+                     interpret: bool = False, plan=None) -> Array:
     """Drop-in replacement for histogram.leaf_histogram (same contract).
 
     Single-leaf = the f32 multi driver with a mask-derived leaf id
@@ -295,6 +448,7 @@ def pallas_histogram(bins_fm: Array, payload: Array, row_mask: Array,
       max_bin: padded bin-axis size MB.
       impl: kept for call-site compatibility; every path now runs the
         single-pass split-bf16 multi kernel.
+      plan: the columns' `lane_plan` (None: every column its own lanes).
     Returns: [F, MB, 3] f32 — matches the segment-sum path to >= f32
       accuracy (the 3-term bf16 split carries ~27 mantissa bits per
       payload element, every sum two limbs).
@@ -304,7 +458,7 @@ def pallas_histogram(bins_fm: Array, payload: Array, row_mask: Array,
     return hist_value(pallas_histogram_multi_rows(
         bins_fm, _split_payload9(payload), lid,
         jnp.zeros((1,), jnp.int32), max_bin, row_tile=row_tile,
-        feat_tile=feat_tile, interpret=interpret)[0])
+        feat_tile=feat_tile, interpret=interpret, plan=plan)[0])
 
 
 # MXU LHS capacity is 128 rows; leaves per kernel pass at 9 / 3 rows each
@@ -324,20 +478,22 @@ def _split_payload9(payload: Array) -> Array:
 
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "row_tile",
-                                             "feat_tile", "interpret"))
+                                             "feat_tile", "interpret",
+                                             "plan"))
 def pallas_histogram_multi(bins_fm: Array, payload: Array, leaf_id: Array,
                            slots: Array, max_bin: int, *,
                            row_tile: int = ROW_TILE, feat_tile: int = 0,
-                           interpret: bool = False) -> Array:
+                           interpret: bool = False, plan=None) -> Array:
     """Histograms of up to `len(slots)` leaves, filling the MXU.
 
-    The economics that make this THE wave-grower kernel: the MXU processes
-    up to 128 LHS rows per pass at the same cost as one, so the
-    single-leaf kernel (9 payload rows) wastes ~93% of each pass on
-    padding.  Packing `MULTI_CHUNK` leaves' masked payloads into one
-    [126, N_t] LHS computes 14 histograms for the price of one — the
-    reference's CUDA learner amortizes differently (per-leaf row subsets);
-    on TPU amortizing across leaves in the M axis is the native form.
+    The economics that make this THE wave-grower kernel: the one-hot
+    tile is the MXU's stationary operand and loading it is what a pass
+    pays for (16 pushes a 128x128 tile against 9 streamed LHS vregs at
+    S = 8), so up to 128 LHS rows ride at little more than the cost of 9:
+    `MULTI_CHUNK` leaves' masked payloads in one [126, N_t] LHS give 14
+    histograms for about the price of one — the reference's CUDA learner
+    amortizes differently (per-leaf row subsets); on TPU amortizing
+    across leaves in the M axis is the native form.
 
     Masking AFTER the 3-way split is exact: each split term is zeroed or
     kept whole, so per-leaf sums still reconstruct >= f32 accuracy.
@@ -349,7 +505,8 @@ def pallas_histogram_multi(bins_fm: Array, payload: Array, leaf_id: Array,
     """
     return hist_value(pallas_histogram_multi_rows(
         bins_fm, _split_payload9(payload), leaf_id, slots, max_bin,
-        row_tile=row_tile, feat_tile=feat_tile, interpret=interpret))
+        row_tile=row_tile, feat_tile=feat_tile, interpret=interpret,
+        plan=plan))
 
 
 def _limbs_from_terms(hi: Array, lo: Array, c: int, max_bin: int) -> Array:
@@ -362,26 +519,31 @@ def _limbs_from_terms(hi: Array, lo: Array, c: int, max_bin: int) -> Array:
 
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "row_tile",
-                                             "feat_tile", "interpret"))
+                                             "feat_tile", "interpret",
+                                             "plan"))
 def pallas_histogram_multi_rows(bins_fm: Array, pw9: Array, leaf_id: Array,
                                 slots: Array, max_bin: int, *,
                                 row_tile: int = ROW_TILE,
                                 feat_tile: int = 0,
-                                interpret: bool = False) -> Array:
+                                interpret: bool = False,
+                                plan=None) -> Array:
     """`pallas_histogram_multi` with the payload ALREADY split to [9, N]
     carrier rows (`_split_payload9`) — the wave grower prepares the rows
     once per tree and reuses them for every wave's call, instead of
     re-splitting the loop-invariant payload inside the while_loop body.
     Returns [S, F, MB, 6], both limbs of every sum, for
     `ops/histogram.hist_sub` to carry through the subtractions
-    (`hist_value` for the [.., 3] sums)."""
+    (`hist_value` for the [.., 3] sums).  `plan` (static, `lane_plan` of
+    the columns' bin counts; None = every column its own `max_bin`
+    lanes) changes the lanes the kernel contracts, not one bit of what
+    is returned."""
     S = slots.shape[0]
     outs = []
     for c0 in range(0, S, MULTI_CHUNK):
         c1 = min(S, c0 + MULTI_CHUNK)
         hi, lo = _run_kernel_multi(bins_fm, pw9, leaf_id, slots[c0:c1],
                                    max_bin, row_tile, feat_tile,
-                                   interpret)        # [F, (c1-c0)*9, MB]
+                                   interpret, plan)  # [F, (c1-c0)*9, MB]
         outs.append(_limbs_from_terms(hi, lo, c1 - c0, max_bin))
     return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
@@ -887,15 +1049,16 @@ _PROBE_CACHE = {}
 def probe_cached(max_bin: int = 256, num_feature: int = 28,
                  multi: bool = False, width: int = None,
                  quantized: bool = None, fused: bool = False,
-                 interpret: bool = False) -> ProbeResult:
-    """probe(), memoised per (backend platform, shape, multi params)."""
+                 interpret: bool = False, plan=None) -> ProbeResult:
+    """probe(), memoised per (backend platform, shape, multi params,
+    lane plan)."""
     key = (jax.devices()[0].platform, max_bin, num_feature, multi,
-           width, quantized, fused, interpret)
+           width, quantized, fused, interpret, plan)
     if key not in _PROBE_CACHE:
         _PROBE_CACHE[key] = probe(interpret=interpret, max_bin=max_bin,
                                   num_feature=num_feature, multi=multi,
                                   width=width, quantized=quantized,
-                                  fused=fused)
+                                  fused=fused, plan=plan)
     return _PROBE_CACHE[key]
 
 
@@ -1014,7 +1177,8 @@ def _probe_fused(interpret: bool, max_bin: int, num_feature: int,
 
 def probe(interpret: bool = False, max_bin: int = 256,
           num_feature: int = 28, multi: bool = False, width: int = None,
-          quantized: bool = None, fused: bool = False) -> ProbeResult:
+          quantized: bool = None, fused: bool = False,
+          plan=None) -> ProbeResult:
     """Runtime check that the kernel compiles and matches a host count on
     the current backend — used by Booster to gate the TPU histogram path.
     On a real TPU (`interpret=False`) a base kernel that RAISES is an
@@ -1034,7 +1198,10 @@ def probe(interpret: bool = False, max_bin: int = 256,
     exactly ONE multi block shape per spec (its root pass pads to the
     wave width), so pass `width` = min(wave_width, num_leaves - 1) and
     `quantized` = (hist_impl == 'pallas_q') to probe that exact shape;
-    the defaults probe a full chunk of both families.
+    the defaults probe a full chunk of both families.  `plan` = the
+    `lane_plan` the f32 kernel will run with: the probe compiles and
+    compares THAT program (bins drawn below each column's `num_bin`) in
+    place of the all-`max_bin` one — still one kernel compile.
 
     `fused=True` gates `hist_impl='pallas_fused'`/`'pallas_fused_q'`:
     a stricter, EXACT-equality probe (`_probe_fused`) over the fused
@@ -1044,7 +1211,7 @@ def probe(interpret: bool = False, max_bin: int = 256,
                             bool(quantized))
     try:
         return _probe_base(interpret, max_bin, num_feature, multi, width,
-                           quantized)
+                           quantized, plan)
     except Exception as e:
         if not interpret and jax.devices()[0].platform == "tpu":
             raise LightGBMError(
@@ -1056,7 +1223,8 @@ def probe(interpret: bool = False, max_bin: int = 256,
 
 
 def _probe_base(interpret: bool, max_bin: int, num_feature: int,
-                multi: bool, width: int, quantized: bool) -> ProbeResult:
+                multi: bool, width: int, quantized: bool,
+                plan=None) -> ProbeResult:
     """`probe`'s compile-and-compare body for the unfused kernels; any
     exception the kernel raises propagates to `probe`.  The reference is
     a float64 count on the host: nothing but the kernel compiles here."""
@@ -1064,8 +1232,11 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
 
     rng = np.random.RandomState(0)
     n = ROW_TILE if not interpret else 128
-    bins_np = rng.randint(0, max_bin, (num_feature, n)).astype(
-        np.uint8 if max_bin <= 256 else np.uint16)
+    bins_np = rng.randint(0, max_bin, (num_feature, n))
+    if plan is not None:
+        # every column below its own num_bin, as the plan presumes
+        bins_np %= np.array([nb for _, _, nb in plan_columns(plan)])[:, None]
+    bins_np = bins_np.astype(np.uint8 if max_bin <= 256 else np.uint16)
     payload_np = rng.randn(n, 3).astype(np.float32)
     mask_np = rng.rand(n) < 0.7
     s = jnp.float32(0.25)
@@ -1113,7 +1284,8 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
             else:
                 got = pallas_histogram_multi(
                     bins, payload, lid, slots, max_bin,
-                    row_tile=min(n, ROW_TILE), interpret=interpret)
+                    row_tile=min(n, ROW_TILE), interpret=interpret,
+                    plan=plan)
                 ref_payload = payload_np
             k = min(3, wdt)
             want = np.stack([host_hist(ref_payload, lid_np == sl)
@@ -1125,7 +1297,7 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
         return _PROBE_OK
     got = pallas_histogram(bins, payload, mask, max_bin,
                            row_tile=min(n, ROW_TILE),
-                           interpret=interpret)
+                           interpret=interpret, plan=plan)
     res = close("single-leaf f32 kernel vs a float64 count", got,
                 host_hist(payload_np, mask_np))
     if not res:

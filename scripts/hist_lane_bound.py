@@ -1,0 +1,105 @@
+"""Time one histogram kernel call on the chip at the benchmark cells' shape.
+
+    chiprun -- python3 scripts/hist_lane_bound.py [--rows N] [--reps K]
+
+Prints one JSON line a variant: seconds a call (median of K, after a
+warm-up call) and ns a (row, column).  Variants: `mb256` / `mb128` (every
+column its own `max_bin` lanes, bins drawn below 128 for both, so only the
+contracted lanes differ: ISSUE 30 step 0), `airline_trivial` and
+`airline_packed` (the thirteen airline bin counts at max_bin 255, without
+and with the lane plan); with `--parent <checkout>` that checkout's kernel
+too, and whether the airline sums equal its sums bit for bit.  Exits 2 where JAX finds no TPU: a CPU time is
+not a device number.
+"""
+import argparse
+import json
+import os
+import statistics
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from lightgbm_tpu.ops import pallas_hist as ph
+
+AIRLINE_NUM_BIN = (22, 12, 31, 7, 255, 255, 29, 255, 255, 255, 255, 255, 2)
+
+
+def _time(fn, args, reps):
+    jax.block_until_ready(fn(*args))                 # compile + warm up
+    out = []
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(*args))
+        out.append(time.perf_counter() - t0)
+    return statistics.median(out), out
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--rows", type=int, default=2048 * 2048)
+    ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--slots", type=int, default=8)
+    ap.add_argument("--rehearse", action="store_true", help="run off the "
+                    "TPU in interpret mode (tiny --rows): finds faults, "
+                    "its times mean nothing")
+    ap.add_argument("--parent", default="", help="checkout of another "
+                    "commit whose kernel is timed and compared bitwise")
+    a = ap.parse_args()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu" and not a.rehearse:
+        print("no TPU: a time from this machine is not a device number",
+              file=sys.stderr)
+        return 2
+    print(json.dumps({"device": dev.device_kind, "rows": a.rows,
+                      "slots": a.slots, "reps": a.reps}))
+    rng = np.random.RandomState(0)
+    n, f = a.rows, len(AIRLINE_NUM_BIN)
+    pw9 = ph._split_payload9(jnp.asarray(
+        np.abs(rng.randn(n, 3)).astype(np.float32)))
+    lid = jnp.asarray(rng.randint(0, 4 * a.slots, n).astype(np.int32))
+    slots = jnp.arange(a.slots, dtype=jnp.int32)
+    low = jnp.asarray(rng.randint(0, 128, (f, n)).astype(np.uint8))
+    air = jnp.asarray(np.stack(
+        [rng.randint(0, nb, n) for nb in AIRLINE_NUM_BIN]).astype(np.uint8))
+    variants = [("mb256", ph, low, 256, None), ("mb128", ph, low, 128, None)]
+    if a.parent:
+        import importlib.util
+        spec = importlib.util.spec_from_file_location(
+            "lightgbm_tpu.ops._parent_pallas_hist",
+            os.path.join(a.parent, "lightgbm_tpu", "ops", "pallas_hist.py"))
+        parent = importlib.util.module_from_spec(spec)
+        sys.modules[spec.name] = parent
+        spec.loader.exec_module(parent)
+        variants += [("parent_mb256", parent, low, 256, None),
+                     ("parent_mb128", parent, low, 128, None),
+                     ("airline_parent", parent, air, 255, None)]
+    variants += [("airline_trivial", ph, air, 255, None),
+                 ("airline_packed", ph, air, 255,
+                  ph.lane_plan(AIRLINE_NUM_BIN, 255))]
+    ref = None
+    for name, mod, bins, mb, plan in variants:
+        kw = {} if plan is None else {"plan": plan}
+
+        def call(b, p, l, s, mb=mb, kw=kw, mod=mod):
+            return mod.pallas_histogram_multi_rows(
+                b, p, l, s, mb, interpret=a.rehearse, **kw)
+        med, all_s = _time(call, (bins, pw9, lid, slots), a.reps)
+        line = {"variant": name, "max_bin": mb, "call_s": med,
+                "ns_per_row_col": med / (n * f) * 1e9, "all_s": all_s}
+        if name.startswith("airline"):
+            got = np.asarray(call(bins, pw9, lid, slots))
+            if ref is None:
+                ref = got
+            else:
+                line["bit_equal_to_first"] = bool(np.array_equal(got, ref))
+        print(json.dumps(line), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -151,3 +151,163 @@ class TestPallasHistogramQuantized:
             interpret=True))
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
         np.testing.assert_array_equal(got[..., 2], want[..., 2])
+
+
+# ------------------------------------------------------------- lane plan
+# the thirteen airline columns as the public Dataset constructor bins them
+AIRLINE_NUM_BIN = (22, 12, 31, 7, 255, 255, 29, 255, 255, 255, 255, 255, 2)
+
+
+def _plan_case(num_bins, n, s_n, seed):
+    rng = np.random.RandomState(seed)
+    from lightgbm_tpu.ops.pallas_hist import _split_payload9
+    bins = np.stack([rng.randint(0, nb, n) for nb in num_bins])
+    # a wide dynamic range, so the folds round and the lo limb is live
+    pay = rng.randn(n, 3) * np.exp(4 * rng.randn(n, 3))
+    pw9 = _split_payload9(jnp.asarray(pay.astype(np.float32)))
+    lid = jnp.asarray(rng.randint(0, s_n + 2, n).astype(np.int32))
+    return (jnp.asarray(bins.astype(np.uint8)), pw9, lid,
+            jnp.arange(s_n, dtype=jnp.int32))
+
+
+def _rows_jaxpr(num_bins, max_bin, **kw):
+    import jax
+    from lightgbm_tpu.ops.pallas_hist import pallas_histogram_multi_rows
+    args = _plan_case(num_bins, 300, 2, seed=0)
+    return str(jax.make_jaxpr(lambda *a: pallas_histogram_multi_rows(
+        *a, max_bin, row_tile=128, interpret=True, **kw))(*args))
+
+
+class TestLanePlan:
+    @pytest.mark.parametrize("s_n", [1, 8])
+    def test_packed_sums_bitwise_equal(self, s_n):
+        # more than FLUSH_TILES row tiles and a ragged last one: both
+        # limbs of every cell equal with and without the plan
+        from lightgbm_tpu.ops import pallas_hist as ph
+        n = 128 * (ph.FLUSH_TILES + 2) + 50
+        args = _plan_case(AIRLINE_NUM_BIN, n, s_n, seed=s_n)
+        plan = ph.lane_plan(AIRLINE_NUM_BIN, 255)
+        want = ph._run_kernel_multi(*args, 255, 128, 0, True, None)
+        got = ph._run_kernel_multi(*args, 255, 128, 0, True, plan)
+        for g, w in zip(got, want):
+            assert g.shape == w.shape == (13, s_n * 9, 255)
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+        assert np.abs(np.asarray(want[1])).sum() > 0   # the lo limb is live
+
+    @pytest.mark.parametrize("case", [
+        "airline", "all_255", "seven_of_30", "129_bins", "uint16",
+        "max_bin_64", "split_feat_tile", "efb_bundle_widths"])
+    def test_lane_plan(self, case):
+        from lightgbm_tpu.ops.pallas_hist import lane_plan, plan_lanes
+        if case == "airline":
+            plan = lane_plan(AIRLINE_NUM_BIN, 255)
+            assert plan_lanes(plan) == 1920
+            assert plan[0] == (128, ((0, 0, 22), (1, 22, 12), (2, 34, 31),
+                                     (3, 65, 7), (6, 72, 29), (12, 101, 2)))
+            assert plan[1:] == tuple((256, ((c, 0, 255),))
+                                     for c in (4, 5, 7, 8, 9, 10, 11))
+        elif case == "all_255":
+            # nothing to pack: no plan, and the parent's program
+            assert lane_plan((255,) * 5, 255) is None
+            assert _rows_jaxpr((255,) * 5, 255,
+                               plan=lane_plan((255,) * 5, 255)) \
+                == _rows_jaxpr((255,) * 5, 255)
+        elif case == "seven_of_30":
+            plan = lane_plan((30,) * 7, 255)
+            assert [len(m) for _, m in plan] == [4, 3]
+            assert plan[1][1][0] == (4, 0, 30) and plan_lanes(plan) == 256
+        elif case == "129_bins":
+            plan = lane_plan((129, 3, 128, 1), 255)
+            assert plan == ((256, ((0, 0, 129),)), (128, ((1, 0, 3),)),
+                            (128, ((2, 0, 128),)), (128, ((3, 0, 1),)))
+        elif case == "uint16":
+            assert lane_plan((3, 5, 1000), 1000) is None
+            assert lane_plan((3, 5, 257), 257) is None
+        elif case == "max_bin_64":
+            # narrow tables pack too; equal lanes to no plan means no plan
+            assert plan_lanes(lane_plan((64, 64, 60), 64)) == 256
+            assert lane_plan((100, 90), 100) is None
+        elif case == "split_feat_tile":
+            # a group's members must share a feature block: no plan
+            plan = lane_plan(AIRLINE_NUM_BIN, 255)
+            assert _rows_jaxpr(AIRLINE_NUM_BIN, 255, feat_tile=4, plan=plan) \
+                == _rows_jaxpr(AIRLINE_NUM_BIN, 255, feat_tile=4)
+            assert _rows_jaxpr(AIRLINE_NUM_BIN, 255, plan=plan) \
+                != _rows_jaxpr(AIRLINE_NUM_BIN, 255)
+        else:
+            import lightgbm_tpu as lgb
+            from lightgbm_tpu.booster import Booster
+            rng = np.random.RandomState(3)
+            X = np.zeros((600, 24), np.float32)     # two exclusive sets
+            for lo in (0, 12):
+                X[np.arange(600), lo + rng.randint(0, 12, 600)] = \
+                    rng.randint(1, 4, 600)
+            bst = Booster(params={"objective": "regression",
+                                  "verbosity": -1, "min_data_in_leaf": 5},
+                          train_set=lgb.Dataset(X, label=rng.randn(600)))
+            efb = bst.train_set.efb
+            assert efb is not None and efb.n_cols == 2
+            w0, w1 = (int(b) for b in efb.col_num_bin)
+            assert bst._grower_spec.hist_lane_plan \
+                == ((128, ((0, 0, w0), (1, w0, w1))),)
+
+    def test_probe_runs_the_plan(self):
+        from lightgbm_tpu.ops.pallas_hist import lane_plan
+        plan = lane_plan(AIRLINE_NUM_BIN, 255)
+        assert probe(interpret=True, max_bin=255, num_feature=13, plan=plan)
+        assert probe(interpret=True, max_bin=255, num_feature=13,
+                     multi=True, width=8, quantized=False, plan=plan)
+        # a plan over other columns than the kernel sees is refused
+        with pytest.raises(ValueError, match="lane plan"):
+            _rows_jaxpr(AIRLINE_NUM_BIN[:12], 255, plan=plan)
+
+    def test_out_of_range_bin_raises_under_debug_checks(self):
+        import jax
+        from lightgbm_tpu.ops.pallas_hist import (assert_bins_in_plan,
+                                                  lane_plan)
+        plan = lane_plan(AIRLINE_NUM_BIN, 255)
+        bins = _plan_case(AIRLINE_NUM_BIN, 64, 1, seed=5)[0]
+        assert_bins_in_plan(bins, plan)
+        jax.effects_barrier()
+        bad = bins.at[12, 7].set(2)          # Diverted has bins 0 and 1
+        check = jax.jit(lambda b: assert_bins_in_plan(b, plan))
+        with pytest.raises(Exception, match="precondition"):
+            check(bad)
+            jax.effects_barrier()
+
+    @pytest.mark.parametrize("policy", ["wave", "leafwise"])
+    def test_booster_runs_the_plan_same_trees(self, policy, monkeypatch):
+        # the booster derives the plan from the mappers, records the two
+        # gauges, and grows the trees it grows without a plan
+        import lightgbm_tpu as lgb
+        from lightgbm_tpu import telemetry
+        from lightgbm_tpu.booster import Booster
+        rng = np.random.RandomState(11)
+        n = 700
+        X = np.stack([rng.randint(0, 7, n), rng.randn(n),
+                      rng.randint(0, 2, n), rng.randint(0, 12, n),
+                      rng.randn(n)], axis=1).astype(np.float32)
+        y = (X[:, 1] + 0.3 * X[:, 0] - X[:, 2] + 0.1 * rng.randn(n) > 1)
+        params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
+                  "min_data_in_leaf": 5, "hist_impl": "pallas",
+                  "hist_interpret": True, "tpu_fused_split": False,
+                  "tpu_debug_nans": True, "tree_grow_policy": policy}
+
+        def model(planned):
+            if not planned:
+                monkeypatch.setattr(Booster, "_hist_lane_plan",
+                                    lambda self: None)
+            bst = lgb.train(params, lgb.Dataset(X, label=y.astype(float)),
+                            num_boost_round=2)
+            snap = telemetry.REGISTRY.snapshot()["gauges"]
+            return bst, snap["hist.lanes_per_row"], \
+                snap["hist.packed_columns"]
+        bst, lanes, packed = model(True)
+        assert bst._grow_policy == policy
+        plan = bst._grower_spec.hist_lane_plan
+        assert [len(m) for _, m in plan] == [3, 1, 1]
+        assert (lanes, packed) == (128 + 2 * 256, 3)
+        ref, lanes, packed = model(False)
+        assert ref._grower_spec.hist_lane_plan is None
+        assert (lanes, packed) == (5 * 256, 0)
+        assert bst.model_to_string() == ref.model_to_string()

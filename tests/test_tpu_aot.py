@@ -76,6 +76,23 @@ def test_hist_kernel_f32(topo, width):
         sds((N,), jnp.int32), sds((width,), jnp.int32), MB).compile()
 
 
+# the benchmark cells' thirteen airline columns (perfbench/configs)
+AIRLINE_NUM_BIN = (22, 12, 31, 7, 255, 255, 29, 255, 255, 255, 255, 255, 2)
+
+
+@pytest.mark.parametrize("width", [1, 8])
+def test_hist_kernel_f32_lane_plan(topo, width):
+    """The packed plan: six few-bin columns in one 128-lane multi-hot
+    group, a [S*9, 1920] accumulator sliced at 128-aligned lanes."""
+    sds, _ = _one(topo)
+    plan = ph.lane_plan(AIRLINE_NUM_BIN, 255)
+    assert ph.plan_lanes(plan) == 1920
+    ph.pallas_histogram_multi.lower(
+        sds((13, N), jnp.uint8), sds((N, 3), jnp.float32),
+        sds((N,), jnp.int32), sds((width,), jnp.int32), 255,
+        plan=plan).compile()
+
+
 @pytest.mark.parametrize("width", [1, 8])
 def test_hist_kernel_int8(topo, width):
     sds, _ = _one(topo)

@@ -8,13 +8,6 @@ measurement and prints ONE JSON line: {"metric", "value", "unit",
 fails the run: the traceback goes to stderr and the exit code is
 non-zero.
 
-`--kernel` runs the r6 histogram+split wave-pass micro-bench instead
-(xla / packed / pallas / pallas_q / pallas_fused / pallas_fused_q) and
-prints one JSON line with a `kernel` block — per-impl ms/pass + fused
-speedups — watched by the telemetry-diff sentinel's timing rules.  An
-impl the compiler refuses is recorded in the block with its message and
-the run exits 1 after printing.
-
 `--serve` adds a `serving` block after the main measurement: closed-loop
 p50/p99 + rows/s through the micro-batched runtime, per-rung splits, the
 sharded plane (when several devices are visible) and the fleet hot-swap.
@@ -732,156 +725,5 @@ def main() -> None:
     telemetry.TRACER.flush()
 
 
-# --------------------------------------------------------------------------
-# --kernel: histogram+split wave-pass micro-bench (r6 fused kernel)
-# --------------------------------------------------------------------------
-
-def main_kernel() -> None:
-    """Per-wave histogram+split pass time across hist impls
-    (xla / packed / pallas / pallas_q / pallas_fused / pallas_fused_q),
-    emitted as one JSON line with a `kernel` block; the headline `value`
-    is the fused-vs-pallas speedup (down_is_bad under the sentinel's
-    timing rules).
-
-    Each pass is one jitted function shaped like the wave grower's
-    per-wave work: build the [S, F, MB, 3] histograms of `width` leaves,
-    then decide every leaf's best split (`find_best_split` for the base
-    impls; the in-kernel candidates + `decide_from_candidates` for the
-    fused impls).  Every pass returns (hist, decision) — the grower
-    carries the histogram either way (sibling subtraction), so the
-    comparison isolates exactly what fusion removes: the XLA scan's
-    re-read of the histogram block and its [case, F, MB] gain grids.
-    An impl the compiler refuses is recorded as `<impl>_error` and makes
-    the exit code 1 — after the impls that do run have been measured."""
-    import numpy as np
-
-    n = int(os.environ.get("BENCH_KERNEL_N", 200_000))
-    width = int(os.environ.get("BENCH_KERNEL_WIDTH", 8))
-    reps = int(os.environ.get("BENCH_KERNEL_REPS", 10))
-    mb = 256
-
-    import jax
-    import jax.numpy as jnp
-
-    from lightgbm_tpu.utils.env import setup_compile_cache
-    setup_compile_cache()
-    devs = _require_tpu()
-
-    from lightgbm_tpu.ops import pallas_hist as ph
-    from lightgbm_tpu.ops.histogram import (hist_value, leaf_histogram_multi,
-                                            leaf_histogram_packed_multi)
-    from lightgbm_tpu.ops.split import (decide_from_candidates,
-                                        find_best_split)
-
-    rng = np.random.RandomState(77)
-    bins = jnp.asarray(rng.randint(0, mb, (F, n)).astype(np.uint8))
-    # quantized-lattice payload so the pallas_q/packed families measure
-    # their real input distribution (exact int8 grid, binary weights)
-    payload = np.stack([rng.randint(-15, 16, n) * 0.25,
-                        rng.randint(1, 16, n) * 0.125,
-                        np.ones(n)], axis=1).astype(np.float32)
-    pj = jnp.asarray(payload)
-    lid_np = rng.randint(0, width, n).astype(np.int32)
-    lid = jnp.asarray(lid_np)
-    slots = jnp.arange(width, dtype=jnp.int32)
-    nb = jnp.full((F,), mb, jnp.int32)
-    miss = jnp.zeros((F,), jnp.int32)
-    fdef = jnp.zeros((F,), jnp.int32)
-    allowed = jnp.ones((F,), bool)
-    iscat = jnp.zeros((F,), bool)
-    parent = jnp.asarray(np.stack([
-        np.bincount(lid_np, weights=payload[:, c], minlength=width)
-        for c in range(3)], axis=1).astype(np.float32))
-    s_g, s_h = jnp.float32(0.25), jnp.float32(0.125)
-    scan_kw = dict(l1=0.0, l2=1.0, min_data_in_leaf=20.0,
-                   min_sum_hessian=1e-3, min_gain_to_split=0.0)
-    find_kw = dict(cat_smooth=10.0, cat_l2=10.0, max_cat_threshold=32,
-                   max_cat_to_onehot=4, has_cat=False, **scan_kw)
-    pw9 = ph._split_payload9(pj)
-    pw3 = ph.quantized_lattice_rows(pj, s_g, s_h)
-
-    def scan_of(h, par):
-        return jax.vmap(
-            lambda hs, p: find_best_split(
-                hs, p[0], p[1], p[2], nb, miss, fdef, allowed, iscat,
-                **find_kw))(h, par)
-
-    def decide_of(cand, par):
-        return jax.vmap(
-            lambda cs, p: decide_from_candidates(
-                cs, p[0], p[1], p[2], miss, fdef, allowed, mb))(cand, par)
-
-    # inputs ride as ARGUMENTS (closing over them lets XLA constant-fold
-    # whole passes at trace time — same hazard grow_wave.py documents)
-    def p_xla(b, p, l, par):
-        h = leaf_histogram_multi(b, p, l, slots, mb)
-        return h, scan_of(h, par)
-
-    def p_packed(b, p, l, par):
-        h = leaf_histogram_packed_multi(b, p, l, slots, mb, s_g, s_h)
-        return h, scan_of(h, par)
-
-    def p_pallas(b, p, l, par):
-        h = ph.pallas_histogram_multi_rows(b, p, l, slots, mb)
-        return h, scan_of(hist_value(h), par)
-
-    def p_pallas_q(b, p, l, par):
-        h = ph.pallas_histogram_multi_quantized_rows(
-            b, p, l, slots, mb, s_g, s_h)
-        return h, scan_of(h, par)
-
-    def p_fused(b, p, l, par):
-        h, cand = ph.pallas_fused_hist_split_rows(
-            b, p, l, slots, nb, miss, par, mb, **scan_kw)
-        return h, decide_of(cand, par)
-
-    def p_fused_q(b, p, l, par):
-        h, cand = ph.pallas_fused_hist_split_quantized_rows(
-            b, p, l, slots, nb, miss, par, mb, s_g, s_h,
-            **scan_kw)
-        return h, decide_of(cand, par)
-
-    entries = [("xla", p_xla, pj), ("packed", p_packed, pj),
-               ("pallas", p_pallas, pw9), ("pallas_q", p_pallas_q, pw3),
-               ("pallas_fused", p_fused, pw9),
-               ("pallas_fused_q", p_fused_q, pw3)]
-    times, errors = {}, {}
-    for name, fn, pw in entries:
-        jfn = jax.jit(fn)
-        try:
-            t0 = time.time()
-            jax.block_until_ready(jfn(bins, pw, lid, parent))  # compile
-            _log(f"kernel {name}: compiled+warm in {time.time() - t0:.1f}s")
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                out = jfn(bins, pw, lid, parent)
-            jax.block_until_ready(out)
-            times[name] = (time.perf_counter() - t0) / reps
-            _log(f"kernel {name}: {times[name] * 1e3:.2f} ms/pass")
-        except Exception as e:  # recorded; fails the run after the loop
-            errors[name] = f"{type(e).__name__}: {e}"
-            _log(f"kernel {name} failed: {errors[name]}")
-    blk = {"n": n, "f": F, "max_bin": mb, "width": width, "reps": reps,
-           "interpret": False}
-    blk.update({f"{k}_ms": round(v * 1e3, 3) for k, v in times.items()})
-    blk.update({f"{k}_error": v[:500] for k, v in errors.items()})
-    for base, fused_ in (("pallas", "pallas_fused"),
-                         ("pallas_q", "pallas_fused_q")):
-        if times.get(base) and times.get(fused_):
-            blk[f"speedup_{fused_}"] = round(times[base] / times[fused_],
-                                             3)
-    print(json.dumps({"metric": "hist_split_fused_speedup",
-                      "value": blk.get("speedup_pallas_fused", 0.0),
-                      "unit": "x",
-                      "backend": f"{devs[0].platform}x{len(devs)}",
-                      "device_kind": devs[0].device_kind,
-                      "kernel": blk}), flush=True)
-    if errors:
-        sys.exit(1)
-
-
 if __name__ == "__main__":
-    if "--kernel" in sys.argv:
-        main_kernel()
-    else:
-        main()
+    main()

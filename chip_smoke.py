@@ -55,13 +55,7 @@ MULTICHIP_AUC_TOL = 0.002
 
 #: `fallback.*` events this run may emit, each with the reason it is
 #: tolerated.  Anything else fails the smoke.
-ALLOWED_FALLBACKS = {
-    "fallback.fused_split":
-        "the fused hist+split kernel is an optional upgrade over the "
-        "unfused Pallas kernel the run then uses; on jax 0.9.0 Pallas TPU "
-        "has no lowering for the in-kernel cumsum (ROADMAP S3), and the "
-        "leafwise and data-parallel phases are ineligible by design",
-}
+ALLOWED_FALLBACKS = {}
 
 FAILURES = []
 
@@ -121,7 +115,6 @@ def main() -> int:
     import lightgbm_tpu as lgb
     from lightgbm_tpu import native, telemetry
     from lightgbm_tpu.metrics import _auc
-    from lightgbm_tpu.ops.pallas_hist import base_hist_impl
     from lightgbm_tpu.utils.env import setup_compile_cache
 
     import bench
@@ -180,7 +173,7 @@ def main() -> int:
         f"{warm_s:.2f}s")
     check(bst._grow_policy == "wave",
           f"tree_grow_policy=wave resolved to {bst._grow_policy!r}")
-    check(base_hist_impl(impl) in ("pallas", "pallas_q"),
+    check(impl in ("pallas", "pallas_q"),
           f"hist_impl resolved to {impl!r}, not the Pallas family")
     check(dry or not bst._grower_spec.hist_interpret,
           "the Pallas kernels ran in interpret mode on the TPU")
@@ -242,7 +235,7 @@ def main() -> int:
         f"AUC {lw_auc:.4f}")
     check(bst_lw._grow_policy == "leafwise",
           f"default policy resolved to {bst_lw._grow_policy!r}")
-    check(base_hist_impl(lw_impl) in ("pallas", "pallas_q"),
+    check(lw_impl in ("pallas", "pallas_q"),
           f"leafwise hist_impl resolved to {lw_impl!r}")
     check(lw_auc >= auc_floor - 0.05,
           f"leafwise AUC {lw_auc:.4f} after {CHUNK} rounds")

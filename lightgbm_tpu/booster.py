@@ -513,7 +513,6 @@ class Booster:
             hist_lane_plan=self._hist_lane_plan(),
         )
         self._grow_policy = self._resolve_grow_policy()
-        self._maybe_fuse_hist_impl()
         self._record_hist_lanes()
         self._rng_key0 = jax.random.PRNGKey(
             self.config.bagging_seed % (2 ** 31))
@@ -968,8 +967,8 @@ class Booster:
         a row) and `hist.packed_columns` (columns that share a lane
         group) of the grower this booster runs; 0 where no Pallas
         histogram kernel runs."""
-        from .ops.pallas_hist import LANE, base_hist_impl, plan_lanes
-        fam = base_hist_impl(self._grower_spec.hist_impl)
+        from .ops.pallas_hist import LANE, plan_lanes
+        fam = self._grower_spec.hist_impl
         plan = self._grower_spec.hist_lane_plan if fam == "pallas" else None
         bins, cols = self._probe_shape()
         lanes = 0
@@ -981,11 +980,7 @@ class Booster:
         telemetry.REGISTRY.gauge("hist.lanes_per_row").set(lanes)
         telemetry.REGISTRY.gauge("hist.packed_columns").set(packed)
 
-    #: legal `hist_impl` requests (fused names resolve to their base
-    #: family here; the fusion upgrade stays `_maybe_fuse_hist_impl`'s
-    #: call, and the fused path is byte-identical to its base anyway)
-    _HIST_IMPLS = ("auto", "segment_sum", "packed", "pallas", "pallas_q",
-                   "pallas_fused", "pallas_fused_q")
+    _HIST_IMPLS = ("auto", "segment_sum", "packed", "pallas", "pallas_q")
 
     def _quant_hist_reasons(self) -> list:
         """Why the int-lattice histogram family cannot apply (empty =
@@ -1029,8 +1024,8 @@ class Booster:
                     + "; ".join(reasons)
                     + " — degrading to the auto-selected path (the "
                     "lattice/kernel family is the fast path: one packed "
-                    "sweep per (g, h) pair on CPU, ~60x over the XLA "
-                    "scatter on TPU; PROFILE.md round 3b)")
+                    "sweep per (g, h) pair on CPU, the Pallas kernel in "
+                    "place of the XLA scatter on TPU)")
 
     def _resolve_hist_impl(self) -> str:
         """Pick the histogram implementation.  Default (`hist_impl=auto`)
@@ -1045,7 +1040,7 @@ class Booster:
         degrades to the auto choice with a priced event
         (degrade-don't-error, like the serving ladder)."""
         cfg = self.config
-        from .ops.pallas_hist import base_hist_impl, probe_cached
+        from .ops.pallas_hist import probe_cached
         req = str(cfg.hist_impl or "auto").lower()
         if req not in self._HIST_IMPLS:
             raise LightGBMError(
@@ -1057,15 +1052,14 @@ class Booster:
         on_tpu = bool(cfg.tpu_use_pallas) \
             and jax.devices()[0].platform == "tpu"
         if req != "auto":
-            base = base_hist_impl(req)
             reasons = []
-            if base in ("packed", "pallas_q"):
+            if req in ("packed", "pallas_q"):
                 if not cfg.use_quantized_grad:
                     reasons.append("use_quantized_grad=False (the "
                                    "int-lattice needs quantized "
                                    "gradients)")
                 reasons.extend(quant_reasons)
-            if base in ("pallas", "pallas_q"):
+            if req in ("pallas", "pallas_q"):
                 if not cfg.tpu_use_pallas:
                     reasons.append("tpu_use_pallas=False")
                 elif not (on_tpu or interpret):
@@ -1079,7 +1073,7 @@ class Booster:
                         reasons.append("a failing Pallas histogram probe "
                                        "on this backend" + _probe_why(res))
             if not reasons:
-                return base
+                return req
             self._hist_impl_fallback(req, reasons)
         # ---- auto: the int-lattice family is the default wherever the
         # model qualifies ----
@@ -1089,9 +1083,8 @@ class Booster:
             self._hist_impl_fallback("quantized", quant_reasons)
         if on_tpu:
             # XLA lowers the 256-segment scatter-add to a SERIAL update
-            # loop on TPU (~60x slower than the kernel — PROFILE.md round
-            # 3b), so the Pallas one-hot-matmul kernel is the default
-            # there.  The probe RAISES when the kernel does not compile
+            # loop on TPU, so the Pallas one-hot-matmul kernel is the
+            # default there.  The probe RAISES when the kernel does not compile
             # or run on the TPU; only a numeric mismatch degrades, with
             # the numbers in the event
             res = probe_cached(*self._probe_shape(),
@@ -1110,69 +1103,6 @@ class Booster:
             # backend's quantized fast path
             return "packed"
         return "segment_sum"
-
-    def _maybe_fuse_hist_impl(self) -> None:
-        """Upgrade a probe-certified pallas/pallas_q impl to the fused
-        hist+split variant (hist_impl='pallas_fused'/'pallas_fused_q',
-        tpu_fused_split): the wave kernel scans each histogram in VMEM
-        and emits compact split candidates instead of re-reading the
-        wave's [S, F, MB, 3] block from HBM for the XLA scan.  The gate
-        mirrors ops/grow_wave.py's `fused` eligibility plus the
-        booster-only conditions the grower cannot check: monotone
-        constraints ride a runtime array there (the in-kernel scan is
-        the PLAIN closed-form gain — finite output bounds switch
-        find_best_split to given-output gain), and the EXACT-parity
-        fused probe (ops/pallas_hist._probe_fused) certifies this
-        backend's Mosaic lowering matches the XLA scan bitwise."""
-        spec = self._grower_spec
-        if spec.hist_impl not in ("pallas", "pallas_q"):
-            return
-        cfg = self.config
-        if not cfg.tpu_fused_split:
-            return
-        reasons = []
-        if self._grow_policy != "wave":
-            reasons.append("tree_grow_policy != wave (the strict policy "
-                           "re-scans cached histograms per split)")
-        if any(int(v) for v in (cfg.monotone_constraints or [])):
-            reasons.append("monotone_constraints")
-        if spec.bundled:
-            reasons.append("EFB bundling")
-        if spec.path_smooth > 0.0:
-            reasons.append("path_smooth")
-        if spec.extra_trees:
-            reasons.append("extra_trees")
-        kind, shards, _, _, _, _ = self._learner_topology()
-        if shards > 1 and kind != "serial":
-            reasons.append(f"tree_learner={kind} (distributed growers "
-                           "scan reduced histograms, not kernel output)")
-        if not reasons:
-            from .ops.grow_wave import wave_sizes
-            from .ops.pallas_hist import probe_cached
-            _, w = wave_sizes(spec)
-            pb, pc = self._probe_shape()
-            res = probe_cached(pb, pc, width=w,
-                               quantized=spec.hist_impl == "pallas_q",
-                               fused=True, interpret=spec.hist_interpret)
-            if not res:
-                reasons.append("a failing fused-kernel exact-parity "
-                               "probe on this backend" + _probe_why(res))
-        if reasons:
-            # priced downgrade: the unfused wave re-reads each wave's
-            # [S, F, MB, 3] histogram block from HBM for the XLA split
-            # scan the fused kernel would have done in VMEM (~15-20% of
-            # wave step time at the 2M bench shape — PROFILE.md r3c)
-            telemetry.REGISTRY.counter("fallback.events").inc()
-            telemetry.event("fallback.fused_split", reasons=reasons)
-            log.warning("fused hist+split is unavailable with "
-                        + "; ".join(reasons)
-                        + f" — using the unfused {spec.hist_impl} kernel "
-                        "(one extra histogram-block HBM read per wave "
-                        "for the XLA split scan)")
-            return
-        self._grower_spec = spec._replace(
-            hist_impl="pallas_fused" if spec.hist_impl == "pallas"
-            else "pallas_fused_q")
 
     def _build_feat(self) -> None:
         """Per-feature metadata pytree for the grower, incl. monotone
@@ -1592,9 +1522,8 @@ class Booster:
             # set_leaf_output mutated the model — cached scores are wrong
             self._rebuild_train_scores()
         fobj = fobj or self._fobj
-        from .ops.pallas_hist import base_hist_impl
-        if fobj is not None and base_hist_impl(
-                self._grower_spec.hist_impl) in ("packed", "pallas_q"):
+        if fobj is not None and self._grower_spec.hist_impl in (
+                "packed", "pallas_q"):
             # ad-hoc update(fobj=...) on a booster whose grower was
             # specialized for packed quantized histograms: custom
             # hessians may be negative, which corrupts the packed field
@@ -1669,9 +1598,7 @@ class Booster:
             from .ops.fused import quantize_gradients
             qkey = jax.random.fold_in(self._rng_key0, it * 2 + 1) \
                 if cfg.stochastic_rounding else None
-            from .ops.pallas_hist import base_hist_impl
-            if base_hist_impl(self._grower_spec.hist_impl) \
-                    in ("packed", "pallas_q"):
+            if self._grower_spec.hist_impl in ("packed", "pallas_q"):
                 grad, hess, qs = quantize_gradients(
                     grad, hess, cfg.num_grad_quant_bins, qkey,
                     return_scales=True,
@@ -3456,7 +3383,6 @@ class Booster:
             wave_gain_ratio=self._wave_gain_ratio(),
             wave_overgrow=self._wave_overgrow())
         self._grow_policy = self._resolve_grow_policy()
-        self._maybe_fuse_hist_impl()
         self._grower = self._make_serial_grower()
         self._build_feat()
         self._setup_tree_learner()

@@ -229,9 +229,7 @@ def make_bulk_trainer(spec: BulkSpec, grad_fn: Callable, renew_args=None,
             # odd stream ids — bagging/GOSS use even fold_in ids on key0
             qkey = jax.random.fold_in(key0, it * 2 + 1) \
                 if spec.quant_stochastic else None
-            from .pallas_hist import base_hist_impl
-            if base_hist_impl(spec.grower.hist_impl) \
-                    in ("packed", "pallas_q"):
+            if spec.grower.hist_impl in ("packed", "pallas_q"):
                 grad, hess, qs = quantize_gradients(
                     grad, hess, spec.quant_bins, qkey, return_scales=True,
                     const_hess_level=spec.grower.packed_const_hess_level)

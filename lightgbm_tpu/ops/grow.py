@@ -152,8 +152,8 @@ class GrowerSpec(NamedTuple):
     # growers only (booster downgrades otherwise).
     monotone_intermediate: bool = False
     # run the Pallas kernels in interpret mode (CPU parity tests: the
-    # pallas/pallas_q/pallas_fused families become runnable — and
-    # byte-comparable — off-TPU); never set on real backends
+    # pallas/pallas_q families become runnable — and byte-comparable —
+    # off-TPU); never set on real backends
     hist_interpret: bool = False
     # static lane plan of the f32 Pallas histogram kernel
     # (ops/pallas_hist.py `lane_plan`): which few-bin columns share one
@@ -471,14 +471,6 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
       psummed — communication drops from O(F·MB) to O(2k·MB), the
       strategy for DCN-crossing meshes.  `n_shards` = total shard count.
     """
-    # the strict policy has no fused hist+split path (its per-split
-    # searches re-scan CACHED histograms, which the fused kernel never
-    # materializes candidates for): normalize a fused impl to its base
-    # histogram family — silent, because the fused candidates are
-    # byte-identical to find_best_split by construction
-    from .pallas_hist import base_hist_impl
-    if spec.hist_impl != base_hist_impl(spec.hist_impl):
-        spec = spec._replace(hist_impl=base_hist_impl(spec.hist_impl))
     L = spec.num_leaves
     MB = spec.max_bin
     find = functools.partial(

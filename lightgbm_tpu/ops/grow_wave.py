@@ -103,9 +103,8 @@ from .histogram import (hist_stream_finalize, hist_stream_init,
                         hist_sub, hist_value, leaf_histogram_multi_limbs,
                         leaf_histogram_packed_multi, ring_fold,
                         ring_ordered_sum)
-from .split import (NEG_INF, decide_from_candidates, find_best_split,
-                    refine_child_sums,
-                    leaf_output, merge_split_results, smooth_output)
+from .split import (NEG_INF, find_best_split, leaf_output,
+                    refine_child_sums, smooth_output)
 
 Array = jax.Array
 
@@ -211,28 +210,6 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                          "align with per-feature blocks)")
     HB = spec.bundle_max_bin if spec.bundled else spec.max_bin
 
-    # fused hist+split (hist_impl="pallas_fused"/"pallas_fused_q"): the
-    # in-kernel scan covers the PLAIN numerical gain path only, so any
-    # mode that alters the numerical gain math or search grid — path
-    # smoothing, extra_trees per-bin candidate masks, distributed block
-    # search, EFB bundle expansion — falls back to the base histogram
-    # family, which is sound because the fused candidates are
-    # byte-identical to `find_best_split` by construction (the booster
-    # additionally resolves a fused impl only with monotone constraints
-    # off: leaf-output bounds must stay infinite for the closed-form
-    # gain).  Categorical features always take the find_best_split
-    # fallback on the carried histogram and merge (`split_of_fused`).
-    from .pallas_hist import base_hist_impl
-    hist_fam = base_hist_impl(spec.hist_impl)
-    fused = (spec.hist_impl != hist_fam and axes_all is None
-             and not spec.bundled and spec.path_smooth <= 0.0
-             and not spec.extra_trees)
-    REGISTRY.gauge("wave.fused").set(int(fused))
-    scan_kw = dict(l1=spec.lambda_l1, l2=spec.lambda_l2,
-                   min_data_in_leaf=spec.min_data_in_leaf,
-                   min_sum_hessian=spec.min_sum_hessian_in_leaf,
-                   min_gain_to_split=spec.min_gain_to_split)
-
     # bin axis is `_` (not F): under EFB bundling bins_fm is [G, N]
     # bundle-major while `allowed` stays [F] over real features
     @contract(bins_fm="[_, N] int", grad="[N] f32", hess="[N] f32",
@@ -264,14 +241,14 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         # per tree here, not inside every wave's while_loop body (XLA's
         # loop-invariant code motion does not reliably hoist the f32
         # 3-way split / int8 lattice conversion out of the loop)
-        if hist_fam == "pallas":
+        if spec.hist_impl == "pallas":
             from .pallas_hist import (_split_payload9, assert_bins_in_plan,
                                       pallas_histogram_multi_rows)
             with jax.named_scope("payload"):
                 pw_prep = _split_payload9(payload)
                 if spec.debug_checks and spec.hist_lane_plan is not None:
                     assert_bins_in_plan(bins_fm, spec.hist_lane_plan)
-        elif hist_fam == "pallas_q":
+        elif spec.hist_impl == "pallas_q":
             from .pallas_hist import (
                 pallas_histogram_multi_quantized_rows,
                 quantized_lattice_rows)
@@ -279,11 +256,6 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 pw_prep = quantized_lattice_rows(
                     payload, feat["qscales"][0], feat["qscales"][1],
                     debug=spec.debug_checks)
-        if fused:
-            from .pallas_hist import (
-                pallas_fused_hist_split_quantized_rows,
-                pallas_fused_hist_split_rows, pallas_split_scan)
-
         # data_rs: each shard stores/searches only its feature block
         # (the SAME shared machinery as the strict grower's block path)
         if block:
@@ -294,7 +266,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
 
         def kernel_hist_multi(leaf_id, slots):
             """This shard's rows through the Pallas kernel."""
-            if hist_fam == "pallas":
+            if spec.hist_impl == "pallas":
                 return pallas_histogram_multi_rows(
                     bins_fm, pw_prep, leaf_id, slots, HB,
                     interpret=spec.hist_interpret,
@@ -314,12 +286,12 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 shard order (`ring_ordered_sum`)."""
                 Fh = bins_fm.shape[0]
                 S = slots.shape[0]
-                if hist_fam in ("pallas", "pallas_q"):
+                if spec.hist_impl in ("pallas", "pallas_q"):
                     with jax.named_scope("ring_fold"):
                         h = ring_ordered_sum(
                             kernel_hist_multi(leaf_id, slots), axis_last,
                             n_shards)
-                elif hist_fam == "packed":
+                elif spec.hist_impl == "packed":
                     chl = spec.packed_const_hess_level
 
                     def fold(acc):
@@ -358,9 +330,9 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             with jax.named_scope("histogram_wave"):
                 if det:
                     return det_hist_multi(leaf_id, slots)
-                if hist_fam in ("pallas", "pallas_q"):
+                if spec.hist_impl in ("pallas", "pallas_q"):
                     h = kernel_hist_multi(leaf_id, slots)
-                elif hist_fam == "packed":
+                elif spec.hist_impl == "packed":
                     h = leaf_histogram_packed_multi(
                         bins_fm, payload, leaf_id, slots, HB,
                         feat["qscales"][0], feat["qscales"][1],
@@ -380,28 +352,6 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 elif axes_all is not None:
                     h = jax.lax.psum(h, axes_all)
             return h
-
-        if fused:
-            def hist_cand_multi(leaf_id, slots, parent):
-                """Fused wave pass: one kernel builds the listed slots'
-                histograms in VMEM and scans them in place, returning
-                (hist [S, F, MB, 6|3], cand [S, 2, F, 8]) — the hist is
-                bitwise `hist_multi`'s (carried as state for sibling
-                subtraction / categorical fallback), the candidates feed
-                `split_of_fused`.  `parent` [S, 3] = each slot's own
-                (g, h, cnt) sums (the scan's gain shift)."""
-                with jax.named_scope("histogram_wave"), \
-                        jax.named_scope("hist_split_fused"):
-                    if hist_fam == "pallas":
-                        return pallas_fused_hist_split_rows(
-                            bins_fm, pw_prep, leaf_id, slots, feat["nb"],
-                            feat["missing"], parent, HB,
-                            interpret=spec.hist_interpret, **scan_kw)
-                    return pallas_fused_hist_split_quantized_rows(
-                        bins_fm, pw_prep, leaf_id, slots, feat["nb"],
-                        feat["missing"], parent, HB, feat["qscales"][0],
-                        feat["qscales"][1],
-                        interpret=spec.hist_interpret, **scan_kw)
 
         # per-node column sampling / extra_trees / CEGB pricing — the
         # SAME shared derivations as the strict grower (ops/grow.py), so
@@ -463,30 +413,6 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                      cand_mask=cm, gain_penalty=penalty)
             return refine_child_sums(s, hist, feat["nb"], feat["missing"])
 
-        if fused:
-            def split_of_fused(hist_sl, cand_sl, g, h, c, node_allowed,
-                               lb, ub, p_out, nid, penalty=None):
-                """Fused counterpart of `split_of`: numerical splits are
-                decoded from the kernel's in-VMEM candidates; categorical
-                features (if any) re-scan the carried histogram slice via
-                `find_best_split` restricted to `is_cat`, and the two
-                results merge under the full search's flat-argmax
-                tie-break (numerical cases precede categorical in the
-                case-major grid, so ties go to `num`)."""
-                na = node_allowed & bynode_mask(nid)
-                num = decide_from_candidates(
-                    cand_sl, g, h, c, feat["missing"], feat["default"],
-                    na & ~feat["is_cat"], MB, gain_penalty=penalty)
-                if spec.has_cat:
-                    cat = find(hist_value(hist_sl), g, h, c, feat["nb"],
-                               feat["missing"], feat["default"],
-                               na & feat["is_cat"], feat["is_cat"],
-                               mono=mono, out_lb=lb, out_ub=ub,
-                               parent_output=p_out, gain_penalty=penalty)
-                    num = merge_split_results(num, cat)
-                return refine_child_sums(num, hist_sl, feat["nb"],
-                                         feat["missing"])
-
         # ---- root ----
         # the root pass uses the SAME [W]-slot call shape as every wave
         # (pad slots LB match nothing), so exactly ONE multi-kernel block
@@ -533,24 +459,11 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 # only features inside a constraint group may ever split
                 allowed = allowed & jnp.any(feat["ic_groups"], axis=0)
             root_pen = cegb_penalty(root_c, jnp.zeros((F,), bool))
-        if fused:
-            with jax.named_scope("init"):
-                root_parent = jnp.zeros((W, 3), jnp.float32).at[0].set(
-                    jnp.stack([root_g, root_h, root_c]))
-            hist0, cand0 = hist_cand_multi(leaf_id0, root_slots,
-                                           root_parent)
-            hist0 = hist0[0]
-            with jax.named_scope("find_split"):
-                s0 = split_of_fused(hist0, cand0[0], root_g, root_h,
-                                    root_c, allowed, jnp.float32(-INF),
-                                    jnp.float32(INF), root_out, 0,
-                                    penalty=root_pen)
-        else:
-            hist0 = hist_multi(leaf_id0, root_slots)[0]
-            with jax.named_scope("find_split"):
-                s0 = split_of(hist0, root_g, root_h, root_c, allowed,
-                              jnp.float32(-INF), jnp.float32(INF),
-                              root_out, 0, penalty=root_pen)
+        hist0 = hist_multi(leaf_id0, root_slots)[0]
+        with jax.named_scope("find_split"):
+            s0 = split_of(hist0, root_g, root_h, root_c, allowed,
+                          jnp.float32(-INF), jnp.float32(INF),
+                          root_out, 0, penalty=root_pen)
 
         with jax.named_scope("init"):
             hist = jnp.zeros((LB,) + hist0.shape, dtype=jnp.float32)\
@@ -637,10 +550,6 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             ONE pick whose smaller child's histogram is read from there
             instead of built."""
             strict = small_hists is not None
-            # the tail's children are searched from the carried histogram
-            # by `split_of`, whose result the fused candidates equal byte
-            # for byte by construction: decided from the static spec
-            fused_here = fused and not strict
             # ---- split phase: best-first among READY leaves (leaves
             # created this wave have no histogram yet and wait for the
             # next wave), up to the batch capacity W ----
@@ -871,15 +780,6 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     with jax.named_scope("hist_cache"):
                         small_h = small_hists[
                             jnp.clip(s1["p_left"], 0, LB - 1)]
-                elif fused:
-                    # per-slot (g, h, cnt) sums = the in-kernel scan's
-                    # gain shift; pad slots clip to junk stats whose
-                    # candidates are dropped by the scatter below
-                    stats = jnp.stack([s1["leaf_g"], s1["leaf_h"],
-                                       s1["leaf_c"]], axis=1)
-                    par_small = stats[jnp.clip(s1["p_small"], 0, LB - 1)]
-                    small_h, cand_small = hist_cand_multi(
-                        s1["leaf_id"], s1["p_small"], par_small)
                 else:
                     small_h = hist_multi(s1["leaf_id"], s1["p_small"])
                 with jax.named_scope("hist_cache"):
@@ -898,27 +798,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     node_ids = jnp.concatenate([2 * s1["p_step"] + 1,
                                                 2 * s1["p_step"] + 2])
 
-                    if fused_here:
-                        # larger children's histograms came from
-                        # subtraction, not the kernel — scan them with the
-                        # scan-only kernel (same in-VMEM code path, no HBM
-                        # gain grids), then route each (left, new) pair's
-                        # candidates to whichever of (small, large) it
-                        # actually is
-                        par_large = stats[jnp.clip(p_large, 0, LB - 1)]
-                        cand_large = pallas_split_scan(
-                            hist_value(large_h), feat["nb"], feat["missing"],
-                            par_large, interpret=spec.hist_interpret,
-                            **scan_kw)
-                        small_is_left = (s1["p_small"] == s1["p_left"])[
-                            :, None, None, None]
-                        cand_left = jnp.where(small_is_left, cand_small,
-                                              cand_large)
-                        cand_new = jnp.where(small_is_left, cand_large,
-                                             cand_small)
-                        cand_all = jnp.concatenate([cand_left, cand_new])
-
-                    def eval_child(slot, nid, *cand_sl):
+                    def eval_child(slot, nid):
                         sl = jnp.clip(slot, 0, LB - 1)
                         g, h, c = s1["leaf_g"][sl], s1["leaf_h"][sl], \
                             s1["leaf_c"][sl]
@@ -929,23 +809,13 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         a = allowed & deep_ok
                         if spec.n_ic_groups:
                             a = a & ic_allowed_from_used(feat, lu)
-                        if fused_here:
-                            sr = split_of_fused(
-                                hist[sl], cand_sl[0], g, h, c, a,
-                                s1["leaf_lb"][sl], s1["leaf_ub"][sl],
-                                s1["leaf_out"][sl], nid,
-                                penalty=cegb_penalty(c, lu))
-                        else:
-                            sr = split_of(hist[sl], g, h, c, a,
-                                          s1["leaf_lb"][sl],
-                                          s1["leaf_ub"][sl],
-                                          s1["leaf_out"][sl], nid,
-                                          penalty=cegb_penalty(c, lu))
+                        sr = split_of(hist[sl], g, h, c, a,
+                                      s1["leaf_lb"][sl], s1["leaf_ub"][sl],
+                                      s1["leaf_out"][sl], nid,
+                                      penalty=cegb_penalty(c, lu))
                         return _split_to_arrays(sr)
 
-                    args = (child_slots, node_ids) + \
-                        ((cand_all,) if fused_here else ())
-                    res = jax.vmap(eval_child)(*args)
+                    res = jax.vmap(eval_child)(child_slots, node_ids)
                     return hist, tuple(
                         s1[k].at[child_slots].set(r, mode="drop")
                         for k, r in zip(LEAF_KEYS, res))

@@ -6,13 +6,12 @@ buffers]; src/treelearner/cuda/cuda_histogram_constructor.cu
 `CUDAConstructHistogramKernel` [shared-memory block histograms + atomics]).
 
 TPUs have no atomics, and XLA lowers a 256-segment scatter-add to a SERIAL
-update loop (~750 ms per 1M x 28 histogram on v5e — measured honestly, see
-PROFILE.md "round 3b").  So scatter-add becomes dense compute the MXU can
-chew: for each (row-tile, lane group) the kernel materialises a one-hot
-comparison of the bin column against the bin axis and contracts it with the
-payload in ONE default-precision bf16 matmul.  Per-tile accumulators live
-in VMEM and revisit across the row-tile grid axis, exactly the role of the
-CUDA kernel's shared-memory histograms (grid-level reduction replaces
+update loop.  So scatter-add becomes dense compute the MXU can chew: for
+each (row-tile, lane group) the kernel materialises a one-hot comparison of
+the bin column against the bin axis and contracts it with the payload in
+ONE default-precision bf16 matmul.  Per-tile accumulators live in VMEM and
+revisit across the row-tile grid axis, exactly the role of the CUDA
+kernel's shared-memory histograms (grid-level reduction replaces
 atomicAdd).
 
 What a call costs (PERF.md section 6, PR 30; one TPU v5e).  Not bytes: a
@@ -73,26 +72,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..analysis.contracts import contract
 from ..utils.log import LightGBMError
 from .histogram import hist_value, limb_add
-from .split import (FUSED_CAND_COLS, FUSED_CASES, fused_numerical_candidates)
 
 Array = jax.Array
 
 ROW_TILE = 2048
-
-# fused impl names -> the histogram family they accumulate with; growers
-# that cannot run the in-kernel scan (categorical-only waves, distributed
-# meshes, strict policy) normalize through `base_hist_impl` — sound
-# because the fused path is byte-identical to its base by construction
-FUSED_IMPLS = {"pallas_fused": "pallas", "pallas_fused_q": "pallas_q"}
-
-
-def base_hist_impl(impl: str) -> str:
-    """Histogram family of a hist_impl name ('pallas_fused' -> 'pallas')."""
-    return FUSED_IMPLS.get(impl, impl)
-
 
 def _split3(x: Array):
     """f32 -> three f32 terms with bf16-REPRESENTABLE values and
@@ -194,8 +179,7 @@ def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
     """Multi-leaf grid cell with IN-KERNEL leaf masking — THE production
     kernel: every public f32 entry point (single-leaf included, via a
     mask-derived leaf id) lowers to this one body, so `probe()` gates
-    exactly the code that training runs (the fused kernel calls it too,
-    so their sums are bit-equal).
+    exactly the code that training runs.
 
     bins_ref: [F_t, N_t]; pw_ref: [R0, N_t] base payload rows (9
     f32-split); lid_ref: [1, N_t] i32 row→leaf; slots_ref: [1, S] i32 leaf
@@ -667,366 +651,6 @@ def pallas_histogram_quantized(bins_fm: Array, payload: Array,
                      axis=-1)
 
 
-# ------------------------------------------------------------------ fused
-# Fused histogram+split: the accumulation grid is IDENTICAL to the multi
-# kernels above, but on the LAST row tile the kernel recombines the
-# VMEM-resident accumulator and runs the two numerical missing-direction
-# scans in place (`ops/split.py fused_numerical_candidates` — shared with
-# the XLA reference, one source of truth for the gain formula), emitting a
-# compact [F_t, S*FUSED_CASES, FUSED_CAND_COLS] candidate block.  The
-# histogram still leaves the kernel — the wave grower carries it as state
-# for sibling subtraction and categorical fallback — but the split scan
-# never re-reads it from HBM and no [case, F, MB] gain grid is ever
-# materialised.  Recombination uses the SAME reshape/sum/scale ops as the
-# XLA wrappers, so the scanned values are bitwise the values the `pallas`
-# path would scan; the probe (`probe(fused=True)`) gates on EXACT equality.
-
-
-def _fused_scan_tail(acc4, nb_ref, miss_ref, par_ref, cand_ref, *, scan_kw):
-    """Shared final-tile scan: [F_t, S, MB, 3] recombined accumulator ->
-    candidate block write."""
-    f_t, s_n = acc4.shape[0], acc4.shape[1]
-    cand = fused_numerical_candidates(
-        acc4, nb_ref[0], miss_ref[0], par_ref[:].T, **scan_kw)
-    cand_ref[:] = cand.reshape(f_t, s_n * FUSED_CASES, FUSED_CAND_COLS)
-
-
-def _fused_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, nb_ref,
-                        miss_ref, par_ref, hi_ref, lo_ref, cand_ref, acc_ref,
-                        *, mb: int, n_rt: int, scan_kw: dict):
-    """f32 fused grid cell: `_hist_kernel_multi` accumulation + in-VMEM
-    split scan on the last row tile.  Extra refs: nb_ref/miss_ref [1, F_t]
-    i32 per-feature bin metadata, par_ref [3, S] f32 parent (g, h, cnt)
-    rows; cand_ref [F_t, S*2, 8] candidate output."""
-    _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
-                       acc_ref, mb=mb, n_rt=n_rt)
-    f_t = bins_ref.shape[0]
-    s_n = slots_ref.shape[1]
-
-    @pl.when(pl.program_id(1) == n_rt - 1)
-    def _scan():
-        # the SAME recombination ops as pallas_histogram_multi_rows and
-        # `hist_value`, so the scanned histogram is bitwise the value of
-        # the limbs the state carries
-        h, l = _combine_terms(hi_ref[:].reshape(f_t, s_n, 3, 3, mb),
-                              lo_ref[:].reshape(f_t, s_n, 3, 3, mb))
-        _fused_scan_tail((h + l).transpose(0, 1, 3, 2), nb_ref, miss_ref,
-                         par_ref, cand_ref, scan_kw=scan_kw)
-
-
-def _fused_kernel_multi_i8(bins_ref, pw_ref, lid_ref, slots_ref, nb_ref,
-                           miss_ref, par_ref, scale_ref, out_ref, cand_ref,
-                           *, mb: int, n_rt: int, scan_kw: dict):
-    """int8 fused grid cell: `_hist_kernel_multi_i8` accumulation +
-    in-VMEM dequantize (same `astype`/`*scale` ops as the XLA wrapper) +
-    split scan on the last row tile.  scale_ref: [1, 2] f32 (s_g, s_h)."""
-    r = pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _init():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    f_t, n_t = bins_ref.shape
-    pw = pw_ref[:]                                   # [3, N_t] int8
-    lid = lid_ref[0, :]
-    s_n = slots_ref.shape[1]
-    lhs = jnp.concatenate(
-        [jnp.where((lid == slots_ref[0, s])[None, :], pw, 0)
-         .astype(jnp.int8) for s in range(s_n)], axis=0)
-    bin_ids = jax.lax.broadcasted_iota(jnp.int32, (n_t, mb), 1)
-    for f in range(f_t):                             # static unroll
-        b = bins_ref[f, :].astype(jnp.int32)
-        onehot = (b[:, None] == bin_ids).astype(jnp.int8)
-        out_ref[f] += jax.lax.dot_general(
-            lhs, onehot, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.int32)
-
-    @pl.when(r == n_rt - 1)
-    def _scan():
-        acc = out_ref[:].reshape(f_t, s_n, 3, mb).astype(jnp.float32)
-        a4 = acc.transpose(0, 1, 3, 2)               # [F_t, S, MB, 3]
-        a4 = jnp.stack([a4[..., 0] * scale_ref[0, 0],
-                        a4[..., 1] * scale_ref[0, 1], a4[..., 2]], axis=-1)
-        _fused_scan_tail(a4, nb_ref, miss_ref, par_ref, cand_ref,
-                         scan_kw=scan_kw)
-
-
-def _fused_feat_meta(feat_nb: Array, feat_missing: Array, f_pad: int):
-    """Pad per-feature metadata for the feature grid: padded features get
-    nb=0 (no valid thresholds -> every candidate -inf) and missing=0."""
-    nb = jnp.pad(feat_nb.astype(jnp.int32), (0, f_pad))
-    miss = jnp.pad(feat_missing.astype(jnp.int32), (0, f_pad))
-    return nb[None, :], miss[None, :]
-
-
-def _run_fused_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
-                     slots: Array, feat_nb: Array, feat_missing: Array,
-                     parent: Array, max_bin: int, row_tile: int,
-                     feat_tile: int, interpret: bool, scan_kw: dict):
-    """Fused f32 driver -> (hi, lo: [F, S*9, MB] f32 two-limb sums,
-    [F, S*2, 8] f32 candidates)."""
-    f, n = bins_fm.shape
-    r0 = pw0.shape[0]
-    s_n = slots.shape[0]
-    n_pad = (-n) % row_tile
-    if n_pad:
-        pw0 = jnp.pad(pw0, ((0, 0), (0, n_pad)))
-        bins_fm = jnp.pad(bins_fm, ((0, 0), (0, n_pad)))
-        leaf_id = jnp.pad(leaf_id, (0, n_pad), constant_values=-1)
-    if feat_tile <= 0 or feat_tile > f:
-        feat_tile = f
-    f_pad = (-f) % feat_tile
-    if f_pad:
-        bins_fm = jnp.pad(bins_fm, ((0, f_pad), (0, 0)))
-    nb2, miss2 = _fused_feat_meta(feat_nb, feat_missing, f_pad)
-    n_rt = (n + n_pad) // row_tile
-    n_ft = (f + f_pad) // feat_tile
-
-    block = (feat_tile, s_n * r0, max_bin)
-    sums = jax.ShapeDtypeStruct((f + f_pad, s_n * r0, max_bin), jnp.float32)
-    hi, lo, cand = pl.pallas_call(
-        functools.partial(_fused_kernel_multi, mb=max_bin, n_rt=n_rt,
-                          scan_kw=scan_kw),
-        grid=(n_ft, n_rt),  # row tiles iterate fastest -> sums revisited
-        in_specs=[
-            pl.BlockSpec((feat_tile, row_tile), lambda j, r: (j, r)),
-            pl.BlockSpec((r0, row_tile), lambda j, r: (0, r)),
-            pl.BlockSpec((1, row_tile), lambda j, r: (0, r)),
-            pl.BlockSpec((1, s_n), lambda j, r: (0, 0)),
-            pl.BlockSpec((1, feat_tile), lambda j, r: (0, j)),
-            pl.BlockSpec((1, feat_tile), lambda j, r: (0, j)),
-            pl.BlockSpec((3, s_n), lambda j, r: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec(block, lambda j, r: (j, 0, 0)),
-            pl.BlockSpec(block, lambda j, r: (j, 0, 0)),
-            pl.BlockSpec((feat_tile, s_n * FUSED_CASES, FUSED_CAND_COLS),
-                         lambda j, r: (j, 0, 0)),
-        ],
-        out_shape=[
-            sums, sums,
-            jax.ShapeDtypeStruct((f + f_pad, s_n * FUSED_CASES,
-                                  FUSED_CAND_COLS), jnp.float32),
-        ],
-        scratch_shapes=[pltpu.VMEM(block, jnp.float32)],
-        interpret=interpret,
-    )(bins_fm, pw0, leaf_id.astype(jnp.int32)[None, :], slots[None, :],
-      nb2, miss2, parent.T.astype(jnp.float32))
-    return hi[:f], lo[:f], cand[:f]
-
-
-def _run_fused_multi_i8(bins_fm: Array, pw0: Array, leaf_id: Array,
-                        slots: Array, feat_nb: Array, feat_missing: Array,
-                        parent: Array, max_bin: int, s_g: Array, s_h: Array,
-                        row_tile: int, feat_tile: int, interpret: bool,
-                        scan_kw: dict):
-    """Fused int8 driver -> ([F, S*3, MB] int32 accumulator,
-    [F, S*2, 8] f32 candidates)."""
-    f, n = bins_fm.shape
-    r0 = pw0.shape[0]
-    s_n = slots.shape[0]
-    n_pad = (-n) % row_tile
-    if n_pad:
-        pw0 = jnp.pad(pw0, ((0, 0), (0, n_pad)))
-        bins_fm = jnp.pad(bins_fm, ((0, 0), (0, n_pad)))
-        leaf_id = jnp.pad(leaf_id, (0, n_pad), constant_values=-1)
-    if feat_tile <= 0 or feat_tile > f:
-        feat_tile = f
-    f_pad = (-f) % feat_tile
-    if f_pad:
-        bins_fm = jnp.pad(bins_fm, ((0, f_pad), (0, 0)))
-    nb2, miss2 = _fused_feat_meta(feat_nb, feat_missing, f_pad)
-    n_rt = (n + n_pad) // row_tile
-    n_ft = (f + f_pad) // feat_tile
-    scale = jnp.stack([s_g, s_h]).astype(jnp.float32)[None, :]
-
-    out, cand = pl.pallas_call(
-        functools.partial(_fused_kernel_multi_i8, mb=max_bin, n_rt=n_rt,
-                          scan_kw=scan_kw),
-        grid=(n_ft, n_rt),
-        in_specs=[
-            pl.BlockSpec((feat_tile, row_tile), lambda j, r: (j, r)),
-            pl.BlockSpec((r0, row_tile), lambda j, r: (0, r)),
-            pl.BlockSpec((1, row_tile), lambda j, r: (0, r)),
-            pl.BlockSpec((1, s_n), lambda j, r: (0, 0)),
-            pl.BlockSpec((1, feat_tile), lambda j, r: (0, j)),
-            pl.BlockSpec((1, feat_tile), lambda j, r: (0, j)),
-            pl.BlockSpec((3, s_n), lambda j, r: (0, 0)),
-            pl.BlockSpec((1, 2), lambda j, r: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((feat_tile, s_n * r0, max_bin),
-                         lambda j, r: (j, 0, 0)),
-            pl.BlockSpec((feat_tile, s_n * FUSED_CASES, FUSED_CAND_COLS),
-                         lambda j, r: (j, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((f + f_pad, s_n * r0, max_bin),
-                                 jnp.int32),
-            jax.ShapeDtypeStruct((f + f_pad, s_n * FUSED_CASES,
-                                  FUSED_CAND_COLS), jnp.float32),
-        ],
-        interpret=interpret,
-    )(bins_fm, pw0, leaf_id.astype(jnp.int32)[None, :], slots[None, :],
-      nb2, miss2, parent.T.astype(jnp.float32), scale)
-    return out[:f], cand[:f]
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "max_bin", "l1", "l2", "min_data_in_leaf", "min_sum_hessian",
-    "min_gain_to_split", "row_tile", "feat_tile", "interpret"))
-@contract(bins_fm="[F, N] int", pw9="[9, N] f32", leaf_id="[N] int",
-          slots="[S] i32", feat_nb="[F] int", feat_missing="[F] int",
-          parent="[S, 3] f32", max_bin="static int", l1="static",
-          l2="static", min_data_in_leaf="static", min_sum_hessian="static",
-          min_gain_to_split="static", row_tile="static int",
-          feat_tile="static int", interpret="static", ret="tree")
-def pallas_fused_hist_split_rows(bins_fm: Array, pw9: Array, leaf_id: Array,
-                                 slots: Array, feat_nb: Array,
-                                 feat_missing: Array, parent: Array,
-                                 max_bin: int, *, l1: float, l2: float,
-                                 min_data_in_leaf: float,
-                                 min_sum_hessian: float,
-                                 min_gain_to_split: float,
-                                 row_tile: int = ROW_TILE,
-                                 feat_tile: int = 0,
-                                 interpret: bool = False):
-    """Fused multi-leaf histogram + numerical split scan (f32 family).
-
-    Same batching/chunking economics as `pallas_histogram_multi_rows`;
-    `parent` [S, 3] carries each slot's (g, h, cnt) sums for the in-kernel
-    gain shift.  Returns `(hist, cand)`:
-      hist: [S, F, MB, 6] f32 limbs — bitwise the `pallas` path's
-        histogram (the wave grower still carries it for
-        sibling subtraction and categorical fallback); the in-kernel scan
-        read its `hist_value`;
-      cand: [S, FUSED_CASES, F, FUSED_CAND_COLS] f32 — per (slot, case,
-        feature) the first-wins best (gain, thr, left_g, left_h, left_cnt),
-        decided by `ops/split.py decide_from_candidates`.
-    """
-    S = slots.shape[0]
-    scan_kw = dict(l1=l1, l2=l2, min_data_in_leaf=min_data_in_leaf,
-                   min_sum_hessian=min_sum_hessian,
-                   min_gain_to_split=min_gain_to_split)
-    houts, couts = [], []
-    for c0 in range(0, S, MULTI_CHUNK):
-        c1 = min(S, c0 + MULTI_CHUNK)
-        hi, lo, cand = _run_fused_multi(
-            bins_fm, pw9, leaf_id, slots[c0:c1], feat_nb, feat_missing,
-            parent[c0:c1], max_bin, row_tile, feat_tile, interpret, scan_kw)
-        f = hi.shape[0]
-        houts.append(_limbs_from_terms(hi, lo, c1 - c0, max_bin))
-        couts.append(cand.reshape(f, c1 - c0, FUSED_CASES, FUSED_CAND_COLS)
-                     .transpose(1, 2, 0, 3))         # [c, 2, F, 8]
-    if len(houts) > 1:
-        return jnp.concatenate(houts), jnp.concatenate(couts)
-    return houts[0], couts[0]
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "max_bin", "l1", "l2", "min_data_in_leaf", "min_sum_hessian",
-    "min_gain_to_split", "row_tile", "feat_tile", "interpret"))
-@contract(bins_fm="[F, N] int", pw3="[3, N] i8", leaf_id="[N] int",
-          slots="[S] i32", feat_nb="[F] int", feat_missing="[F] int",
-          parent="[S, 3] f32", max_bin="static int", s_g="[] f32",
-          s_h="[] f32", l1="static", l2="static",
-          min_data_in_leaf="static", min_sum_hessian="static",
-          min_gain_to_split="static", row_tile="static int",
-          feat_tile="static int", interpret="static", ret="tree")
-def pallas_fused_hist_split_quantized_rows(
-        bins_fm: Array, pw3: Array, leaf_id: Array, slots: Array,
-        feat_nb: Array, feat_missing: Array, parent: Array, max_bin: int,
-        s_g: Array, s_h: Array, *, l1: float, l2: float,
-        min_data_in_leaf: float, min_sum_hessian: float,
-        min_gain_to_split: float, row_tile: int = ROW_TILE,
-        feat_tile: int = 0, interpret: bool = False):
-    """Quantized fused variant: int8 lattice accumulation at 2x MXU rate,
-    in-kernel dequantize (same ops as the XLA wrapper, so the scanned
-    histogram is bitwise the `pallas_q` one), then the shared scan.
-    Returns `(hist, cand)` exactly like `pallas_fused_hist_split_rows`."""
-    S = slots.shape[0]
-    scan_kw = dict(l1=l1, l2=l2, min_data_in_leaf=min_data_in_leaf,
-                   min_sum_hessian=min_sum_hessian,
-                   min_gain_to_split=min_gain_to_split)
-    houts, couts = [], []
-    for c0 in range(0, S, MULTI_CHUNK_Q):
-        c1 = min(S, c0 + MULTI_CHUNK_Q)
-        out, cand = _run_fused_multi_i8(
-            bins_fm, pw3, leaf_id, slots[c0:c1], feat_nb, feat_missing,
-            parent[c0:c1], max_bin, s_g, s_h, row_tile, feat_tile,
-            interpret, scan_kw)
-        f = out.shape[0]
-        h = out.reshape(f, c1 - c0, 3, max_bin).astype(jnp.float32)
-        houts.append(h.transpose(1, 0, 3, 2))        # [c, F, MB, 3]
-        couts.append(cand.reshape(f, c1 - c0, FUSED_CASES, FUSED_CAND_COLS)
-                     .transpose(1, 2, 0, 3))
-    hist = jnp.concatenate(houts) if len(houts) > 1 else houts[0]
-    hist = jnp.stack([hist[..., 0] * s_g, hist[..., 1] * s_h,
-                      hist[..., 2]], axis=-1)
-    cand = jnp.concatenate(couts) if len(couts) > 1 else couts[0]
-    return hist, cand
-
-
-def _scan_only_kernel(hist_ref, nb_ref, miss_ref, par_ref, cand_ref, *,
-                      scan_kw: dict):
-    """Split-scan-only grid cell for histograms that never went through
-    the fused kernel (the wave grower's subtraction-derived large
-    siblings): hist_ref [S, F_t, 3, MB] -> cand block."""
-    h4 = hist_ref[:].transpose(1, 0, 3, 2)           # [F_t, S, MB, 3]
-    _fused_scan_tail(h4, nb_ref, miss_ref, par_ref, cand_ref,
-                     scan_kw=scan_kw)
-
-
-@functools.partial(jax.jit, static_argnames=(
-    "l1", "l2", "min_data_in_leaf", "min_sum_hessian", "min_gain_to_split",
-    "feat_tile", "interpret"))
-@contract(hist="[S, F, MB, 3] f32", feat_nb="[F] int",
-          feat_missing="[F] int", parent="[S, 3] f32", l1="static",
-          l2="static", min_data_in_leaf="static", min_sum_hessian="static",
-          min_gain_to_split="static", feat_tile="static int",
-          interpret="static", ret="[S, 2, F, 8] f32")
-def pallas_split_scan(hist: Array, feat_nb: Array, feat_missing: Array,
-                      parent: Array, *, l1: float, l2: float,
-                      min_data_in_leaf: float, min_sum_hessian: float,
-                      min_gain_to_split: float, feat_tile: int = 0,
-                      interpret: bool = False) -> Array:
-    """Numerical split scan of materialised [S, F, MB, 3] histograms as a
-    Pallas kernel — the fused path's companion for sibling-subtracted
-    histograms (one [MB]-strided read per histogram, compact candidates
-    out, no [case, F, MB] gain grids in HBM).  Same scan body as the fused
-    kernels, so candidates are bitwise interchangeable."""
-    s_n, f, mb, _ = hist.shape
-    scan_kw = dict(l1=l1, l2=l2, min_data_in_leaf=min_data_in_leaf,
-                   min_sum_hessian=min_sum_hessian,
-                   min_gain_to_split=min_gain_to_split)
-    if feat_tile <= 0 or feat_tile > f:
-        feat_tile = f
-    f_pad = (-f) % feat_tile
-    hist_cm = hist.transpose(0, 1, 3, 2)             # [S, F, 3, MB]
-    if f_pad:
-        hist_cm = jnp.pad(hist_cm, ((0, 0), (0, f_pad), (0, 0), (0, 0)))
-    nb2, miss2 = _fused_feat_meta(feat_nb, feat_missing, f_pad)
-    n_ft = (f + f_pad) // feat_tile
-
-    cand = pl.pallas_call(
-        functools.partial(_scan_only_kernel, scan_kw=scan_kw),
-        grid=(n_ft,),
-        in_specs=[
-            pl.BlockSpec((s_n, feat_tile, 3, mb), lambda j: (0, j, 0, 0)),
-            pl.BlockSpec((1, feat_tile), lambda j: (0, j)),
-            pl.BlockSpec((1, feat_tile), lambda j: (0, j)),
-            pl.BlockSpec((3, s_n), lambda j: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((feat_tile, s_n * FUSED_CASES,
-                                FUSED_CAND_COLS), lambda j: (j, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct(
-            (f + f_pad, s_n * FUSED_CASES, FUSED_CAND_COLS), jnp.float32),
-        interpret=interpret,
-    )(hist_cm, nb2, miss2, parent.T.astype(jnp.float32))
-    return cand[:f].reshape(f, s_n, FUSED_CASES, FUSED_CAND_COLS)\
-        .transpose(1, 2, 0, 3)                       # [S, 2, F, 8]
-
-
 @dataclasses.dataclass(frozen=True)
 class ProbeResult:
     """Verdict of a kernel probe; truthy iff the kernel may be used.
@@ -1048,17 +672,17 @@ _PROBE_CACHE = {}
 
 def probe_cached(max_bin: int = 256, num_feature: int = 28,
                  multi: bool = False, width: int = None,
-                 quantized: bool = None, fused: bool = False,
-                 interpret: bool = False, plan=None) -> ProbeResult:
+                 quantized: bool = None, interpret: bool = False,
+                 plan=None) -> ProbeResult:
     """probe(), memoised per (backend platform, shape, multi params,
     lane plan)."""
     key = (jax.devices()[0].platform, max_bin, num_feature, multi,
-           width, quantized, fused, interpret, plan)
+           width, quantized, interpret, plan)
     if key not in _PROBE_CACHE:
         _PROBE_CACHE[key] = probe(interpret=interpret, max_bin=max_bin,
                                   num_feature=num_feature, multi=multi,
                                   width=width, quantized=quantized,
-                                  fused=fused, plan=plan)
+                                  plan=plan)
     return _PROBE_CACHE[key]
 
 
@@ -1080,110 +704,14 @@ def _mismatch(what: str, got, want) -> ProbeResult:
         f"{int(np.count_nonzero(got != want))}/{got.size} elements differ")
 
 
-# the fused probe's static scan parameters are placeholders — the gate is
-# structural (does the in-kernel scan lower and match the XLA decide
-# bitwise on this backend?), not numerical, so any regular values work
-_PROBE_SCAN_KW = dict(l1=0.0, l2=1.0, min_data_in_leaf=1.0,
-                      min_sum_hessian=1e-3, min_gain_to_split=0.0)
-
-
-def _probe_fused(interpret: bool, max_bin: int, num_feature: int,
-                 width: int, quantized: bool) -> ProbeResult:
-    """EXACT-parity gate for the fused path: the fused kernel's histogram
-    must be bitwise the multi kernel's, and `decide_from_candidates` over
-    its candidate tensor must reproduce `find_best_split` field-for-field
-    (gain, feature, threshold, missing direction, child sums).  Bitwise —
-    not allclose — because byte-identical models are the fused path's
-    whole contract; any backend where Mosaic lowers the scan differently
-    (cumsum association, lane gathers) degrades to the base impl here.
-    The fused kernel is an UPGRADE over a working base, so a refusal
-    degrades on every platform — but the compiler's message rides in the
-    result (and from there in `fallback.fused_split`)."""
-    import numpy as np
-
-    from .split import decide_from_candidates, find_best_split
-    rng = np.random.RandomState(1)
-    n = ROW_TILE if not interpret else 128
-    wdt = width or (MULTI_CHUNK_Q if quantized else MULTI_CHUNK)
-    bins_np = rng.randint(0, max_bin, (num_feature, n))
-    # realistic metadata mix: short bin counts, all three missing types
-    nb_np = rng.randint(3, max_bin + 1, num_feature).astype(np.int32)
-    miss_np = rng.randint(0, 3, num_feature).astype(np.int32)
-    bins_np %= np.maximum(nb_np[:, None], 1)         # keep bins in range
-    bins = jnp.asarray(bins_np.astype(np.uint8 if max_bin <= 256
-                                      else np.uint16))
-    nb, miss = jnp.asarray(nb_np), jnp.asarray(miss_np)
-    fdef = jnp.zeros((num_feature,), jnp.int32)
-    lid_np = rng.randint(0, wdt + 2, n).astype(np.int32)
-    lid = jnp.asarray(lid_np)
-    slots = jnp.arange(wdt, dtype=jnp.int32)
-    s = jnp.float32(0.25)
-    if quantized:
-        payload = np.stack([np.round(rng.randn(n) * 8) * 0.25,
-                            np.abs(np.round(rng.randn(n) * 8)) * 0.25,
-                            np.ones(n)], axis=1).astype(np.float32)
-    else:
-        payload = rng.randn(n, 3).astype(np.float32)
-        payload[:, 2] = np.abs(payload[:, 2])
-    pj = jnp.asarray(payload)
-    parent = np.stack([
-        np.bincount(np.clip(lid_np, 0, wdt), weights=payload[:, c],
-                    minlength=wdt + 1)[:wdt] for c in range(3)],
-        axis=1).astype(np.float32)
-    pjj = jnp.asarray(parent)
-    try:
-        if quantized:
-            want_h = pallas_histogram_multi_quantized(
-                bins, pj, lid, slots, max_bin, s, s,
-                row_tile=min(n, ROW_TILE), interpret=interpret)
-            got_h, cand = pallas_fused_hist_split_quantized_rows(
-                bins, quantized_lattice_rows(pj, s, s), lid, slots,
-                nb, miss, pjj, max_bin, s, s,
-                row_tile=min(n, ROW_TILE), interpret=interpret,
-                **_PROBE_SCAN_KW)
-        else:
-            want_h = pallas_histogram_multi_rows(
-                bins, _split_payload9(pj), lid, slots, max_bin,
-                row_tile=min(n, ROW_TILE), interpret=interpret)
-            got_h, cand = pallas_fused_hist_split_rows(
-                bins, _split_payload9(pj), lid, slots, nb, miss, pjj,
-                max_bin, row_tile=min(n, ROW_TILE), interpret=interpret,
-                **_PROBE_SCAN_KW)
-        got_h, want_h, cand = jax.device_get((got_h, want_h, cand))
-        if not np.array_equal(got_h, want_h):
-            return _mismatch("fused histogram vs multi kernel", got_h,
-                             want_h)
-        allowed = jnp.ones((num_feature,), bool)
-        iscat = jnp.zeros((num_feature,), bool)
-        for sl in range(min(3, wdt)):
-            pg, ph, pc = (jnp.float32(parent[sl, c]) for c in range(3))
-            ref = find_best_split(
-                hist_value(jnp.asarray(want_h[sl])), pg, ph, pc, nb, miss,
-                fdef,
-                allowed, iscat, cat_smooth=10.0, cat_l2=10.0,
-                max_cat_threshold=32, max_cat_to_onehot=4, has_cat=False,
-                **_PROBE_SCAN_KW)
-            got = decide_from_candidates(
-                jnp.asarray(cand[sl]), pg, ph, pc, miss, fdef, allowed,
-                max_bin)
-            ref, got = jax.device_get((ref, got))
-            for i, (a, b) in enumerate(zip(ref, got)):
-                if not np.array_equal(a, b):
-                    return _mismatch(f"slot {sl} decision field {i}", b, a)
-        return _PROBE_OK
-    except Exception as e:  # the compiler's verdict, kept verbatim
-        return _refused(e)
-
-
 def probe(interpret: bool = False, max_bin: int = 256,
           num_feature: int = 28, multi: bool = False, width: int = None,
-          quantized: bool = None, fused: bool = False,
-          plan=None) -> ProbeResult:
+          quantized: bool = None, plan=None) -> ProbeResult:
     """Runtime check that the kernel compiles and matches a host count on
     the current backend — used by Booster to gate the TPU histogram path.
-    On a real TPU (`interpret=False`) a base kernel that RAISES is an
+    On a real TPU (`interpret=False`) a kernel that RAISES is an
     error, not a degradation: `LightGBMError` carries Mosaic's message
-    instead of training ~60x slower on segment-sum behind a log line.  A
+    instead of training on segment-sum behind a log line.  A
     kernel that runs but disagrees numerically still returns a falsy
     result, with the numbers in `detail`.
     Probes at the PRODUCTION bin count / feature count / ROW_TILE (Mosaic
@@ -1201,14 +729,7 @@ def probe(interpret: bool = False, max_bin: int = 256,
     the defaults probe a full chunk of both families.  `plan` = the
     `lane_plan` the f32 kernel will run with: the probe compiles and
     compares THAT program (bins drawn below each column's `num_bin`) in
-    place of the all-`max_bin` one — still one kernel compile.
-
-    `fused=True` gates `hist_impl='pallas_fused'`/`'pallas_fused_q'`:
-    a stricter, EXACT-equality probe (`_probe_fused`) over the fused
-    kernel's histogram AND its in-kernel split candidates — see there."""
-    if fused:
-        return _probe_fused(interpret, max_bin, num_feature, width,
-                            bool(quantized))
+    place of the all-`max_bin` one — still one kernel compile."""
     try:
         return _probe_base(interpret, max_bin, num_feature, multi, width,
                            quantized, plan)
@@ -1225,7 +746,7 @@ def probe(interpret: bool = False, max_bin: int = 256,
 def _probe_base(interpret: bool, max_bin: int, num_feature: int,
                 multi: bool, width: int, quantized: bool,
                 plan=None) -> ProbeResult:
-    """`probe`'s compile-and-compare body for the unfused kernels; any
+    """`probe`'s compile-and-compare body; any
     exception the kernel raises propagates to `probe`.  The reference is
     a float64 count on the host: nothing but the kernel compiles here."""
     import numpy as np

@@ -98,9 +98,7 @@ def size_constraints_ok(left: Array, right: Array,
                         min_data_in_leaf: float,
                         min_sum_hessian: float) -> Array:
     """Child-size gate (ref: feature_histogram.hpp the min_data_in_leaf /
-    min_sum_hessian_in_leaf guards in both threshold finders).  Shared by
-    `find_best_split` and the fused Pallas scan (ops/pallas_hist.py) — one
-    source of truth, so the two paths cannot drift."""
+    min_sum_hessian_in_leaf guards in both threshold finders)."""
     return ((left[..., 2] >= min_data_in_leaf)
             & (right[..., 2] >= min_data_in_leaf)
             & (left[..., 1] >= min_sum_hessian)
@@ -110,8 +108,7 @@ def size_constraints_ok(left: Array, right: Array,
 def plain_split_gain(left: Array, right: Array, l1: float, l2_eff: float,
                      shift: Array) -> Array:
     """Closed-form split gain `GetLeafGain(l) + GetLeafGain(r) - shift`
-    (ref: feature_histogram.hpp `GetSplitGains` without constraints).
-    Shared by `find_best_split` and the fused Pallas scan."""
+    (ref: feature_histogram.hpp `GetSplitGains` without constraints)."""
     return (leaf_gain(left[..., 0], left[..., 1], l1, l2_eff)
             + leaf_gain(right[..., 0], right[..., 1], l1, l2_eff)
             - shift)
@@ -404,145 +401,6 @@ def _decide_numerical(gain0, gain1, left0, left1, parent, feat_missing,
         left_sum_g=left[0], left_sum_h=left[1], left_cnt=left[2],
         right_sum_g=right[0], right_sum_h=right[1], right_cnt=right[2],
     )
-
-
-# --------------------------------------------------------------------- fused
-# The fused Pallas path (ops/pallas_hist.py) runs the two numerical
-# missing-direction scans in-kernel over the VMEM-resident histogram and
-# emits only a compact per-(slot, feature, case) candidate tensor.  The
-# scan body and the decide stage live HERE so the gain formula has one
-# source of truth with `find_best_split`.
-
-FUSED_CASES = 2        # case 0: missing right, case 1: missing left
-FUSED_CAND_COLS = 8    # gain, thr, left_g, left_h, left_cnt + 3 pad lanes
-
-
-def fused_numerical_candidates(hist: Array, feat_nb: Array,
-                               feat_missing: Array, parent: Array, *,
-                               l1: float, l2: float,
-                               min_data_in_leaf: float,
-                               min_sum_hessian: float,
-                               min_gain_to_split: float) -> Array:
-    """Per-(feature, slot, case) reduction of `find_best_split`'s two
-    numerical missing-direction scans — called from INSIDE the fused
-    Pallas kernel (and by its XLA reference in the probe/tests).
-
-    Args:
-      hist: [F, S, MB, 3] f32 per-slot histograms (g, h, cnt channels).
-      feat_nb / feat_missing: [F] i32 per-feature bin metadata.
-      parent: [S, 3] f32 per-slot parent (g, h, cnt) sums.
-
-    Returns [F, S, FUSED_CASES, FUSED_CAND_COLS] f32: each row is
-    (gain, threshold_bin, left_g, left_h, left_cnt, 0, 0, 0) at the
-    case's first-wins best threshold.  Feature gates (interaction
-    constraints, bynode sampling, CEGB penalties) are applied LATER in
-    `decide_from_candidates` — per-feature-constant masking and penalty
-    subtraction commute with the within-feature argmax, so composing this
-    reduction with a flat argmax over (case, feature) groups in case-major
-    order reproduces `find_best_split`'s flat argmax over the whole
-    [case, F, MB] grid exactly, first-wins ties included.
-    """
-    f, s, mb, _ = hist.shape
-    bin_fm = jax.lax.broadcasted_iota(jnp.int32, (f, mb), 1)     # [F, MB]
-    valid_bin = bin_fm < feat_nb[:, None]
-    h = jnp.where(valid_bin[:, None, :, None], hist, 0.0)
-    cum = jnp.cumsum(h, axis=2)                                  # [F,S,MB,3]
-    has_nan = feat_missing == MISSING_NAN                        # [F]
-    nan_idx = jnp.where(has_nan, feat_nb - 1, 0).astype(jnp.int32)
-    nanv = jnp.take_along_axis(
-        h, nan_idx[:, None, None, None], axis=2)[:, :, 0, :]     # [F, S, 3]
-    nanv = jnp.where(has_nan[:, None, None], nanv, 0.0)
-    t_max = feat_nb - 2 - has_nan.astype(jnp.int32)
-    valid_t = bin_fm <= t_max[:, None]                           # [F, MB]
-
-    shift = (leaf_gain(parent[:, 0], parent[:, 1], l1, l2)
-             + min_gain_to_split)                                # [S]
-    p4 = parent[None, :, None, :]
-
-    def case_best(left, valid):
-        right = p4 - left
-        g = plain_split_gain(left, right, l1, l2, shift[None, :, None])
-        ok = valid & size_constraints_ok(left, right,
-                                         min_data_in_leaf, min_sum_hessian)
-        g = jnp.where(ok, g, NEG_INF)                            # [F, S, MB]
-        thr = jnp.argmax(g, axis=2).astype(jnp.int32)            # first wins
-        gb = jnp.take_along_axis(g, thr[..., None], axis=2)[..., 0]
-        lv = jnp.take_along_axis(left, thr[..., None, None],
-                                 axis=2)[:, :, 0, :]             # [F, S, 3]
-        pad = jnp.zeros(lv.shape[:-1] + (FUSED_CAND_COLS - 5,), jnp.float32)
-        return jnp.concatenate(
-            [gb[..., None], thr.astype(jnp.float32)[..., None], lv, pad],
-            axis=-1)                                             # [F, S, 8]
-
-    c0 = case_best(cum, valid_t[:, None, :])
-    c1 = case_best(cum + nanv[:, :, None, :],
-                   (valid_t & has_nan[:, None])[:, None, :])
-    return jnp.stack([c0, c1], axis=2)
-
-
-@contract(cand="[2, F, 8] f32",
-          parent_g="[] float", parent_h="[] float", parent_c="[] float",
-          feat_missing="[F] int", feat_default="[F] int",
-          allowed_num="[F] bool", max_bin="static int",
-          gain_penalty="[F] float?", ret="tree")
-def decide_from_candidates(cand: Array,
-                           parent_g: Array, parent_h: Array, parent_c: Array,
-                           feat_missing: Array, feat_default: Array,
-                           allowed_num: Array, max_bin: int,
-                           gain_penalty: Array = None) -> SplitResult:
-    """SplitResult for ONE leaf from a fused candidate tensor.
-
-    `cand` is `fused_numerical_candidates` output transposed to
-    case-major [FUSED_CASES, F, FUSED_CAND_COLS].  `allowed_num` [F]
-    applies the node's feature gate (numerical-only ∧ interaction
-    constraints ∧ bynode sampling) after the in-kernel reduction; the
-    selection — penalty subtraction, flat argmax in case-major order,
-    missing-direction decode — mirrors `_decide_numerical` exactly.
-    """
-    F = cand.shape[1]
-    gains = jnp.where(allowed_num[None, :], cand[..., 0], NEG_INF)
-    if gain_penalty is not None:
-        gains = gains - gain_penalty[None, :]
-    flat = gains.reshape(-1)
-    best = jnp.argmax(flat)
-    best_gain = flat[best]
-    case = best // F
-    feat = (best % F).astype(jnp.int32)
-    row = cand[case, feat]
-    thr = row[1].astype(jnp.int32)
-    left = row[2:5]
-    parent = jnp.stack([parent_g, parent_h, parent_c])
-    right = parent - left
-
-    mtype = feat_missing[feat]
-    dl = jnp.where(mtype == MISSING_NAN, case == 1,
-                   jnp.where(mtype == MISSING_ZERO,
-                             feat_default[feat] <= thr, False))
-
-    no_split = ~jnp.isfinite(best_gain)
-    return SplitResult(
-        gain=jnp.where(no_split, NEG_INF, best_gain),
-        feature=jnp.where(no_split, -1, feat),
-        threshold_bin=thr,
-        default_left=dl,
-        is_cat=jnp.bool_(False),
-        cat_mask=jnp.zeros((max_bin,), bool),
-        left_sum_g=left[0], left_sum_h=left[1], left_cnt=left[2],
-        right_sum_g=right[0], right_sum_h=right[1], right_cnt=right[2],
-    )
-
-
-def merge_split_results(num: SplitResult, cat: SplitResult) -> SplitResult:
-    """Winner between a fused numerical result and a categorical
-    `find_best_split` result (the fused path's fallback features).  Ties
-    go to `num`: the numerical cases precede the categorical cases in the
-    reference flat argmax order, so `>=` reproduces its tie-break."""
-    pick = num.gain >= cat.gain
-
-    def sel(a, b):
-        return jnp.where(pick, a, b)
-
-    return SplitResult(*(sel(a, b) for a, b in zip(num, cat)))
 
 
 def bin_goes_left(bins: Array, nb: Array, missing: Array, thr: Array,

@@ -602,8 +602,7 @@ class ServingRuntime:
         self._note_rung("device_sum", cause, detail)
 
     def _probe_device_sum(self, ex: Dict) -> Tuple[str, str]:
-        """Export-time exact-parity gate (the `_probe_fused` pattern
-        from ops/pallas_hist.py): the device-sum program must
+        """Export-time exact-parity gate: the device-sum program must
         bit-match the host f64 gather/sum over the SAME device slots —
         raw and converted — on a threshold-clustered probe batch, or
         the model degrades to the slot path.  Returns (verdict,

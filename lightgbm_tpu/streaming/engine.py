@@ -141,14 +141,10 @@ class StreamingWaveGrower:
         self.L = spec.num_leaves
         self.MB = spec.max_bin
         self.LB, self.W = wave_sizes(spec)
-        from ..ops.pallas_hist import base_hist_impl
         # the Pallas kernels are probe-gated bitwise-equal to their XLA
         # base family, so streaming the base family preserves identity
-        # with any resolved impl; fused impls fall back the same way the
-        # in-memory grower's categorical path does (`find_best_split`
-        # candidates are byte-identical by construction)
-        self.packed = base_hist_impl(spec.hist_impl) in ("packed",
-                                                         "pallas_q")
+        # with any resolved impl
+        self.packed = spec.hist_impl in ("packed", "pallas_q")
         self.chl = spec.packed_const_hess_level if self.packed else 0
         cegb_on = spec.cegb_tradeoff > 0.0 and \
             (spec.cegb_penalty_split > 0.0 or spec.cegb_coupled
@@ -164,7 +160,6 @@ class StreamingWaveGrower:
         REGISTRY.gauge("wave.width").set(self.W)
         REGISTRY.gauge("wave.grow_leaves").set(self.LB)
         REGISTRY.gauge("wave.shards").set(1)
-        REGISTRY.gauge("wave.fused").set(0)
         REGISTRY.gauge("stream.shards").set(store.n_shards)
         # two watermarks (memledger satellite): `peak_staging_bytes` is
         # what `datastore_budget_mb` sizes — at most the current +

@@ -264,15 +264,6 @@ RULES: List[Tuple[str, str, str]] = [
     ("*serve.device_errors", "up_is_bad", "counter"),
     ("gauges.serve.*", "ignore", "counter"),
     ("counters.serve.*", "ignore", "counter"),
-    # r6 fused-kernel micro-bench (`bench.py --kernel`): per-impl
-    # wave-pass times are wall-clock (up is bad); the fused speedup
-    # ratios shrink when fusion stops paying (down is bad); the shape /
-    # config keys (n, f, max_bin, width, reps, interpret) are identity.
-    # The headline `value` of a --kernel line is speedup_pallas_fused,
-    # already covered by the `value` down_is_bad rule above.
-    ("kernel.speedup_*", "down_is_bad", "timing"),
-    ("kernel.*_ms", "up_is_bad", "timing"),
-    ("kernel.*", "ignore", "counter"),
     # external-memory datastore: prefetch stalls growing means the
     # read-ahead stopped hiding disk latency (timing class — thread
     # scheduling makes the exact count jittery); hits, spill volume and

@@ -202,12 +202,12 @@ _PARAMS: Dict[str, Tuple[Any, str, Tuple[str, ...]]] = {
     "stochastic_rounding": (True, "bool", ()),
     # histogram implementation request (booster._resolve_hist_impl):
     # "auto" picks the fastest eligible path — the int-lattice family
-    # (packed on CPU, pallas_q/pallas_fused_q on TPU) is the default
-    # wherever the model qualifies, with priced fallback events when the
-    # lattice disqualifies.  An explicit value (segment_sum / packed /
-    # pallas / pallas_q / pallas_fused / pallas_fused_q) pins the path;
-    # an ineligible request degrades to auto with a priced fallback
-    # event rather than erroring (degrade-don't-error, like the ladder)
+    # (packed on CPU, pallas_q on TPU) is the default wherever the model
+    # qualifies, with priced fallback events when the lattice
+    # disqualifies.  An explicit value (segment_sum / packed / pallas /
+    # pallas_q) pins the path; an ineligible request degrades to auto
+    # with a priced fallback event rather than erroring
+    # (degrade-don't-error, like the ladder)
     "hist_impl": ("auto", "str", ()),
     # run Pallas histogram kernels in interpret mode off-TPU (CI/tests:
     # lets an explicit pallas-family hist_impl execute on CPU for
@@ -224,15 +224,6 @@ _PARAMS: Dict[str, Tuple[Any, str, Tuple[str, ...]]] = {
     # Only consulted on TPU backends (CPU keeps segment-sum), and probe-
     # gated so a Mosaic regression degrades to the XLA path
     "tpu_use_pallas": (True, "bool", ()),
-    # fused Pallas histogram+split (ops/pallas_hist.py, wave policy
-    # only): the wave kernel scans each histogram in VMEM and emits
-    # compact split candidates instead of re-reading the [S, F, MB, 3]
-    # block from HBM for the XLA scan.  Byte-identical to the unfused
-    # kernel by construction and probe-gated on EXACT output equality,
-    # so any backend divergence degrades to the base pallas/pallas_q
-    # path.  Auto-disabled off the plain numerical gain path (monotone
-    # constraints, path smoothing, extra_trees, EFB, distributed)
-    "tpu_fused_split": (True, "bool", ("fused_split",)),
     # growth policy (ops/grow_wave.py): "leafwise" = stock-exact strict
     # best-first (ref: serial_tree_learner.cpp Train); "wave" = TPU-first
     # wave-batched best-first — each wave splits every positive-gain

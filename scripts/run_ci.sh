@@ -44,48 +44,6 @@ assert text(1) == text(4), "pipelined model differs from serial"
 print("[run_ci] pipeline smoke: depth 4 == depth 1 (byte-identical)")
 EOF
 
-# fused histogram+split smoke (r6): the interpret-mode wave grower must
-# produce byte-identical trees for pallas vs pallas_fused — fast CPU
-# wiring check of the fused kernel + candidate-decide path; the full
-# matrix (quantized, categorical merge, fallback configs, probe) lives
-# in tests/test_pallas_fused.py
-JAX_PLATFORMS=cpu python - <<'EOF'
-import numpy as np
-import jax.numpy as jnp
-
-from lightgbm_tpu.ops.grow import GrowerSpec
-from lightgbm_tpu.ops.grow_wave import make_wave_grower
-
-rng = np.random.RandomState(3)
-n, f, mb = 1500, 5, 32
-bins = rng.randint(0, mb, (f, n)).astype(np.int32)
-grad = rng.randn(n).astype(np.float32)
-hess = (0.1 + rng.rand(n)).astype(np.float32)
-sw = np.ones(n, np.float32)
-feat = dict(nb=jnp.full(f, mb, jnp.int32),
-            missing=jnp.zeros(f, jnp.int32),
-            default=jnp.zeros(f, jnp.int32), is_cat=jnp.zeros(f, bool),
-            mono=jnp.zeros(f, jnp.int32))
-
-
-def tree(impl):
-    spec = GrowerSpec(num_leaves=15, max_depth=0, max_bin=mb,
-                      lambda_l1=0.0, lambda_l2=1.0, min_data_in_leaf=5.0,
-                      min_sum_hessian_in_leaf=1e-3, min_gain_to_split=0.0,
-                      max_delta_step=0.0, hist_impl=impl, wave_width=4,
-                      has_cat=False, hist_interpret=True)
-    return make_wave_grower(spec)(jnp.asarray(bins), jnp.asarray(grad),
-                                  jnp.asarray(hess), jnp.asarray(sw),
-                                  feat, jnp.ones(f, bool))
-
-
-a, b = tree("pallas"), tree("pallas_fused")
-assert int(a.n_splits) > 0
-assert all(np.array_equal(np.asarray(x), np.asarray(y))
-           for x, y in zip(a, b)), "fused wave tree != pallas wave tree"
-print("[run_ci] fused smoke: pallas_fused == pallas (byte-identical)")
-EOF
-
 # serving smoke: a golden model behind the stdlib HTTP frontend on an
 # ephemeral port — POST /predict must be byte-identical to
 # booster.predict, /healthz and /metrics must answer, an X-Request-Id
@@ -338,7 +296,7 @@ EOF
 # quantized-default training smoke: under quantized gradients the auto
 # hist_impl resolution now lands on the int-lattice path by DEFAULT,
 # and must produce trees BYTE-IDENTICAL to an explicit
-# hist_impl=pallas_fused_q run (interpret-mode, wave policy) — the
+# hist_impl=pallas_q run (interpret-mode, wave policy) — the
 # default is a routing decision, never a numerics change.  The full
 # impl matrix + priced-fallback cases live in tests/test_bounded_serving.py
 JAX_PLATFORMS=cpu python - <<'EOF'
@@ -357,14 +315,14 @@ def trees(extra):
     bst = lgb.train({**base, **extra}, lgb.Dataset(X, label=y),
                     num_boost_round=4)
     s = bst.model_to_string()
-    return s[s.index("end of parameters"):]   # params echo the knobs
+    return s[:s.index("\nparameters:")]       # params echo the knobs
 
 
 auto = trees({})
-fused_q = trees({"hist_impl": "pallas_fused_q", "hist_interpret": True})
-assert auto == fused_q, \
-    "auto quantized-default trees != explicit pallas_fused_q trees"
-print("[run_ci] quantized-default smoke: auto == pallas_fused_q "
+pallas_q = trees({"hist_impl": "pallas_q", "hist_interpret": True})
+assert auto == pallas_q, \
+    "auto quantized-default trees != explicit pallas_q trees"
+print("[run_ci] quantized-default smoke: auto == pallas_q "
       "(byte-identical trees)")
 EOF
 

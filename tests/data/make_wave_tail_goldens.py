@@ -67,10 +67,9 @@ CASES = [(f"{d}-l{leaves}-t{tail}", d, leaves, tail, {})
 CASES.append(("numerical-l31-t16-pallas-w8", "numerical", 31, 16,
               {"hist_impl": "pallas", "hist_interpret": True,
                "tpu_wave_width": 8}))
-# the fused histogram+scan kernel runs the waves; the tail searches its
-# children with the plain scan, which the fused candidates equal
-CASES.append(("categorical-l31-t16-fused-w8", "categorical", 31, 16,
-              {"hist_impl": "pallas_fused", "hist_interpret": True,
+# the same through a categorical column's many-vs-rest search
+CASES.append(("categorical-l31-t16-pallas-w8", "categorical", 31, 16,
+              {"hist_impl": "pallas", "hist_interpret": True,
                "tpu_wave_width": 8}))
 
 

@@ -236,7 +236,7 @@ def _hist_train(X, y, **extra):
 
 def _trees(bst):
     s = bst.model_to_string()
-    return s[s.index("end of parameters"):]
+    return s[:s.index("\nparameters:")]         # the echo names the knobs
 
 
 @pytest.fixture(scope="module")
@@ -264,14 +264,15 @@ def test_hist_impl_pallas_q_interpret_byte_identical(hist_data):
     assert _trees(b) == _trees(base)
 
 
-def test_hist_impl_fused_q_interpret_byte_identical(hist_data):
-    # pallas_fused_q resolves to pallas_q and upgrades through the fused
-    # probe under the wave policy — trees byte-identical to the auto
-    # choice trained under the same policy
+def test_hist_impl_pallas_q_wave_interpret_byte_identical(hist_data):
+    # the same contract under the wave policy (the multi-leaf int8
+    # kernel): trees byte-identical to the auto choice trained under the
+    # same policy
     X, y = hist_data
-    b = _hist_train(X, y, hist_impl="pallas_fused_q",
+    b = _hist_train(X, y, hist_impl="pallas_q",
                     hist_interpret=True, tree_grow_policy="wave")
-    assert b._grower_spec.hist_impl == "pallas_fused_q"
+    assert b._grower_spec.hist_impl == "pallas_q"
+    assert b._grow_policy == "wave"
     auto = _hist_train(X, y, tree_grow_policy="wave")
     assert _trees(b) == _trees(auto)
 

@@ -20,6 +20,8 @@ import subprocess
 import sys
 import tempfile
 
+import pytest
+
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SMOKE = os.path.join(REPO, "chip_smoke.py")
 REPO_CACHE = os.path.join(REPO, ".jax_cache")
@@ -68,29 +70,48 @@ def start_dry_run() -> DryRun:
     return DryRun()
 
 
-def test_dry_run_passes_and_cache_follows_env(request):
+@pytest.fixture(scope="module")
+def dry_run(request):
+    """(run, returncode, stdout, stderr) of the one dry run both tests
+    below read: conftest's, or one started here when nobody did."""
     run = getattr(request.config, "_chip_smoke_dry_run", None)
     own = run is None
     if own:
         run = start_dry_run()
     try:
-        rc, out, err = run.finish(timeout=600)
-        assert rc == 0, (out[-3000:], err[-3000:])
-        lines = out.splitlines()
-        assert "DRY RUN" in lines[0]
-        assert "=== DRY RUN passed ===" in lines[-1]
-        assert f"compile_cache: dir={run.cache}" in out
-        assert '"claim": null}' in out
-        # the dry run leaves no device record a driver could mistake for
-        # a chip result
-        assert '{"ok": true, "device"' not in out
-        assert os.path.isdir(run.cache) and os.listdir(run.cache), \
-            "nothing was cached under JAX_COMPILATION_CACHE_DIR"
-        assert os.path.exists(REPO_CACHE) == run.had_repo_cache, \
-            "the run created <checkout>/.jax_cache despite the env setting"
+        yield (run,) + run.finish(timeout=600)
     finally:
         if own:
             run.cleanup()
+
+
+def test_dry_run_passes_and_cache_follows_env(dry_run):
+    run, rc, out, err = dry_run
+    assert rc == 0, (out[-3000:], err[-3000:])
+    lines = out.splitlines()
+    assert "DRY RUN" in lines[0]
+    assert "=== DRY RUN passed ===" in lines[-1]
+    assert f"compile_cache: dir={run.cache}" in out
+    assert '"claim": null}' in out
+    # the dry run leaves no device record a driver could mistake for
+    # a chip result
+    assert '{"ok": true, "device"' not in out
+    assert os.path.isdir(run.cache) and os.listdir(run.cache), \
+        "nothing was cached under JAX_COMPILATION_CACHE_DIR"
+    assert os.path.exists(REPO_CACHE) == run.had_repo_cache, \
+        "the run created <checkout>/.jax_cache despite the env setting"
+
+
+def test_dry_run_allows_no_fallback(dry_run):
+    # the smoke tolerates no `fallback.*` event, and its three training
+    # phases (wave, leafwise, data-parallel) and serving emit none
+    import chip_smoke
+    assert chip_smoke.ALLOWED_FALLBACKS == {}
+    _, rc, out, err = dry_run
+    assert rc == 0, (out[-3000:], err[-3000:])
+    assert "\nfallback.events=0\n" in out
+    assert "\n  fallback." not in out
+    assert '"fallbacks": []' in out
 
 
 def test_refuses_without_tpu():

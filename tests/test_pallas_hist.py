@@ -3,6 +3,8 @@ on CPU; the driver's TPU bench exercises the compiled kernel).
 
 Analog of the reference's CPU-vs-GPU histogram consistency checks
 (tests/python_package_test/test_dual.py)."""
+import os
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -71,9 +73,8 @@ class TestPallasHistogram:
             self, monkeypatch):
         # off-TPU a kernel the backend refuses is a falsy result that
         # carries the message (compiled Pallas on CPU is refused); on a
-        # TPU the BASE probes raise instead of letting training fall to
-        # segment-sum, while the fused probe — an upgrade over a working
-        # base — still degrades and keeps the message
+        # TPU the probes raise instead of letting training fall to
+        # segment-sum
         from lightgbm_tpu.ops import pallas_hist as ph
         from lightgbm_tpu.utils.log import LightGBMError
         res = probe(interpret=False)
@@ -87,9 +88,6 @@ class TestPallasHistogram:
         for kw in ({}, {"multi": True, "width": 4, "quantized": False}):
             with pytest.raises(LightGBMError, match="interpret mode"):
                 probe(interpret=False, **kw)
-        res = probe(interpret=False, fused=True, width=4, quantized=False)
-        assert not res and res.cause == "compile"
-        assert "interpret mode" in res.detail
 
     def test_multi_matches_per_leaf_interpret(self):
         rng = np.random.RandomState(21)
@@ -290,8 +288,8 @@ class TestLanePlan:
         y = (X[:, 1] + 0.3 * X[:, 0] - X[:, 2] + 0.1 * rng.randn(n) > 1)
         params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
                   "min_data_in_leaf": 5, "hist_impl": "pallas",
-                  "hist_interpret": True, "tpu_fused_split": False,
-                  "tpu_debug_nans": True, "tree_grow_policy": policy}
+                  "hist_interpret": True, "tpu_debug_nans": True,
+                  "tree_grow_policy": policy}
 
         def model(planned):
             if not planned:
@@ -311,3 +309,167 @@ class TestLanePlan:
         assert ref._grower_spec.hist_lane_plan is None
         assert (lanes, packed) == (5 * 256, 0)
         assert bst.model_to_string() == ref.model_to_string()
+
+
+# ------------------------------------------------ one histogram route
+# (PR 31: the fused hist+split family, its two `hist_impl` names and its
+# parameter are gone; what their tests guarded is held here on the
+# routes that remain)
+def _trees_text(bst):
+    """Model text up to the parameter echo: header and trees."""
+    s = bst.model_to_string()
+    return s[:s.index("\nparameters:")]
+
+
+def _mini_train(**extra):
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(0)
+    X = rng.randn(400, 5)
+    y = (X[:, 0] + 0.5 * X[:, 1] > 0).astype(float)
+    params = {"objective": "binary", "num_leaves": 8, "verbosity": -1,
+              **extra}
+    return lgb.train(params, lgb.Dataset(X, label=y), num_boost_round=1)
+
+
+@pytest.mark.parametrize("impl", ["pallas_fused", "pallas_fused_q"])
+def test_removed_hist_impl_is_refused(impl):
+    from lightgbm_tpu.utils.log import LightGBMError
+    with pytest.raises(LightGBMError, match="Unknown hist_impl") as e:
+        _mini_train(hist_impl=impl)
+    legal = str(e.value).split("expected one of ")[1].rstrip(")")
+    assert legal.split(", ") == ["auto", "segment_sum", "packed", "pallas",
+                                 "pallas_q"]
+
+
+@pytest.mark.parametrize("key", ["tpu_fused_split", "fused_split"])
+def test_removed_parameter_is_unknown(key):
+    # a stale configuration takes the path of any unknown key: recorded,
+    # warned about once a Config, and the trees are the trees without it
+    bst = _mini_train(**{key: False})
+    assert bst.config.unknown_params == {key: False}
+    assert _trees_text(bst) == _trees_text(_mini_train())
+
+
+def _cell_configs():
+    from perfbench import manifest
+    d = os.path.join(manifest.HERE, "configs")
+    return sorted(f[:-5] for f in os.listdir(d) if f.endswith(".json"))
+
+
+@pytest.mark.parametrize("name", _cell_configs())
+def test_cell_params_build_without_fallback(name):
+    # the booster of every benchmark configuration, over a few rows of
+    # its own data, resolves to the planned f32 kernel without a
+    # `fallback.*` event (built as tests/perfbench/test_perfbench_aot.py
+    # `grower_for_chip` builds it)
+    import lightgbm_tpu as lgb
+    from lightgbm_tpu import telemetry
+    from perfbench import manifest
+    from perfbench.generators import tabular_codes
+    from perfbench.jobs.train import build_dataset
+    config = manifest.config(name)
+    data = config["data"]
+    codes, label = tabular_codes.generate(7, data, 0, 8192)
+    params = {**config["params"], "hist_impl": "pallas",
+              "hist_interpret": True}
+    ds = build_dataset(lgb, codes, label, params,
+                       [c["name"] for c in data["columns"]])
+    counter = telemetry.REGISTRY.counter("fallback.events")
+    before = counter.value
+    sink = telemetry.TRACER.add_sink(telemetry.MemorySink())
+    try:
+        bst = lgb.Booster(params=params, train_set=ds)
+    finally:
+        telemetry.TRACER.remove_sink(sink)
+    assert counter.value == before
+    assert [e["name"] for e in sink.events if e.get("ev") == "event"
+            and str(e.get("name", "")).startswith("fallback.")] == []
+    assert bst._grower_spec.hist_impl == "pallas"
+    assert bst._grower_spec.hist_lane_plan is not None
+    assert bst._grow_policy == config["params"]["tree_grow_policy"]
+
+
+def _wave_case(seed=7, n=3000, f=6, mb=32):
+    """Bins with a short column and a NaN-bin column; gradients and
+    hessians on a dyadic lattice (small integers times 2^-4), so every
+    sum of them is exact in float32 in any order: two histogram routes
+    that share no kernel must then agree to the last bit."""
+    rng = np.random.RandomState(seed)
+    bins = rng.randint(0, mb, (f, n)).astype(np.int32)
+    nb = np.full(f, mb, np.int32)
+    nb[1] = 17
+    bins[1] %= 17
+    missing = np.zeros(f, np.int32)
+    missing[2] = 2
+    s = np.float32(2.0 ** -4)
+    grad = (rng.randint(-15, 16, n) * s).astype(np.float32)
+    hess = (rng.randint(1, 16, n) * s).astype(np.float32)
+    sw = np.ones(n, np.float32)
+    feat = dict(nb=jnp.asarray(nb), missing=jnp.asarray(missing),
+                default=jnp.zeros(f, jnp.int32),
+                is_cat=jnp.zeros(f, bool), mono=jnp.zeros(f, jnp.int32),
+                qscales=jnp.asarray(np.stack([s, s])))
+    return bins, grad, hess, sw, feat, jnp.ones(f, bool)
+
+
+def _wave_grower(impl, mb=32, **spec_kw):
+    from lightgbm_tpu.ops.grow import GrowerSpec
+    from lightgbm_tpu.ops.grow_wave import make_wave_grower
+    kw = dict(num_leaves=15, max_depth=0, max_bin=mb, lambda_l1=0.0,
+              lambda_l2=1.0, min_data_in_leaf=5.0,
+              min_sum_hessian_in_leaf=1e-3, min_gain_to_split=0.0,
+              max_delta_step=0.0, hist_impl=impl, wave_width=4,
+              has_cat=False, hist_interpret=True)
+    kw.update(spec_kw)
+    return make_wave_grower(GrowerSpec(**kw))
+
+
+def _grow(grow, bins, grad, hess, sw, feat, allowed):
+    import jax
+    return jax.block_until_ready(grow(
+        jnp.asarray(bins), jnp.asarray(grad), jnp.asarray(hess),
+        jnp.asarray(sw), feat, allowed))
+
+
+def _assert_trees_equal(a, b, ctx=""):
+    for name, x, y in zip(a._fields, a, b):
+        assert np.array_equal(np.asarray(x), np.asarray(y)), \
+            f"{ctx}: field {name} differs"
+
+
+@pytest.mark.parametrize("kernel,reference,spec_kw", [
+    pytest.param("pallas", "segment_sum", {}, id="plain"),
+    pytest.param("pallas", "segment_sum", {"has_cat": True},
+                 id="categorical"),
+    pytest.param("pallas", "segment_sum", {"path_smooth": 1.0},
+                 id="path_smooth"),
+    pytest.param("pallas_q", "packed", {}, id="quantized"),
+])
+def test_wave_kernel_route_grows_the_reference_tree(kernel, reference,
+                                                    spec_kw):
+    # a kernel route through the whole wave grower (sibling subtraction
+    # and limbs included) against a route that shares no kernel with it
+    bins, grad, hess, sw, feat, allowed = _wave_case()
+    if spec_kw.get("has_cat"):
+        feat = dict(feat, is_cat=jnp.asarray(
+            np.array([0, 0, 0, 1, 0, 0], bool)))
+    args = (bins, grad, hess, sw, feat, allowed)
+    a = _grow(_wave_grower(kernel, **spec_kw), *args)
+    b = _grow(_wave_grower(reference, **spec_kw), *args)
+    assert int(a.n_splits) > 0
+    _assert_trees_equal(a, b, f"{kernel} vs {reference} {spec_kw}")
+
+
+@pytest.mark.parametrize("impl", ["pallas", "pallas_q"])
+def test_wave_recompile_bound(impl):
+    # repeated trees of one shape share one compiled program
+    from lightgbm_tpu import telemetry
+    assert telemetry.install_compile_listener()
+    grow = _wave_grower(impl)
+    _grow(grow, *_wave_case(seed=19))                # warm: compiles
+    recompiles = telemetry.REGISTRY.counter("jit.recompiles")
+    before = recompiles.value
+    _grow(grow, *_wave_case(seed=23))
+    assert recompiles.value == before, \
+        f"second same-shape wave tree recompiled " \
+        f"({recompiles.value - before} new)"

@@ -102,35 +102,6 @@ def test_hist_kernel_int8(topo, width):
         sds((), jnp.float32), sds((), jnp.float32)).compile()
 
 
-_SCAN_KW = dict(l1=0.0, l2=1.0, min_data_in_leaf=20.0,
-                min_sum_hessian=1e-3, min_gain_to_split=0.0)
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError, reason=(
-    "jax 0.9.0: 'Unimplemented primitive in Pallas TPU lowering for "
-    "KernelType.TC: cumsum' — the in-kernel split scan "
-    "(ops/split.py fused_numerical_candidates); ROADMAP S3"))
-def test_fused_hist_split_kernel(topo):
-    sds, _ = _one(topo)
-    ph.pallas_fused_hist_split_rows.lower(
-        sds((F, N), jnp.uint8), sds((9, N), jnp.float32),
-        sds((N,), jnp.int32), sds((8,), jnp.int32), sds((F,), jnp.int32),
-        sds((F,), jnp.int32), sds((8, 3), jnp.float32), MB,
-        **_SCAN_KW).compile()
-
-
-@pytest.mark.xfail(strict=True, raises=NotImplementedError, reason=(
-    "jax 0.9.0: 'Unimplemented primitive in Pallas TPU lowering for "
-    "KernelType.TC: cumsum' — same scan body as the fused kernel; "
-    "ROADMAP S3"))
-def test_split_scan_kernel(topo):
-    sds, _ = _one(topo)
-    ph.pallas_split_scan.lower(
-        sds((8, F, MB, 3), jnp.float32), sds((F,), jnp.int32),
-        sds((F,), jnp.int32), sds((8, 3), jnp.float32),
-        **_SCAN_KW).compile()
-
-
 # ---------------------------------------------------------- train chunks
 def _data(n=N):
     rng = np.random.RandomState(0)
@@ -141,12 +112,12 @@ def _data(n=N):
 
 def _booster(extra):
     """A CPU-built booster whose grower spec names the compiled (not
-    interpreted) unfused Pallas kernel — what `hist_impl=auto` resolves
-    to on the chip once the fused probe has declined."""
+    interpreted) Pallas kernel — what `hist_impl=auto` resolves to on
+    the chip."""
     X, y = _data()
     params = {"objective": "binary", "num_leaves": 31, "max_bin": 255,
               "verbosity": -1, "hist_impl": "pallas",
-              "hist_interpret": True, "tpu_fused_split": False, **extra}
+              "hist_interpret": True, **extra}
     bst = Booster(params=params, train_set=lgb.Dataset(X, label=y))
     bst._grower_spec = bst._grower_spec._replace(hist_interpret=False)
     bst._boost_from_average()

@@ -241,7 +241,11 @@ def _count_growth(tree: Tree) -> None:
     """What growing `tree` cost, onto the always-on counters: the rows its
     histograms needed, its leaves, and (wave grower) the waves' passes and
     its strict tail's passes, splits served from a speculated histogram,
-    and speculated ones left unused: passes a tree = 1 + waves + tail."""
+    and speculated ones left unused: passes a tree = 1 + waves + tail;
+    on the f32 Pallas kernel, its calls by the body that ran
+    (`grow.hist_passes_full`, `grow.hist_passes_c<capacity>`) and the rows
+    they contracted: needed / contracted is the useful share of what the
+    MXU multiplies."""
     counter = telemetry.REGISTRY.counter
     counter("grow.hist_rows_needed").inc(tree.hist_rows_needed())
     counter("grow.leaves").inc(tree.num_leaves)
@@ -251,6 +255,11 @@ def _count_growth(tree: Tree) -> None:
         counter("grow.tail_spec_hits").inc(hits)
         counter("grow.tail_spec_unused").inc(unused)
         counter("grow.wave_passes").inc(waves)
+    if tree.hist_calls is not None:
+        from .ops.pallas_hist import LANE, hist_bodies
+        for (body, _), calls in zip(hist_bodies(), tree.hist_calls):
+            counter(f"grow.hist_passes_{body}").inc(calls)
+        counter("grow.hist_rows_contracted").inc(tree.hist_calls[-1] * LANE)
 
 
 @jax.jit

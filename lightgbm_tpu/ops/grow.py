@@ -193,6 +193,11 @@ class DeviceTree(NamedTuple):
     # speculated histograms never used / made, and the histogram passes
     # of the waves before the tail (ops/grow_wave.py)
     tail_stats: Array = None
+    # [len(pallas_hist.hist_bodies()) + 1] i32, wave grower on the f32
+    # Pallas kernel only (None elsewhere) — the kernel's calls by the
+    # body that ran (full, then each compacting capacity; summed over the
+    # shards) and, last, the 128-row groups those calls contracted
+    hist_calls: Array = None
 
 
 def _split_to_arrays(s: SplitResult):

@@ -242,7 +242,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         # loop-invariant code motion does not reliably hoist the f32
         # 3-way split / int8 lattice conversion out of the loop)
         if spec.hist_impl == "pallas":
-            from .pallas_hist import (_split_payload9, assert_bins_in_plan,
+            from .pallas_hist import (LANE, ROW_TILE, _split_payload9,
+                                      assert_bins_in_plan, hist_bodies,
                                       pallas_histogram_multi_rows)
             with jax.named_scope("payload"):
                 pw_prep = _split_payload9(payload)
@@ -264,20 +265,29 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         else:
             bfeat, bmono = feat, mono
 
-        def kernel_hist_multi(leaf_id, slots):
-            """This shard's rows through the Pallas kernel."""
+        # kernel calls by the body that ran (`hist_bodies`): only the f32
+        # Pallas kernel has more than one
+        no_calls = jnp.zeros((len(hist_bodies())
+                              if spec.hist_impl == "pallas" else 0,),
+                             jnp.int32)
+
+        def kernel_hist_multi(leaf_id, slots, root=False):
+            """This shard's rows through the Pallas kernel: (sums, the
+            f32 kernel's calls by the body that ran).  The root pass
+            holds every row: it is traced with the full body alone."""
             if spec.hist_impl == "pallas":
                 return pallas_histogram_multi_rows(
                     bins_fm, pw_prep, leaf_id, slots, HB,
                     interpret=spec.hist_interpret,
-                    plan=spec.hist_lane_plan)
+                    plan=spec.hist_lane_plan, count_bodies=True,
+                    body="full" if root else None)
             return pallas_histogram_multi_quantized_rows(
                 bins_fm, pw_prep, leaf_id, slots, HB,
                 feat["qscales"][0], feat["qscales"][1],
-                interpret=spec.hist_interpret)
+                interpret=spec.hist_interpret), no_calls
 
         if det:
-            def det_hist_multi(leaf_id, slots):
+            def det_hist_multi(leaf_id, slots, root):
                 """Ring-chained deterministic wave histogram.  On the XLA
                 families it is bitwise the serial `hist_multi` (pad rows
                 carry leaf_id -1 and match no slot, so they never touch
@@ -286,11 +296,11 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 shard order (`ring_ordered_sum`)."""
                 Fh = bins_fm.shape[0]
                 S = slots.shape[0]
+                calls = no_calls
                 if spec.hist_impl in ("pallas", "pallas_q"):
                     with jax.named_scope("ring_fold"):
-                        h = ring_ordered_sum(
-                            kernel_hist_multi(leaf_id, slots), axis_last,
-                            n_shards)
+                        h, calls = kernel_hist_multi(leaf_id, slots, root)
+                        h = ring_ordered_sum(h, axis_last, n_shards)
                 elif spec.hist_impl == "packed":
                     chl = spec.packed_const_hess_level
 
@@ -319,19 +329,21 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     h = jax.lax.dynamic_slice_in_dim(
                         h, jax.lax.axis_index(axis_last) * Fb_h, Fb_h,
                         axis=1)
-                return h
+                return h, calls
 
-        def hist_multi(leaf_id, slots):
-            """[S, F|G|Fb, HB, 6] histograms of the listed leaf slots in
+        def hist_multi(leaf_id, slots, root=False):
+            """([S, F|G|Fb, HB, 6] histograms of the listed leaf slots in
             one batched sweep (both limbs of every sum; [.., 3] from the
-            quantized families); pad slots (value LB) yield zeros.  Under
+            quantized families); pad slots (value LB) yield zeros, this
+            shard's kernel calls by body (`no_calls`' shape)).  Under
             data_rs the returned feature axis is this shard's summed
             block (psum_scatter over ICI + psum over DCN)."""
+            calls = no_calls
             with jax.named_scope("histogram_wave"):
                 if det:
-                    return det_hist_multi(leaf_id, slots)
+                    return det_hist_multi(leaf_id, slots, root)
                 if spec.hist_impl in ("pallas", "pallas_q"):
-                    h = kernel_hist_multi(leaf_id, slots)
+                    h, calls = kernel_hist_multi(leaf_id, slots, root)
                 elif spec.hist_impl == "packed":
                     h = leaf_histogram_packed_multi(
                         bins_fm, payload, leaf_id, slots, HB,
@@ -351,7 +363,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         h = jax.lax.psum(h, axes_dcn)
                 elif axes_all is not None:
                     h = jax.lax.psum(h, axes_all)
-            return h
+            return h, calls
 
         # per-node column sampling / extra_trees / CEGB pricing — the
         # SAME shared derivations as the strict grower (ops/grow.py), so
@@ -459,7 +471,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 # only features inside a constraint group may ever split
                 allowed = allowed & jnp.any(feat["ic_groups"], axis=0)
             root_pen = cegb_penalty(root_c, jnp.zeros((F,), bool))
-        hist0 = hist_multi(leaf_id0, root_slots)[0]
+        hist0, root_calls = hist_multi(leaf_id0, root_slots, root=True)
+        hist0 = hist0[0]
         with jax.named_scope("find_split"):
             s0 = split_of(hist0, root_g, root_h, root_c, allowed,
                           jnp.float32(-INF), jnp.float32(INF),
@@ -506,6 +519,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 nodes=nodes,
                 # histogram passes the waves made (DeviceTree.tail_stats)
                 wave_passes=jnp.int32(0),
+                # kernel calls by body so far (DeviceTree.hist_calls)
+                hist_calls=root_calls,
             )
             if track_used:
                 state["leaf_used"] = jnp.zeros((LB, F), bool)
@@ -773,6 +788,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 # ---- histogram phase: ONE batched pass for all smaller
                 # children; larger children by subtraction (the parent
                 # histogram still lives in the left child's slot) ----
+                calls = no_calls
                 if strict:
                     # speculated for the leaf that was just split, under
                     # the very split it was split by; pad slots gather
@@ -781,7 +797,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         small_h = small_hists[
                             jnp.clip(s1["p_left"], 0, LB - 1)]
                 else:
-                    small_h = hist_multi(s1["leaf_id"], s1["p_small"])
+                    small_h, calls = hist_multi(s1["leaf_id"],
+                                                s1["p_small"])
                 with jax.named_scope("hist_cache"):
                     parents = st["hist"][jnp.clip(s1["p_left"], 0, LB - 1)]
                     large_h = hist_sub(parents, small_h)
@@ -818,22 +835,23 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     res = jax.vmap(eval_child)(child_slots, node_ids)
                     return hist, tuple(
                         s1[k].at[child_slots].set(r, mode="drop")
-                        for k, r in zip(LEAF_KEYS, res))
+                        for k, r in zip(LEAF_KEYS, res)), calls
 
             def tree_full(_):
                 # capacity reached mid-wave: the children can never be
                 # split, so skip the whole histogram pass + find fan-out
                 # (one full-data pass saved on every capacity-bound tree)
-                return st["hist"], tuple(s1[k] for k in LEAF_KEYS)
+                return st["hist"], tuple(s1[k] for k in LEAF_KEYS), no_calls
 
-            hist, leaf_upd = jax.lax.cond(s1["step"] >= LB - 1, tree_full,
-                                          hist_and_find, None)
+            hist, leaf_upd, calls = jax.lax.cond(
+                s1["step"] >= LB - 1, tree_full, hist_and_find, None)
 
             new_state = {**st, **{k: s1[k] for k in carry_keys}}
             if not strict:
                 new_state["wave_passes"] = st["wave_passes"] + \
                     (s1["step"] < LB - 1).astype(jnp.int32)
             new_state["hist"] = hist
+            new_state["hist_calls"] = st["hist_calls"] + calls
             for k, v in zip(LEAF_KEYS, leaf_upd):
                 new_state[k] = v
             return new_state
@@ -872,13 +890,13 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 # one slot at a time: one [N] routing mask alive, not W
                 slot_of_row = jax.lax.fori_loop(
                     0, W, fill_slot, jnp.full((N,), -1, jnp.int32))
-            small_h = hist_multi(slot_of_row,
-                                 jnp.arange(W, dtype=jnp.int32))
+            small_h, calls = hist_multi(slot_of_row,
+                                        jnp.arange(W, dtype=jnp.int32))
             with jax.named_scope("hist_cache"):
                 dst = jnp.where(chosen, top_leaf, LB)
                 return (st["spec_hist"].at[dst].set(small_h, mode="drop"),
                         st["spec_ok"].at[dst].set(True, mode="drop"),
-                        jnp.sum(chosen, dtype=jnp.int32))
+                        jnp.sum(chosen, dtype=jnp.int32), calls)
 
         def tail_body(st):
             """One split of the strict tail: a histogram pass only if
@@ -892,16 +910,18 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 fills = st["step"] + 1 >= LB - 1
                 held = st["spec_ok"][best]
                 miss = ~held & ~fills
-            spec_hist, spec_ok, n_spec = jax.lax.cond(
+            spec_hist, spec_ok, n_spec, calls = jax.lax.cond(
                 miss, speculate,
-                lambda st: (st["spec_hist"], st["spec_ok"], jnp.int32(0)),
+                lambda st: (st["spec_hist"], st["spec_ok"], jnp.int32(0),
+                            no_calls),
                 st)
             # the pick rewrites `leaf_id`, which the pass reads: tie the
             # pick behind the pass, or XLA keeps both orders open and
             # copies the [N] ids every split
             leaf_id, spec_hist = jax.lax.optimization_barrier(
                 (st["leaf_id"], spec_hist))
-            new_state = body({**st, "leaf_id": leaf_id},
+            new_state = body({**st, "leaf_id": leaf_id,
+                              "hist_calls": st["hist_calls"] + calls},
                              small_hists=spec_hist)
             # the split leaf's two children are new leaves with no entry
             new_state["spec_hist"] = spec_hist
@@ -923,6 +943,18 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         else:
             tail_stats = jnp.zeros((4,), jnp.int32)
         tail_stats = jnp.concatenate([tail_stats, st["wave_passes"][None]])
+        hist_calls = None
+        if spec.hist_impl == "pallas":
+            # and the 128-row groups those calls contracted (int32 holds
+            # a tree's groups where it would not hold its rows)
+            groups_a_call = jnp.array(
+                [-(-N // ROW_TILE) * rows // LANE
+                 for _, rows in hist_bodies()], jnp.int32)
+            hist_calls = jnp.concatenate(
+                [st["hist_calls"],
+                 jnp.sum(st["hist_calls"] * groups_a_call)[None]])
+            if axes_all is not None:         # every shard's calls
+                hist_calls = jax.lax.psum(hist_calls, axes_all)
 
         if LB > L:
             with jax.named_scope("prune"):
@@ -939,7 +971,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 leaf_g=leaves_f["g"], leaf_h=leaves_f["h"],
                 leaf_cnt=leaves_f["c"],
                 leaf_id=leaf_id_f,
-                tail_stats=tail_stats,
+                tail_stats=tail_stats, hist_calls=hist_calls,
                 **nodes_f,
             )
 
@@ -964,7 +996,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             leaf_g=st["leaf_g"], leaf_h=st["leaf_h"],
             leaf_cnt=st["leaf_c"],
             leaf_id=st["leaf_id"],
-            tail_stats=tail_stats,
+            tail_stats=tail_stats, hist_calls=hist_calls,
         )
 
     return jax.jit(grow)

@@ -14,19 +14,29 @@ revisit across the row-tile grid axis, exactly the role of the CUDA
 kernel's shared-memory histograms (grid-level reduction replaces
 atomicAdd).
 
-What a call costs (PERF.md section 6, PR 30; one TPU v5e).  Not bytes: a
-call ran at 0.49 % of its HBM bound.  Until PR 30 the one-hot was built
-as `bins[:, None] == lane iota`, which spreads every bin row from lanes
-to sublanes with one XLU permute per 8 rows and column: a flat 0.57 ns
-per (row, column) whatever the bin count (128 lanes cost what 256 did).
-The operand is now built TRANSPOSED, `bins[None, :] == sublane iota`
-([lanes, N_t]; a bin row never leaves the lanes it arrives on), and the
-dot contracts both operands' last axis, the MXU loading the 0/1 tile
-transposed.  That left the MXU's slots as the bound — about 1 ps per
-(row, contracted lane) plus 1 us a tile — so the lanes count, and the
-LANE PLAN (`lane_plan`) packs the columns of few bins into shared
-128-lane multi-hot groups: the airline table contracts 1,920 lanes a
-row, not 3,328.  Same products, same order: sums bit-equal throughout.
+What a call costs (PERF.md sections 6 and 7.5, PR 30 and 32; one TPU
+v5e).  Not bytes: a call runs at 1.8 % of its HBM bound.  Until PR 30 the
+one-hot was built as `bins[:, None] == lane iota`, which spreads every
+bin row from lanes to sublanes with one XLU permute per 8 rows and
+column: a flat 0.57 ns per (row, column) whatever the bin count (128
+lanes cost what 256 did).  The operand is now built TRANSPOSED,
+`bins[None, :] == sublane iota` ([lanes, N_t]; a bin row never leaves the
+lanes it arrives on), and the dot contracts both operands' last axis,
+the MXU loading the 0/1 tile transposed.  That left the MXU's slots as
+the bound — 0.74 ps per (row, contracted lane) plus 1.0 us a 2048-row
+tile (4.19M rows: 1,664 lanes 7.26 ms, 3,328 lanes 12.44 ms; the packed
+1,920 lanes 9.24 ms, 1.15 ps all in) — so the lanes count, and the LANE
+PLAN (`lane_plan`) packs the columns of few bins into shared 128-lane
+multi-hot groups: the airline table contracts 1,920 lanes a row, not
+3,328.  Same products, same order: sums bit-equal throughout.  At S = 8
+that is 70 % of the MXU's bf16 peak on a 72-row LHS, so what is left is
+the ROWS: past a tree's first waves most rows of a pass are in no slot
+(73 % / 90 % of all rows contracted in the benchmark's cells).  A pass
+whose every tile holds at most 256 or 512 active rows (`COMPACT_CAPS`)
+lands them in that many columns with one selection matmul — a one-hot
+like any other — and contracts those for the tile's 2048
+(`_hist_kernel_multi_compact`): the same call takes 3.2 ms / 4.9 ms for
+9.4 ms whatever the share of active rows, the ranking fusion included.
 
 Precision design (replaces the old Precision.HIGHEST formulation, which
 cost 3-6 MXU passes): the one-hot operand is {0,1} — exact in bf16 at any
@@ -174,12 +184,72 @@ def assert_bins_in_plan(bins_fm: Array, plan) -> None:
     jax.debug.callback(_check, bins_fm.max(axis=1))
 
 
+def _lane_groups(plan, f_t: int, mb: int):
+    """(groups, cells) of a kernel body: the lane groups it contracts (a
+    group `(lanes, members)` as in `lane_plan`; without a plan every
+    column its own group of `mb` lanes) and each group's cell of the sums
+    block ([F_t, S*R0, MB] without a plan, [S*R0, L] with one)."""
+    if plan is None:
+        return (tuple((mb, ((f, 0, mb),)) for f in range(f_t)),
+                tuple(range(f_t)))
+    cells, lane0 = [], 0
+    for lanes, _ in plan:
+        cells.append((slice(None), slice(lane0, lane0 + lanes)))
+        lane0 += lanes
+    return plan, tuple(cells)
+
+
+def _zero_sums_first(r, hi_ref, lo_ref, acc_ref):
+    @pl.when(r == 0)
+    def _init():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        hi_ref[:] = jnp.zeros_like(hi_ref)
+        lo_ref[:] = jnp.zeros_like(lo_ref)
+
+
+def _contract_groups(acc_ref, lhs, bin_row, n_c: int, groups, cells):
+    """One dot a lane group into `acc_ref`: `lhs` [S*R0, n_c] against the
+    group's multi-hot of the bin rows `bin_row(f)` ([1, n_c] i32)."""
+    bin_ids = {}
+    for (lanes, members), cell in zip(groups, cells):    # static unroll
+        if lanes not in bin_ids:
+            bin_ids[lanes] = jax.lax.broadcasted_iota(
+                jnp.int32, (lanes, n_c), 0)
+        # the operand is built TRANSPOSED, [lanes, n_c]: a bin row stays
+        # on the lanes it arrives on and is compared against a sublane
+        # iota; the dot contracts both operands' last axis (the MXU loads
+        # the transposed tile natively).  `b[:, None] == lane iota` cost
+        # one XLU permute per 8 rows and column to spread the row over
+        # sublanes — the whole price of a call before PR 30
+        hot = None
+        for f, off, _ in members:
+            b = bin_row(f)                               # [1, n_c]
+            if off:
+                b = b + off
+            one = b == bin_ids[lanes]
+            hot = one if hot is None else hot | one
+        acc_ref[cell] += jax.lax.dot_general(
+            lhs, hot.astype(jnp.float32), (((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.DEFAULT,
+            preferred_element_type=jnp.float32)
+
+
+def _fold_sums(r, n_rt: int, hi_ref, lo_ref, acc_ref, cells):
+    @pl.when(((r + 1) % FLUSH_TILES == 0) | (r == n_rt - 1))
+    def _flush():
+        for cell in cells:                           # a group at a time
+            hi_ref[cell], lo_ref[cell] = limb_add(hi_ref[cell], lo_ref[cell],
+                                                  acc_ref[cell])
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+
 def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
                        acc_ref, *, mb: int, n_rt: int, plan=None):
     """Multi-leaf grid cell with IN-KERNEL leaf masking — THE production
     kernel: every public f32 entry point (single-leaf included, via a
-    mask-derived leaf id) lowers to this one body, so `probe()` gates
-    exactly the code that training runs.
+    mask-derived leaf id) lowers to this body or its compacting twin
+    (`_hist_kernel_multi_compact`, the same group loop over fewer rows),
+    so `probe()` gates exactly the code that training runs.
 
     bins_ref: [F_t, N_t]; pw_ref: [R0, N_t] base payload rows (9
     f32-split); lid_ref: [1, N_t] i32 row→leaf; slots_ref: [1, S] i32 leaf
@@ -204,57 +274,81 @@ def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
     select are VPU work overlapping the MXU dots.
     """
     r = pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _init():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        hi_ref[:] = jnp.zeros_like(hi_ref)
-        lo_ref[:] = jnp.zeros_like(lo_ref)
-
+    _zero_sums_first(r, hi_ref, lo_ref, acc_ref)
     f_t, n_t = bins_ref.shape
-    if plan is None:
-        groups = tuple((mb, ((f, 0, mb),)) for f in range(f_t))
-        cells = tuple(range(f_t))                    # block [F_t, S*R0, MB]
-    else:
-        groups, cells, lane0 = plan, [], 0           # block [S*R0, L]
-        for lanes, _ in plan:
-            cells.append((slice(None), slice(lane0, lane0 + lanes)))
-            lane0 += lanes
+    groups, cells = _lane_groups(plan, f_t, mb)
     pw = pw_ref[:]                                   # [R0, N_t]
     lid = lid_ref[0, :]                              # [N_t] i32
-    s_n = slots_ref.shape[1]
     lhs = jnp.concatenate(
         [jnp.where((lid == slots_ref[0, s])[None, :], pw, 0.0)
-         for s in range(s_n)], axis=0)               # [S*R0, N_t]
-    bin_ids = {}
-    for (lanes, members), cell in zip(groups, cells):    # static unroll
-        if lanes not in bin_ids:
-            bin_ids[lanes] = jax.lax.broadcasted_iota(
-                jnp.int32, (lanes, n_t), 0)
-        # the operand is built TRANSPOSED, [lanes, N_t]: a bin row stays
-        # on the lanes it arrives on and is compared against a sublane
-        # iota; the dot contracts both operands' last axis (the MXU loads
-        # the transposed tile natively).  `b[:, None] == lane iota` cost
-        # one XLU permute per 8 rows and column to spread the row over
-        # sublanes — the whole price of a call before PR 30
-        hot = None
-        for f, off, _ in members:
-            b = bins_ref[f:f + 1, :].astype(jnp.int32)   # [1, N_t]
-            if off:
-                b = b + off
-            one = b == bin_ids[lanes]
-            hot = one if hot is None else hot | one
-        acc_ref[cell] += jax.lax.dot_general(
-            lhs, hot.astype(jnp.float32), (((1,), (1,)), ((), ())),
-            precision=jax.lax.Precision.DEFAULT,
-            preferred_element_type=jnp.float32)
+         for s in range(slots_ref.shape[1])], axis=0)    # [S*R0, N_t]
+    _contract_groups(
+        acc_ref, lhs, lambda f: bins_ref[f:f + 1, :].astype(jnp.int32),
+        n_t, groups, cells)
+    _fold_sums(r, n_rt, hi_ref, lo_ref, acc_ref, cells)
 
-    @pl.when(((r + 1) % FLUSH_TILES == 0) | (r == n_rt - 1))
-    def _flush():
-        for cell in cells:                           # a group at a time
-            hi_ref[cell], lo_ref[cell] = limb_add(hi_ref[cell], lo_ref[cell],
-                                                  acc_ref[cell])
-        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+# a row's code for the compacting body: its rank among its tile's active
+# rows times 2**SLOT_BITS, plus its slot's index + 1 (MULTI_CHUNK < 16);
+# -1 for a row in no slot
+SLOT_BITS = 4
+
+
+def _compact_rows(x: Array, code: Array, cap: int) -> Array:
+    """[R, N_t] f32 rows x [1, N_t] i32 codes -> [R, cap]: the columns of
+    `x` whose code is not negative, each at column `code >> SLOT_BITS`
+    (its rank among them), zeros beyond.  One matmul against the
+    selection one-hot, built transposed as the histogram's is."""
+    pt = (code >> SLOT_BITS) == jax.lax.broadcasted_iota(
+        jnp.int32, (cap, x.shape[1]), 0)             # [cap, N_t]
+    return jax.lax.dot_general(
+        x, pt.astype(jnp.float32), (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.DEFAULT,
+        preferred_element_type=jnp.float32)
+
+
+def _hist_kernel_multi_compact(bins_ref, pw_ref, code_ref, hi_ref, lo_ref,
+                               acc_ref, *, mb: int, n_rt: int, plan,
+                               cap: int, s_n: int):
+    """`_hist_kernel_multi` over the tile's ACTIVE rows only (rows in one
+    of the call's slots), for a pass whose every tile holds at most `cap`
+    of them (`_run_kernel_multi` sees to that).  A selection matrix is a
+    one-hot, and one-hots transposed are what this kernel contracts
+    best: with `dest` a row's rank among its tile's active rows (-1 in
+    no slot), `PT = (dest[None, :] == sublane iota)` is [cap, N_t], and
+    `X . PT^T` over X = (payload rows, slot index, bin rows) lands the
+    active rows, in order, in `cap` columns.  Every cell of that product
+    is ONE product plus zeros and every value of X is exact in bf16
+    (uint8 bins, the split terms by construction, a slot index under
+    16), so the compaction is exact.  Then today's body over `cap`
+    columns for N_t: the unfilled ones are all zero (payload 0, slot 0)
+    and add nothing.
+
+    code_ref: [1, N_t] i32, `_row_codes`' (dest << SLOT_BITS | slot + 1),
+    -1 where inactive (the ranks are XLA's: computed in the kernel, a
+    matmul and four sublane roll-adds a tile, the chain from ids to PT
+    is exposed latency — a C256 call took 0.0557 s for 0.0417 s, against
+    0.0055 s for the fusions that write the codes; PERF.md section 6,
+    PR 32); the sums blocks and the folds are `_hist_kernel_multi`'s."""
+    r = pl.program_id(1)
+    _zero_sums_first(r, hi_ref, lo_ref, acc_ref)
+    f_t, n_t = bins_ref.shape
+    r0 = pw_ref.shape[0]
+    groups, cells = _lane_groups(plan, f_t, mb)
+    code = code_ref[:]                               # [1, N_t]
+    slot = (code & ((1 << SLOT_BITS) - 1)).astype(jnp.float32)
+    comp = _compact_rows(jnp.concatenate(
+        [pw_ref[:], slot,
+         bins_ref[:].astype(jnp.int32).astype(jnp.float32)], axis=0),
+        code, cap)                                   # [R0 + 1 + F_t, cap]
+    pw_c, slot_c = comp[:r0], comp[r0:r0 + 1]
+    bins_c = comp[r0 + 1:].astype(jnp.int32)         # [F_t, cap]
+    lhs = jnp.concatenate(
+        [jnp.where(slot_c == s + 1.0, pw_c, 0.0) for s in range(s_n)],
+        axis=0)                                      # [S*R0, cap]
+    _contract_groups(acc_ref, lhs, lambda f: bins_c[f:f + 1, :], cap,
+                     groups, cells)
+    _fold_sums(r, n_rt, hi_ref, lo_ref, acc_ref, cells)
 
 
 def _combine_terms(hi: Array, lo: Array):
@@ -265,16 +359,82 @@ def _combine_terms(hi: Array, lo: Array):
     return limb_add(h, l, hi[..., 2, :], lo[..., 2, :])
 
 
+# Active rows a tile that a compacting body holds, ascending: a pass runs
+# the smallest that holds its fullest tile, else the full body.  From one
+# call alone on the chip (PERF.md section 6, PR 32): kept where a call is
+# at most 0.8x of the full one at the largest share of active rows it
+# admits.
+COMPACT_CAPS = (256, 512)
+
+
+def hist_bodies(row_tile: int = ROW_TILE):
+    """The f32 kernel's bodies in the order `pallas_histogram_multi_rows(
+    .., count_bodies=True)` counts them: (name, rows contracted a tile).
+    "full" first, then a "c<capacity>" each compacting capacity."""
+    return (("full", row_tile),) + tuple(
+        (f"c{c}", c) for c in COMPACT_CAPS if c < row_tile)
+
+
+def _slot_of_rows(leaf_id: Array, slots: Array):
+    """(slot, ambiguous): [N] i32, 1 + the index of the slot a row's leaf
+    is listed in (0: in none), and whether some row's leaf is listed
+    twice (the full body then sums it into both slots; a row has one
+    code).  A static unroll over the S <= MULTI_CHUNK slots, elementwise
+    over [N]: it fuses into whatever reads it."""
+    slot = jnp.zeros(leaf_id.shape, jnp.int32)
+    listed = jnp.zeros(leaf_id.shape, jnp.int32)
+    for s in range(slots.shape[0]):
+        hit = leaf_id == slots[s]
+        slot = jnp.where(hit, s + 1, slot)
+        listed = listed + hit
+    return slot, jnp.max(listed, initial=0) > 1
+
+
+def _row_codes(leaf_id: Array, slots: Array, row_tile: int) -> Array:
+    """[N] i32 codes for `_hist_kernel_multi_compact` (N a multiple of
+    `row_tile`, `row_tile` of LANE): (rank among the tile's active rows)
+    << SLOT_BITS | (slot index + 1), -1 for a row in no slot.  The ranks
+    are an exclusive prefix count within each tile: within each 128-row
+    chunk by one upper-triangular bf16 matmul (0/1 operands, counts to
+    128: exact), the chunks' offsets by a cumsum over row_tile/128
+    totals."""
+    slot, _ = _slot_of_rows(leaf_id, slots)
+    active = slot > 0
+    k = jnp.arange(LANE)
+    upper = (k[:, None] <= k[None, :]).astype(jnp.bfloat16)
+    seen = jnp.dot(active.reshape(-1, LANE).astype(jnp.bfloat16), upper,
+                   preferred_element_type=jnp.float32)  # inclusive, a chunk
+    seen = seen.astype(jnp.int32).reshape(-1, row_tile // LANE, LANE)
+    chunk = seen[:, :, -1]                               # [tiles, chunks]
+    dest = seen - 1 + (jnp.cumsum(chunk, axis=1) - chunk)[:, :, None]
+    return jnp.where(active, (dest.reshape(-1) << SLOT_BITS) | slot, -1)
+
+
 def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
                       slots: Array, max_bin: int, row_tile: int,
-                      feat_tile: int, interpret: bool, plan=None):
+                      feat_tile: int, interpret: bool, plan=None,
+                      body: str = None):
     """pallas_call driver for the in-kernel-masked multi-leaf kernel:
     [F, N] bins x [R0, N] payload x [N] leaf ids x [S] slots ->
-    (hi, lo), each [F, S*R0, MB] f32.  With a lane `plan` the kernel sums
-    into [S*R0, L] and each column's `[offset, offset + num_bin)` lanes
-    are sliced back out here and zero-padded to MB, so callers see one
-    layout; a `feat_tile` that splits the columns over several feature
-    blocks takes no plan (a group's members must share a block)."""
+    (hi, lo, body): the sums, each [F, S*R0, MB] f32, and the index in
+    `hist_bodies(row_tile)` of the body that ran.  With a lane `plan` the
+    kernel sums into [S*R0, L] and each column's `[offset, offset +
+    num_bin)` lanes are sliced back out here and zero-padded to MB, so
+    callers see one layout; a `feat_tile` that splits the columns over
+    several feature blocks takes no plan (a group's members must share a
+    block).
+
+    WHICH BODY is read off the call's own rows: the fullest tile's count
+    of active rows (rows whose leaf is in `slots`) picks the smallest
+    compacting capacity that holds it, else the full body — no tile can
+    overflow, so a table in an adversarial row order simply runs the
+    full body, and nothing is approximate.  Statically full: uint16 bins
+    (not exact in bf16), a `feat_tile` that splits the columns, a
+    `row_tile` that is no multiple of 128.  ONE pallas_call executes
+    (`lax.switch`), in a call named `pallas_histogram_multi_rows_*`:
+    trace readers select the kernel's custom-calls by that prefix.
+    `body` (a name of `hist_bodies`) forces one body: for timing a call
+    alone (`scripts/hist_lane_bound.py`) and for tests."""
     f, n = bins_fm.shape
     r0 = pw0.shape[0]
     s_n = slots.shape[0]
@@ -284,6 +444,7 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
         bins_fm = jnp.pad(bins_fm, ((0, 0), (0, n_pad)))
         # padded rows carry leaf -1: matches no slot, contributes nothing
         leaf_id = jnp.pad(leaf_id, (0, n_pad), constant_values=-1)
+    leaf_id = leaf_id.astype(jnp.int32)
     if feat_tile <= 0 or feat_tile > f:
         feat_tile = f
     if feat_tile < f:
@@ -305,24 +466,66 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
         shape = block = (s_n * r0, plan_lanes(plan))
         sums_spec = pl.BlockSpec(block, lambda j, r: (0, 0))
     sums = jax.ShapeDtypeStruct(shape, jnp.float32)
+    row_spec = pl.BlockSpec((1, row_tile), lambda j, r: (0, r))
 
-    hi, lo = pl.pallas_call(
-        functools.partial(_hist_kernel_multi, mb=max_bin, n_rt=n_rt,
-                          plan=plan),
-        grid=(n_ft, n_rt),  # row tiles iterate fastest -> sums revisited
-        in_specs=[
-            pl.BlockSpec((feat_tile, row_tile), lambda j, r: (j, r)),
-            pl.BlockSpec((r0, row_tile), lambda j, r: (0, r)),
-            pl.BlockSpec((1, row_tile), lambda j, r: (0, r)),
-            pl.BlockSpec((1, s_n), lambda j, r: (0, 0)),
-        ],
-        out_specs=[sums_spec, sums_spec],
-        out_shape=[sums, sums],
-        scratch_shapes=[pltpu.VMEM(block, jnp.float32)],
-        interpret=interpret,
-    )(bins_fm, pw0, leaf_id.astype(jnp.int32)[None, :], slots[None, :])
+    def call(kernel, row_args, row_specs):
+        return pl.pallas_call(
+            kernel,
+            grid=(n_ft, n_rt),  # row tiles iterate fastest -> sums revisited
+            in_specs=[
+                pl.BlockSpec((feat_tile, row_tile), lambda j, r: (j, r)),
+                pl.BlockSpec((r0, row_tile), lambda j, r: (0, r)),
+            ] + row_specs,
+            out_specs=[sums_spec, sums_spec],
+            out_shape=[sums, sums],
+            scratch_shapes=[pltpu.VMEM(block, jnp.float32)],
+            interpret=interpret,
+        )(bins_fm, pw0, *row_args)
+
+    def full(bins_fm, pw0, leaf_id, slots):
+        return call(
+            functools.partial(_hist_kernel_multi, mb=max_bin, n_rt=n_rt,
+                              plan=plan),
+            [leaf_id[None, :], slots[None, :]],
+            [row_spec, pl.BlockSpec((1, s_n), lambda j, r: (0, 0))])
+
+    def compact(cap):
+        def run(bins_fm, pw0, leaf_id, slots):
+            return call(
+                functools.partial(_hist_kernel_multi_compact, mb=max_bin,
+                                  n_rt=n_rt, plan=plan, cap=cap, s_n=s_n),
+                [_row_codes(leaf_id, slots, row_tile)[None, :]], [row_spec])
+        return run
+
+    bodies = hist_bodies(row_tile)
+    if bins_fm.dtype != jnp.uint8 or feat_tile < f or row_tile % LANE:
+        bodies = bodies[:1]
+    names = [name for name, _ in bodies]
+    caps = [rows for _, rows in bodies[1:]]
+    runs = [full] + [compact(cap) for cap in caps]
+    args = (bins_fm, pw0, leaf_id, slots)
+    if body is None and not caps:
+        body = "full"
+    if body is not None:
+        k = names.index(body)
+        which = jnp.int32(k)
+        hi, lo = runs[k](*args)
+    else:
+        slot, ambiguous = _slot_of_rows(leaf_id, slots)
+        fullest = jnp.max(jnp.sum((slot > 0).reshape(n_rt, row_tile),
+                                  axis=1, dtype=jnp.int32))
+        fits = jnp.array(caps) >= fullest
+        which = jnp.where(jnp.any(fits) & ~ambiguous,
+                          1 + jnp.argmax(fits), 0).astype(jnp.int32)
+        # a named call a body, for the trace: an HLO instruction takes
+        # the name of the function it is traced in (a bare switch branch
+        # would call the kernel `branch_1_fun`)
+        hi, lo = jax.lax.switch(
+            which, [jax.named_call(fn, name="pallas_histogram_multi_rows_"
+                                   + nm) for fn, nm in zip(runs, names)],
+            *args)
     if plan is None:
-        return hi[:f], lo[:f]
+        return hi[:f], lo[:f], which
     # the barrier hands callers two materialised arrays, as the kernel
     # does without a plan: XLA then compiles what follows as it did,
     # whereas fused into the unpacking a grower's f32 reductions over
@@ -330,7 +533,7 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
     # hessian sum moved by one ulp on the chip)
     return jax.lax.optimization_barrier(
         (_columns_of_plan(hi, plan, max_bin),
-         _columns_of_plan(lo, plan, max_bin)))
+         _columns_of_plan(lo, plan, max_bin))) + (which,)
 
 
 def _columns_of_plan(sums: Array, plan, max_bin: int) -> Array:
@@ -412,11 +615,12 @@ def _run_kernel_multi_i8(bins_fm: Array, pw0: Array, leaf_id: Array,
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "impl", "row_tile",
                                              "feat_tile", "interpret",
-                                             "plan"))
+                                             "plan", "body"))
 def pallas_histogram(bins_fm: Array, payload: Array, row_mask: Array,
                      max_bin: int, *, impl: str = "onehot",
                      row_tile: int = ROW_TILE, feat_tile: int = 0,
-                     interpret: bool = False, plan=None) -> Array:
+                     interpret: bool = False, plan=None,
+                     body: str = None) -> Array:
     """Drop-in replacement for histogram.leaf_histogram (same contract).
 
     Single-leaf = the f32 multi driver with a mask-derived leaf id
@@ -433,6 +637,7 @@ def pallas_histogram(bins_fm: Array, payload: Array, row_mask: Array,
       impl: kept for call-site compatibility; every path now runs the
         single-pass split-bf16 multi kernel.
       plan: the columns' `lane_plan` (None: every column its own lanes).
+      body: force one kernel body (`pallas_histogram_multi_rows`).
     Returns: [F, MB, 3] f32 — matches the segment-sum path to >= f32
       accuracy (the 3-term bf16 split carries ~27 mantissa bits per
       payload element, every sum two limbs).
@@ -442,7 +647,7 @@ def pallas_histogram(bins_fm: Array, payload: Array, row_mask: Array,
     return hist_value(pallas_histogram_multi_rows(
         bins_fm, _split_payload9(payload), lid,
         jnp.zeros((1,), jnp.int32), max_bin, row_tile=row_tile,
-        feat_tile=feat_tile, interpret=interpret, plan=plan)[0])
+        feat_tile=feat_tile, interpret=interpret, plan=plan, body=body)[0])
 
 
 # MXU LHS capacity is 128 rows; leaves per kernel pass at 9 / 3 rows each
@@ -463,11 +668,12 @@ def _split_payload9(payload: Array) -> Array:
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "row_tile",
                                              "feat_tile", "interpret",
-                                             "plan"))
+                                             "plan", "count_bodies"))
 def pallas_histogram_multi(bins_fm: Array, payload: Array, leaf_id: Array,
                            slots: Array, max_bin: int, *,
                            row_tile: int = ROW_TILE, feat_tile: int = 0,
-                           interpret: bool = False, plan=None) -> Array:
+                           interpret: bool = False, plan=None,
+                           count_bodies: bool = False):
     """Histograms of up to `len(slots)` leaves, filling the MXU.
 
     The economics that make this THE wave-grower kernel: the one-hot
@@ -477,7 +683,12 @@ def pallas_histogram_multi(bins_fm: Array, payload: Array, leaf_id: Array,
     `MULTI_CHUNK` leaves' masked payloads in one [126, N_t] LHS give 14
     histograms for about the price of one — the reference's CUDA learner
     amortizes differently (per-leaf row subsets); on TPU amortizing
-    across leaves in the M axis is the native form.
+    across leaves in the M axis is the native form.  The reference's row
+    subsets come back as the compacting bodies: where the call's slots
+    hold few rows of every tile, a tile's one-hot is loaded for 256 or
+    512 columns of active rows and not for its 2048 (one call alone on a
+    v5e, 4.19M rows, S = 8: 9.4 ms full, 4.9 ms at 512, 3.2 ms at 256;
+    1,024 would be 7.8 ms and is not kept).
 
     Masking AFTER the 3-way split is exact: each split term is zeroed or
     kept whole, so per-leaf sums still reconstruct >= f32 accuracy.
@@ -485,12 +696,14 @@ def pallas_histogram_multi(bins_fm: Array, payload: Array, leaf_id: Array,
     Args:
       slots: [S] i32 leaf ids; pad entries (any value absent from
         leaf_id, canonically num_leaves) produce zero histograms.
-    Returns: [S, F, MB, 3] f32.
+    Returns: [S, F, MB, 3] f32 (with `count_bodies`, and the kernel's
+      calls by body, as `pallas_histogram_multi_rows`).
     """
-    return hist_value(pallas_histogram_multi_rows(
+    out, calls = pallas_histogram_multi_rows(
         bins_fm, _split_payload9(payload), leaf_id, slots, max_bin,
         row_tile=row_tile, feat_tile=feat_tile, interpret=interpret,
-        plan=plan))
+        plan=plan, count_bodies=True)
+    return (hist_value(out), calls) if count_bodies else hist_value(out)
 
 
 def _limbs_from_terms(hi: Array, lo: Array, c: int, max_bin: int) -> Array:
@@ -504,13 +717,15 @@ def _limbs_from_terms(hi: Array, lo: Array, c: int, max_bin: int) -> Array:
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "row_tile",
                                              "feat_tile", "interpret",
-                                             "plan"))
+                                             "plan", "count_bodies",
+                                             "body"))
 def pallas_histogram_multi_rows(bins_fm: Array, pw9: Array, leaf_id: Array,
                                 slots: Array, max_bin: int, *,
                                 row_tile: int = ROW_TILE,
                                 feat_tile: int = 0,
                                 interpret: bool = False,
-                                plan=None) -> Array:
+                                plan=None, count_bodies: bool = False,
+                                body: str = None):
     """`pallas_histogram_multi` with the payload ALREADY split to [9, N]
     carrier rows (`_split_payload9`) — the wave grower prepares the rows
     once per tree and reuses them for every wave's call, instead of
@@ -520,16 +735,23 @@ def pallas_histogram_multi_rows(bins_fm: Array, pw9: Array, leaf_id: Array,
     (`hist_value` for the [.., 3] sums).  `plan` (static, `lane_plan` of
     the columns' bin counts; None = every column its own `max_bin`
     lanes) changes the lanes the kernel contracts, not one bit of what
-    is returned."""
+    is returned.  Which rows it contracts the call reads off its own
+    `leaf_id` (`_run_kernel_multi`): the same rows summed whatever body
+    runs.  With `count_bodies` it returns (limbs, [len(hist_bodies())]
+    i32 kernel calls by the body that ran) for the growers' counters;
+    `body` forces one (timing, tests)."""
     S = slots.shape[0]
     outs = []
+    calls = jnp.zeros((len(hist_bodies(row_tile)),), jnp.int32)
     for c0 in range(0, S, MULTI_CHUNK):
         c1 = min(S, c0 + MULTI_CHUNK)
-        hi, lo = _run_kernel_multi(bins_fm, pw9, leaf_id, slots[c0:c1],
-                                   max_bin, row_tile, feat_tile,
-                                   interpret, plan)  # [F, (c1-c0)*9, MB]
+        hi, lo, which = _run_kernel_multi(
+            bins_fm, pw9, leaf_id, slots[c0:c1], max_bin, row_tile,
+            feat_tile, interpret, plan, body)        # [F, (c1-c0)*9, MB]
         outs.append(_limbs_from_terms(hi, lo, c1 - c0, max_bin))
-    return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
+        calls = calls.at[which].add(1)
+    out = jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
+    return (out, calls) if count_bodies else out
 
 
 @functools.partial(jax.jit, static_argnames=("max_bin", "row_tile",
@@ -729,7 +951,15 @@ def probe(interpret: bool = False, max_bin: int = 256,
     the defaults probe a full chunk of both families.  `plan` = the
     `lane_plan` the f32 kernel will run with: the probe compiles and
     compares THAT program (bins drawn below each column's `num_bin`) in
-    place of the all-`max_bin` one — still one kernel compile."""
+    place of the all-`max_bin` one.  The f32 program holds a kernel body
+    a capacity (`hist_bodies`): the multi-leaf probe runs and compares
+    each, on rows sparse enough for the dispatch to pick it, and reads
+    back that it did.  The single-leaf probe compiles the full body alone
+    (`body="full"`: a kernel compile is most of a probe's time, and every
+    TPU booster pays for this one): the strict grower's compacting bodies
+    are the wave's at one slot, compiled for the chip at that width by
+    `tests/test_tpu_aot.py` and by the first round, compared with the
+    full body's sums by `tests/test_pallas_hist_compact.py`."""
     try:
         return _probe_base(interpret, max_bin, num_feature, multi, width,
                            quantized, plan)
@@ -759,13 +989,18 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
         bins_np %= np.array([nb for _, _, nb in plan_columns(plan)])[:, None]
     bins_np = bins_np.astype(np.uint8 if max_bin <= 256 else np.uint16)
     payload_np = rng.randn(n, 3).astype(np.float32)
-    mask_np = rng.rand(n) < 0.7
     s = jnp.float32(0.25)
     pq_np = np.stack([np.round(payload_np[:, 0] * 8) * 0.25,
                       np.abs(np.round(payload_np[:, 1] * 8)) * 0.25,
                       np.ones(n)], axis=1).astype(np.float32)
-    bins, payload, mask, pq = (jnp.asarray(a) for a in (
-        bins_np, payload_np, mask_np, pq_np))
+    bins, payload, pq = (jnp.asarray(a) for a in (
+        bins_np, payload_np, pq_np))
+    row_tile = min(n, ROW_TILE)
+
+    def some_rows(count):
+        rows = np.zeros(n, bool)
+        rows[rng.choice(n, count, replace=False)] = True
+        return rows
 
     def host_hist(pay, rows):
         """[F, MB, 3] sums over `rows` (bool [n]), in float64."""
@@ -784,6 +1019,12 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
         return _mismatch(what, got, want)
 
     if multi:
+        # active rows that make the dispatch run each f32 body: most rows
+        # for the full one, 7/8 of its capacity for a compacting one
+        actives = [(name, int(0.7 * n) if name == "full" else rows * 7 // 8)
+                   for name, rows in hist_bodies(row_tile)]
+        if max_bin > 256:
+            actives = actives[:1]            # uint16 bins: the full body
         # the wave grower's multi-leaf block shapes, at the exact
         # production width when the caller supplies one
         if quantized is None:
@@ -794,31 +1035,42 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
                      width or (MULTI_CHUNK_Q if quantized
                                else MULTI_CHUNK))]
         for quant_f, wdt in fams:
-            lid_np = rng.randint(0, wdt + 2, (n,)).astype(np.int32)
-            lid = jnp.asarray(lid_np)
             slots = jnp.arange(wdt, dtype=jnp.int32)
-            if quant_f:
-                got = pallas_histogram_multi_quantized(
-                    bins, pq, lid, slots, max_bin, s, s,
-                    row_tile=min(n, ROW_TILE), interpret=interpret)
-                ref_payload = pq_np
-            else:
-                got = pallas_histogram_multi(
-                    bins, payload, lid, slots, max_bin,
-                    row_tile=min(n, ROW_TILE), interpret=interpret,
-                    plan=plan)
-                ref_payload = payload_np
             k = min(3, wdt)
-            want = np.stack([host_hist(ref_payload, lid_np == sl)
-                             for sl in range(k)])
-            res = close(f"multi-leaf kernel (quantized={quant_f}, "
-                        f"width={wdt}) vs a float64 count", got[:k], want)
-            if not res:
-                return res
+            for b, (body, count) in enumerate(actives[:1] if quant_f
+                                              else actives):
+                lid_np = np.where(some_rows(count), rng.randint(0, wdt, n),
+                                  wdt + 1).astype(np.int32)
+                lid = jnp.asarray(lid_np)
+                if quant_f:
+                    got = pallas_histogram_multi_quantized(
+                        bins, pq, lid, slots, max_bin, s, s,
+                        row_tile=row_tile, interpret=interpret)
+                    ref_payload = pq_np
+                else:
+                    got, calls = pallas_histogram_multi(
+                        bins, payload, lid, slots, max_bin,
+                        row_tile=row_tile, interpret=interpret,
+                        plan=plan, count_bodies=True)
+                    ref_payload = payload_np
+                    # one call a chunk of MULTI_CHUNK slots, all `body`
+                    if jax.device_get(calls)[b] != -(-wdt // MULTI_CHUNK):
+                        return ProbeResult(
+                            False, "mismatch", f"the dispatch did not run "
+                            f"body {body} on {count} active rows of {n}: "
+                            f"calls by body {calls}")
+                want = np.stack([host_hist(ref_payload, lid_np == sl)
+                                 for sl in range(k)])
+                res = close(f"multi-leaf kernel (quantized={quant_f}, "
+                            f"width={wdt}, body={body}) vs a float64 "
+                            "count", got[:k], want)
+                if not res:
+                    return res
         return _PROBE_OK
-    got = pallas_histogram(bins, payload, mask, max_bin,
-                           row_tile=min(n, ROW_TILE),
-                           interpret=interpret, plan=plan)
+    mask_np = some_rows(int(0.7 * n))
+    got = pallas_histogram(bins, payload, jnp.asarray(mask_np), max_bin,
+                           row_tile=row_tile, interpret=interpret,
+                           plan=plan, body="full")
     res = close("single-leaf f32 kernel vs a float64 count", got,
                 host_hist(payload_np, mask_np))
     if not res:
@@ -826,8 +1078,8 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
     # the quantized kernel runs DIFFERENT block shapes (3-row payload)
     # — probe it too, or a Mosaic regression there would crash the
     # pallas_q path that this probe is supposed to gate
-    gotq = pallas_histogram_quantized(bins, pq, mask, max_bin, s, s,
-                                      row_tile=min(n, ROW_TILE),
+    gotq = pallas_histogram_quantized(bins, pq, jnp.asarray(mask_np),
+                                      max_bin, s, s, row_tile=row_tile,
                                       interpret=interpret)
     return close("single-leaf int8 kernel vs a float64 count", gotq,
                  host_hist(pq_np, mask_np))

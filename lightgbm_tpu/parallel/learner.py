@@ -150,7 +150,8 @@ def make_distributed_grower(spec: GrowerSpec, mesh: Mesh, kind: str,
         default_left=P(), split_is_cat=P(), split_cat_mask=P(),
         split_gain=P(), internal_g=P(), internal_h=P(), internal_cnt=P(),
         leaf_value=P(), leaf_g=P(), leaf_h=P(), leaf_cnt=P(),
-        leaf_id=row_sp, tail_stats=P() if wave else None)
+        leaf_id=row_sp, tail_stats=P() if wave else None,
+        hist_calls=P() if wave and spec.hist_impl == "pallas" else None)
     in_specs = (P(None, axes) if mode != "feature" else P(None, None),
                 row_sp, row_sp, row_sp, P(None), P(None))
     sharded = shard_map(grow, mesh=mesh, in_specs=in_specs,

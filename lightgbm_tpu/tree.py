@@ -66,6 +66,10 @@ class Tree:
         # speculated histogram, speculated histograms unused and made,
         # and the waves' histogram passes
         self.tail_stats = None
+        # the f32 Pallas kernel's calls for this tree by the body that
+        # ran, then the 128-row groups they contracted
+        # (DeviceTree.hist_calls; None off that kernel)
+        self.hist_calls = None
         self.num_cat = 0
         # categorical split storage (ref: tree.h cat_boundaries_/cat_threshold_)
         self.cat_boundaries: np.ndarray = np.zeros(1, dtype=np.int64)
@@ -100,19 +104,21 @@ class Tree:
         import jax
         (n_splits_h, split_leaf, feat, thr_bin, dl, is_cat, cat_masks,
          gains, ig, ih, ic, leaf_value_h, leaf_h_h, leaf_cnt_h,
-         tail_stats) = \
+         tail_stats, hist_calls) = \
             jax.device_get((dev.n_splits, dev.split_leaf, dev.split_feature,
                             dev.threshold_bin, dev.default_left,
                             dev.split_is_cat, dev.split_cat_mask,
                             dev.split_gain, dev.internal_g, dev.internal_h,
                             dev.internal_cnt, dev.leaf_value, dev.leaf_h,
-                            dev.leaf_cnt, dev.tail_stats))
+                            dev.leaf_cnt, dev.tail_stats, dev.hist_calls))
         ns = int(n_splits_h)
         nl = ns + 1
         t = cls(nl)
         t.shrinkage = shrinkage
         if tail_stats is not None:
             t.tail_stats = tuple(int(v) for v in tail_stats)
+        if hist_calls is not None:
+            t.hist_calls = tuple(int(v) for v in hist_calls)
         split_leaf = split_leaf[:ns]
         feat = feat[:ns]
         thr_bin = thr_bin[:ns]

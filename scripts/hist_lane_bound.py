@@ -8,8 +8,18 @@ column its own `max_bin` lanes, bins drawn below 128 for both, so only the
 contracted lanes differ: ISSUE 30 step 0), `airline_trivial` and
 `airline_packed` (the thirteen airline bin counts at max_bin 255, without
 and with the lane plan); with `--parent <checkout>` that checkout's kernel
-too, and whether the airline sums equal its sums bit for bit.  Exits 2 where JAX finds no TPU: a CPU time is
-not a device number.
+too, and whether the airline sums equal its sums bit for bit.
+
+    chiprun -- python3 scripts/hist_lane_bound.py --active-share 0.04,0.08 \
+        --capacity 256,512,1024
+
+is ISSUE 32's step 0: the packed airline call with a `leaf_id` that puts a
+share p of iid rows into the S slots, through the full body, through each
+compacting capacity that holds the fullest tile (forced: `body=`), and as
+the dispatch picks (`auto`); each line carries the largest |difference|
+of its sums from the full body's, relative to the largest sum.
+
+Exits 2 where JAX finds no TPU: a CPU time is not a device number.
 """
 import argparse
 import json
@@ -49,6 +59,10 @@ def main():
                     "its times mean nothing")
     ap.add_argument("--parent", default="", help="checkout of another "
                     "commit whose kernel is timed and compared bitwise")
+    ap.add_argument("--active-share", default="", help="comma-separated "
+                    "shares of rows in a slot: time the compacting bodies")
+    ap.add_argument("--capacity", default="", help="comma-separated "
+                    "capacities to time beside pallas_hist.COMPACT_CAPS")
     a = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not a.rehearse:
@@ -57,6 +71,8 @@ def main():
         return 2
     print(json.dumps({"device": dev.device_kind, "rows": a.rows,
                       "slots": a.slots, "reps": a.reps}))
+    if a.active_share:
+        return compaction(a)
     rng = np.random.RandomState(0)
     n, f = a.rows, len(AIRLINE_NUM_BIN)
     pw9 = ph._split_payload9(jnp.asarray(
@@ -98,6 +114,50 @@ def main():
             else:
                 line["bit_equal_to_first"] = bool(np.array_equal(got, ref))
         print(json.dumps(line), flush=True)
+    return 0
+
+
+def compaction(a):
+    """ISSUE 32 step 0: one line a (share, body)."""
+    caps = sorted(set(ph.COMPACT_CAPS)
+                  | {int(c) for c in a.capacity.split(",") if c})
+    ph.COMPACT_CAPS = tuple(caps)        # read where a call is traced
+    rng = np.random.RandomState(0)
+    n = a.rows
+    plan = ph.lane_plan(AIRLINE_NUM_BIN, 255)
+    pw9 = ph._split_payload9(jnp.asarray(
+        np.abs(rng.randn(n, 3)).astype(np.float32)))
+    air = jnp.asarray(np.stack(
+        [rng.randint(0, nb, n) for nb in AIRLINE_NUM_BIN]).astype(np.uint8))
+    slots = jnp.arange(a.slots, dtype=jnp.int32)
+    row_tile = min(n, ph.ROW_TILE)
+
+    def call(lid, body):
+        return ph.pallas_histogram_multi_rows(
+            air, pw9, lid, slots, 255, plan=plan, interpret=a.rehearse,
+            row_tile=row_tile, body=body, count_bodies=True)
+
+    for p in (float(v) for v in a.active_share.split(",")):
+        lid_np = np.where(rng.rand(n) < p, rng.randint(0, a.slots, n),
+                          a.slots + rng.randint(0, 3 * a.slots, n))
+        fullest = int((lid_np < a.slots).reshape(-1, row_tile).sum(1).max())
+        lid = jnp.asarray(lid_np.astype(np.int32))
+        ref = None
+        for body in ["full"] + [f"c{c}" for c in caps if fullest <= c
+                                and c < row_tile] + [None]:
+            med, all_s = _time(call, (lid, body), a.reps)
+            sums, calls = call(lid, body)
+            sums = np.asarray(ph.hist_value(sums), np.float64)
+            if ref is None:
+                ref = sums
+            print(json.dumps({
+                "active_share": p, "fullest_tile": fullest,
+                "body": body or "auto", "call_s": med,
+                "ran": dict(zip([nm for nm, _ in ph.hist_bodies(row_tile)],
+                                np.asarray(calls).tolist())),
+                "rel_diff_from_full": float(
+                    np.abs(sums - ref).max() / np.abs(ref).max()),
+                "all_s": all_s}), flush=True)
     return 0
 
 

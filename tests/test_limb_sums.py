@@ -71,7 +71,7 @@ def test_kernel_over_1600_tiles_against_float64():
     tiles, tile = 1600, 128
     n = tiles * tile
     bins = (np.arange(n) % 2).astype(np.uint8)[None, :]
-    hi, lo = ph._run_kernel_multi(
+    hi, lo, _ = ph._run_kernel_multi(
         jnp.asarray(bins), jnp.full((1, n), H0), jnp.zeros(n, jnp.int32),
         jnp.zeros((1,), jnp.int32), 8, tile, 0, True)
     got = np.asarray(hi, np.float64) + np.asarray(lo, np.float64)

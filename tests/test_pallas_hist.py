@@ -187,7 +187,7 @@ class TestLanePlan:
         plan = ph.lane_plan(AIRLINE_NUM_BIN, 255)
         want = ph._run_kernel_multi(*args, 255, 128, 0, True, None)
         got = ph._run_kernel_multi(*args, 255, 128, 0, True, plan)
-        for g, w in zip(got, want):
+        for g, w in zip(got[:2], want[:2]):
             assert g.shape == w.shape == (13, s_n * 9, 255)
             np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
         assert np.abs(np.asarray(want[1])).sum() > 0   # the lo limb is live
@@ -433,6 +433,8 @@ def _grow(grow, bins, grad, hess, sw, feat, allowed):
 
 def _assert_trees_equal(a, b, ctx=""):
     for name, x, y in zip(a._fields, a, b):
+        if name == "hist_calls":    # the kernel's record, not the tree's
+            continue
         assert np.array_equal(np.asarray(x), np.asarray(y)), \
             f"{ctx}: field {name} differs"
 

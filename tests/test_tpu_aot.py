@@ -93,6 +93,29 @@ def test_hist_kernel_f32_lane_plan(topo, width):
         plan=plan).compile()
 
 
+@pytest.mark.parametrize("body", [None, "full", "c256", "c512"])
+@pytest.mark.parametrize("width", [1, 8])
+def test_hist_kernel_f32_compacting_bodies(topo, width, body):
+    """Each compacting body alone (forced) and the dispatching program
+    (`body=None`: the three kernels under one `lax.switch`), at the cells'
+    columns and plan.  Each kernel's custom-call is named
+    `pallas_histogram_multi_rows*`: the benchmark's trace readers select
+    the histogram kernel by that prefix."""
+    sds, _ = _one(topo)
+    plan = ph.lane_plan(AIRLINE_NUM_BIN, 255)
+    text = ph.pallas_histogram_multi_rows.lower(
+        sds((13, N), jnp.uint8), sds((9, N), jnp.float32),
+        sds((N,), jnp.int32), sds((width,), jnp.int32), 255,
+        plan=plan, body=body, count_bodies=True).compile().as_text()
+    kernels = [line.split("=")[0].strip().lstrip("%").rsplit(".", 1)[0]
+               for line in text.splitlines()
+               if "custom-call(" in line and "tpu_custom_call" in line]
+    assert sorted(kernels) == (
+        ["pallas_histogram_multi_rows"] if body else
+        ["pallas_histogram_multi_rows_" + name
+         for name, _ in sorted(ph.hist_bodies())])
+
+
 @pytest.mark.parametrize("width", [1, 8])
 def test_hist_kernel_int8(topo, width):
     sds, _ = _one(topo)

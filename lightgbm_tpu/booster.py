@@ -86,10 +86,14 @@ class _DeviceData:
             from .datastore.prefetch import PrefetchRunStats
             self._pf_stats = PrefetchRunStats()
         self._for_train = for_train
+        # uploaded at first use (`bins_fm`): a sharded learner places the
+        # host matrix on its mesh (`bins_host`), and the whole of it
+        # never passes through one device
         self._bins_fm = None
-        if ds.bin_data is not None:
-            bins = np.asarray(ds.bin_data)
-            self._bins_fm = jnp.asarray(np.ascontiguousarray(bins.T))
+        # where the per-row arrays go: the learner's row sharding, set by
+        # the booster before it first reads them (None: the default
+        # device)
+        self.row_sharding = None
         # raw values retained for linear-tree leaf fits / scoring
         self.raw_ref = ds.data if ds.data is not None else None
         self._raw2d: Optional[np.ndarray] = None
@@ -131,11 +135,7 @@ class _DeviceData:
             dtype=bool)
         self.is_cat = jnp.asarray(self.is_cat_np)
         self.max_bin = max(int(m.num_bin) for m in mappers)
-        label = ds.get_label()
-        self.label = jnp.asarray(label.astype(np.float32)) \
-            if label is not None else None
-        w = ds.get_weight()
-        self.weight = jnp.asarray(w.astype(np.float32)) if w is not None else None
+        self._label = self._weight = None
         self.init_score = ds.get_init_score()
         self.query_boundaries = ds._query_boundaries
 
@@ -162,10 +162,43 @@ class _DeviceData:
                                       prefetch_depth=depth,
                                       run_stats=self._pf_stats)
 
+    def _put_rows(self, rows: Optional[np.ndarray]) -> Optional[jax.Array]:
+        """A per-row host array as f32 on the device(s) the training rows
+        are on; None stays None."""
+        if rows is None:
+            return None
+        rows = np.asarray(rows, np.float32)
+        if self.row_sharding is None:
+            return jnp.asarray(rows)
+        return jax.device_put(rows, self.row_sharding)
+
+    @property
+    def label(self):
+        """[N] f32 on the device, uploaded at first use; None without."""
+        if self._label is None:
+            self._label = self._put_rows(self._ds.get_label())
+        return self._label
+
+    @property
+    def weight(self):
+        if self._weight is None:
+            self._weight = self._put_rows(self._ds.get_weight())
+        return self._weight
+
+    def bins_host(self) -> Optional[np.ndarray]:
+        """The dense bins feature-major [F, N] on the host; None where the
+        data set keeps none (sparse EFB, spilled to a shard store)."""
+        if self._ds.bin_data is None:
+            return None
+        return np.ascontiguousarray(np.asarray(self._ds.bin_data).T)
+
     @property
     def bins_fm(self):
         if self._bins_fm is None:
-            if self._store is not None:
+            host = self.bins_host()
+            if host is not None:
+                self._bins_fm = jnp.asarray(host)
+            elif self._store is not None:
                 self._bins_fm = self._assemble_from_store("bins")
             else:
                 log.warning("materializing the dense [N, F] bin matrix "
@@ -237,15 +270,19 @@ def _probe_why(res) -> str:
     return f" ({res.cause}: {detail[:600]})" if detail else ""
 
 
-def _count_growth(tree: Tree) -> None:
+def _count_growth(tree: Tree, reduce_bytes: int = 0) -> None:
     """What growing `tree` cost, onto the always-on counters: the rows its
     histograms needed, its leaves, and (wave grower) the waves' passes and
     its strict tail's passes, splits served from a speculated histogram,
     and speculated ones left unused: passes a tree = 1 + waves + tail;
     on the f32 Pallas kernel, its calls by the body that ran
-    (`grow.hist_passes_full`, `grow.hist_passes_c<capacity>`) and the rows
-    they contracted: needed / contracted is the useful share of what the
-    MXU multiplies."""
+    (`grow.hist_passes_full`, `grow.hist_passes_c<capacity>`, summed over
+    the shards of a mesh) and the rows they contracted: needed /
+    contracted is the useful share of what the MXU multiplies.  Over a
+    mesh (`reduce_bytes` > 0: what one shard hands to the collectives of
+    one reduction, `parallel/learner.hist_reduce_bytes`) every pass ends
+    in one cross-shard histogram reduction: `grow.reduce_passes`,
+    `grow.reduce_bytes` (one shard's)."""
     counter = telemetry.REGISTRY.counter
     counter("grow.hist_rows_needed").inc(tree.hist_rows_needed())
     counter("grow.leaves").inc(tree.num_leaves)
@@ -255,11 +292,29 @@ def _count_growth(tree: Tree) -> None:
         counter("grow.tail_spec_hits").inc(hits)
         counter("grow.tail_spec_unused").inc(unused)
         counter("grow.wave_passes").inc(waves)
+        if reduce_bytes:
+            counter("grow.reduce_passes").inc(1 + waves + passes)
+            counter("grow.reduce_bytes").inc(
+                (1 + waves + passes) * reduce_bytes)
     if tree.hist_calls is not None:
         from .ops.pallas_hist import LANE, hist_bodies
         for (body, _), calls in zip(hist_bodies(), tree.hist_calls):
             counter(f"grow.hist_passes_{body}").inc(calls)
         counter("grow.hist_rows_contracted").inc(tree.hist_calls[-1] * LANE)
+
+
+def _with_rows(jitted, *rows):
+    """`jitted(score, *rows, *more)` as a function of (score, *more):
+    per-row arrays handed to a jitted function as arguments at every
+    call, behind the signature its callers know."""
+    def call(score, *more):
+        return jitted(score, *rows, *more)
+    # telemetry's cache poll; a Python function, not the bound method
+    # itself: jaxlib's method objects are invisible to the cycle
+    # collector, and booster -> call -> method -> jitted -> booster
+    # would keep every booster's device arrays for good
+    call._cache_size = lambda: jitted._cache_size()
+    return call
 
 
 @jax.jit
@@ -553,7 +608,13 @@ class Booster:
             telemetry.TRACER.enable(True)
             install_compile_listener()
             sample_memory("init")
-        self._ones = jnp.ones((self._dd.num_data,), dtype=jnp.float32)
+        # per-row state lives where the training rows live: split over
+        # the learner's mesh like the bin matrix, so that gradients, the
+        # score update and the grower's inputs never gather on one chip
+        self._dd.row_sharding = self._row_sharding()
+        telemetry.REGISTRY.gauge("mesh.shards").set(
+            1 if self._mesh is None else self._mesh.devices.size)
+        self._ones = self._rows_of(self._dd, 1.0)
 
         K = self.num_tree_per_iteration
         self._init_scores = [0.0] * K
@@ -573,14 +634,18 @@ class Booster:
         self._grad_key0 = jax.random.PRNGKey(
             self.config.objective_seed % (2 ** 31))
         if self.objective_ is not None:
+            # labels and weights are ARGUMENTS of the jitted gradients: a
+            # closed-over [N] array is baked into the executable as a
+            # constant (4 B a row, once a device of a mesh), and keeps
+            # whatever placement it had when it was traced
             lbl = self._dd.label
             wgt = self._dd.weight
             if getattr(self.objective_, "needs_rng", False):
-                def _grad(score, key):
+                def _grad(score, lbl, wgt, key):
                     return self.objective_.grad_hess(score, lbl, wgt, key=key)
                 # per-iteration key = fold_in(key0, it) — the SAME derivation
                 # the fused chunk trainer uses, so both paths are identical
-                self._grad_rng_fn = jax.jit(_grad)
+                self._grad_rng_fn = _with_rows(jax.jit(_grad), lbl, wgt)
                 self._grad_fn = lambda s: self._grad_rng_fn(
                     s, jax.random.fold_in(self._grad_key0, self.cur_iter))
             elif getattr(self.objective_, "has_state", False):
@@ -589,10 +654,11 @@ class Booster:
                 # be baked into the jit as a constant and never update
                 self._obj_state = self.objective_.init_state()
 
-                def _grad_state(score, state):
+                def _grad_state(score, lbl, wgt, state):
                     return self.objective_.grad_hess(score, lbl, wgt,
                                                      state=state)
-                self._grad_state_fn = jax.jit(_grad_state)
+                self._grad_state_fn = _with_rows(jax.jit(_grad_state),
+                                                 lbl, wgt)
 
                 def _grad(s):
                     g, h, self._obj_state = self._grad_state_fn(
@@ -600,9 +666,9 @@ class Booster:
                     return g, h
                 self._grad_fn = _grad
             else:
-                def _grad(score):
+                def _grad(score, lbl, wgt):
                     return self.objective_.grad_hess(score, lbl, wgt)
-                self._grad_fn = jax.jit(_grad)
+                self._grad_fn = _with_rows(jax.jit(_grad), lbl, wgt)
 
     def _packed_const_hess_level(self) -> int:
         """Nonzero when the packed quantized histogram may derive counts
@@ -888,7 +954,7 @@ class Booster:
                 f"{spec.num_leaves}; dropping the cap restores the wave "
                 "policy at the cost of the pool's memory bound — "
                 "COVERAGE.md r6 decision note)")
-        kind, shards, _, _, _, s_last = self._learner_topology()
+        kind, shards = self._learner_topology()[:2]
         if shards <= 1:
             kind = "serial"      # the one-device fallback (wave-eligible)
         if kind not in ("serial", "data"):
@@ -902,15 +968,9 @@ class Booster:
             from .ops.grow_wave import wave_sizes
             from .ops.pallas_hist import probe_cached
             _, w = wave_sizes(spec)
+            # (`_probe_shape` holds the data-parallel learner's pad
+            # columns: the probe certifies the shape a shard runs)
             pb, pc = self._probe_shape()
-            if kind == "data" and self._dd.efb is None:
-                # distributed data_rs block-pads the feature axis — the
-                # kernel runs at the PADDED column count, so that is the
-                # shape the probe must certify (Mosaic regressions are
-                # shape-specific); s_last comes from the ONE topology
-                # resolver so probe and mesh can't drift
-                from .parallel.learner import padded_feature_count
-                pc = padded_feature_count(pc, s_last)
             res = probe_cached(pb, pc, multi=True, width=w,
                                quantized=spec.hist_impl == "pallas_q",
                                interpret=spec.hist_interpret,
@@ -944,31 +1004,46 @@ class Booster:
             return make_wave_grower(self._grower_spec)
         return make_grower(self._grower_spec)
 
+    def _pad_columns(self) -> int:
+        """Empty columns the data-parallel learner appends so that the
+        column count divides its shards (`place_training_data`'s
+        `pad_features`, `parallel/learner.padded_feature_count`): every
+        shard's kernel sees them, all rows in bin 0."""
+        kind, shards, _, _, _, s_last = self._learner_topology()
+        if self._dd.efb is not None or shards <= 1 or kind != "data":
+            return 0
+        from .parallel.learner import padded_feature_count
+        return padded_feature_count(self._dd.num_feature, s_last) \
+            - self._dd.num_feature
+
     def _probe_shape(self):
         """(bin count, column count) the histogram kernels will ACTUALLY
         run at: the BUNDLE matrix shape under EFB (bundle columns can be
         wider than any single feature's bin count — probing the
-        per-feature shape would certify the wrong Mosaic block)."""
+        per-feature shape would certify the wrong Mosaic block), the
+        padded column count under the data-parallel learner (Mosaic
+        regressions are shape-specific)."""
         efb = self._dd.efb
         if efb is not None:
             return efb.max_bin, efb.n_cols
-        return self._dd.max_bin, self._dd.num_feature
+        return self._dd.max_bin, self._dd.num_feature + self._pad_columns()
 
     def _hist_lane_plan(self):
         """The f32 histogram kernel's static lane plan
         (ops/pallas_hist.py `lane_plan`), from the bin counts of the
-        columns the kernel will see: the mappers' `num_bin`, under EFB
-        the bundle columns' widths — the columns of `_probe_shape`.
-        None (every column on its own lanes) where a distributed learner
-        pads or slices the column axis (`place_training_data`'s
-        `pad_features`: the kernel then sees other columns than these)."""
+        columns the kernel will see — the columns of `_probe_shape`: the
+        mappers' `num_bin`, under EFB the bundle columns' widths, under
+        the data-parallel learner also its pad columns (one bin each:
+        they join a group).  None (every column on its own lanes) for the
+        feature-parallel learner, whose shards each histogram another
+        slice of the columns."""
         from .ops.pallas_hist import lane_plan
         efb = self._dd.efb
         kind, shards = self._learner_topology()[:2]
-        if efb is None and shards > 1 and kind in ("data", "feature"):
+        if efb is None and shards > 1 and kind == "feature":
             return None
-        num_bins = self._dd.num_bins if efb is None \
-            else tuple(int(b) for b in efb.col_num_bin)
+        num_bins = self._dd.num_bins + (1,) * self._pad_columns() \
+            if efb is None else tuple(int(b) for b in efb.col_num_bin)
         return lane_plan(num_bins, self._probe_shape()[0])
 
     def _record_hist_lanes(self) -> None:
@@ -1076,6 +1151,7 @@ class Booster:
                                    "hist_interpret is off)")
                 else:
                     res = probe_cached(*self._probe_shape(),
+                                       quantized=req == "pallas_q",
                                        interpret=not on_tpu,
                                        plan=self._hist_lane_plan())
                     if not res:
@@ -1096,7 +1172,7 @@ class Booster:
             # default there.  The probe RAISES when the kernel does not compile
             # or run on the TPU; only a numeric mismatch degrades, with
             # the numbers in the event
-            res = probe_cached(*self._probe_shape(),
+            res = probe_cached(*self._probe_shape(), quantized=quant_ok,
                                plan=self._hist_lane_plan())
             if res:
                 return "pallas_q" if quant_ok else "pallas"
@@ -1255,8 +1331,12 @@ class Booster:
                             "assembles the full device matrix before "
                             "replicating it on the mesh (features are "
                             "copied to every shard)")
-            # EFB: training reads the bundled matrix (see _DeviceData)
-            train_src = self._dd.bundle_fm if bundled else self._dd.bins_fm
+            # EFB: training reads the bundled matrix (see _DeviceData);
+            # dense bins go from the host straight onto the mesh
+            train_src = self._dd.bundle_fm if bundled \
+                else self._dd.bins_host()
+            if train_src is None:
+                train_src = self._dd.bins_fm
             self._train_bins = place_training_data(
                 np.asarray(train_src), self._mesh, kind,
                 pad_features=pad_features)
@@ -1354,10 +1434,30 @@ class Booster:
         self._train_bins = self._dd.bundle_fm \
             if self._dd.efb is not None else self._dd.bins_fm
 
+    def _row_sharding(self):
+        """How the learner's mesh splits a per-row [N] array: over all its
+        axes, as `place_training_data` splits the bin matrix's rows.
+        None — the default device — for the serial learner, for
+        feature-parallel (rows replicated), and for a row count that does
+        not divide the shards (the grower pads those inside its program,
+        so its inputs cannot arrive split)."""
+        mesh = getattr(self, "_mesh", None)
+        if mesh is None or self._learner_topology()[0] == "feature" \
+                or self._dd.num_data % mesh.devices.size:
+            return None
+        from jax.sharding import NamedSharding, PartitionSpec
+        return NamedSharding(mesh, PartitionSpec(tuple(mesh.axis_names)))
+
+    @staticmethod
+    def _rows_of(dd: _DeviceData, value: float, k: int = 1) -> jax.Array:
+        """[N] (or [N, k]) f32 of `value`, made where `dd`'s rows are."""
+        shape = (dd.num_data,) if k == 1 else (dd.num_data, k)
+        return jnp.full(shape, value, dtype=jnp.float32,
+                        device=dd.row_sharding)
+
     def _zero_score(self, dd: _DeviceData) -> jax.Array:
-        K = self.num_tree_per_iteration
-        shape = (dd.num_data,) if K == 1 else (dd.num_data, K)
-        score = jnp.zeros(shape, dtype=jnp.float32)
+        score = self._rows_of(dd, 0.0, self.num_tree_per_iteration)
+        shape = score.shape
         if dd.init_score is not None:
             s = np.asarray(dd.init_score, dtype=np.float32)
             score = score + jnp.asarray(s.reshape(shape, order="F"))
@@ -1658,7 +1758,7 @@ class Booster:
                     jax.block_until_ready(dev.n_splits)
             with telemetry.span("train.decode"):
                 tree = Tree.from_device(dev, self.train_set.bin_mappers, lr)
-            _count_growth(tree)
+            _count_growth(tree, getattr(self._grower, "reduce_bytes", 0))
             if "cegb_used" in self._feat and tree.num_leaves > 1:
                 # coupled penalties charge a feature once per MODEL
                 used = np.array(jax.device_get(self._feat["cegb_used"]))
@@ -2236,7 +2336,7 @@ class Booster:
                 dev = DeviceTree(*[None if f is None else np.asarray(f[at])
                                    for f in host])
                 tree = Tree.from_device(dev, self.train_set.bin_mappers, lr)
-                _count_growth(tree)
+                _count_growth(tree, getattr(self._grower, "reduce_bytes", 0))
                 if tree.num_leaves > 1:
                     all_const = False
                 if self.cur_iter == 0 and abs(self._init_scores[k]) > 1e-35:

@@ -70,6 +70,11 @@ names the trace readers select by (`perfbench/program_readers.py`), so
 they are fixed: `payload` (kernel payload carrier), `init` (root sums,
 empty node and leaf tables), `histogram_wave` (every histogram pass),
 `find_split` (the root's search, the per-child fan-out and its scatter),
+`hist_reduce` (inside `histogram_wave`, sharded growers only: the
+cross-shard sum of a pass's histograms — the ordered chain, under
+`ring_fold`, or `psum_scatter` / `psum` — and the slice of this shard's
+column block), `split_allreduce` (inside `find_split`, sharded block
+search only: the SplitInfo exchange),
 `partition` (the pick loop: choice, `split_go_left`, `leaf_id` rewrite,
 node and leaf records; in the tail also the choice of the leaves to
 speculate and their rows' slot ids), `hist_cache` (sibling subtraction,
@@ -150,6 +155,12 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
       (`_merge_split_across_shards`, vmapped over the wave).  DCN slices
       allreduce the scattered block, so heavy traffic rides ICI.
 
+    With `det_reduce` over one mesh axis (the booster's default) neither
+    collective sums: each shard's histograms are added in ascending shard
+    order around a ring and the total is gathered back onto every shard
+    (`ops/histogram.ring_ordered_sum`, `ring_fold`); in `data_rs` a shard
+    then keeps its column block of that total.
+
     Histograms are globally summed/scattered before split finding, so
     size constraints need no per-shard rescaling (unlike the voting
     learner's local vote)."""
@@ -190,13 +201,14 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
     block = axes_all is not None and mode == "data_rs"
     axis_last = axes_all[-1] if axes_all else None
     axes_dcn = axes_all[:-1] if axes_all else ()
-    # deterministic fixed-order reduction (ROADMAP 1a) — same contract
-    # as the strict grower: wave histograms fold shard-by-shard around a
-    # ring in ascending shard order (the streamed-carry entries of
-    # ops/histogram.py make the fold bitwise-equal to the one-pass
-    # multi-leaf builders) and root sums reduce the gathered rows with
-    # the serial expression, so multi-round sharded wave training stays
-    # byte-identical to serial.  Single data axis only.
+    # deterministic fixed-order reduction (ROADMAP 1a): wave histograms
+    # fold shard-by-shard around a ring in ascending shard order (the
+    # Pallas families chain each shard's kernel sums limb-wise,
+    # `ring_ordered_sum`; the XLA families chain the scatter-add itself,
+    # which keeps their histograms bitwise the one-pass builders') and
+    # the root sums add the shards' own sums in the same order: the same
+    # trees in every run, and the serial learner's up to the order of
+    # summation.  Single data axis only.
     det = bool(det_reduce) and axes_all is not None \
         and len(axes_all) == 1 and n_shards > 1 and num_data > 0
     if det_reduce and axes_all is not None and not det:
@@ -298,8 +310,9 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 S = slots.shape[0]
                 calls = no_calls
                 if spec.hist_impl in ("pallas", "pallas_q"):
-                    with jax.named_scope("ring_fold"):
-                        h, calls = kernel_hist_multi(leaf_id, slots, root)
+                    h, calls = kernel_hist_multi(leaf_id, slots, root)
+                    with jax.named_scope("hist_reduce"), \
+                            jax.named_scope("ring_fold"):
                         h = ring_ordered_sum(h, axis_last, n_shards)
                 elif spec.hist_impl == "packed":
                     chl = spec.packed_const_hess_level
@@ -310,9 +323,10 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                             feat["qscales"][0], feat["qscales"][1],
                             const_hess_level=chl)
 
-                    full = ring_fold(
-                        fold, hist_stream_packed_init(Fh, S, HB, chl),
-                        axis_last, n_shards)
+                    with jax.named_scope("hist_reduce"):
+                        full = ring_fold(
+                            fold, hist_stream_packed_init(Fh, S, HB, chl),
+                            axis_last, n_shards)
                     h = hist_stream_packed_finalize(
                         full, Fh, S, HB, feat["qscales"][0],
                         feat["qscales"][1], const_hess_level=chl)
@@ -321,14 +335,16 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         return hist_stream_update(acc, bins_fm, payload,
                                                   leaf_id, slots, HB)
 
-                    full = ring_fold(fold, hist_stream_init(Fh, S, HB),
-                                     axis_last, n_shards)
+                    with jax.named_scope("hist_reduce"):
+                        full = ring_fold(fold, hist_stream_init(Fh, S, HB),
+                                         axis_last, n_shards)
                     h = hist_stream_finalize(full, Fh, S, HB)
                 if block:
                     Fb_h = h.shape[1] // n_shards
-                    h = jax.lax.dynamic_slice_in_dim(
-                        h, jax.lax.axis_index(axis_last) * Fb_h, Fb_h,
-                        axis=1)
+                    with jax.named_scope("hist_reduce"):
+                        h = jax.lax.dynamic_slice_in_dim(
+                            h, jax.lax.axis_index(axis_last) * Fb_h, Fb_h,
+                            axis=1)
                 return h, calls
 
         def hist_multi(leaf_id, slots, root=False):
@@ -356,13 +372,15 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     # ref: Network::ReduceScatter of histogram buffers —
                     # each shard receives the summed feature block it
                     # will scan (over ICI); DCN slices allreduce it
-                    h = jax.lax.psum_scatter(h, axis_last,
-                                             scatter_dimension=1,
-                                             tiled=True)
-                    if axes_dcn:
-                        h = jax.lax.psum(h, axes_dcn)
+                    with jax.named_scope("hist_reduce"):
+                        h = jax.lax.psum_scatter(h, axis_last,
+                                                 scatter_dimension=1,
+                                                 tiled=True)
+                        if axes_dcn:
+                            h = jax.lax.psum(h, axes_dcn)
                 elif axes_all is not None:
-                    h = jax.lax.psum(h, axes_all)
+                    with jax.named_scope("hist_reduce"):
+                        h = jax.lax.psum(h, axes_all)
             return h, calls
 
         # per-node column sampling / extra_trees / CEGB pricing — the
@@ -414,8 +432,9 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                          gain_penalty=penalty)
                 s = refine_child_sums(s, hist, bfeat["nb"],
                                       bfeat["missing"])
-                return rebase_and_merge_block_split(s, offset, axis_last,
-                                                    n_shards)
+                with jax.named_scope("split_allreduce"):
+                    return rebase_and_merge_block_split(s, offset,
+                                                        axis_last, n_shards)
             if spec.bundled:
                 # bundle columns expand to features by value: no limbs
                 hist = expand_bundled(hist_value(hist), g, h, c)
@@ -449,15 +468,18 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     jnp.zeros((N,), jnp.int32))
             root_slots = jnp.full((W,), LB, jnp.int32).at[0].set(0)
             if det:
-                # deterministic root stats: gather the rows back into
-                # storage order (pad tail sliced off) and reduce with the
-                # serial grower's own expression — no psum of per-shard
-                # partials
-                gp = jax.lax.all_gather(payload, axis_last, axis=0,
-                                        tiled=True)[:num_data]
-                root_g = gp[:, 0].sum()
-                root_h = gp[:, 1].sum()
-                root_c = gp[:, 2].sum()
+                # deterministic root stats: every shard sums its own rows
+                # (pad rows carry payload 0) and the shards' sums are
+                # added in ascending shard order, on every shard alike —
+                # no reduction tree of the backend's choosing, and no
+                # shard ever holds another's rows (the rows of all shards
+                # gathered for the serial expression were 12 B a row of
+                # the WHOLE table on every chip)
+                parts = jax.lax.all_gather(
+                    jnp.stack([payload[:, 0].sum(), payload[:, 1].sum(),
+                               payload[:, 2].sum()]), axis_last)
+                root_g, root_h, root_c = functools.reduce(
+                    jnp.add, [parts[i] for i in range(n_shards)])
             else:
                 root_g = payload[:, 0].sum()
                 root_h = payload[:, 1].sum()

@@ -166,6 +166,71 @@ def plan_columns(plan) -> list:
     return sorted(cols)
 
 
+# Scoped VMEM a Mosaic kernel gets by default, and what one call needs of
+# it (`_vmem_need`): its sums block ([S*R0 rows, lanes] f32, rows padded to
+# 8 sublanes) up to three times — the accumulator scratch and both limbs'
+# output blocks (XLA keeps an output in VMEM itself where it finds room, so
+# a larger block may compile alone and be refused inside a grower) — and a
+# tile's operands (the masked LHS, the one-hot, a compacting body's
+# selection), which grow with the LHS's rows.  Read off the v5e compiler
+# (PERF.md section 6, PR 33: "scoped allocation with size .. and limit
+# 16.00M"): beside three blocks it took 0.5-1.3 MiB at S = 8 (72 rows)
+# and 2.9 MiB at S = 14 (126 rows): 24 KiB a row covers both.  At S = 8 the
+# 68 columns of a four-shard Criteo table (4.7 MiB packed) compile as one
+# block in every body; 86 columns (6.0 MiB) are refused.
+VMEM_BYTES = 16 << 20
+
+
+def _vmem_need(sum_rows: int, lanes: int) -> int:
+    rows = -(-sum_rows // 8) * 8
+    return 3 * rows * lanes * 4 + rows * (24 << 10)
+
+
+def column_blocks(num_col: int, sum_rows: int, max_bin: int, plan=None,
+                  feat_tile: int = 0):
+    """The column blocks one histogram pass is made in, a kernel call
+    each: `((first column, end column, lane plan of the block), ...)`.
+
+    One block wherever a call over all columns fits the scoped VMEM
+    (`_vmem_need` <= `VMEM_BYTES`; then `plan` itself is the block's);
+    else the fewest contiguous blocks of near-equal column counts that
+    each fit.  A lane
+    group never spans blocks: each block's plan is `lane_plan` of its own
+    columns' bin counts (read back off `plan`), numbered from the block's
+    first column, or None where that saves the block no lane.  Any
+    grouping sums the same products in the same order, so the blocks'
+    sums are bit-equal to one block's.  No parameter: `feat_tile` > 0
+    forces blocks of at most that many columns (timing a call alone,
+    tests)."""
+    wide = -(-max_bin // LANE) * LANE
+    num_bins = None if plan is None else \
+        [nb for _, _, nb in plan_columns(plan)]
+
+    def blocks_of(k):
+        base, extra = divmod(num_col, k)
+        out, c0 = [], 0
+        for j in range(k):
+            c1 = c0 + base + (j < extra)
+            sub = plan if k == 1 or plan is None else \
+                lane_plan(num_bins[c0:c1], max_bin)
+            out.append((c0, c1, sub))
+            c0 = c1
+        return tuple(out)
+
+    def fits(blocks):
+        return all(_vmem_need(
+            sum_rows, (c1 - c0) * wide if sub is None else plan_lanes(sub))
+            <= VMEM_BYTES for c0, c1, sub in blocks)
+
+    if feat_tile > 0:
+        return blocks_of(-(-num_col // min(feat_tile, num_col)))
+    for k in range(1, num_col):
+        blocks = blocks_of(k)
+        if fits(blocks):
+            return blocks
+    return blocks_of(max(num_col, 1))
+
+
 def assert_bins_in_plan(bins_fm: Array, plan) -> None:
     """Debug check (`GrowerSpec.debug_checks`): every bin below its
     column's `num_bin`.  Without a plan an out-of-range bin lands in its
@@ -244,16 +309,20 @@ def _fold_sums(r, n_rt: int, hi_ref, lo_ref, acc_ref, cells):
 
 
 def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
-                       acc_ref, *, mb: int, n_rt: int, plan=None):
+                       acc_ref, *, mb: int, n_rt: int, plan=None,
+                       cols=None):
     """Multi-leaf grid cell with IN-KERNEL leaf masking — THE production
     kernel: every public f32 entry point (single-leaf included, via a
     mask-derived leaf id) lowers to this body or its compacting twin
     (`_hist_kernel_multi_compact`, the same group loop over fewer rows),
     so `probe()` gates exactly the code that training runs.
 
-    bins_ref: [F_t, N_t]; pw_ref: [R0, N_t] base payload rows (9
+    bins_ref: [F, N_t], every column, of which this call sums the block
+    `cols` = (first, end) (`column_blocks`; None: all, F_t = F);
+    pw_ref: [R0, N_t] base payload rows (9
     f32-split); lid_ref: [1, N_t] i32 row→leaf; slots_ref: [1, S] i32 leaf
-    slots.  One dot a lane GROUP (`lane_plan`): without a plan every
+    slots.  One dot a lane GROUP (`lane_plan`, over the block's columns
+    numbered from its first): without a plan every
     column is its own group of `mb` lanes and hi_ref, lo_ref are
     [F_t, S*R0, MB] two-limb sums; with one they are [S*R0, L], each
     group a static 128-aligned lane slice, its operand the OR of its
@@ -275,15 +344,17 @@ def _hist_kernel_multi(bins_ref, pw_ref, lid_ref, slots_ref, hi_ref, lo_ref,
     """
     r = pl.program_id(1)
     _zero_sums_first(r, hi_ref, lo_ref, acc_ref)
-    f_t, n_t = bins_ref.shape
-    groups, cells = _lane_groups(plan, f_t, mb)
+    n_t = bins_ref.shape[1]
+    c0, c1 = cols or (0, bins_ref.shape[0])
+    groups, cells = _lane_groups(plan, c1 - c0, mb)
     pw = pw_ref[:]                                   # [R0, N_t]
     lid = lid_ref[0, :]                              # [N_t] i32
     lhs = jnp.concatenate(
         [jnp.where((lid == slots_ref[0, s])[None, :], pw, 0.0)
          for s in range(slots_ref.shape[1])], axis=0)    # [S*R0, N_t]
     _contract_groups(
-        acc_ref, lhs, lambda f: bins_ref[f:f + 1, :].astype(jnp.int32),
+        acc_ref, lhs,
+        lambda f: bins_ref[c0 + f:c0 + f + 1, :].astype(jnp.int32),
         n_t, groups, cells)
     _fold_sums(r, n_rt, hi_ref, lo_ref, acc_ref, cells)
 
@@ -309,7 +380,7 @@ def _compact_rows(x: Array, code: Array, cap: int) -> Array:
 
 def _hist_kernel_multi_compact(bins_ref, pw_ref, code_ref, hi_ref, lo_ref,
                                acc_ref, *, mb: int, n_rt: int, plan,
-                               cap: int, s_n: int):
+                               cap: int, s_n: int, cols=None):
     """`_hist_kernel_multi` over the tile's ACTIVE rows only (rows in one
     of the call's slots), for a pass whose every tile holds at most `cap`
     of them (`_run_kernel_multi` sees to that).  A selection matrix is a
@@ -332,15 +403,16 @@ def _hist_kernel_multi_compact(bins_ref, pw_ref, code_ref, hi_ref, lo_ref,
     PR 32); the sums blocks and the folds are `_hist_kernel_multi`'s."""
     r = pl.program_id(1)
     _zero_sums_first(r, hi_ref, lo_ref, acc_ref)
-    f_t, n_t = bins_ref.shape
     r0 = pw_ref.shape[0]
-    groups, cells = _lane_groups(plan, f_t, mb)
+    c0, c1 = cols or (0, bins_ref.shape[0])
+    groups, cells = _lane_groups(plan, c1 - c0, mb)
     code = code_ref[:]                               # [1, N_t]
     slot = (code & ((1 << SLOT_BITS) - 1)).astype(jnp.float32)
-    comp = _compact_rows(jnp.concatenate(
-        [pw_ref[:], slot,
-         bins_ref[:].astype(jnp.int32).astype(jnp.float32)], axis=0),
-        code, cap)                                   # [R0 + 1 + F_t, cap]
+    bins = bins_ref[:].astype(jnp.int32).astype(jnp.float32)
+    if (c0, c1) != (0, bins_ref.shape[0]):           # this call's block
+        bins = bins[c0:c1]
+    comp = _compact_rows(jnp.concatenate([pw_ref[:], slot, bins], axis=0),
+                         code, cap)                  # [R0 + 1 + F_t, cap]
     pw_c, slot_c = comp[:r0], comp[r0:r0 + 1]
     bins_c = comp[r0 + 1:].astype(jnp.int32)         # [F_t, cap]
     lhs = jnp.concatenate(
@@ -420,21 +492,29 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
     `hist_bodies(row_tile)` of the body that ran.  With a lane `plan` the
     kernel sums into [S*R0, L] and each column's `[offset, offset +
     num_bin)` lanes are sliced back out here and zero-padded to MB, so
-    callers see one layout; a `feat_tile` that splits the columns over
-    several feature blocks takes no plan (a group's members must share a
-    block).
+    callers see one layout.
+
+    COLUMN BLOCKS (`column_blocks`): a pass is one kernel call while the
+    sums of all columns fit the kernel's scoped VMEM, else a call a column
+    block, each over its own columns of the same row tiles with its own
+    lane plan; every call reads the whole [F, N_t] bin tile (a few KB a
+    column) and sums only its block, so nothing is sliced or copied in
+    HBM.  The body is chosen once a pass, the row codes and ranks of a
+    compacting pass are made once and shared by the blocks, and every
+    sum is bit-equal to one block's.
 
     WHICH BODY is read off the call's own rows: the fullest tile's count
     of active rows (rows whose leaf is in `slots`) picks the smallest
     compacting capacity that holds it, else the full body — no tile can
     overflow, so a table in an adversarial row order simply runs the
     full body, and nothing is approximate.  Statically full: uint16 bins
-    (not exact in bf16), a `feat_tile` that splits the columns, a
-    `row_tile` that is no multiple of 128.  ONE pallas_call executes
-    (`lax.switch`), in a call named `pallas_histogram_multi_rows_*`:
-    trace readers select the kernel's custom-calls by that prefix.
-    `body` (a name of `hist_bodies`) forces one body: for timing a call
-    alone (`scripts/hist_lane_bound.py`) and for tests."""
+    (not exact in bf16), a `row_tile` that is no multiple of 128.  ONE
+    body executes (`lax.switch`), its calls named
+    `pallas_histogram_multi_rows_*`: trace readers select the kernel's
+    custom-calls by that prefix.
+    `body` (a name of `hist_bodies`) forces one body, `feat_tile` > 0
+    column blocks of at most that many columns: for timing a call alone
+    (`scripts/hist_lane_bound.py`) and for tests."""
     f, n = bins_fm.shape
     r0 = pw0.shape[0]
     s_n = slots.shape[0]
@@ -445,47 +525,45 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
         # padded rows carry leaf -1: matches no slot, contributes nothing
         leaf_id = jnp.pad(leaf_id, (0, n_pad), constant_values=-1)
     leaf_id = leaf_id.astype(jnp.int32)
-    if feat_tile <= 0 or feat_tile > f:
-        feat_tile = f
-    if feat_tile < f:
-        plan = None
-    f_pad = (-f) % feat_tile
-    if f_pad:
-        bins_fm = jnp.pad(bins_fm, ((0, f_pad), (0, 0)))
     n_rt = (n + n_pad) // row_tile
-    n_ft = (f + f_pad) // feat_tile
-    if plan is None:
-        shape = (f + f_pad, s_n * r0, max_bin)
-        block = (feat_tile,) + shape[1:]
-        sums_spec = pl.BlockSpec(block, lambda j, r: (j, 0, 0))
-    else:
+    if plan is not None:
         planned = [c for c, _, _ in plan_columns(plan)]
         if planned != list(range(f)):
             raise ValueError(f"lane plan over columns {planned} for {f} "
                              "columns")
-        shape = block = (s_n * r0, plan_lanes(plan))
-        sums_spec = pl.BlockSpec(block, lambda j, r: (0, 0))
-    sums = jax.ShapeDtypeStruct(shape, jnp.float32)
+    blocks = column_blocks(f, s_n * r0, max_bin, plan, feat_tile)
+    whole = len(blocks) == 1
     row_spec = pl.BlockSpec((1, row_tile), lambda j, r: (0, r))
 
     def call(kernel, row_args, row_specs):
-        return pl.pallas_call(
-            kernel,
-            grid=(n_ft, n_rt),  # row tiles iterate fastest -> sums revisited
-            in_specs=[
-                pl.BlockSpec((feat_tile, row_tile), lambda j, r: (j, r)),
-                pl.BlockSpec((r0, row_tile), lambda j, r: (0, r)),
-            ] + row_specs,
-            out_specs=[sums_spec, sums_spec],
-            out_shape=[sums, sums],
-            scratch_shapes=[pltpu.VMEM(block, jnp.float32)],
-            interpret=interpret,
-        )(bins_fm, pw0, *row_args)
+        """`kernel` once a column block: the blocks' (hi, lo), flat."""
+        out = ()
+        for c0, c1, sub in blocks:
+            if sub is None:
+                shape = (c1 - c0, s_n * r0, max_bin)
+                sums_spec = pl.BlockSpec(shape, lambda j, r: (j, 0, 0))
+            else:
+                shape = (s_n * r0, plan_lanes(sub))
+                sums_spec = pl.BlockSpec(shape, lambda j, r: (0, 0))
+            sums = jax.ShapeDtypeStruct(shape, jnp.float32)
+            out += tuple(pl.pallas_call(
+                functools.partial(kernel, plan=sub,
+                                  cols=None if whole else (c0, c1)),
+                grid=(1, n_rt),     # the sums block revisited a row tile
+                in_specs=[
+                    pl.BlockSpec((f, row_tile), lambda j, r: (j, r)),
+                    pl.BlockSpec((r0, row_tile), lambda j, r: (0, r)),
+                ] + row_specs,
+                out_specs=[sums_spec, sums_spec],
+                out_shape=[sums, sums],
+                scratch_shapes=[pltpu.VMEM(shape, jnp.float32)],
+                interpret=interpret,
+            )(bins_fm, pw0, *row_args))
+        return out
 
     def full(bins_fm, pw0, leaf_id, slots):
         return call(
-            functools.partial(_hist_kernel_multi, mb=max_bin, n_rt=n_rt,
-                              plan=plan),
+            functools.partial(_hist_kernel_multi, mb=max_bin, n_rt=n_rt),
             [leaf_id[None, :], slots[None, :]],
             [row_spec, pl.BlockSpec((1, s_n), lambda j, r: (0, 0))])
 
@@ -493,12 +571,12 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
         def run(bins_fm, pw0, leaf_id, slots):
             return call(
                 functools.partial(_hist_kernel_multi_compact, mb=max_bin,
-                                  n_rt=n_rt, plan=plan, cap=cap, s_n=s_n),
+                                  n_rt=n_rt, cap=cap, s_n=s_n),
                 [_row_codes(leaf_id, slots, row_tile)[None, :]], [row_spec])
         return run
 
     bodies = hist_bodies(row_tile)
-    if bins_fm.dtype != jnp.uint8 or feat_tile < f or row_tile % LANE:
+    if bins_fm.dtype != jnp.uint8 or row_tile % LANE:
         bodies = bodies[:1]
     names = [name for name, _ in bodies]
     caps = [rows for _, rows in bodies[1:]]
@@ -509,7 +587,7 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
     if body is not None:
         k = names.index(body)
         which = jnp.int32(k)
-        hi, lo = runs[k](*args)
+        out = runs[k](*args)
     else:
         slot, ambiguous = _slot_of_rows(leaf_id, slots)
         fullest = jnp.max(jnp.sum((slot > 0).reshape(n_rt, row_tile),
@@ -520,20 +598,27 @@ def _run_kernel_multi(bins_fm: Array, pw0: Array, leaf_id: Array,
         # a named call a body, for the trace: an HLO instruction takes
         # the name of the function it is traced in (a bare switch branch
         # would call the kernel `branch_1_fun`)
-        hi, lo = jax.lax.switch(
+        out = jax.lax.switch(
             which, [jax.named_call(fn, name="pallas_histogram_multi_rows_"
                                    + nm) for fn, nm in zip(runs, names)],
             *args)
-    if plan is None:
-        return hi[:f], lo[:f], which
+    his, los = [], []
+    for (_, _, sub), hi, lo in zip(blocks, out[0::2], out[1::2]):
+        if sub is not None:
+            hi = _columns_of_plan(hi, sub, max_bin)
+            lo = _columns_of_plan(lo, sub, max_bin)
+        his.append(hi)
+        los.append(lo)
+    hi, lo = (his[0], los[0]) if whole else \
+        (jnp.concatenate(his), jnp.concatenate(los))
+    if all(sub is None for _, _, sub in blocks):
+        return hi, lo, which
     # the barrier hands callers two materialised arrays, as the kernel
     # does without a plan: XLA then compiles what follows as it did,
     # whereas fused into the unpacking a grower's f32 reductions over
     # the bin axis associated differently (an internal node's stated
     # hessian sum moved by one ulp on the chip)
-    return jax.lax.optimization_barrier(
-        (_columns_of_plan(hi, plan, max_bin),
-         _columns_of_plan(lo, plan, max_bin))) + (which,)
+    return jax.lax.optimization_barrier((hi, lo)) + (which,)
 
 
 def _columns_of_plan(sums: Array, plan, max_bin: int) -> Array:
@@ -941,7 +1026,11 @@ def probe(interpret: bool = False, max_bin: int = 256,
     pass and the real call would still crash), with a single row tile to
     keep the probe cheap.
 
-    `multi=False` covers the single-leaf block shapes gating `hist_impl`;
+    `multi=False` covers the single-leaf block shapes gating `hist_impl`
+    (`quantized` False / True: the f32 / the int8 kernel alone — the
+    booster names the family it will run, since the int8 kernel, which
+    has no column blocks, is refused from 67 columns up where the f32
+    one is not: PERF.md section 6, PR 33; None: both);
     `multi=True` covers ONLY the multi-leaf shapes gating the wave policy
     — kept separate so a wave-shape regression degrades the wave policy,
     not every strict-policy user's histogram path.  The wave grower runs
@@ -1068,13 +1157,16 @@ def _probe_base(interpret: bool, max_bin: int, num_feature: int,
                     return res
         return _PROBE_OK
     mask_np = some_rows(int(0.7 * n))
-    got = pallas_histogram(bins, payload, jnp.asarray(mask_np), max_bin,
-                           row_tile=row_tile, interpret=interpret,
-                           plan=plan, body="full")
-    res = close("single-leaf f32 kernel vs a float64 count", got,
-                host_hist(payload_np, mask_np))
-    if not res:
-        return res
+    if quantized is not True:
+        got = pallas_histogram(bins, payload, jnp.asarray(mask_np), max_bin,
+                               row_tile=row_tile, interpret=interpret,
+                               plan=plan, body="full")
+        res = close("single-leaf f32 kernel vs a float64 count", got,
+                    host_hist(payload_np, mask_np))
+        if not res:
+            return res
+    if quantized is False:
+        return _PROBE_OK
     # the quantized kernel runs DIFFERENT block shapes (3-row payload)
     # — probe it too, or a Mosaic regression there would crash the
     # pallas_q path that this probe is supposed to gate

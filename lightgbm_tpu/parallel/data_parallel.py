@@ -7,15 +7,25 @@ application; SURVEY §3.4).
 
 Mapping:
  - row shard            → `Mesh` axis "data", bins_fm [F, N] sharded on N
- - histogram reduce     → `lax.psum` inside the grower (ops/grow.py,
-                          `make_grower(spec, axis_name="data")`)
+ - histogram reduce     → inside the grower (ops/grow.py,
+                          `make_grower(spec, axis_name="data")`, its
+                          `mode="data"`: the whole histogram on every
+                          shard).  With `det_reduce` (the default) the
+                          shards' histograms are added in ascending shard
+                          order around a ring (`ppermute` hops, then an
+                          `all_gather` of the total:
+                          `ops/histogram.ring_ordered_sum` / `ring_fold`)
+                          and the root sums reduce the gathered rows;
+                          without it, `lax.psum`
  - SplitInfo allreduce  → every shard argmaxes the identical summed
-                          histogram (replicated compute, zero extra comm)
+                          histogram (replicated compute, no exchange)
  - split application    → shard-local `where` on the local leaf_id vector
 
 The full training step (grad/hess → grow → score update) runs under ONE
-`jax.shard_map`, so a boosting iteration on a v5e-8 is a single SPMD program
-with two psums per split riding ICI.
+`jax.shard_map`: a boosting iteration is a single SPMD program, one
+histogram reduction a split.  `parallel/learner.py` is what
+`tree_learner=data` runs through `Booster.update` (block search,
+`data_rs`); this step is the multi-controller path (tests/mh_worker.py).
 """
 from __future__ import annotations
 
@@ -32,6 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..mesh.placement import emit_collective_round, local_device_ids
 from ..ops.grow import DeviceTree, GrowerSpec, make_grower
+from .learner import hist_hop_bytes
 
 Array = jax.Array
 
@@ -131,11 +142,10 @@ def make_sharded_train_step(spec: GrowerSpec, mesh: Mesh,
     # LOCAL device per training round, stamped host-side at dispatch —
     # this is the path a multi-controller gloo cluster runs
     # (tests/mh_worker.py), so the spool aggregator sees every rank's
-    # devices and can name the straggler.  Host-computed payload:
-    # the det ring-fold carry is [3, F, HB+1] f32 per hop.  R005: no
-    # telemetry inside the shard_map body; zero added syncs.
+    # devices and can name the straggler.  Host-computed payload: what
+    # one hop of one histogram reduction carries (`hist_hop_bytes`).
+    # R005: no telemetry inside the shard_map body; zero added syncs.
     coll_name = "ring_fold" if det_reduce else "hist_psum"
-    hb = (spec.bundle_max_bin if spec.bundled else spec.max_bin)
     rounds = itertools.count()
 
     def dispatched(score, label, weight, bins_fm, feat, allowed):
@@ -143,7 +153,8 @@ def make_sharded_train_step(spec: GrowerSpec, mesh: Mesh,
         if not TRACER.active:
             return jitted(score, label, weight, bins_fm, feat, allowed)
         # .shape is metadata — no transfer, no sync
-        payload_bytes = 3 * int(bins_fm.shape[0]) * (hb + 1) * 4
+        payload_bytes = hist_hop_bytes(spec, int(bins_fm.shape[0]), 1,
+                                       det_reduce and num_data > 0)
         emit_collective_round(coll_name, local_device_ids(mesh),
                               payload_bytes, next(rounds),
                               shards=int(mesh.shape[axis]))

@@ -11,8 +11,12 @@ is oblivious to the device topology — the reference achieves the same with
 virtual dispatch, we do it with jit + sharding.
 
 Strategy mapping (SURVEY §2.7):
- - data    → rows sharded, histogram `psum_scatter` over the feature axis,
-             per-shard split finding on its block, SplitInfo allreduce-max
+ - data    → rows sharded; every pass's histograms summed across shards
+             (`deterministic_reduce`, the default: chained limb-wise in
+             ascending shard order around a ring and gathered back, each
+             shard keeping its column block; else `psum_scatter` over the
+             feature axis), per-shard split finding on its block,
+             SplitInfo allreduce-max
              (ref: data_parallel_tree_learner.cpp).
  - feature → bins replicated, per-shard feature-block search, SplitInfo
              allreduce-max, shard-local split apply
@@ -99,9 +103,19 @@ def make_distributed_grower(spec: GrowerSpec, mesh: Mesh, kind: str,
     `wave=True` plugs in the wave-batched grower (ops/grow_wave.py) —
     data-parallel only (rows sharded; the booster downgrades other kinds
     before reaching here).  Like the strict grower, the wave runs the
-    production `data_rs` reduce-scatter mode (block-sharded histograms,
+    `data_rs` mode (each shard searches its block of the columns,
     per-wave SplitInfo allreduce-max) except under EFB, where bundle
-    columns force the full-histogram psum.
+    columns force the full histogram on every shard.  How a pass's
+    histograms are summed across shards is `det_reduce`'s: true (the
+    booster's default, one mesh axis) chains every shard's histogram in
+    ascending shard order (`ring_ordered_sum` / `ring_fold`: ppermute
+    hops, then an all_gather of the total) and each shard slices its
+    block; false runs `psum_scatter` (`psum` under EFB).
+
+    The returned function carries `reduce_bytes`: the bytes one shard
+    hands to the collectives of ONE histogram reduction
+    (`hist_reduce_bytes`), for the booster's `grow.reduce_bytes`; and
+    `jitted`, the program itself (`jit_grow`).
     """
     axes = tuple(mesh.axis_names)     # ("data",) or ("dcn", "ici")
     S_last = int(mesh.shape[axes[-1]])
@@ -157,7 +171,9 @@ def make_distributed_grower(spec: GrowerSpec, mesh: Mesh, kind: str,
     sharded = shard_map(grow, mesh=mesh, in_specs=in_specs,
                         out_specs=tree_specs, check_vma=False)
 
-    def padded(bins_fm, grad, hess, sw, feat, allowed):
+    # named `grow` like the serial growers' jitted function: one grower
+    # is one program name, `jit_grow`, on one chip and on four
+    def grow(bins_fm, grad, hess, sw, feat, allowed):
         # named scopes label the XProf timeline: padding vs the SPMD body
         # (whose collectives — psum_scatter / allreduce-max — show up
         # under parallel.grow_sharded); zero runtime cost, compile-time
@@ -183,17 +199,21 @@ def make_distributed_grower(spec: GrowerSpec, mesh: Mesh, kind: str,
             dev = dev._replace(leaf_id=dev.leaf_id[:num_data])
         return dev
 
-    jitted = jax.jit(padded)
+    jitted = jax.jit(grow)
     # per-device collective timeline (ISSUE 16): stamp one
     # mesh.collective.<name> point event per local device per dispatch
     # round, host-side around the jitted call (graft-lint R005 keeps
-    # telemetry out of the SPMD body; named_scopes inside ops/grow*.py
-    # label the device trace instead).  Payload = the ring-fold carry
-    # (det_reduce: [3, F, HB+1] f32 per hop) or the full-histogram psum.
+    # telemetry out of the SPMD body; the `hist_reduce` named_scope
+    # inside ops/grow*.py labels the device trace instead).  Payload =
+    # what one hop of one histogram reduction carries (`hist_hop_bytes`).
     # Zero added device syncs: events ride the async dispatch.
     coll_name = "ring_fold" if det_reduce else "hist_psum"
-    hb = (spec.bundle_max_bin if spec.bundled else spec.max_bin)
-    payload_bytes = 3 * (num_feature + f_extra) * (hb + 1) * 4
+    slots = 1
+    if wave:
+        from ..ops.grow_wave import wave_sizes
+        slots = wave_sizes(spec)[1]
+    det = bool(det_reduce) and len(axes) == 1   # as the growers decide
+    payload_bytes = hist_hop_bytes(spec, num_feature + f_extra, slots, det)
     rounds = itertools.count()
 
     def dispatched(*args):
@@ -205,7 +225,40 @@ def make_distributed_grower(spec: GrowerSpec, mesh: Mesh, kind: str,
                               mode=mode, shards=S_total)
         return jitted(*args)
 
+    dispatched.jitted = jitted      # ahead-of-time compiles lower this
+    dispatched.reduce_bytes = hist_reduce_bytes(
+        spec, num_feature + f_extra, slots, det, S_last)
     return dispatched
+
+
+def hist_hop_bytes(spec: GrowerSpec, columns: int, slots: int,
+                   det: bool) -> int:
+    """Bytes of what ONE shard hands to ONE collective of a histogram
+    reduction over `columns` (padded) columns and `slots` leaf slots:
+    the f32 families' two-limb sums [slots, columns, bins, 6] (three
+    channels from the quantized ones), or under `det` on the XLA
+    families the streamed carry that is chained in their place
+    (`ops/histogram.hist_stream_*`)."""
+    from ..ops import histogram as H
+    hb = spec.bundle_max_bin if spec.bundled else spec.max_bin
+    quantized = spec.hist_impl in ("pallas_q", "packed")
+    if det and spec.hist_impl not in ("pallas", "pallas_q"):
+        init = functools.partial(
+            H.hist_stream_packed_init, columns, slots, hb,
+            spec.packed_const_hess_level) if quantized else \
+            functools.partial(H.hist_stream_init, columns, slots, hb)
+        return sum(a.size * a.dtype.itemsize
+                   for a in jax.tree.leaves(jax.eval_shape(init)))
+    return slots * columns * hb * (3 if quantized else 6) * 4
+
+
+def hist_reduce_bytes(spec: GrowerSpec, columns: int, slots: int, det: bool,
+                      n_shards: int) -> int:
+    """Bytes one shard hands to the collectives of ONE histogram
+    reduction: the ordered chain permutes its carry `n_shards - 1` times
+    and gathers it once; `psum_scatter` / `psum` take one operand."""
+    return hist_hop_bytes(spec, columns, slots, det) * \
+        (n_shards if det else 1)
 
 
 def place_training_data(bins_fm, mesh: Mesh, kind: str,

@@ -19,6 +19,17 @@ compacting capacity that holds the fullest tile (forced: `body=`), and as
 the dispatch picks (`auto`); each line carries the largest |difference|
 of its sums from the full body's, relative to the largest sum.
 
+    chiprun -- python3 scripts/hist_lane_bound.py --num-bins 255x9,12,255x57,1 \
+        --blocks 1,2,3,4
+
+is ISSUE 33's step 0: the call at other columns than the airline's (here
+the 68 a shard of `criteo67-lgbpar-l255` sees), in 1, 2, 3 and 4 column
+blocks (`pallas_hist.column_blocks`, forced through `feat_tile`), without
+and with the lane plan, through each body (`full` over rows that are all
+in a slot's reach as the waves' are, `c512` / `c256` at 18% / 8% of the
+rows active); a line says whether its sums equal the first line's of its
+share bit for bit, or carries the compiler's refusal.
+
 Exits 2 where JAX finds no TPU: a CPU time is not a device number.
 """
 import argparse
@@ -63,6 +74,10 @@ def main():
                     "shares of rows in a slot: time the compacting bodies")
     ap.add_argument("--capacity", default="", help="comma-separated "
                     "capacities to time beside pallas_hist.COMPACT_CAPS")
+    ap.add_argument("--num-bins", default="", help="the columns' bin "
+                    "counts, comma-separated, `255x9` for nine of 255: "
+                    "time the call at these columns by column blocks")
+    ap.add_argument("--blocks", default="1,2,3,4")
     a = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu" and not a.rehearse:
@@ -71,6 +86,8 @@ def main():
         return 2
     print(json.dumps({"device": dev.device_kind, "rows": a.rows,
                       "slots": a.slots, "reps": a.reps}))
+    if a.num_bins:
+        return column_blocks(a)
     if a.active_share:
         return compaction(a)
     rng = np.random.RandomState(0)
@@ -114,6 +131,54 @@ def main():
             else:
                 line["bit_equal_to_first"] = bool(np.array_equal(got, ref))
         print(json.dumps(line), flush=True)
+    return 0
+
+
+def column_blocks(a):
+    """ISSUE 33 step 0: one line a (body, plan, blocks)."""
+    num_bins = []
+    for part in a.num_bins.split(","):
+        nb, _, times = part.partition("x")
+        num_bins += [int(nb)] * int(times or 1)
+    rng = np.random.RandomState(0)
+    n, f = a.rows, len(num_bins)
+    row_tile = min(n, ph.ROW_TILE)
+    pw9 = ph._split_payload9(jnp.asarray(
+        np.abs(rng.randn(n, 3)).astype(np.float32)))
+    bins = jnp.asarray(np.stack(
+        [rng.randint(0, nb, n) for nb in num_bins]).astype(np.uint8))
+    slots = jnp.arange(a.slots, dtype=jnp.int32)
+    for body, share in (("full", 0.5), ("c512", 0.18), ("c256", 0.08)):
+        lid = jnp.asarray(np.where(
+            rng.rand(n) < share, rng.randint(0, a.slots, n),
+            a.slots + rng.randint(0, 3 * a.slots, n)).astype(np.int32))
+        ref = None
+        for plan in (None, ph.lane_plan(num_bins, 255)):
+            for k in (int(b) for b in a.blocks.split(",")):
+                feat_tile = -(-f // k)
+                line = {"columns": f, "body": body, "active_share": share,
+                        "plan": plan is not None, "blocks": len(
+                            ph.column_blocks(f, a.slots * 9, 255, plan,
+                                             feat_tile))}
+
+                def call(b, p, l, s, plan=plan, feat_tile=feat_tile):
+                    return ph.pallas_histogram_multi_rows(
+                        b, p, l, s, 255, plan=plan, feat_tile=feat_tile,
+                        interpret=a.rehearse, row_tile=row_tile, body=body)
+                try:
+                    med, all_s = _time(call, (bins, pw9, lid, slots), a.reps)
+                except Exception as e:      # the compiler's refusal
+                    line["refused"] = f"{type(e).__name__}: {e}"[:600]
+                    print(json.dumps(line), flush=True)
+                    continue
+                got = np.asarray(call(bins, pw9, lid, slots))
+                ref = got if ref is None else ref
+                line.update(call_s=med, all_s=all_s,
+                            ps_per_row_lane=med / n / (
+                                ph.plan_lanes(plan) if plan else f * 256)
+                            * 1e12,
+                            bit_equal_to_first=bool(np.array_equal(got, ref)))
+                print(json.dumps(line), flush=True)
     return 0
 
 

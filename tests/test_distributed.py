@@ -419,3 +419,167 @@ class TestShardedGrower:
         np.testing.assert_allclose(np.asarray(tree.leaf_value),
                                    np.asarray(ref.leaf_value),
                                    rtol=2e-4, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 33: four shards x 67 columns, the wave grower under the default
+# `deterministic_reduce`, the Pallas kernel (interpreted) on every shard —
+# the CPU stand-in of the cell `criteo67-lgbpar-l255.train`
+# ---------------------------------------------------------------------------
+WIDE_SHARDS = 4
+
+
+@pytest.fixture(scope="module")
+def wide_runs():
+    """The 67 columns of the four-chip cell at 4 x 1,024 rows, 15 leaves,
+    two rounds: the serial learner once, the data-parallel learner over
+    four shards twice; each with what it counted."""
+    from lightgbm_tpu import telemetry
+    from perfbench import manifest
+    from perfbench.generators import tabular_codes
+    from perfbench.jobs.train import build_dataset
+    config = manifest.config("criteo67-lgbpar-l255")
+    rows = tabular_codes.make(5, config["data"], WIDE_SHARDS * 1024, 1)
+    names = [c["name"] for c in config["data"]["columns"]]
+    base = dict(config["params"], num_leaves=15, hist_impl="pallas",
+                hist_interpret=True, num_machines=WIDE_SHARDS)
+
+    def run_of(learner):
+        params = dict(base, tree_learner=learner)
+        ds = build_dataset(lgb, rows["codes"], rows["label"], params, names)
+        bst = lgb.Booster(params=params, train_set=ds)
+        before = telemetry.REGISTRY.snapshot()["counters"]
+        for _ in range(2):
+            bst.update()
+        after = telemetry.REGISTRY.snapshot()["counters"]
+        grown = {k: after[k] - before.get(k, 0) for k in after
+                 if k.startswith("grow.")}
+        gauges = telemetry.REGISTRY.snapshot()["gauges"]
+        return {"booster": bst, "grown": grown,
+                "gauges": {k: gauges[k] for k in
+                           ("mesh.shards", "hist.lanes_per_row",
+                            "hist.packed_columns")},
+                "dump": json.dumps(bst.dump_model()["tree_info"],
+                                   sort_keys=True)}
+
+    import json
+    return {"serial": run_of("serial"), "data": run_of("data"),
+            "again": run_of("data")}
+
+
+def _permuted(jaxpr, found):
+    """Operand avals of every ppermute / all_gather in a jaxpr, nested
+    jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("ppermute", "all_gather"):
+            found.append((eqn.primitive.name, eqn.invars[0].aval))
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    _permuted(inner, found)
+    return found
+
+
+@pytest.mark.parametrize("what", [
+    "the_serial_learners_trees", "the_same_dump_twice",
+    "a_lane_plan_over_the_padded_columns", "hist_passes_of_four_shards",
+    "reduce_bytes_are_the_arrays_permuted", "one_program_name"])
+def test_four_shards_67_columns_wave(wide_runs, what):
+    serial, data, again = (wide_runs[k] for k in ("serial", "data", "again"))
+    bst = data["booster"]
+    assert bst._mesh is not None \
+        and dict(bst._mesh.shape) == {"data": WIDE_SHARDS}
+    assert bst._grow_policy == serial["booster"]._grow_policy == "wave"
+    if what == "the_serial_learners_trees":
+        # node for node; the sums are the serial learner's up to the
+        # order of summation
+        for ts, td in zip(serial["booster"].trees, bst.trees):
+            assert ts.num_leaves == td.num_leaves == 15
+            for field in ("split_feature", "threshold_bin", "left_child",
+                          "right_child", "leaf_count", "internal_count"):
+                np.testing.assert_array_equal(getattr(ts, field),
+                                              getattr(td, field), field)
+            np.testing.assert_allclose(td.leaf_value, ts.leaf_value,
+                                       rtol=0, atol=1e-6)
+    elif what == "the_same_dump_twice":
+        assert data["dump"] == again["dump"]
+    elif what == "a_lane_plan_over_the_padded_columns":
+        from lightgbm_tpu.ops.pallas_hist import plan_columns, plan_lanes
+        plan = bst._hist_lane_plan()
+        assert plan is not None and plan == bst._grower_spec.hist_lane_plan
+        assert [c for c, _, _ in plan_columns(plan)] == list(range(68))
+        assert plan_columns(plan)[-1][2] == 1          # the pad column
+        assert plan_lanes(plan) == 66 * 256 + 128
+        assert data["gauges"] == {"mesh.shards": 4,
+                                  "hist.lanes_per_row": 17024,
+                                  "hist.packed_columns": 2}
+        # the serial learner sees 67 columns: I10 alone in its group
+        assert [c for c, _, _ in plan_columns(
+            serial["booster"]._hist_lane_plan())] == list(range(67))
+        assert serial["gauges"] == {"mesh.shards": 1,
+                                    "hist.lanes_per_row": 17024,
+                                    "hist.packed_columns": 0}
+    elif what == "hist_passes_of_four_shards":
+        def passes(run):
+            g = run["grown"]
+            return g["grow.wave_passes"] + g["grow.tail_passes"] + 2
+
+        def calls(run):
+            return sum(v for k, v in run["grown"].items()
+                       if k.startswith("grow.hist_passes_"))
+        assert passes(data) == passes(serial) == calls(serial)
+        assert calls(data) == WIDE_SHARDS * calls(serial)
+        assert data["grown"]["grow.reduce_passes"] == passes(data)
+        assert "grow.reduce_passes" not in serial["grown"] \
+            or serial["grown"]["grow.reduce_passes"] == 0
+    elif what == "reduce_bytes_are_the_arrays_permuted":
+        n = WIDE_SHARDS * 1024
+        ones = jnp.ones((n,), jnp.float32)
+        found = _permuted(jax.make_jaxpr(bst._grower)(
+            bst._train_bins, ones, ones, ones, bst._feat,
+            jnp.ones((67,), bool)).jaxpr, [])
+        hops = [a for name, a in found if name == "ppermute"]
+        # root pass, wave pass, tail pass: three hops each, of one shape
+        assert len(hops) == 3 * (WIDE_SHARDS - 1)
+        assert {a.shape for a in hops} == {(8, 68, 255, 6)}
+        hop = hops[0].size * hops[0].dtype.itemsize
+        gathered = [a for name, a in found
+                    if name == "all_gather" and a.shape == hops[0].shape]
+        assert len(gathered) == 3
+        a_pass = (WIDE_SHARDS - 1) * hop + hop
+        assert bst._grower.reduce_bytes == a_pass
+        assert data["grown"]["grow.reduce_bytes"] \
+            == data["grown"]["grow.reduce_passes"] * a_pass
+    else:
+        # the jitted function is `grow` on one chip and on four
+        from lightgbm_tpu.parallel.learner import make_distributed_grower
+        grow = make_distributed_grower(
+            bst._grower_spec, bst._mesh, "data", 67, WIDE_SHARDS * 1024,
+            wave=True, det_reduce=True)
+        assert grow is bst._grower
+        assert grow.jitted.__name__ == "grow"
+        assert serial["booster"]._make_serial_grower().__name__ == "grow"
+
+
+@pytest.mark.parametrize("learner", ["serial", "data"])
+def test_a_deleted_booster_frees_its_device_arrays(learner):
+    """`perfbench.readings` trains one booster a seed in one process, and
+    `jobs/train.py` frees the program's state before the reference takes
+    the device: a booster that outlives its last reference keeps its bin
+    matrix on the chip (PR 33: a jaxlib bound method in a reference cycle
+    is invisible to the collector)."""
+    import gc
+    import weakref
+    X, y = make_data(1024, f=5, seed=9)
+    bst = lgb.Booster(params={"objective": "binary", "num_leaves": 7,
+                              "tree_learner": learner, "num_machines": 4,
+                              "verbosity": -1},
+                      train_set=lgb.Dataset(X, label=y))
+    bst.update()
+    assert (bst._mesh is not None) == (learner == "data")
+    bins = weakref.ref(bst._train_bins)
+    alive = weakref.ref(bst)
+    del bst
+    gc.collect()
+    assert alive() is None and bins() is None

@@ -73,15 +73,19 @@ def test_the_control_and_both_faults_fail_the_fixtures_limits(tmp_path,
 
 # ----------------------------------------------------------- the manifest
 def test_two_configurations_two_cells_sixteen_metric_files():
+    """The benchmark's first two configurations and cells and their
+    sixteen metric files (later PRs append: tests/test_criteo67_cell.py)."""
     assert manifest.problems() == []
     b = manifest.benchmark()
-    assert [c["name"] for c in b["configs"]] == ["airline13-l31",
-                                                 "airline13-lgbexp-l255"]
-    assert [w["name"] for w in b["workloads"]] == [OLD_CELL, CELL]
-    assert len(b["per_layer"]) == 16
+    assert [c["name"] for c in b["configs"]][:2] == [
+        "airline13-l31", "airline13-lgbexp-l255"]
+    assert [w["name"] for w in b["workloads"]][:2] == [OLD_CELL, CELL]
+    mine = [m for m in b["per_layer"][:16]]
+    assert all(m["workloads"] in ([OLD_CELL], [CELL, OLD_CELL])
+               for m in mine)
     files = [f for f in os.listdir(os.path.join(manifest.HERE,
                                                 "layer_metrics"))
-             if f.endswith(".json")]
+             if f.endswith(".json") and not f.startswith("par4.")]
     assert len(files) == 16
     assert b["workloads"][1]["chips"] == 1
 
@@ -96,7 +100,7 @@ def test_each_new_metric_is_an_old_readers_twin_and_lists_the_new_cell():
     d = os.path.join(manifest.HERE, "layer_metrics")
     new = {m["name"]: m for m in manifest.layer_metrics(CELL)}
     old = {m["name"]: m for m in manifest.layer_metrics(OLD_CELL)
-           if not m["name"].startswith("l255.")}
+           if not m["name"].startswith(("l255.", "par4."))}
     assert len(new) == len(old) == 8
     assert set(new) == {"l255." + n for n in old}
     for name, m in new.items():

@@ -226,10 +226,22 @@ class TestLanePlan:
             assert plan_lanes(lane_plan((64, 64, 60), 64)) == 256
             assert lane_plan((100, 90), 100) is None
         elif case == "split_feat_tile":
-            # a group's members must share a feature block: no plan
+            # a group's members share a column block: each block of a
+            # split pass plans its own columns, numbered from its first
+            from lightgbm_tpu.ops.pallas_hist import column_blocks
             plan = lane_plan(AIRLINE_NUM_BIN, 255)
+            blocks = column_blocks(13, 18, 255, plan, feat_tile=4)
+            assert [(c0, c1) for c0, c1, _ in blocks] \
+                == [(0, 4), (4, 7), (7, 10), (10, 13)]
+            assert blocks[0][2] == ((128, ((0, 0, 22), (1, 22, 12),
+                                           (2, 34, 31), (3, 65, 7))),)
+            assert blocks[1][2] == ((256, ((0, 0, 255),)),
+                                    (256, ((1, 0, 255),)),
+                                    (128, ((2, 0, 29),)))
+            assert blocks[2][2] is None        # three wide columns
+            assert column_blocks(13, 18, 255, plan) == ((0, 13, plan),)
             assert _rows_jaxpr(AIRLINE_NUM_BIN, 255, feat_tile=4, plan=plan) \
-                == _rows_jaxpr(AIRLINE_NUM_BIN, 255, feat_tile=4)
+                != _rows_jaxpr(AIRLINE_NUM_BIN, 255, feat_tile=4)
             assert _rows_jaxpr(AIRLINE_NUM_BIN, 255, plan=plan) \
                 != _rows_jaxpr(AIRLINE_NUM_BIN, 255)
         else:
@@ -258,6 +270,25 @@ class TestLanePlan:
         # a plan over other columns than the kernel sees is refused
         with pytest.raises(ValueError, match="lane plan"):
             _rows_jaxpr(AIRLINE_NUM_BIN[:12], 255, plan=plan)
+
+    @pytest.mark.parametrize("quantized", [False, True])
+    def test_single_leaf_probe_runs_the_named_family(self, quantized,
+                                                     monkeypatch):
+        """The booster names the kernel family it will run: the int8
+        kernel is refused by the v5e compiler from 67 columns up (scoped
+        VMEM), and an f32 booster must not be stopped by its probe."""
+        from lightgbm_tpu.ops import pallas_hist as ph
+
+        def other(*a, **k):
+            raise AssertionError("the probe ran the other family's kernel")
+        monkeypatch.setattr(ph, "pallas_histogram" if quantized
+                            else "pallas_histogram_quantized", other)
+        assert ph.probe(interpret=True, max_bin=255, num_feature=5,
+                        quantized=quantized)
+        # unnamed, it probes both: off the TPU a raising kernel is a
+        # falsy result that carries the message
+        res = ph.probe(interpret=True, max_bin=255, num_feature=5)
+        assert not res and "other family" in res.detail
 
     def test_out_of_range_bin_raises_under_debug_checks(self):
         import jax

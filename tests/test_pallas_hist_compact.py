@@ -130,7 +130,7 @@ def _one_tile_with(count, n_tiles=3):
     ("all_rows_active", "full"), ("no_row_active", "c256"),
     ("a_tile_at_256", "c256"), ("a_tile_at_257", "c512"),
     ("a_tile_at_512", "c512"), ("a_tile_at_513", "full"),
-    ("uint16_bins", "full"), ("split_feat_tile", "full"),
+    ("uint16_bins", "full"), ("split_feat_tile", "c256"),
     ("a_leaf_listed_twice", "full"), ("row_tile_of_100", "full")])
 def test_dispatch_runs_the_body_the_rule_says(case, body):
     n = 3 * TILE
@@ -153,8 +153,7 @@ def test_dispatch_runs_the_body_the_rule_says(case, body):
         args = args[:3] + (args[3].at[S - 1].set(0),)
     got, ran = _rows(args, mb, **kw)
     assert ran[body] == 1 and sum(ran.values()) == 1, ran
-    statically_full = case in ("uint16_bins", "split_feat_tile",
-                               "row_tile_of_100")
+    statically_full = case in ("uint16_bins", "row_tile_of_100")
     assert len(ran) == (1 if case == "row_tile_of_100" else 3)
     if not statically_full:
         want, _ = _rows(args, mb, body="full", **kw)
@@ -184,6 +183,79 @@ def test_more_slots_than_a_chunk_count_a_call_a_chunk():
     want = ph.pallas_histogram_multi_rows(bins, pw9, lid, slots, 16,
                                           interpret=True, body="full")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+# ------------------------------------------------ (d2) the column blocks
+# the 68 columns one of four shards sees of the Criteo-shaped table: 67
+# padded to the shard count (perfbench/configs/criteo67-lgbpar-l255.json)
+CRITEO_NUM_BIN = (255,) * 9 + (12,) + (255,) * 57 + (1,)
+_ONE_BLOCK = {}
+
+
+def _wide_case():
+    """Two row tiles of 640 rows, the second ragged; 12% of the rows in
+    the slots, so a tile holds under 256 of them."""
+    if not _ONE_BLOCK:
+        n = 640 + 300
+        rng = np.random.RandomState(11)
+        args, *_ = _case(n, rng.rand(n) < 0.12, 12, integer=True,
+                         num_bins=CRITEO_NUM_BIN)
+        want, ran = _rows(args, row_tile=640, body="full")
+        assert ran["full"] == 1 and np.abs(want).sum() > 0
+        _ONE_BLOCK.update(args=args, want=want)
+    return _ONE_BLOCK["args"], _ONE_BLOCK["want"]
+
+
+@pytest.mark.parametrize("body", ["full", "c256", "c512"])
+@pytest.mark.parametrize("planned", [False, True])
+@pytest.mark.parametrize("n_blocks", [1, 2, 4])
+def test_column_blocks_sum_the_same_bits(n_blocks, planned, body):
+    """68 columns in 1, 2 and 4 column blocks, with and without the lane
+    plan, through each body: both limbs equal one block's full body's."""
+    args, want = _wide_case()
+    plan = ph.lane_plan(CRITEO_NUM_BIN, 255) if planned else None
+    feat_tile = -(-68 // n_blocks)     # forced: 68 columns force one
+    blocks = ph.column_blocks(68, S * 9, 255, plan, feat_tile)
+    assert len(blocks) == n_blocks
+    assert [c0 for c0, _, _ in blocks][1:] == [c1 for _, c1, _ in blocks][:-1]
+    if planned:     # the 12-bin column and the pad column share no block
+        packed = [len(sub) < c1 - c0 if sub else False
+                  for c0, c1, sub in blocks]
+        assert any(packed) == (n_blocks == 1)
+    got, ran = _rows(args, row_tile=640, plan=plan, body=body,
+                     feat_tile=feat_tile)
+    assert ran[body] == 1 and sum(ran.values()) == 1
+    assert got.shape == want.shape == (S, 68, 255, 6)
+    np.testing.assert_array_equal(got, want)
+    if body == "c256" and planned and n_blocks == 4:
+        # and as the dispatch picks: the same body
+        picked, ran = _rows(args, row_tile=640, plan=plan,
+                            feat_tile=feat_tile)
+        assert ran == {"full": 0, "c256": 1, "c512": 0}
+        np.testing.assert_array_equal(picked, want)
+
+
+@pytest.mark.parametrize("columns,slots,n_blocks", [
+    (13, 8, 1), (68, 8, 1), (256, 8, 4), (68, 14, 2), (256, 14, 8)])
+def test_chosen_column_blocks_fit_the_vmem_budget(columns, slots, n_blocks):
+    """As few blocks as fit: the 68 columns a shard of the four-chip cell
+    sees are ONE block at the cell's eight slots under their plan (without
+    one, all 68 on 256 lanes each, they are two)."""
+    num_bins = (CRITEO_NUM_BIN * 4)[:columns]
+    for plan in (None, ph.lane_plan(num_bins, 255)):
+        blocks = ph.column_blocks(columns, slots * 9, 255, plan)
+        assert len(blocks) == n_blocks + (
+            (columns, slots, plan) == (68, 8, None))
+        assert blocks[0][0] == 0 and blocks[-1][1] == columns
+        for (c0, c1, sub), nxt in zip(blocks, blocks[1:] + ((columns,),)):
+            assert c1 == nxt[0] and c1 > c0
+            lanes = (c1 - c0) * 256 if sub is None else ph.plan_lanes(sub)
+            assert ph._vmem_need(slots * 9, lanes) <= ph.VMEM_BYTES
+            if sub is not None:
+                assert [c for c, _, _ in ph.plan_columns(sub)] \
+                    == list(range(c1 - c0))
+        if len(blocks) == 1:
+            assert blocks == ((0, columns, plan),)
 
 
 # ------------------------------------------- (e) a tree through the grower

@@ -275,6 +275,9 @@ def _count_growth(tree: Tree, reduce_bytes: int = 0) -> None:
     histograms needed, its leaves, and (wave grower) the waves' passes and
     its strict tail's passes, splits served from a speculated histogram,
     and speculated ones left unused: passes a tree = 1 + waves + tail;
+    the routing passes over the rows (`grow.route_passes`: one a wave and
+    a speculation where `ops/route.py` serves, else one a pick and a
+    slot) and the picks and slots they routed (`grow.route_picks`);
     on the f32 Pallas kernel, its calls by the body that ran
     (`grow.hist_passes_full`, `grow.hist_passes_c<capacity>`, summed over
     the shards of a mesh) and the rows they contracted: needed /
@@ -287,11 +290,14 @@ def _count_growth(tree: Tree, reduce_bytes: int = 0) -> None:
     counter("grow.hist_rows_needed").inc(tree.hist_rows_needed())
     counter("grow.leaves").inc(tree.num_leaves)
     if tree.tail_stats is not None:
-        passes, hits, unused, _, waves = tree.tail_stats
+        passes, hits, unused, _, waves, route_passes, route_picks = \
+            tree.tail_stats
         counter("grow.tail_passes").inc(passes)
         counter("grow.tail_spec_hits").inc(hits)
         counter("grow.tail_spec_unused").inc(unused)
         counter("grow.wave_passes").inc(waves)
+        counter("grow.route_passes").inc(route_passes)
+        counter("grow.route_picks").inc(route_picks)
         if reduce_bytes:
             counter("grow.reduce_passes").inc(1 + waves + passes)
             counter("grow.reduce_bytes").inc(

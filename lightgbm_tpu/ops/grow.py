@@ -188,10 +188,11 @@ class DeviceTree(NamedTuple):
     leaf_h: Array         # [L] f32
     leaf_cnt: Array       # [L] f32
     leaf_id: Array        # [N] i32 — final row→leaf assignment (train rows)
-    # [5] i32, wave grower only (None elsewhere) — its strict tail's
+    # [7] i32, wave grower only (None elsewhere) — its strict tail's
     # histogram passes, splits served from a speculated histogram,
-    # speculated histograms never used / made, and the histogram passes
-    # of the waves before the tail (ops/grow_wave.py)
+    # speculated histograms never used / made, the histogram passes
+    # of the waves before the tail, and the routing passes over the rows
+    # with the picks and slots they routed (ops/grow_wave.py)
     tail_stats: Array = None
     # [len(pallas_hist.hist_bodies()) + 1] i32, wave grower on the f32
     # Pallas kernel only (None elsewhere) — the kernel's calls by the

@@ -75,9 +75,20 @@ cross-shard sum of a pass's histograms — the ordered chain, under
 `ring_fold`, or `psum_scatter` / `psum` — and the slice of this shard's
 column block), `split_allreduce` (inside `find_split`, sharded block
 search only: the SplitInfo exchange),
-`partition` (the pick loop: choice, `split_go_left`, `leaf_id` rewrite,
-node and leaf records; in the tail also the choice of the leaves to
-speculate and their rows' slot ids), `hist_cache` (sibling subtraction,
+`partition` (the pick loop: choice, node and leaf records; then the rows'
+routing, ONCE a wave and once a speculating pass: the wave's picks are
+distinct leaves that were ready at its start and the loop's choices read
+the per-leaf tables only, so the loop carries no [N] array and one pass
+of `ops/route.py` (`route_wave_rows`, a Pallas call of that name on the
+kernel families; (F + 8) bytes a row for up to W picks) applies the
+recorded picks to `leaf_id`, and the same pass writes a speculation's
+slot ids.  The strict tail's single pick routes itself by
+`split_go_left` and a `leaf_id` rewrite, one bin row for all; so does
+every pick of a bundled or categorical spec, of two-byte bins and of
+more than `route.ROUTE_MAX_COLUMNS` columns, whose routing is another
+computation or whose all-column pass costs more than a row a pick:
+static facts, no option.  `DeviceTree.tail_stats[5:7]` count the routing
+passes and the picks and slots they routed), `hist_cache` (sibling subtraction,
 the two cache scatters, the speculated histograms' reads and scatters),
 `prune` (`prune_wave_tail`, only with overgrow).  An
 op's phase is the innermost of these on its name stack; what is under
@@ -101,6 +112,8 @@ from .grow import (DeviceTree, GrowerSpec, _split_to_arrays,
                    make_feature_blocks, make_node_samplers,
                    rebase_and_merge_block_split, split_go_left)
 from ..analysis.contracts import contract
+from .route import (batched_route_applies, pick_records, route_rows_xla,
+                    route_wave_rows)
 from .histogram import (hist_stream_finalize, hist_stream_init,
                         hist_stream_packed_finalize,
                         hist_stream_packed_init,
@@ -248,6 +261,23 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             expand_bundled, decode_bins = make_bundled_expander(spec, feat)
         else:
             decode_bins = None
+        # one routing pass a wave and a speculation (`route_picks`), or
+        # one a pick: static facts of the spec and of the bins
+        batch_route = not spec.bundled and not spec.has_cat \
+            and batched_route_applies(bins_fm)
+
+        def route_picks(leaf_id, live, leaf, f, t, dl, if_left, if_right,
+                        fill=None):
+            """[N]: the rows of each live pick's leaf take the pick's
+            value of their side under its split (column `f`, threshold
+            `t`, `dl`), the ONE rule `split.bin_goes_left`; every other
+            row `fill` (None: its own `leaf_id`).  [W] arrays a field."""
+            rec = pick_records(live, leaf, f, t, dl, feat["nb"],
+                               feat["missing"], if_left, if_right)
+            if spec.hist_impl in ("pallas", "pallas_q"):
+                return route_wave_rows(bins_fm, leaf_id, rec, fill=fill,
+                                       interpret=spec.hist_interpret)
+            return route_rows_xla(bins_fm, leaf_id, rec, fill=fill)
 
         # the kernel payload carrier is loop-INVARIANT: prepare it once
         # per tree here, not inside every wave's while_loop body (XLA's
@@ -543,6 +573,9 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 wave_passes=jnp.int32(0),
                 # kernel calls by body so far (DeviceTree.hist_calls)
                 hist_calls=root_calls,
+                # routing passes over the rows, and the picks and slots
+                # they routed (the last two of DeviceTree.tail_stats)
+                route_stats=jnp.zeros((2,), jnp.int32),
             )
             if track_used:
                 state["leaf_used"] = jnp.zeros((LB, F), bool)
@@ -587,12 +620,18 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             ONE pick whose smaller child's histogram is read from there
             instead of built."""
             strict = small_hists is not None
+            # a wave's picks are distinct leaves that were READY at its
+            # start and its choices read the per-leaf tables only: the
+            # loop records them and ONE pass routes their rows after it.
+            # The tail's single pick routes itself (one bin row for all)
+            batched = batch_route and not strict
             # ---- split phase: best-first among READY leaves (leaves
             # created this wave have no histogram yet and wait for the
             # next wave), up to the batch capacity W ----
-            carry_keys = ("step", "nl", "leaf_id", "nodes", "leaf_g",
+            carry_keys = ("step", "nl", "nodes", "leaf_g",
                           "leaf_h", "leaf_c", "leaf_lb", "leaf_ub",
                           "leaf_out", "leaf_depth") + \
+                (() if batched else ("leaf_id",)) + \
                 (("leaf_used",) if track_used else ()) + \
                 (("forced_n",) if n_forced else ())
             istate = {k: st[k] for k in carry_keys + LEAF_KEYS}
@@ -699,12 +738,6 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                     chosen = stored
                 (gain_s, f, t, dl, lg, lh, lc, rg_, rh, rc, node_cat,
                  node_mask) = chosen
-                in_leaf = s["leaf_id"] == best
-
-                # ---- partition (shared decode with the strict grower) --
-                go_left = split_go_left(spec, feat, bins_fm, decode_bins,
-                                        f, t, dl, node_cat, node_mask)
-                leaf_id = jnp.where(in_leaf & ~go_left, new, s["leaf_id"])
 
                 nodes = s["nodes"]
                 nodes = dict(
@@ -755,6 +788,15 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                                          floor_w0)
 
                 out = dict(s)
+                if not batched:
+                    # ---- partition (shared decode with the strict
+                    # grower) ----
+                    go_left = split_go_left(spec, feat, bins_fm,
+                                            decode_bins, f, t, dl,
+                                            node_cat, node_mask)
+                    out["leaf_id"] = jnp.where(
+                        (s["leaf_id"] == best) & ~go_left, new,
+                        s["leaf_id"])
                 if track_used:
                     # both children share the path's used set ∪ {f}
                     child_used = s["leaf_used"][best].at[f].set(True)
@@ -763,7 +805,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 if n_forced:
                     out["forced_n"] = forced_n_new
                 out.update(
-                    step=step + 1, nl=new + 1, leaf_id=leaf_id,
+                    step=step + 1, nl=new + 1,
                     nodes=nodes, w=s["w"] + 1,
                     g_floor=jnp.where(s["w"] == 0, floor_w0,
                                       s["g_floor"]),
@@ -805,6 +847,20 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 # the tail's loop condition IS icond at w == 0: one pick
                 s1 = ibody(istate) if strict else \
                     jax.lax.while_loop(icond, ibody, istate)
+                # a slot whose pick was not applied (the wave ended, a
+                # forced split failed) kept the pad leaf LB: no rows
+                live = s1["p_left"] < LB
+                picks = jnp.sum(live, dtype=jnp.int32)
+                if batched:
+                    at = s1["p_step"]
+                    s1["leaf_id"] = route_picks(
+                        st["leaf_id"], live, s1["p_left"],
+                        s1["nodes"]["split_feature"][at],
+                        s1["nodes"]["threshold_bin"][at],
+                        s1["nodes"]["default_left"][at],
+                        s1["p_left"], s1["p_new"])
+                routed = jnp.stack([jnp.int32(1) if batched else picks,
+                                    picks])
 
             def hist_and_find(_):
                 # ---- histogram phase: ONE batched pass for all smaller
@@ -869,6 +925,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 s1["step"] >= LB - 1, tree_full, hist_and_find, None)
 
             new_state = {**st, **{k: s1[k] for k in carry_keys}}
+            new_state["leaf_id"] = s1["leaf_id"]
+            new_state["route_stats"] = st["route_stats"] + routed
             if not strict:
                 new_state["wave_passes"] = st["wave_passes"] + \
                     (s1["step"] < LB - 1).astype(jnp.int32)
@@ -894,24 +952,36 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 top_gain, top_leaf = jax.lax.top_k(
                     jnp.where(st["spec_ok"], NEG_INF, st["leaf_gain"]), W)
                 chosen = top_gain > 0.0
+                small_is_left = st["leaf_lc"][top_leaf] \
+                    <= st["leaf_rc"][top_leaf]
 
-                def fill_slot(k, slot_of_row):
-                    # the partition's own routing and the pick's own
-                    # smaller-side rule, under the leaf's stored split
-                    lf = top_leaf[k]
-                    go_left = split_go_left(
-                        spec, feat, bins_fm, decode_bins,
-                        st["leaf_feat"][lf], st["leaf_thr"][lf],
-                        st["leaf_dl"][lf], st["leaf_iscat"][lf],
-                        st["leaf_catmask"][lf])
-                    small_is_left = st["leaf_lc"][lf] <= st["leaf_rc"][lf]
-                    return jnp.where(
-                        chosen[k] & (st["leaf_id"] == lf)
-                        & (go_left == small_is_left), k, slot_of_row)
+                if batch_route:
+                    # the wave's pass with another value a side: slot k
+                    # on the smaller side, no slot elsewhere
+                    k = jnp.arange(W, dtype=jnp.int32)
+                    slot_of_row = route_picks(
+                        st["leaf_id"], chosen, top_leaf,
+                        st["leaf_feat"][top_leaf], st["leaf_thr"][top_leaf],
+                        st["leaf_dl"][top_leaf],
+                        jnp.where(small_is_left, k, -1),
+                        jnp.where(small_is_left, -1, k), fill=-1)
+                else:
+                    def fill_slot(k, slot_of_row):
+                        # the partition's own routing and the pick's own
+                        # smaller-side rule, under the leaf's stored split
+                        lf = top_leaf[k]
+                        go_left = split_go_left(
+                            spec, feat, bins_fm, decode_bins,
+                            st["leaf_feat"][lf], st["leaf_thr"][lf],
+                            st["leaf_dl"][lf], st["leaf_iscat"][lf],
+                            st["leaf_catmask"][lf])
+                        return jnp.where(
+                            chosen[k] & (st["leaf_id"] == lf)
+                            & (go_left == small_is_left[k]), k, slot_of_row)
 
-                # one slot at a time: one [N] routing mask alive, not W
-                slot_of_row = jax.lax.fori_loop(
-                    0, W, fill_slot, jnp.full((N,), -1, jnp.int32))
+                    # one slot at a time: one [N] routing mask alive, not W
+                    slot_of_row = jax.lax.fori_loop(
+                        0, W, fill_slot, jnp.full((N,), -1, jnp.int32))
             small_h, calls = hist_multi(slot_of_row,
                                         jnp.arange(W, dtype=jnp.int32))
             with jax.named_scope("hist_cache"):
@@ -942,8 +1012,13 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             # copies the [N] ids every split
             leaf_id, spec_hist = jax.lax.optimization_barrier(
                 (st["leaf_id"], spec_hist))
+            # a speculating pass routes W slots: in one pass, or in W
+            spec_routed = miss.astype(jnp.int32) * jnp.array(
+                [1 if batch_route else W, W], jnp.int32)
             new_state = body({**st, "leaf_id": leaf_id,
-                              "hist_calls": st["hist_calls"] + calls},
+                              "hist_calls": st["hist_calls"] + calls,
+                              "route_stats": st["route_stats"]
+                              + spec_routed},
                              small_hists=spec_hist)
             # the split leaf's two children are new leaves with no entry
             new_state["spec_hist"] = spec_hist
@@ -964,7 +1039,8 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 jnp.sum(st["spec_ok"], dtype=jnp.int32))
         else:
             tail_stats = jnp.zeros((4,), jnp.int32)
-        tail_stats = jnp.concatenate([tail_stats, st["wave_passes"][None]])
+        tail_stats = jnp.concatenate([tail_stats, st["wave_passes"][None],
+                                      st["route_stats"]])
         hist_calls = None
         if spec.hist_impl == "pallas":
             # and the 128-row groups those calls contracted (int32 holds

@@ -64,7 +64,8 @@ class Tree:
         # growth record of a wave-grown tree (DeviceTree.tail_stats):
         # the strict tail's histogram passes, splits served from a
         # speculated histogram, speculated histograms unused and made,
-        # and the waves' histogram passes
+        # the waves' histogram passes, the routing passes over the rows
+        # and the picks and slots those routed
         self.tail_stats = None
         # the f32 Pallas kernel's calls for this tree by the body that
         # ran, then the 128-row groups they contracted

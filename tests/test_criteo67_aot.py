@@ -25,6 +25,8 @@ from perfbench import manifest
 from perfbench.generators import tabular_codes
 from perfbench.jobs.train import build_dataset
 
+from aot_common import row_array_copies, tpu_kernels
+
 CONFIG = "criteo67-lgbpar-l255"
 HBM_BYTES = 16 * 2 ** 30
 CHIPS = 4           # of the described v5e:2x2 host
@@ -94,3 +96,8 @@ def test_sharded_grower_compiles_at_cell_size(topo):
                       "output": mem.output_size_in_bytes,
                       "temp": mem.temp_size_in_bytes, "total": total}))
     assert total < HBM_BYTES
+    # a shard routes a wave's picks and a speculation's slots in one pass
+    # each (68 columns a shard), and rewrites its ids in place
+    text = compiled.as_text()
+    assert tpu_kernels(text).count("route_wave_rows") == 2
+    assert row_array_copies(text, n // CHIPS) == []

@@ -26,11 +26,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 import lightgbm_tpu as lgb
 from lightgbm_tpu.booster import Booster
 from lightgbm_tpu.ops import pallas_hist as ph
+from lightgbm_tpu.ops import route as rt
 from lightgbm_tpu.ops.fused import make_bulk_trainer
 
 sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmarks"))
 import configs_r4  # noqa: E402
+from aot_common import row_array_copies, tpu_kernels  # noqa: E402
 
 pytestmark = pytest.mark.slow
 
@@ -107,13 +109,56 @@ def test_hist_kernel_f32_compacting_bodies(topo, width, body):
         sds((13, N), jnp.uint8), sds((9, N), jnp.float32),
         sds((N,), jnp.int32), sds((width,), jnp.int32), 255,
         plan=plan, body=body, count_bodies=True).compile().as_text()
-    kernels = [line.split("=")[0].strip().lstrip("%").rsplit(".", 1)[0]
-               for line in text.splitlines()
-               if "custom-call(" in line and "tpu_custom_call" in line]
-    assert sorted(kernels) == (
+    assert sorted(tpu_kernels(text)) == (
         ["pallas_histogram_multi_rows"] if body else
         ["pallas_histogram_multi_rows_" + name
          for name, _ in sorted(ph.hist_bodies())])
+
+
+@pytest.mark.parametrize("fill", [None, -1], ids=["leaf_id", "slot_of_row"])
+@pytest.mark.parametrize("shape", [(13, 83_886_080), (68, 50_331_648)],
+                         ids=["lgbexp", "criteo67_shard"])
+def test_route_wave_rows_compiles_at_cell_size(topo, shape, fill):
+    """The wave's routing pass at the cells' real columns and rows.  Its
+    custom-call is NOT named `pallas_histogram*`: the benchmark's readers
+    select the histogram kernel by that prefix, and the routing belongs
+    to `grower.other_pct`."""
+    sds, _ = _one(topo)
+    f, n = shape
+    compiled = rt.route_wave_rows.lower(
+        sds((f, n), jnp.uint8), sds((n,), jnp.int32),
+        sds((8, rt.REC_FIELDS), jnp.int32), fill=fill).compile()
+    assert tpu_kernels(compiled.as_text()) == ["route_wave_rows"]
+    # no [8, N] operand or result of the one-hot product reaches HBM
+    assert compiled.memory_analysis().temp_size_in_bytes < 4 * n
+
+
+@pytest.mark.parametrize("name", ["airline13-l31", "airline13-lgbexp-l255"])
+def test_wave_grower_routes_in_place_at_cell_size(topo, name):
+    """`jit_grow` of the one-chip configurations at full size: the wave
+    body and the speculation route through `route_wave_rows`, and no
+    array over all rows is copied in HBM anywhere in the program (the
+    ids are rewritten in place: by the pass in a wave, by the pick behind
+    the barrier in the tail)."""
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), "perfbench"))
+    from test_perfbench_aot import grower_for_chip
+    from perfbench import manifest
+    sds, _ = _one(topo)
+    config = manifest.config(name)
+    bst = grower_for_chip(config)
+    n = int(config["train_rows"])
+    n_feat = len(config["data"]["columns"])
+    feat = jax.tree.map(lambda a: sds(np.shape(a), a.dtype), bst._feat)
+    text = bst._grower.lower(
+        sds((n_feat, n), jnp.uint8), sds((n,), jnp.float32),
+        sds((n,), jnp.float32), sds((n,), jnp.float32), feat,
+        sds((n_feat,), jnp.bool_)).compile().as_text()
+    kernels = tpu_kernels(text)
+    assert kernels.count("route_wave_rows") == 2
+    assert all(k == "route_wave_rows" or k.startswith("pallas_histogram")
+               for k in kernels)
+    assert row_array_copies(text, n) == []
 
 
 @pytest.mark.parametrize("width", [1, 8])

@@ -35,7 +35,7 @@ from .metrics import Metric, create_metrics
 from .objectives import ObjectiveFunction, create_objective
 from .ops.grow import DeviceTree, GrowerSpec, make_grower
 from .ops.predict import traverse_bins
-from .tree import Tree
+from .tree import K_CATEGORICAL_MASK, Tree
 from .utils import log
 from .utils.binning import BIN_TYPE_CATEGORICAL
 from .utils.config import Config
@@ -277,7 +277,10 @@ def _count_growth(tree: Tree, reduce_bytes: int = 0) -> None:
     and speculated ones left unused: passes a tree = 1 + waves + tail;
     the routing passes over the rows (`grow.route_passes`: one a wave and
     a speculation where `ops/route.py` serves, else one a pick and a
-    slot) and the picks and slots they routed (`grow.route_picks`);
+    slot) and the picks and slots they routed (`grow.route_picks`; with
+    categorical columns `grow.route_cat_picks` of them categorical);
+    its categorical splits and the bins in their left sets
+    (`grow.cat_splits`, `grow.cat_left_bins`);
     on the f32 Pallas kernel, its calls by the body that ran
     (`grow.hist_passes_full`, `grow.hist_passes_c<capacity>`, summed over
     the shards of a mesh) and the rows they contracted: needed /
@@ -289,9 +292,12 @@ def _count_growth(tree: Tree, reduce_bytes: int = 0) -> None:
     counter = telemetry.REGISTRY.counter
     counter("grow.hist_rows_needed").inc(tree.hist_rows_needed())
     counter("grow.leaves").inc(tree.num_leaves)
+    counter("grow.cat_splits").inc(tree.num_cat)
+    counter("grow.cat_left_bins").inc(int(tree.cat_bin_masks.sum()))
     if tree.tail_stats is not None:
-        passes, hits, unused, _, waves, route_passes, route_picks = \
-            tree.tail_stats
+        passes, hits, unused, _, waves, route_passes, route_picks, \
+            *cat_picks = tree.tail_stats
+        counter("grow.route_cat_picks").inc(sum(cat_picks))
         counter("grow.tail_passes").inc(passes)
         counter("grow.tail_spec_hits").inc(hits)
         counter("grow.tail_spec_unused").inc(unused)
@@ -551,6 +557,7 @@ class Booster:
             cat_l2=self.config.cat_l2,
             max_cat_threshold=self.config.max_cat_threshold,
             max_cat_to_onehot=self.config.max_cat_to_onehot,
+            min_data_per_group=float(self.config.min_data_per_group),
             hist_impl=self._resolve_hist_impl(),
             hist_interpret=bool(self.config.hist_interpret),
             bundled=self._dd.efb is not None,
@@ -3129,12 +3136,18 @@ class Booster:
                         "leaf_value": float(t.leaf_value[leaf]),
                         "leaf_weight": float(t.leaf_weight[leaf]),
                         "leaf_count": int(t.leaf_count[leaf])}
+            if t.decision_type[node] & K_CATEGORICAL_MASK:
+                # ref: Tree::NodeToJSON
+                kind, threshold = "==", "||".join(
+                    str(c) for c in t.left_categories(node))
+            else:
+                kind, threshold = "<=", float(t.threshold[node])
             return {
                 "split_index": int(node),
                 "split_feature": int(t.split_feature[node]),
                 "split_gain": float(t.split_gain[node]),
-                "threshold": float(t.threshold[node]),
-                "decision_type": "<=",
+                "threshold": threshold,
+                "decision_type": kind,
                 "default_left": bool(t.decision_type[node] & 2),
                 "missing_type": ["None", "Zero", "NaN"][
                     (t.decision_type[node] >> 2) & 3],

@@ -65,6 +65,8 @@ class GrowerSpec(NamedTuple):
     cat_l2: float = 10.0
     max_cat_threshold: int = 32
     max_cat_to_onehot: int = 4
+    # categorical group gate (ops/split.py; upstream's default)
+    min_data_per_group: float = 100.0
     hist_impl: str = "segment_sum"  # or "pallas" (ops/pallas_hist.py)
     # EFB (ref: dataset.cpp FindGroups / feature_group.h): bins_fm holds
     # BUNDLE columns [G, N]; histograms are built per bundle and expanded
@@ -188,11 +190,13 @@ class DeviceTree(NamedTuple):
     leaf_h: Array         # [L] f32
     leaf_cnt: Array       # [L] f32
     leaf_id: Array        # [N] i32 — final row→leaf assignment (train rows)
-    # [7] i32, wave grower only (None elsewhere) — its strict tail's
-    # histogram passes, splits served from a speculated histogram,
-    # speculated histograms never used / made, the histogram passes
-    # of the waves before the tail, and the routing passes over the rows
-    # with the picks and slots they routed (ops/grow_wave.py)
+    # [7] i32 ([8] with categorical columns), wave grower only (None
+    # elsewhere) — its strict tail's histogram passes, splits served from
+    # a speculated histogram, speculated histograms never used / made, the
+    # histogram passes of the waves before the tail, and the routing
+    # passes over the rows with the picks and slots they routed and, with
+    # categorical columns, how many of those were categorical
+    # (ops/grow_wave.py)
     tail_stats: Array = None
     # [len(pallas_hist.hist_bodies()) + 1] i32, wave grower on the f32
     # Pallas kernel only (None elsewhere) — the kernel's calls by the
@@ -489,6 +493,7 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
         cat_smooth=spec.cat_smooth, cat_l2=spec.cat_l2,
         max_cat_threshold=spec.max_cat_threshold,
         max_cat_to_onehot=spec.max_cat_to_onehot,
+        min_data_per_group=spec.min_data_per_group,
         path_smooth=spec.path_smooth, has_cat=spec.has_cat)
     # voting: local votes use the shard's row subset, so size constraints
     # scale by 1/shards (ref: VotingParallelTreeLearner ctor divides
@@ -503,6 +508,7 @@ def make_grower(spec: GrowerSpec, axis_name: str = None, mode: str = "data",
         cat_smooth=spec.cat_smooth, cat_l2=spec.cat_l2,
         max_cat_threshold=spec.max_cat_threshold,
         max_cat_to_onehot=spec.max_cat_to_onehot,
+        min_data_per_group=spec.min_data_per_group,
         path_smooth=spec.path_smooth, want_feature_gains=True,
         has_cat=spec.has_cat)
 

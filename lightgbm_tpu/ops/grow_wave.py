@@ -82,13 +82,19 @@ the per-leaf tables only, so the loop carries no [N] array and one pass
 of `ops/route.py` (`route_wave_rows`, a Pallas call of that name on the
 kernel families; (F + 8) bytes a row for up to W picks) applies the
 recorded picks to `leaf_id`, and the same pass writes a speculation's
-slot ids.  The strict tail's single pick routes itself by
-`split_go_left` and a `leaf_id` rewrite, one bin row for all; so does
-every pick of a bundled or categorical spec, of two-byte bins and of
+slot ids.  A model with categorical columns hands every pick to the pass
+as its left set over the bins (`route.left_sets`: `bin_goes_left` on all
+256 bins), so a category-set split is one more record of the same pass.
+The strict tail's single pick routes itself by `split_go_left` and a
+`leaf_id` rewrite, one bin row for all, where the model has no
+categorical column (with one it goes through the pass too: a look-up in
+the pass costs a hundredth of a gather in the pick's mask at [N]); so does
+every pick of a bundled spec, of two-byte bins and of
 more than `route.ROUTE_MAX_COLUMNS` columns, whose routing is another
 computation or whose all-column pass costs more than a row a pick:
-static facts, no option.  `DeviceTree.tail_stats[5:7]` count the routing
-passes and the picks and slots they routed), `hist_cache` (sibling subtraction,
+static facts, no option.  `DeviceTree.tail_stats[5:]` count the routing
+passes, the picks and slots they routed and, with categorical columns,
+how many of those were categorical), `hist_cache` (sibling subtraction,
 the two cache scatters, the speculated histograms' reads and scatters),
 `prune` (`prune_wave_tail`, only with overgrow).  An
 op's phase is the innermost of these on its name stack; what is under
@@ -112,8 +118,8 @@ from .grow import (DeviceTree, GrowerSpec, _split_to_arrays,
                    make_feature_blocks, make_node_samplers,
                    rebase_and_merge_block_split, split_go_left)
 from ..analysis.contracts import contract
-from .route import (batched_route_applies, pick_records, route_rows_xla,
-                    route_wave_rows)
+from .route import (batched_route_applies, left_sets, pick_records,
+                    route_rows_xla, route_wave_rows)
 from .histogram import (hist_stream_finalize, hist_stream_init,
                         hist_stream_packed_finalize,
                         hist_stream_packed_init,
@@ -203,6 +209,7 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
         cat_smooth=spec.cat_smooth, cat_l2=spec.cat_l2,
         max_cat_threshold=spec.max_cat_threshold,
         max_cat_to_onehot=spec.max_cat_to_onehot,
+        min_data_per_group=spec.min_data_per_group,
         path_smooth=spec.path_smooth, has_cat=spec.has_cat)
 
     def clamp_output(g, h):
@@ -263,21 +270,27 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             decode_bins = None
         # one routing pass a wave and a speculation (`route_picks`), or
         # one a pick: static facts of the spec and of the bins
-        batch_route = not spec.bundled and not spec.has_cat \
-            and batched_route_applies(bins_fm)
+        batch_route = not spec.bundled and batched_route_applies(bins_fm)
 
-        def route_picks(leaf_id, live, leaf, f, t, dl, if_left, if_right,
-                        fill=None):
+        def route_picks(leaf_id, live, leaf, f, t, dl, cat, if_left,
+                        if_right, fill=None):
             """[N]: the rows of each live pick's leaf take the pick's
             value of their side under its split (column `f`, threshold
             `t`, `dl`), the ONE rule `split.bin_goes_left`; every other
-            row `fill` (None: its own `leaf_id`).  [W] arrays a field."""
+            row `fill` (None: its own `leaf_id`).  [W] arrays a field.
+            `cat`: the picks' (`is_cat`, left bins `cat_mask`) where the
+            model has categorical columns, and then every pick is handed
+            over as its left set; else None."""
             rec = pick_records(live, leaf, f, t, dl, feat["nb"],
                                feat["missing"], if_left, if_right)
+            sets = None if cat is None else left_sets(
+                f, t, dl, feat["nb"], feat["missing"], *cat)
             if spec.hist_impl in ("pallas", "pallas_q"):
                 return route_wave_rows(bins_fm, leaf_id, rec, fill=fill,
-                                       interpret=spec.hist_interpret)
-            return route_rows_xla(bins_fm, leaf_id, rec, fill=fill)
+                                       interpret=spec.hist_interpret,
+                                       sets=sets)
+            return route_rows_xla(bins_fm, leaf_id, rec, fill=fill,
+                                  sets=sets)
 
         # the kernel payload carrier is loop-INVARIANT: prepare it once
         # per tree here, not inside every wave's while_loop body (XLA's
@@ -574,8 +587,11 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 # kernel calls by body so far (DeviceTree.hist_calls)
                 hist_calls=root_calls,
                 # routing passes over the rows, and the picks and slots
-                # they routed (the last two of DeviceTree.tail_stats)
-                route_stats=jnp.zeros((2,), jnp.int32),
+                # they routed (the last of DeviceTree.tail_stats); with
+                # categorical columns also how many of those were
+                # categorical
+                route_stats=jnp.zeros((3 if spec.has_cat else 2,),
+                                      jnp.int32),
             )
             if track_used:
                 state["leaf_used"] = jnp.zeros((LB, F), bool)
@@ -623,8 +639,10 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             # a wave's picks are distinct leaves that were READY at its
             # start and its choices read the per-leaf tables only: the
             # loop records them and ONE pass routes their rows after it.
-            # The tail's single pick routes itself (one bin row for all)
-            batched = batch_route and not strict
+            # The tail's single pick routes itself (one bin row for all),
+            # unless it may be categorical: its look-up in the pass costs
+            # a hundredth of a gather in its mask at [N]
+            batched = batch_route and (spec.has_cat or not strict)
             # ---- split phase: best-first among READY leaves (leaves
             # created this wave have no histogram yet and wait for the
             # next wave), up to the batch capacity W ----
@@ -851,16 +869,23 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 # forced split failed) kept the pad leaf LB: no rows
                 live = s1["p_left"] < LB
                 picks = jnp.sum(live, dtype=jnp.int32)
+                at = s1["p_step"]
                 if batched:
-                    at = s1["p_step"]
                     s1["leaf_id"] = route_picks(
                         st["leaf_id"], live, s1["p_left"],
                         s1["nodes"]["split_feature"][at],
                         s1["nodes"]["threshold_bin"][at],
                         s1["nodes"]["default_left"][at],
+                        (s1["nodes"]["split_is_cat"][at],
+                         s1["nodes"]["split_cat_mask"][at])
+                        if spec.has_cat else None,
                         s1["p_left"], s1["p_new"])
-                routed = jnp.stack([jnp.int32(1) if batched else picks,
-                                    picks])
+                routed = [jnp.int32(1) if batched else picks, picks]
+                if spec.has_cat:
+                    routed.append(jnp.sum(
+                        live & s1["nodes"]["split_is_cat"][at],
+                        dtype=jnp.int32))
+                routed = jnp.stack(routed)
 
             def hist_and_find(_):
                 # ---- histogram phase: ONE batched pass for all smaller
@@ -963,6 +988,9 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                         st["leaf_id"], chosen, top_leaf,
                         st["leaf_feat"][top_leaf], st["leaf_thr"][top_leaf],
                         st["leaf_dl"][top_leaf],
+                        (st["leaf_iscat"][top_leaf],
+                         st["leaf_catmask"][top_leaf])
+                        if spec.has_cat else None,
                         jnp.where(small_is_left, k, -1),
                         jnp.where(small_is_left, -1, k), fill=-1)
                 else:
@@ -986,9 +1014,13 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                                         jnp.arange(W, dtype=jnp.int32))
             with jax.named_scope("hist_cache"):
                 dst = jnp.where(chosen, top_leaf, LB)
+                # (a model without categorical columns counts none: it
+                # runs the program it ran before the count existed)
+                n_cat = (jnp.sum(chosen & st["leaf_iscat"][top_leaf],
+                                 dtype=jnp.int32),) if spec.has_cat else ()
                 return (st["spec_hist"].at[dst].set(small_h, mode="drop"),
                         st["spec_ok"].at[dst].set(True, mode="drop"),
-                        jnp.sum(chosen, dtype=jnp.int32), calls)
+                        jnp.sum(chosen, dtype=jnp.int32), calls) + n_cat
 
         def tail_body(st):
             """One split of the strict tail: a histogram pass only if
@@ -1002,10 +1034,10 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
                 fills = st["step"] + 1 >= LB - 1
                 held = st["spec_ok"][best]
                 miss = ~held & ~fills
-            spec_hist, spec_ok, n_spec, calls = jax.lax.cond(
+            spec_hist, spec_ok, n_spec, calls, *n_cat = jax.lax.cond(
                 miss, speculate,
                 lambda st: (st["spec_hist"], st["spec_ok"], jnp.int32(0),
-                            no_calls),
+                            no_calls) + (jnp.int32(0),) * spec.has_cat,
                 st)
             # the pick rewrites `leaf_id`, which the pass reads: tie the
             # pick behind the pass, or XLA keeps both orders open and
@@ -1015,6 +1047,9 @@ def make_wave_grower(spec: GrowerSpec, axis_name=None, mode: str = "data",
             # a speculating pass routes W slots: in one pass, or in W
             spec_routed = miss.astype(jnp.int32) * jnp.array(
                 [1 if batch_route else W, W], jnp.int32)
+            if spec.has_cat:
+                spec_routed = jnp.concatenate([spec_routed,
+                                               n_cat[0][None]])
             new_state = body({**st, "leaf_id": leaf_id,
                               "hist_calls": st["hist_calls"] + calls,
                               "route_stats": st["route_stats"]

@@ -19,9 +19,21 @@ matching `SplitInfo` deterministic tie-break order) picks the winner:
   case 1: numerical, missing left       case 4: categorical desc-prefix
   case 2: categorical one-vs-rest
 
-Categorical deviation from the reference: bin 0 (this build's "other/rare +
+The categorical candidate set is upstream's (`FindBestThresholdCategorical
+Inner`): a bin is admitted with at least `cat_smooth` rows; at most
+`max_cat_to_onehot` admitted bins are tried one against the rest, more are
+sorted by `sum_gradient / (sum_hessian + cat_smooth)` and the prefixes of
+1 .. min(`max_cat_threshold`, (admitted + 1) // 2) bins are tried from each
+end; walking a direction, a prefix is a candidate only where both sides
+pass the leaf gates, the right side keeps `min_data_per_group` rows and the
+rows gained since the last candidate are at least `min_data_per_group`
+(the count starts again after each candidate).  Stated departures
+(COVERAGE.md, `perfbench/configs/airline13-lgbcat-l255.json`): the
+histogram's exact row counts stand where upstream estimates a count from
+the hessian sum; a one-vs-rest candidate's gain carries `cat_l2` too;
+leaf values use `lambda_l2` alone; and bin 0 (this build's "other/rare +
 missing" categorical bin) is never placed in the left subset, so unseen
-categories and NaN always route right — which keeps bin-level training
+categories and NaN always route right, which keeps bin-level training
 decisions and raw-value bitset prediction exactly consistent.
 """
 from __future__ import annotations
@@ -123,7 +135,7 @@ def plain_split_gain(left: Array, right: Array, l1: float, l2_eff: float,
           min_gain_to_split="static",
           cat_smooth="static", cat_l2="static",
           max_cat_threshold="static int", max_cat_to_onehot="static int",
-          max_delta_step="static",
+          max_delta_step="static", min_data_per_group="static",
           mono="[F] int?", out_lb="[] float?", out_ub="[] float?",
           path_smooth="static",
           parent_output="[] float?",
@@ -148,7 +160,8 @@ def find_best_split(hist: Array,
                     cand_mask: Array = None,
                     gain_penalty: Array = None,
                     want_feature_gains: bool = False,
-                    has_cat: bool = True):
+                    has_cat: bool = True,
+                    min_data_per_group: float = 0.0):
     """Best split over all features of one leaf (numerical + categorical).
 
     `mono` [F] in {-1, 0, +1} plus scalar leaf output bounds [out_lb, out_ub]
@@ -169,6 +182,9 @@ def find_best_split(hist: Array,
     skips the categorical cases entirely — four [F, MB] argsorts plus
     three gain grids per call; callers with a static feature inventory
     (the growers) thread it from their spec.
+
+    `min_data_per_group` (static) is the categorical group gate of the
+    module docstring; 0 gates nothing.
     """
     F, MB, _ = hist.shape
     bin_ar = jnp.arange(MB, dtype=jnp.int32)
@@ -270,9 +286,10 @@ def find_best_split(hist: Array,
         g = jnp.where(cat_bounded, cg, plain)
         return jnp.where(valid & constraints_ok(left, right), g, NEG_INF)
     cnt = h[..., 2]
-    # bin 0 = other/missing bin: never in the left subset (see docstring)
-    cat_valid = (bin_ar[None, :] >= 1) & valid_bin & (cnt > 0) \
-        & cat_ok[:, None]                                        # [F, MB]
+    # bin 0 = other/missing bin: never in the left subset (see docstring);
+    # a bin is admitted with at least cat_smooth rows
+    cat_valid = (bin_ar[None, :] >= 1) & valid_bin & (cnt >= cat_smooth) \
+        & (cnt > 0) & cat_ok[:, None]                            # [F, MB]
     used = cat_valid.sum(axis=1)                                 # [F]
 
     # case 2: one-vs-rest (used <= max_cat_to_onehot)
@@ -283,24 +300,45 @@ def find_best_split(hist: Array,
 
     # cases 3/4: sorted many-vs-rest (used > max_cat_to_onehot)
     # ref: FindBestThresholdCategorical sorts by sum_grad/(sum_hess+cat_smooth)
-    ratio = jnp.where(cat_valid,
-                      h[..., 0] / (h[..., 1] + cat_smooth), jnp.inf)
-    order_asc = jnp.argsort(ratio, axis=1)                       # [F, MB]
-    ratio_desc = jnp.where(cat_valid, ratio, -jnp.inf)
-    order_desc = jnp.argsort(-ratio_desc, axis=1)
+    k_max = jnp.minimum(max_cat_threshold, (used + 1) // 2)      # [F]
+    group_steps = min(int(max_cat_threshold), MB)
+
+    def group_gate(rows_gained, eligible):
+        """[F, MB] bool: the eligible prefixes that are candidates.  Rows
+        gained are counted since the last candidate, along the sorted
+        order; only the first `max_cat_threshold` prefixes can be one."""
+        def step(group, x):
+            n_k, e_k = x
+            group = group + n_k
+            cand = e_k & (group >= min_data_per_group)
+            return jnp.where(cand, 0.0, group), cand
+
+        _, cand = jax.lax.scan(
+            step, jnp.zeros((F,), rows_gained.dtype),
+            (rows_gained[:, :group_steps].T, eligible[:, :group_steps].T))
+        return jnp.zeros((F, MB), bool).at[:, :group_steps].set(cand.T)
 
     def prefix_gains(order):
         hs = jnp.take_along_axis(h, order[..., None], axis=1)
         cumk = jnp.cumsum(hs, axis=1)       # prefix of k = t+1 sorted bins
         k = bin_ar[None, :] + 1
-        okk = (k <= max_cat_threshold) & (k < used[:, None]) \
+        okk = (k <= k_max[:, None]) \
             & (used[:, None] > max_cat_to_onehot) & cat_ok[:, None]
         right = parent[None, None, :] - cumk
+        if min_data_per_group > 0:
+            okk = group_gate(hs[..., 2], okk & constraints_ok(cumk, right)
+                             & (right[..., 2] >= min_data_per_group))
         g = cat_gain(cumk, right, okk)
         return g, cumk
 
-    gain3, cum3 = prefix_gains(order_asc)
-    gain4, cum4 = prefix_gains(order_desc)
+    with jax.named_scope("cat_scan"):
+        ratio = jnp.where(cat_valid,
+                          h[..., 0] / (h[..., 1] + cat_smooth), jnp.inf)
+        order_asc = jnp.argsort(ratio, axis=1)                   # [F, MB]
+        ratio_desc = jnp.where(cat_valid, ratio, -jnp.inf)
+        order_desc = jnp.argsort(-ratio_desc, axis=1)
+        gain3, cum3 = prefix_gains(order_asc)
+        gain4, cum4 = prefix_gains(order_desc)
 
     # ------------------------------------------------------------- decide
     gains = jnp.stack([gain0, gain1, gain2, gain3, gain4])       # [5, F, MB]

@@ -196,6 +196,7 @@ class StreamingWaveGrower:
             cat_smooth=spec.cat_smooth, cat_l2=spec.cat_l2,
             max_cat_threshold=spec.max_cat_threshold,
             max_cat_to_onehot=spec.max_cat_to_onehot,
+            min_data_per_group=spec.min_data_per_group,
             path_smooth=spec.path_smooth, has_cat=spec.has_cat)
 
         def split_of(hist, g, h, c, node_allowed, lb, ub, p_out, nid,

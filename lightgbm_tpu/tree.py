@@ -498,6 +498,14 @@ class Tree:
     def num_internal(self) -> int:
         return max(self.num_leaves - 1, 0)
 
+    def left_categories(self, node: int) -> np.ndarray:
+        """The category VALUES a categorical node sends left, ascending,
+        read from its bitset (ref: tree.h cat_boundaries_/cat_threshold_)."""
+        lo, hi = self.cat_boundaries[int(self.threshold[node]):][:2]
+        words = np.ascontiguousarray(self.cat_threshold[lo:hi], "<u4")
+        return np.nonzero(np.unpackbits(words.view(np.uint8),
+                                        bitorder="little"))[0]
+
     def feature_importance_split(self, out: np.ndarray) -> None:
         for f in self.split_feature[:self.num_internal()]:
             out[f] += 1

@@ -75,6 +75,14 @@ _PARAMS: Dict[str, Tuple[Any, str, Tuple[str, ...]]] = {
     "drop_seed": (4, "int", ()),
     "top_rate": (0.2, "float", ()),
     "other_rate": (0.1, "float", ()),
+    # the categorical search (ops/split.py, its docstring): a sorted prefix
+    # is a candidate only where it gained min_data_per_group rows since the
+    # last candidate and the right side keeps as many (binds since PR 35);
+    # a bin is admitted with cat_smooth rows; at most min(max_cat_threshold,
+    # (admitted + 1) // 2) bins a left set.  Departures from upstream: exact
+    # row counts where it estimates them from the hessian sum, cat_l2 also
+    # in a one-vs-rest gain, leaf values with lambda_l2 alone, bin 0
+    # ("other" + missing) never left
     "min_data_per_group": (100, "int", ()),
     "max_cat_threshold": (32, "int", ()),
     "cat_l2": (10.0, "float", ()),

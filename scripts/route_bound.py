@@ -13,6 +13,14 @@ rows, routed by:
   c       `ops/route.route_rows_xla`, the same in plain `jax.numpy`, the
           eight picks unrolled into what XLA makes of them
   a_spec / b_spec / c_spec   the speculation's `slot_of_row` (default -1)
+  a_cat   ISSUE 35's step 0: five of the eight picks categorical (left
+          sets of 25 of 254 bins), today's pick loop, whose categorical
+          picks gather `cat_mask[bins]` at [N]
+  b_cat / c_cat   the same picks as records with their left sets
+          (`ops/route.left_sets`) through the Pallas pass / the XLA unroll
+          (PR 35 also timed the pass with a left set as a 128-lane row
+          gathered along the lanes, 7.6 ms, and as a 0/1 table contracted
+          on the MXU, 64 ms, for the kept selects' 6.3 ms: PERF.md 6)
 
 One JSON line a form: ms a pass (median of `--reps` after a warm-up
 call), ps a row, the pass's `(F + 8)`-byte floor at 819 GB/s, and whether
@@ -66,9 +74,22 @@ def make_inputs(f, n, seed=0):
         default_left=rng.randint(0, 2, K).astype(bool),
         new=16 + np.arange(K), small_is_left=rng.randint(0, 2, K) > 0)
     rec["leaf"][5] = 40
+    # ISSUE 35: picks 0, 2, 3, 5, 6 categorical, 25 of the column's bins
+    # (never bin 0) in each left set
+    rec["is_cat"] = np.isin(np.arange(K), (0, 2, 3, 5, 6))
+    rec["cat_mask"] = np.zeros((K, 255), bool)
+    for k in range(K):
+        top = max(int(nb[feat[k]]) - 1, 1)
+        rec["cat_mask"][k, 1 + rng.choice(top, min(25, top),
+                                          replace=False)] = True
     missing = np.where(np.arange(f) % 2 == 0, MISSING_NAN, 0).astype(np.int32)
     return bins, lid, {k: jnp.asarray(v) for k, v in rec.items()}, \
         jnp.asarray(nb), jnp.asarray(missing)
+
+
+def sets_of(r, nb, missing):
+    return rt.left_sets(r["feature"], r["thr"], r["default_left"], nb,
+                        missing, r["is_cat"], r["cat_mask"])
 
 
 def records(r, nb, missing, spec):
@@ -83,12 +104,18 @@ def records(r, nb, missing, spec):
                            r["new"])
 
 
-def form_a(spec):
-    """The grower's per-pick routing as it stands: one pick a loop turn."""
+def form_a(spec, cat=False):
+    """The grower's per-pick routing as it stands: one pick a loop turn
+    (`cat`: as a model with categorical columns routes, the categorical
+    picks by a gather in their mask)."""
     def run(bins, lid, r, nb, missing):
         def go_left(k):
             f = r["feature"][k]
             fbins = jnp.take(bins, f, axis=0).astype(jnp.int32)
+            if cat:
+                return bin_goes_left(fbins, nb[f], missing[f], r["thr"][k],
+                                     r["default_left"][k], r["is_cat"][k],
+                                     r["cat_mask"][k])
             return bin_goes_left(fbins, nb[f], missing[f], r["thr"][k],
                                  r["default_left"][k])
 
@@ -106,18 +133,21 @@ def form_a(spec):
     return run
 
 
-def form_b(spec, interpret):
+def form_b(spec, interpret, cat=False):
     def run(bins, lid, r, nb, missing):
         return rt.route_wave_rows.__wrapped__(
             bins, lid, records(r, nb, missing, spec),
-            fill=-1 if spec else None, interpret=interpret)
+            fill=-1 if spec else None, interpret=interpret,
+            sets=sets_of(r, nb, missing) if cat else None)
     return run
 
 
-def form_c(spec):
+def form_c(spec, cat=False):
     def run(bins, lid, r, nb, missing):
         return rt.route_rows_xla(bins, lid, records(r, nb, missing, spec),
-                                 fill=-1 if spec else None)
+                                 fill=-1 if spec else None,
+                                 sets=sets_of(r, nb, missing) if cat
+                                 else None)
     return run
 
 
@@ -166,6 +196,7 @@ def main():
         ref = {}
         for form in a.forms.split(","):
             spec = form.endswith("_spec")
+            cat = "_cat" in form
             variants = [(None, None)]
             if form[0] == "b":
                 variants = [(t, c) for t in tiles for c in chunks]
@@ -174,8 +205,9 @@ def main():
                 if tile:
                     rt.ROUTE_TILE, rt.ROUTE_CHUNK = tile, chunk
                     line.update(tile=tile, chunk=chunk)
-                fn = {"a": form_a(spec), "b": form_b(spec, a.rehearse),
-                      "c": form_c(spec)}[form[0]]
+                fn = {"a": form_a(spec, cat),
+                      "b": form_b(spec, a.rehearse, cat),
+                      "c": form_c(spec, cat)}[form[0]]
                 try:
                     med, got = timed(fn, bins, lid, rest, a.reps,
                                      donate=not spec)
@@ -183,8 +215,8 @@ def main():
                     line["refused"] = str(e)[:300]
                     print(json.dumps(line), flush=True)
                     continue
-                ref.setdefault(spec, got)
-                equal = bool(np.array_equal(got, ref[spec]))
+                ref.setdefault((spec, cat), got)
+                equal = bool(np.array_equal(got, ref[spec, cat]))
                 ok &= equal
                 line.update(ms_a_pass=med * 1e3, ps_a_row=med / n * 1e12,
                             floor_ms=floor_s * 1e3,

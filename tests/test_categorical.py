@@ -98,3 +98,107 @@ class TestCategorical:
         ds = lgb.Dataset(df, label=y, categorical_feature=["c"])
         bst = lgb.train({"objective": "regression", "verbosity": -1}, ds, 5)
         assert np.isfinite(bst.predict(df)).all()
+
+
+# ------------------------------------------------- dump_model (ISSUE 35)
+def _nodes(tree_info):
+    stack, out = [tree_info["tree_structure"]], []
+    while stack:
+        node = stack.pop()
+        if "split_index" in node:
+            out.append(node)
+            stack += [node["left_child"], node["right_child"]]
+    return out
+
+
+def _predict_by_dump(tree_info, X):
+    """Route raw rows by what the dump states: `==` sends the listed
+    category values left and everything else (unseen, NaN) right."""
+    out = np.zeros(len(X))
+    for r, row in enumerate(X):
+        node = tree_info["tree_structure"]
+        while "split_index" in node:
+            v = row[node["split_feature"]]
+            if node["decision_type"] == "==":
+                left = not np.isnan(v) and int(v) in {
+                    int(c) for c in node["threshold"].split("||")}
+            else:
+                left = v <= node["threshold"]
+            node = node["left_child"] if left else node["right_child"]
+        out[r] += node["leaf_value"]
+    return out
+
+
+def test_dump_model_states_categorical_nodes_as_upstream_does():
+    X, y = make_cat_data()
+    bst = lgb.train({"objective": "regression", "verbosity": -1,
+                     "min_data_in_leaf": 20, "min_data_per_group": 20},
+                    lgb.Dataset(X, label=y, categorical_feature=[0]), 5)
+    dump = bst.dump_model()
+    cat_nodes = 0
+    for t, info in zip(bst.trees, dump["tree_info"]):
+        assert info["num_cat"] == t.num_cat
+        for node in _nodes(info):
+            if node["split_feature"] != 0:
+                assert node["decision_type"] == "<="
+                assert isinstance(node["threshold"], float)
+                continue
+            cat_nodes += 1
+            assert node["decision_type"] == "=="
+            assert node["default_left"] is False
+            cats = [int(c) for c in node["threshold"].split("||")]
+            assert cats == sorted(set(cats)) and 0 <= min(cats)
+            # the node's own bitset, value by value
+            i = int(t.threshold[node["split_index"]])
+            words = t.cat_threshold[t.cat_boundaries[i]:
+                                    t.cat_boundaries[i + 1]]
+            assert cats == [c for c in range(32 * len(words))
+                            if (int(words[c // 32]) >> (c % 32)) & 1]
+    assert cat_nodes == sum(t.num_cat for t in bst.trees) > 0
+    # predict on raw values is routing by the dumped lists, unseen
+    # categories and NaN included
+    Xq = X[:200].copy()
+    Xq[:5, 0] = 99.0
+    Xq[5:10, 0] = np.nan
+    by_dump = sum(_predict_by_dump(info, Xq) for info in dump["tree_info"])
+    np.testing.assert_allclose(bst.predict(Xq), by_dump, rtol=0, atol=1e-6)
+
+
+def test_numerical_dumps_are_what_they_were():
+    """A model without categorical nodes: every node `<=` with the float
+    threshold of `Tree.threshold`, as before `==` existed."""
+    X, y = make_cat_data()
+    bst = lgb.train({"objective": "regression", "verbosity": -1},
+                    lgb.Dataset(X, label=y), 3)
+    for t, info in zip(bst.trees, bst.dump_model()["tree_info"]):
+        nodes = _nodes(info)
+        assert len(nodes) == t.num_leaves - 1
+        for node in nodes:
+            assert node["decision_type"] == "<="
+            assert node["threshold"] == float(
+                t.threshold[node["split_index"]])
+            assert list(node)[:6] == ["split_index", "split_feature",
+                                      "split_gain", "threshold",
+                                      "decision_type", "default_left"]
+
+
+def test_min_data_per_group_binds():
+    """The group gate of the categorical scan: a larger group changes
+    the trees, and no left set gains fewer rows than the gate."""
+    X, y = make_cat_data(n=3000, n_cats=40)
+    ds = lambda: lgb.Dataset(X, label=y, categorical_feature=[0])  # noqa
+    p = {"objective": "regression", "verbosity": -1, "num_leaves": 8,
+         "min_data_in_leaf": 5, "cat_smooth": 1}
+    small = lgb.train(dict(p, min_data_per_group=1), ds(), 3)
+    large = lgb.train(dict(p, min_data_per_group=400), ds(), 3)
+    assert small.model_to_string().split("parameters:")[0] != \
+        large.model_to_string().split("parameters:")[0]
+    for info in large.dump_model()["tree_info"]:
+        for node in _nodes(info):
+            if node["decision_type"] == "==":
+                left = node["left_child"]
+                n_left = left.get("internal_count", left.get("leaf_count"))
+                right = node["right_child"]
+                n_right = right.get("internal_count",
+                                    right.get("leaf_count"))
+                assert n_left >= 400 and n_right >= 400

@@ -205,16 +205,20 @@ def test_the_reference_imports_nothing_of_the_program():
 
 # ----------------------------------------------------------- the manifest
 def test_three_configurations_three_cells_one_on_four_chips():
+    """The benchmark's first three configurations and cells (PR 35
+    appended a fourth, on one chip: tests/test_lgbcat_cell.py)."""
     assert manifest.problems() == []
     b = manifest.benchmark()
-    assert [c["name"] for c in b["configs"]][-1] == CONFIG
-    assert [w["name"] for w in b["workloads"]][-1] == CELL
-    assert [w["chips"] for w in b["workloads"]] == [1, 1, 4]
-    assert len(b["per_layer"]) == 25
+    assert [c["name"] for c in b["configs"]][2] == CONFIG
+    assert [w["name"] for w in b["workloads"]][2] == CELL
+    assert [w["chips"] for w in b["workloads"]] == [1, 1, 4, 1]
+    assert len(b["per_layer"]) == 34
+    assert len(b["per_layer"][:25]) == 25 and all(
+        not m["name"].startswith("cat.") for m in b["per_layer"][:25])
     files = [f for f in os.listdir(os.path.join(manifest.HERE,
                                                 "layer_metrics"))
              if f.endswith(".json")]
-    assert len(files) == 25
+    assert len(files) == 34
 
 
 def test_a_second_four_chip_cell_is_caught_beside_the_benchmarks_own(
@@ -280,7 +284,8 @@ def test_each_new_metric_reads_an_existing_reader_and_lists_both_cells():
     assert len(new) == 9 and all(n.startswith("par4.") for n in new)
     old = {m["name"]: m for m in manifest.layer_metrics(OLD_CELL)
            if "." in m["name"] and m["name"].split(".")[0]
-           not in ("l255", "par4") or m["name"] == "hist_kernel_roofline"}
+           not in ("l255", "par4", "cat")
+           or m["name"] == "hist_kernel_roofline"}
     for name, m in new.items():
         assert m["workloads"] == [CELL, OLD_CELL]
         assert m["moves"] == "train_rounds_per_s"

@@ -85,7 +85,8 @@ def test_two_configurations_two_cells_sixteen_metric_files():
                for m in mine)
     files = [f for f in os.listdir(os.path.join(manifest.HERE,
                                                 "layer_metrics"))
-             if f.endswith(".json") and not f.startswith("par4.")]
+             if f.endswith(".json")
+             and not f.startswith(("par4.", "cat."))]
     assert len(files) == 16
     assert b["workloads"][1]["chips"] == 1
 
@@ -100,7 +101,7 @@ def test_each_new_metric_is_an_old_readers_twin_and_lists_the_new_cell():
     d = os.path.join(manifest.HERE, "layer_metrics")
     new = {m["name"]: m for m in manifest.layer_metrics(CELL)}
     old = {m["name"]: m for m in manifest.layer_metrics(OLD_CELL)
-           if not m["name"].startswith(("l255.", "par4."))}
+           if not m["name"].startswith(("l255.", "par4.", "cat."))}
     assert len(new) == len(old) == 8
     assert set(new) == {"l255." + n for n in old}
     for name, m in new.items():

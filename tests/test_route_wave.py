@@ -18,7 +18,8 @@ from lightgbm_tpu import telemetry
 from lightgbm_tpu.ops import grow_wave
 from lightgbm_tpu.ops import route as rt
 from lightgbm_tpu.ops.grow import GrowerSpec, split_go_left
-from lightgbm_tpu.ops.split import MISSING_NAN, MISSING_NONE, MISSING_ZERO
+from lightgbm_tpu.ops.split import (MISSING_NAN, MISSING_NONE, MISSING_ZERO,
+                                    bin_goes_left)
 
 AIRLINE_NUM_BIN = (22, 12, 31, 7, 255, 255, 29, 255, 255, 255, 255, 255, 2)
 WIDE_NUM_BIN = (255,) * 68
@@ -144,6 +145,127 @@ def test_a_pass_of_several_grid_steps_with_a_short_last_one(monkeypatch):
         np.testing.assert_array_equal(np.asarray(got), want)
 
 
+# ------------------------------------------------ categorical records too
+CAT_SPEC = SPEC._replace(has_cat=True)
+
+
+def _cat_case(num_bin, n, live, seed, cat=(0, 2, 3, 5, 6)):
+    """`_case` with the picks of `cat` categorical: a left set of up to
+    25 of the column's bins, never bin 0."""
+    c = _case(num_bin, n, live, MISSING_NAN, True, seed)
+    rng = np.random.RandomState(seed + 1)
+    nb = np.asarray(num_bin)
+    mask = np.zeros((K, 255), bool)
+    for k, f in enumerate(np.asarray(c["feature"])):
+        top = max(int(nb[f]) - 1, 1)
+        mask[k, 1 + rng.choice(top, min(25, top), replace=False)] = True
+    c["is_cat"] = jnp.asarray(np.isin(np.arange(K), cat))
+    c["cat_mask"] = jnp.asarray(mask)
+    return c
+
+
+def _cat_go_left(c, k):
+    return split_go_left(CAT_SPEC, c["feat"], c["bins"], None,
+                         c["feature"][k], c["thr"][k], c["dl"][k],
+                         c["is_cat"][k], c["cat_mask"][k])
+
+
+def _sets(c):
+    return rt.left_sets(c["feature"], c["thr"], c["dl"], c["feat"]["nb"],
+                        c["feat"]["missing"], c["is_cat"], c["cat_mask"])
+
+
+@pytest.mark.parametrize("speculation", [False, True],
+                         ids=["leaf_id", "slot_of_row"])
+@pytest.mark.parametrize("n,tile", [(100, None), (5000, None),
+                                    (70001, None),
+                                    (3 * 8192 + 2048 + 17, 8192)],
+                         ids=["100", "5000", "70001", "short_last_step"])
+def test_mixed_records_route_as_the_pick_loop(monkeypatch, n, tile,
+                                              speculation):
+    """Numerical and categorical picks in one pass, in both forms,
+    against the per-pick loop whose categorical picks gather
+    `cat_mask[bins]`: the same integers, also where the last grid step is
+    short."""
+    if tile:
+        monkeypatch.setattr(rt, "ROUTE_TILE", tile)
+        monkeypatch.setattr(rt, "ROUTE_CHUNK", 2048)
+    c = _cat_case(AIRLINE_NUM_BIN, n, 8, seed=n % 97)
+    want = jnp.full_like(c["leaf_id"], -1) if speculation else c["leaf_id"]
+    for k in range(K):
+        in_leaf = c["live"][k] & (c["leaf_id"] == c["leaf"][k])
+        if speculation:
+            want = jnp.where(
+                in_leaf & (_cat_go_left(c, k) == c["small_is_left"][k]),
+                k, want)
+        else:
+            want = jnp.where((want == c["leaf"][k]) & c["live"][k]
+                             & ~_cat_go_left(c, k), c["new"][k], want)
+    rec, sets = _records(c, speculation), _sets(c)
+    fill = -1 if speculation else None
+    np.testing.assert_array_equal(
+        np.asarray(rt.route_rows_xla(c["bins"], c["leaf_id"], rec,
+                                     fill=fill, sets=sets)), want)
+    np.testing.assert_array_equal(
+        np.asarray(rt.route_wave_rows.__wrapped__(
+            c["bins"], c["leaf_id"], rec, fill=fill, interpret=True,
+            sets=sets)), want)
+    # the categorical picks moved rows their thresholds would not have
+    plain = rt.route_rows_xla(c["bins"], c["leaf_id"], rec, fill=fill)
+    assert not np.array_equal(np.asarray(plain), np.asarray(want))
+
+
+@pytest.mark.parametrize("missing", [MISSING_NONE, MISSING_ZERO,
+                                     MISSING_NAN],
+                         ids=["none", "zero", "nan"])
+def test_the_pass_and_bin_goes_left_agree_on_all_256_bins(missing):
+    """ONE rule: a table column of every bin 0..255, each record's leaf
+    holding all of them; what the kernel sends left is what
+    `bin_goes_left` sends left, for numerical and categorical records,
+    with the left sets and (numerical records) without."""
+    c = _cat_case((255,) * 4, 256, 8, seed=3)
+    c["feat"]["missing"] = jnp.full((4,), missing, jnp.int32)
+    c["feat"]["nb"] = jnp.asarray([256, 255, 31, 2], jnp.int32)
+    c["bins"] = jnp.tile(jnp.arange(256, dtype=jnp.uint8), (4, 1))
+    c["dl"] = jnp.asarray(np.arange(K) % 2 == 0)
+    every = jnp.arange(256, dtype=jnp.int32)
+    for k in range(K):
+        f = c["feature"][k]
+        c["leaf_id"] = jnp.full((256,), c["leaf"][k], jnp.int32)
+        rec = _records(c, False)
+        for with_sets in (True, False):
+            is_cat = c["is_cat"][k] if with_sets else jnp.bool_(False)
+            want = bin_goes_left(every, c["feat"]["nb"][f],
+                                 c["feat"]["missing"][f], c["thr"][k],
+                                 c["dl"][k], is_cat,
+                                 # bin 255 is beyond a 255-bin mask: in no set
+                                 jnp.pad(c["cat_mask"][k], (0, 1)))
+            got = rt.route_wave_rows.__wrapped__(
+                c["bins"], c["leaf_id"], rec, interpret=True,
+                sets=_sets(c) if with_sets else None)
+            np.testing.assert_array_equal(
+                np.asarray(got) == int(c["leaf"][k]), np.asarray(want))
+
+
+def test_a_numerical_spec_lowers_without_the_left_sets():
+    """No categorical column: the Pallas call has the three operands it
+    had (records, bins, ids), and a fourth only with the left sets."""
+    c = _cat_case(AIRLINE_NUM_BIN, 5000, 8, seed=9)
+    rec = _records(c, False)
+
+    def operands(**kw):
+        jaxpr = jax.make_jaxpr(lambda *a: rt.route_wave_rows.__wrapped__(
+            *a, interpret=True, **kw))(c["bins"], c["leaf_id"], rec)
+        calls = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        assert len(calls) == 1
+        return [v.aval.shape for v in calls[0].invars]
+
+    assert operands() == [(8, rt.REC_FIELDS), (13, 5000), (1, 5000)]
+    assert operands(sets=_sets(c)) == [(8, rt.REC_FIELDS),
+                                       (8, rt.SET_WORDS), (13, 5000),
+                                       (1, 5000)]
+
+
 def test_wide_or_two_byte_bins_keep_the_pick_loop():
     assert rt.batched_route_applies(jnp.zeros((13, 8), jnp.uint8))
     assert rt.batched_route_applies(
@@ -221,35 +343,78 @@ def test_the_pass_grows_the_per_pick_loops_trees(monkeypatch, family):
     jax.clear_caches()
 
 
-@pytest.mark.parametrize("kind", ["bundled", "has_cat"])
-def test_bundled_and_categorical_specs_keep_the_pick_loop(monkeypatch,
-                                                          kind):
-    """Their routing is another computation (`decode_bins`, the
-    `cat_mask[bins]` gather): static flags of the spec, read at trace."""
+def _cat_table(n=3000, seed=2):
+    rng = np.random.RandomState(seed)
+    X = rng.randn(n, 6).astype(np.float32)
+    X[:, 0] = rng.randint(0, 9, n)
+    y = ((X[:, 0] % 3 == 0) ^ (X[:, 1] > 0)).astype(np.float64)
+    return X, y
+
+
+def test_a_bundled_spec_keeps_the_pick_loop(monkeypatch):
+    """Its routing is another computation (`decode_bins`): a static flag
+    of the spec, read at trace."""
     traced = _spy(monkeypatch)
     rng = np.random.RandomState(2)
     n = 3000
-    if kind == "bundled":
-        # mutually exclusive sparse columns: EFB bundles them
-        X = np.zeros((n, 12), np.float32)
-        X[np.arange(n), rng.randint(0, 12, n)] = rng.rand(n) + 0.5
-        y = (X[:, 0] + X[:, 3] - X[:, 7] + 0.1 * rng.randn(n) > 0.2)
-        ds = lgb.Dataset(X, label=y.astype(np.float64))
-        params = dict(WAVE, num_leaves=12, enable_bundle=True)
-    else:
-        X = rng.randn(n, 6).astype(np.float32)
-        X[:, 0] = rng.randint(0, 9, n)
-        y = ((X[:, 0] % 3 == 0) ^ (X[:, 1] > 0)).astype(np.float64)
-        ds = lgb.Dataset(X, label=y, categorical_feature=[0])
-        params = dict(WAVE, num_leaves=12)
+    # mutually exclusive sparse columns: EFB bundles them
+    X = np.zeros((n, 12), np.float32)
+    X[np.arange(n), rng.randint(0, 12, n)] = rng.rand(n) + 0.5
+    y = (X[:, 0] + X[:, 3] - X[:, 7] + 0.1 * rng.randn(n) > 0.2)
     before = telemetry.REGISTRY.snapshot()["counters"]
-    bst = lgb.train(params, ds, num_boost_round=2)
+    bst = lgb.train(dict(WAVE, num_leaves=12, enable_bundle=True),
+                    lgb.Dataset(X, label=y.astype(np.float64)),
+                    num_boost_round=2)
     assert bst._grow_policy == "wave"
-    assert getattr(bst._grower_spec, kind)
+    assert bst._grower_spec.bundled
     assert traced == {"kernel": 0, "xla": 0}
     # one routing pass a pick and a slot
     grown = _grown(before)
     assert grown["grow.route_passes"] == grown["grow.route_picks"] > 0
+    grow_wave.make_wave_grower.cache_clear()
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("family", ["xla", "kernel"])
+def test_a_categorical_spec_routes_in_the_pass_the_loops_trees(monkeypatch,
+                                                               family):
+    """A model with a categorical column: every wave, speculation AND
+    tail pick goes through the pass (three traces: the wave body, the
+    tail body, the speculation), and the model text is the per-pick
+    loop's."""
+    X, y = _cat_table()
+    params = dict(WAVE, num_leaves=40, min_data_per_group=20)
+    if family == "kernel":
+        params.update(hist_impl="pallas", hist_interpret=True)
+    texts, traced, grown = [], [], []
+    for batched in (True, False):
+        if not batched:
+            monkeypatch.setattr(grow_wave, "batched_route_applies",
+                                lambda bins: False)
+        traced.append(_spy(monkeypatch))
+        before = telemetry.REGISTRY.snapshot()["counters"]
+        bst = lgb.train(params, lgb.Dataset(X, label=y,
+                                            categorical_feature=[0]),
+                        num_boost_round=2)
+        assert bst._grow_policy == "wave" and bst._grower_spec.has_cat
+        texts.append(bst.model_to_string())
+        grown.append(_grown(before))
+    assert texts[0] == texts[1]
+    other = "xla" if family == "kernel" else "kernel"
+    assert traced[0][family] == 3 and traced[0][other] == 0
+    assert traced[1] == {"kernel": 0, "xla": 0}
+    cat_splits = sum(t.num_cat for t in bst.trees)
+    assert cat_splits > 0
+    for g in grown:
+        assert g["grow.cat_splits"] == cat_splits
+        assert g["grow.cat_left_bins"] >= cat_splits
+        # the categorical picks, and the categorical slots speculated
+        assert g["grow.route_cat_picks"] >= cat_splits
+    # one pass a wave, a speculation and a tail pick, against one a pick
+    # and a slot
+    assert grown[0]["grow.route_passes"] < grown[0]["grow.route_picks"]
+    assert grown[1]["grow.route_passes"] == grown[1]["grow.route_picks"]
+    assert grown[0]["grow.route_picks"] == grown[1]["grow.route_picks"]
     grow_wave.make_wave_grower.cache_clear()
     jax.clear_caches()
 

@@ -18,6 +18,7 @@ partitions never leave the device; only the finished tree structure does
 from __future__ import annotations
 
 import copy
+import functools
 import hashlib
 import io
 import json
@@ -34,6 +35,7 @@ from .basic import Dataset, _to_2d_float
 from .metrics import Metric, create_metrics
 from .objectives import ObjectiveFunction, create_objective
 from .ops.grow import DeviceTree, GrowerSpec, make_grower
+from .ops.leaf_rows import leaf_rows, pass_serves
 from .ops.predict import traverse_bins
 from .tree import K_CATEGORICAL_MASK, Tree
 from .utils import log
@@ -327,11 +329,6 @@ def _with_rows(jitted, *rows):
     # would keep every booster's device arrays for good
     call._cache_size = lambda: jitted._cache_size()
     return call
-
-
-@jax.jit
-def _add_leaf_values(score, leaf_idx, values):
-    return score + values[leaf_idx]
 
 
 class Booster:
@@ -1832,8 +1829,28 @@ class Booster:
                                              float(renew_alpha), lr)
         else:
             scaled = dev.leaf_value * lr
-        # train score: final leaf_id from growth → direct gather
-        return scaled[dev.leaf_id]
+        # train score: final leaf_id from growth → one look-up a row
+        return self._leaf_values(scaled, dev.leaf_id, grown=True)
+
+    def _leaf_values(self, table, leaf_id, grown: bool = False) -> jax.Array:
+        """[N] f32 `table[leaf_id]`, a tree's value for every row
+        (`ops/leaf_rows.py`), counted on `score.lookup_rows` (rows the
+        Pallas pass looked up) or `score.gather_rows` (rows that took the
+        gather).  `grown`: the ids are the grower's own `leaf_id`, which
+        a mesh's grower looks up shard by shard; a mesh's other rows
+        (valid sets, a replayed tree's) are not split like them and keep
+        the gather."""
+        spec = self._grower_spec
+        lookup = getattr(self._grower, "leaf_rows", None) if grown else None
+        if lookup is None and self._mesh is None:
+            lookup = functools.partial(leaf_rows, hist_impl=spec.hist_impl,
+                                       interpret=spec.hist_interpret)
+        on_pass = lookup is not None and \
+            pass_serves(table.shape[0], spec.hist_impl)
+        telemetry.REGISTRY.counter(
+            "score.lookup_rows" if on_pass else "score.gather_rows").inc(
+                int(leaf_id.shape[0]))
+        return table[leaf_id] if lookup is None else lookup(table, leaf_id)
 
     def _renew_tree_output(self, tree: Tree, dev: DeviceTree, sw,
                            alpha: float, lr: float) -> jax.Array:
@@ -1844,7 +1861,6 @@ class Booster:
         (ops/renew.py) — the reference's per-leaf host loop has no business
         on a remote accelerator.  Returns the shrunken per-slot leaf values
         and rewrites the host tree in place."""
-        import functools
         from .ops.renew import renew_leaf_values
         dd = self._dd
         weighted, base_w = self._renew_base()
@@ -1933,7 +1949,7 @@ class Booster:
             leaf_idx = _jit_traverse(feat, thr, dl, left, right, iscat,
                                      catmask, dd.feat_nb, dd.feat_missing,
                                      dd.bins_fm)
-            contrib = v[leaf_idx]
+            contrib = self._leaf_values(v, leaf_idx)
         if record is not None:
             self._last_contribs.append(("valid", record, k, contrib))
         if score.ndim == 1:
@@ -2484,7 +2500,7 @@ class Booster:
             np.asarray(tree.leaf_value - bias, dtype=np.float32))
         leaf_idx = _jit_traverse(feat, thr, dl, left, right, iscat, catmask,
                                  dd.feat_nb, dd.feat_missing, dd.bins_fm)
-        contrib = v[leaf_idx]
+        contrib = self._leaf_values(v, leaf_idx)
         if score.ndim == 1:
             return score - contrib
         return score.at[:, k].add(-contrib)

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 
 from .grow import GrowerSpec, make_grower
+from .leaf_rows import leaf_rows
 from ..analysis.contracts import contract
 
 Array = jax.Array
@@ -187,6 +188,15 @@ def make_bulk_trainer(spec: BulkSpec, grad_fn: Callable, renew_args=None,
     # distributed shard_map'ped learner plugs in here, so multi-chip
     # training gets the same one-sync-per-chunk behavior
     grow = grow_fn if grow_fn is not None else make_grower(spec.grower)
+    # a tree's value for every row (`ops/leaf_rows.py`): the distributed
+    # grower brings the look-up over its own row shards; the valid rows
+    # are not split like them and keep the gather there
+    serial_lookup = functools.partial(
+        leaf_rows, hist_impl=spec.grower.hist_impl,
+        interpret=spec.grower.hist_interpret)
+    lookup = getattr(grow, "leaf_rows", serial_lookup)
+    valid_lookup = serial_lookup if grow_fn is None \
+        else (lambda table, ids: table[ids])
     K = spec.num_class
     lr = 1.0 if spec.rf else spec.learning_rate
     if spec.renew_alpha >= 0.0:
@@ -266,7 +276,7 @@ def make_bulk_trainer(spec: BulkSpec, grad_fn: Callable, renew_args=None,
                     dev = dev._replace(leaf_value=jnp.where(
                         dev.n_splits > 0, renewed, dev.leaf_value))
             with jax.named_scope("update_scores"):
-                contrib = dev.leaf_value[dev.leaf_id] * lr
+                contrib = lookup(dev.leaf_value, dev.leaf_id) * lr
                 if K == 1:
                     new_score = new_score + contrib
                 else:
@@ -274,7 +284,7 @@ def make_bulk_trainer(spec: BulkSpec, grad_fn: Callable, renew_args=None,
                 for vi, vbins in enumerate(valid_bins):
                     vlid = replay_leaf_ids(dev, vbins, feat["nb"],
                                            feat["missing"])
-                    vcontrib = dev.leaf_value[vlid] * lr
+                    vcontrib = valid_lookup(dev.leaf_value, vlid) * lr
                     if K == 1:
                         new_vscores[vi] = new_vscores[vi] + vcontrib
                     else:

@@ -42,6 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..mesh.placement import emit_collective_round, local_device_ids
 from ..ops.grow import DeviceTree, GrowerSpec, make_grower
+from ..ops.leaf_rows import leaf_rows
 from .learner import hist_hop_bytes
 
 Array = jax.Array
@@ -121,7 +122,9 @@ def make_sharded_train_step(spec: GrowerSpec, mesh: Mesh,
             dev = grow(bins_fm, grad.astype(jnp.float32),
                        hess.astype(jnp.float32), weight, feat, allowed)
         with jax.named_scope("update_scores"):
-            new_score = score + dev.leaf_value[dev.leaf_id] * lr
+            new_score = score + leaf_rows(
+                dev.leaf_value, dev.leaf_id, spec.hist_impl,
+                spec.hist_interpret) * lr
         return new_score, dev
 
     tree_specs = DeviceTree(

@@ -48,6 +48,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..mesh.placement import emit_collective_round, local_device_ids, \
     padded_feature_count, padded_row_count, record_placement
 from ..ops.grow import DeviceTree, GrowerSpec, make_grower
+from ..ops import leaf_rows as leaf_rows_op
 from ..utils import log
 
 TREE_LEARNER_ALIASES = {
@@ -114,8 +115,11 @@ def make_distributed_grower(spec: GrowerSpec, mesh: Mesh, kind: str,
 
     The returned function carries `reduce_bytes`: the bytes one shard
     hands to the collectives of ONE histogram reduction
-    (`hist_reduce_bytes`), for the booster's `grow.reduce_bytes`; and
-    `jitted`, the program itself (`jit_grow`).
+    (`hist_reduce_bytes`), for the booster's `grow.reduce_bytes`;
+    `jitted`, the program itself (`jit_grow`); and `leaf_rows(table,
+    leaf_id)`, the score update's look-up (`ops/leaf_rows.py`) over the
+    grower's own `leaf_id`: each shard looks its rows up in the
+    replicated table, no collective.
     """
     axes = tuple(mesh.axis_names)     # ("data",) or ("dcn", "ici")
     S_last = int(mesh.shape[axes[-1]])
@@ -225,6 +229,21 @@ def make_distributed_grower(spec: GrowerSpec, mesh: Mesh, kind: str,
                               mode=mode, shards=S_total)
         return jitted(*args)
 
+    rows_lookup = shard_map(
+        functools.partial(leaf_rows_op.leaf_rows, hist_impl=spec.hist_impl,
+                          interpret=spec.hist_interpret),
+        mesh=mesh, in_specs=(P(), row_sp), out_specs=row_sp,
+        check_vma=False)
+
+    # named like the serial look-up: one program name, `jit_leaf_rows`
+    def leaf_rows(table, leaf_id):
+        if not n_extra:
+            return rows_lookup(table, leaf_id)
+        # pad rows carry -1 like the grower's; their values are dropped
+        return rows_lookup(table, jnp.pad(
+            leaf_id, (0, n_extra), constant_values=-1))[:num_data]
+
+    dispatched.leaf_rows = jax.jit(leaf_rows)
     dispatched.jitted = jitted      # ahead-of-time compiles lower this
     dispatched.reduce_bytes = hist_reduce_bytes(
         spec, num_feature + f_extra, slots, det, S_last)

@@ -101,3 +101,33 @@ def test_sharded_grower_compiles_at_cell_size(topo):
     text = compiled.as_text()
     assert tpu_kernels(text).count("route_wave_rows") == 2
     assert row_array_copies(text, n // CHIPS) == []
+
+
+def test_sharded_look_up_compiles_at_cell_size(topo):
+    """The score update's look-up over the four row shards (ISSUE 36):
+    `make_distributed_grower`'s `leaf_rows` at the cell's 201,326,592
+    rows is one Pallas call a device, no gather and no collective, and
+    its values come back split like the ids."""
+    config = manifest.config(CONFIG)
+    bst = booster_for_chip(config)
+    n = int(config["train_rows"])
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    grow = make_distributed_grower(
+        bst._grower_spec, mesh, "data", len(config["data"]["columns"]), n,
+        wave=bst._grow_policy == "wave",
+        det_reduce=bool(bst.config.deterministic_reduce))
+    compiled = grow.leaf_rows.lower(
+        jax.ShapeDtypeStruct((int(config["params"]["num_leaves"]),),
+                             jnp.float32,
+                             sharding=NamedSharding(mesh, P())),
+        jax.ShapeDtypeStruct((n,), jnp.int32,
+                             sharding=NamedSharding(mesh, P("data")))
+    ).compile()
+    text = compiled.as_text()
+    assert tpu_kernels(text) == ["leaf_rows"]
+    assert " gather(" not in text
+    for collective in ("all-gather", "all-reduce", "collective-permute",
+                       "all-to-all"):
+        assert collective not in text
+    assert compiled.output_shardings.spec == P("data")
+    assert row_array_copies(text, n // CHIPS) == []

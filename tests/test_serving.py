@@ -545,11 +545,18 @@ def test_registry_staleness_and_auto_refresh():
         bst.best_iteration = -1    # unpin predict from the old round
         assert reg.status()["stale"] == ["m"]
         assert telemetry.REGISTRY.gauge("serve.stale").value == 1
-        # auto-refresh re-exports on the next predict
+        # auto-refresh re-exports on the next predict, OFF the request
+        # thread: the stale export keeps serving until the new one is
+        # swapped in, so wait for the swap before comparing
+        reg.predict(X, model="m", raw_score=True)
+        assert ar.value == before + 1
+        deadline = time.monotonic() + 60.0
+        while reg.status()["stale"] and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert reg.status()["stale"] == []
         got = reg.predict(X, model="m", raw_score=True)
         assert ar.value == before + 1
         assert np.array_equal(got, bst.predict(X, raw_score=True))
-        assert reg.status()["stale"] == []
         assert telemetry.REGISTRY.gauge("serve.stale").value == 0
     finally:
         reg.close()

@@ -26,6 +26,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 import lightgbm_tpu as lgb
 from lightgbm_tpu.booster import Booster
 from lightgbm_tpu.ops import pallas_hist as ph
+from lightgbm_tpu.ops import leaf_rows as lr
 from lightgbm_tpu.ops import route as rt
 from lightgbm_tpu.ops.fused import make_bulk_trainer
 
@@ -131,6 +132,26 @@ def test_route_wave_rows_compiles_at_cell_size(topo, shape, fill):
     assert tpu_kernels(compiled.as_text()) == ["route_wave_rows"]
     # no [8, N] operand or result of the one-hot product reaches HBM
     assert compiled.memory_analysis().temp_size_in_bytes < 4 * n
+
+
+@pytest.mark.parametrize("rows,leaves", [
+    (83_886_080, 255), (114_999_296, 31), (83_886_080, lr.LEAF_MAX_ENTRIES)],
+    ids=["lgbexp", "l31", "widest"])
+def test_leaf_rows_compiles_at_cell_size(topo, rows, leaves):
+    """The score update's look-up (ISSUE 36) at the one-chip cells' rows
+    and tree sizes, and at the widest table it serves: one Pallas call
+    that is NOT named `pallas_histogram*` (the readers reckon a
+    histogram's work for every such name), no gather, and nothing over
+    all rows but the ids read and the values written."""
+    sds, _ = _one(topo)
+    compiled = lr.leaf_rows.lower(sds((leaves,), jnp.float32),
+                                  sds((rows,), jnp.int32),
+                                  "pallas").compile()
+    text = compiled.as_text()
+    assert tpu_kernels(text) == ["leaf_rows"]
+    assert " gather(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 20
+    assert row_array_copies(text, rows) == []
 
 
 @pytest.mark.parametrize("name", ["airline13-l31", "airline13-lgbexp-l255"])

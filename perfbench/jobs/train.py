@@ -12,6 +12,12 @@ the timing: the peak memory is read, the hold-out rows are scored by the
 model of exactly `quality_rounds` rounds, the program's state is freed, and
 the plain reference follows the first `check_rounds` trees
 (`perfbench/check.py` decides `correct`).
+
+A traced run (`ctx.trace`) also records the program itself
+(`ProgramRecord`): its spans from before the rows are made, and its
+counters and gauges at the window's two edges.  The result then carries
+`program`, and `counters` is the window's change of every counter; an
+untraced run attaches nothing and counts `jit.recompiles` alone.
 """
 from __future__ import annotations
 
@@ -126,6 +132,48 @@ def round_failed(booster, rounds_before: int) -> Optional[str]:
     return None
 
 
+class ProgramRecord:
+    """What the program records of itself in a traced run: a `MemorySink`
+    on `lightgbm_tpu.telemetry.TRACER` and the compile listener, attached
+    when made (before anything of the program runs), and its counters and
+    gauges when the window opens and closes; the sink comes off at the
+    close."""
+
+    def __init__(self):
+        from lightgbm_tpu import telemetry
+        from lightgbm_tpu.telemetry.recorder import install_compile_listener
+        self.telemetry = telemetry
+        self.sink = telemetry.TRACER.add_sink(telemetry.MemorySink())
+        install_compile_listener()
+        self.start: Dict[str, Dict[str, float]] = {}
+        self.end: Dict[str, Dict[str, float]] = {}
+
+    def _snapshot(self) -> Dict[str, Dict[str, float]]:
+        snap = self.telemetry.REGISTRY.snapshot()
+        return {"counters": snap["counters"], "gauges": snap["gauges"]}
+
+    def open(self) -> None:
+        self.start = self._snapshot()
+
+    def close(self) -> None:
+        self.end = self._snapshot()
+        self.telemetry.TRACER.remove_sink(self.sink)
+
+    def fields(self) -> Dict[str, Any]:
+        """The result's `program`, and `counters` as the window's change
+        of every counter."""
+        before = self.start["counters"]
+        return {
+            "program": {
+                "spans": [e for e in self.sink.events
+                          if e.get("ev") == "span"],
+                "counters_start": {**before, **self.start["gauges"]},
+                "counters_end": {**self.end["counters"],
+                                 **self.end["gauges"]}},
+            "counters": {k: v - before.get(k, 0)
+                         for k, v in self.end["counters"].items()}}
+
+
 def wait_for_rounds(booster) -> None:
     """Block until the device work of the rounds made so far is done (the
     score update is dispatched after the tree has been decoded)."""
@@ -186,6 +234,7 @@ def run(ctx) -> Dict[str, Any]:
     traffic = cell["traffic_params"]
     annotate = tracelib.annotation if ctx.trace else tracelib.no_annotation
 
+    record = ProgramRecord() if ctx.trace else None
     rows, ds, params = make_inputs(lgb, config, ctx.seed,
                                    int(traffic["holdout_rows"]), say)
     install_compile_listener()
@@ -216,6 +265,7 @@ def run(ctx) -> Dict[str, Any]:
     setup_s = time.perf_counter() - ctx.t0
     tracer = tracelib.Tracer(ctx.trace_dir) if ctx.trace else None
     if tracer:
+        record.open()
         tracer.start()
     try:
         window = run_window(
@@ -226,7 +276,10 @@ def run(ctx) -> Dict[str, Any]:
             finish=lambda: wait_for_rounds(booster))
     finally:
         if tracer:
+            t = time.perf_counter()
             tracer.stop()
+            record.close()
+            say(f"window: trace written in {time.perf_counter() - t:.2f} s")
     counters_after = {"jit.recompiles": compiles.value}
 
     # ----------------------------------------------- after the window closes
@@ -292,4 +345,6 @@ def run(ctx) -> Dict[str, Any]:
         "compared": check.compared_lines(numbers, limits),
         "trace_file": tracer.file() if tracer else None,
     }
+    if record:
+        out.update(record.fields())
     return out

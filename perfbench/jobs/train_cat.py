@@ -15,7 +15,8 @@ configuration states.
 
 Set-up, window, quality and check are `jobs/train.run`'s, call for call:
 `run_window`, `auc`, `round_failed` and `wait_for_rounds` are imported from
-there and nothing of the timing differs.  The result's `counters` also
+there and nothing of the timing differs, and a traced run records the
+program by `jobs.train.ProgramRecord`.  An untraced run's `counters` also
 carry the window's change of `grow.cat_splits`, `grow.route_passes` and
 `grow.route_picks` (0 where the program under test has no such counter).
 """
@@ -32,7 +33,8 @@ import numpy as np
 
 from .. import check, trace as tracelib
 from ..manifest import load_module
-from .train import auc, round_failed, run_window, wait_for_rounds
+from .train import (ProgramRecord, auc, round_failed, run_window,
+                    wait_for_rounds)
 
 COUNTERS = ("jit.recompiles", "grow.cat_splits", "grow.route_passes",
             "grow.route_picks")
@@ -120,6 +122,7 @@ def run(ctx) -> Dict[str, Any]:
     traffic = cell["traffic_params"]
     annotate = tracelib.annotation if ctx.trace else tracelib.no_annotation
 
+    record = ProgramRecord() if ctx.trace else None
     rows, ds, params = make_inputs(lgb, config, ctx.seed,
                                    int(traffic["holdout_rows"]), say)
     install_compile_listener()
@@ -150,6 +153,7 @@ def run(ctx) -> Dict[str, Any]:
     setup_s = time.perf_counter() - ctx.t0
     tracer = tracelib.Tracer(ctx.trace_dir) if ctx.trace else None
     if tracer:
+        record.open()
         tracer.start()
     try:
         window = run_window(
@@ -160,7 +164,10 @@ def run(ctx) -> Dict[str, Any]:
             finish=lambda: wait_for_rounds(booster))
     finally:
         if tracer:
+            t = time.perf_counter()
             tracer.stop()
+            record.close()
+            say(f"window: trace written in {time.perf_counter() - t:.2f} s")
     counters_after = {k: c.value for k, c in counters.items()}
     say("window: counters " + json.dumps(
         {k: counters_after[k] - counters_before[k] for k in COUNTERS}))
@@ -210,7 +217,7 @@ def run(ctx) -> Dict[str, Any]:
         and window["completed"] >= int(traffic["min_window_rounds"]) \
         and math.isfinite(holdout_auc)
 
-    return {
+    out = {
         "correct": bool(ok),
         "attempted": window["attempted"], "failed": window["failed"],
         "window": window,
@@ -231,3 +238,6 @@ def run(ctx) -> Dict[str, Any]:
         "compared": check.compared_lines(numbers, limits),
         "trace_file": tracer.file() if tracer else None,
     }
+    if record:
+        out.update(record.fields())
+    return out

@@ -6,6 +6,10 @@ metric is a file of its own under `perfbench/`:
   workloads/<cell>.json        config, chips, job, traffic parameters, why
   configs/<config>.json        source, parameters, shape, reduced, assumed
   layer_metrics/<metric>.json  layer, unit, moves, workloads, reader + args
+  program_metrics/<metric>.json  the same, for a metric of the program's
+                               own record that `BENCHMARK.json` cannot list
+                               yet (PERF.md, Open questions): the traced
+                               run reports it under `program_metrics`
 
 `BENCHMARK.json` at the root of the checkout names them.  No cell, config
 or metric name appears in code.
@@ -68,9 +72,22 @@ def with_population(config: dict, population_seed: int) -> dict:
     return dict(config, data=data)
 
 
-def layer_metrics(cell: str, bench_dir: str = HERE) -> List[dict]:
+#: TEMPORARY, the one place that says so (PERF.md 7.1): the folder of the
+#: program's metrics that `BENCHMARK.json` cannot list while tests outside
+#: the benchmark pin its `per_layer` at 34 entries.  The change that lifts
+#: the pins moves these files into `layer_metrics/`, lists them in
+#: `per_layer`, and deletes in the same change this constant, the `folder`
+#: argument below, `run.per_layer`'s second `_read` and the line's
+#: `program_metrics` key.  Nothing else belongs to this second channel.
+PROGRAM_METRICS = "program_metrics"
+
+
+def layer_metrics(cell: str, bench_dir: str = HERE,
+                  folder: str = "layer_metrics") -> List[dict]:
     """Every per-layer metric whose file lists the cell, by name."""
-    d = os.path.join(bench_dir, "layer_metrics")
+    d = os.path.join(bench_dir, folder)
+    if not os.path.isdir(d):
+        return []
     out = []
     for fn in sorted(os.listdir(d)):
         if fn.endswith(".json"):

@@ -3,9 +3,11 @@
 A per-layer metric is a file `layer_metrics/<name>.json` that names one of
 these readers and gives its arguments as data, so a new metric over an
 existing reader is one new file.  A reader gets the run's context
-(`trace`, `counters`, `memory`, `units`, `shape`, `peaks`) and returns a
-number, or None where it finds nothing to read: the harness then leaves the
-metric out of the line.  No reader returns 0 for a share of a roofline.
+(`trace`, `trace_file`, `program`, `counters`, `memory`, `units`, `shape`,
+`peaks`: `run.per_layer` builds it) and returns a number, or None where it
+finds nothing to read: the harness then leaves the metric out of the line.
+No reader returns 0 for a share of a roofline.  `READERS` also holds those
+of `program_readers.py`, which read what the program records of itself.
 
   scope_share        100 * device time of the selected operations / busy time
                      args: scope, name, program, opcode, not_scope,
@@ -26,24 +28,22 @@ from __future__ import annotations
 import re
 from typing import Callable, Dict, Optional
 
+from . import program_readers as P
 from . import trace as T
 from . import work as W
-
-_SELECT = ("scope", "name", "program", "opcode", "not_scope", "not_name",
-           "not_program")
 
 
 def _selected(ctx, args):
     if ctx.get("trace") is None:
         return None
-    return T.select(ctx["trace"], **{k: args[k] for k in _SELECT if k in args})
+    return T.select_by(ctx["trace"], args)
 
 
 def scope_share(ctx, args) -> Optional[float]:
     ops = _selected(ctx, args)
     if ops is None:
         return None
-    busy = T.busy_seconds(ctx["trace"])
+    busy = P.busy_s(ctx)
     if busy <= 0:
         return None
     if not ops and not args.get("zero_if_absent", False):
@@ -66,7 +66,7 @@ def idle_share(ctx, args) -> Optional[float]:
     lo, hi = T.window_of(tr)
     if hi <= lo:
         return None
-    return 100.0 * (1.0 - T.busy_seconds(tr) / ((hi - lo) / 1e9))
+    return 100.0 * (1.0 - P.busy_s(ctx) / ((hi - lo) / 1e9))
 
 
 def roofline_share(ctx, args) -> Optional[float]:
@@ -113,6 +113,7 @@ READERS: Dict[str, Callable[[dict, dict], Optional[float]]] = {
     "roofline_share": roofline_share,
     "counter_delta": counter_delta,
     "memory_peak_share": memory_peak_share,
+    **P.READERS,
 }
 
 

@@ -11,8 +11,10 @@ configuration and (with `--trace 1`) every per-layer metric that lists the
 cell; exits 2 before measuring anything where JAX finds no TPU or fewer
 chips than the cell asks for.  The last line of standard output is one JSON
 object with `correct`, `attempted`, `failed`, `metrics`, `device`, with
-`--trace 1` also `breakdown`, and last `compared`: each number the
-comparison read beside its limit.  See `perfbench/README.md`.
+`--trace 1` also `breakdown` and `program_metrics` (the metrics of the
+program's own record that `BENCHMARK.json` does not list yet), and last
+`compared`: each number the comparison read beside its limit.  See
+`perfbench/README.md`.
 """
 from __future__ import annotations
 
@@ -46,47 +48,69 @@ def cache_entries(path: str) -> int:
 
 
 def default_hooks() -> SimpleNamespace:
-    """What a test may replace: the look for a chip, the persistent
-    compile cache (a test run must not create `<checkout>/.jax_cache`), how
-    the booster is built, and what is done to the trees before they are
-    compared."""
+    """What a test or a builder's tool may replace: the look for a chip,
+    the persistent compile cache (a test run must not create
+    `<checkout>/.jax_cache`), how the booster is built, what is done to
+    the trees before they are compared, and what is done with the job's
+    result before it is reduced (`program_run` keeps it)."""
     return SimpleNamespace(
         require_chip=True,
         compile_cache=True,
         make_booster=lambda lgb, params, ds: lgb.Booster(params=params,
                                                          train_set=ds),
-        alter_trees=lambda trees: None)
+        alter_trees=lambda trees: None,
+        on_result=lambda result: None)
+
+
+def _read(metrics: List[dict], ctx: dict) -> Dict[str, Any]:
+    from . import readers
+    out = {}
+    for m in metrics:
+        v = readers.read(m, ctx)
+        if v is not None and math.isfinite(v):
+            out[m["name"]] = {"value": v, "unit": m["unit"]}
+    return out
 
 
 def per_layer(cell_name: str, result: dict, device_kind: str,
               bench_dir: str) -> Dict[str, Any]:
-    """Reduce the traced run to the cell's per-layer metrics, the device's
-    busy time and the breakdown."""
-    from . import readers, trace as T
-    tr = T.load(result["trace_file"]) if result.get("trace_file") else None
+    """Reduce the traced run to the cell's per-layer metrics, the metrics
+    of `program_metrics/` that list it, the device's busy time and the
+    breakdown: the heaviest operations, the longest idle gaps named
+    `<annotation>/<innermost program span>`, device-idle ms a round by
+    program span, and the grower's device seconds by phase scope."""
+    from . import program_readers as P, trace as T
+    path = result.get("trace_file")
+    tr = T.load(path, P.span_names(result.get("program"))) if path else None
     try:
         peaks = manifest.peaks(device_kind, bench_dir)
     except KeyError:
         if tr is not None and tr.devices:
             raise
         peaks = {}
-    ctx = {"trace": tr, "counters": result.get("counters", {}),
+    units = result.get("units_in_window", {})
+    ctx = {"trace": tr, "trace_file": path, "program": result.get("program"),
+           "counters": result.get("counters", {}),
            "memory": {"peak_bytes": result.get("memory_peak_bytes")},
-           "units": result.get("units_in_window", {}),
-           "shape": result.get("shape", {}), "peaks": peaks}
-    metrics = {}
-    for m in manifest.layer_metrics(cell_name, bench_dir):
-        v = readers.read(m, ctx)
-        if v is not None and math.isfinite(v):
-            metrics[m["name"]] = {"value": v, "unit": m["unit"]}
-    out: Dict[str, Any] = {"metrics": metrics}
+           "units": units, "shape": result.get("shape", {}), "peaks": peaks}
+    out: Dict[str, Any] = {
+        "metrics": _read(manifest.layer_metrics(cell_name, bench_dir), ctx),
+        "program_metrics": _read(manifest.layer_metrics(
+            cell_name, bench_dir, manifest.PROGRAM_METRICS), ctx)}
     if tr is not None:
         lo, hi = T.window_of(tr)
-        out["busy_s"] = T.busy_seconds(tr)
+        rounds = max(units.get("rounds", 0), 1)
+        by_span = sorted(P.gap_ns_by_span(ctx).items(), key=lambda kv: -kv[1])
+        phases = sorted((P.phase_seconds(ctx, "^jit_grow$") or {}).items(),
+                        key=lambda kv: -kv[1])
+        out["busy_s"] = P.busy_s(ctx)
         out["window_s"] = (hi - lo) / 1e9
         out["breakdown"] = {
             "device_ops": [[k, v] for k, v in T.top_ops(tr)],
-            "idle_gaps": [[k, v] for k, v in T.idle_gaps(tr)]}
+            "idle_gaps": [[k, v] for k, v in P.idle_gaps(ctx)],
+            "host_gap_ms_by_span": [[k, v / 1e6 / rounds]
+                                    for k, v in by_span[:10]],
+            "phase_s": [[k or "unattributed", v] for k, v in phases[:10]]}
     return out
 
 
@@ -157,6 +181,7 @@ def main(argv: Optional[List[str]] = None, hooks: Optional[SimpleNamespace]
         trace_dir=os.path.join(manifest.ROOT, ".perfbench_trace"),
         make_booster=hooks.make_booster, alter_trees=hooks.alter_trees)
     result = job.run(ctx)
+    hooks.on_result(result)
     say(f"compile cache: entries_after={cache_entries(cache_dir)} "
         f"(before {n_cache})")
     say("window: " + json.dumps(result.get("window", {})))
@@ -167,7 +192,9 @@ def main(argv: Optional[List[str]] = None, hooks: Optional[SimpleNamespace]
         "attempted": int(result["attempted"]),
         "failed": int(result["failed"])}
     if args.trace:
+        t = time.perf_counter()
         pl = per_layer(args.workload, result, device["kind"], bench_dir)
+        say(f"trace: reduced in {time.perf_counter() - t:.2f} s")
         line["metrics"] = pl["metrics"]
         if "busy_s" in pl:
             device["busy_s"] = pl["busy_s"]
@@ -175,6 +202,8 @@ def main(argv: Optional[List[str]] = None, hooks: Optional[SimpleNamespace]
         line["device"] = device
         if "breakdown" in pl:
             line["breakdown"] = pl["breakdown"]
+        if pl["program_metrics"]:     # temporary: manifest.PROGRAM_METRICS
+            line["program_metrics"] = pl["program_metrics"]
     else:
         line["metrics"] = {k: {"value": _finite(v["value"]),
                                "unit": v["unit"]}

@@ -64,6 +64,9 @@ class Trace(NamedTuple):
     modules: List[Span]           # program executions (first device)
     spans: List[Span]             # the benchmark's host annotations
     devices: List[int]
+    # the program's own spans (`load`'s `names`), one list a host thread in
+    # order of (start, longest first), so a parent comes before its children
+    lines: List[List[Span]] = []
 
 
 # ----------------------------------------------------------------- recording
@@ -126,13 +129,17 @@ def _self_times(events: List[Tuple[float, float]]) -> List[float]:
     return [max(x, 0.0) for x in out]
 
 
-def load(path: str) -> Trace:
+def load(path: str, names: Iterable[str] = ()) -> Trace:
+    """The file's device operations and annotations and, in the same pass
+    over the host planes, the host events named in `names`."""
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
+    names = frozenset(names)
     ops: List[Op] = []
     modules: List[Span] = []
     spans: List[Span] = []
     devices: List[int] = []
+    lines: List[List[Span]] = []
     for plane in data.planes:
         m = DEVICE_PLANE.match(plane.name)
         if m:
@@ -168,12 +175,19 @@ def load(path: str) -> Trace:
                               ev.duration_ns, self_ns, dev))
         elif plane.name.startswith("/host:"):
             for line in plane.lines:
+                found = []
                 for ev in line.events:
                     if ev.name.startswith(ANNOTATION_PREFIX):
                         spans.append(Span(ev.name[len(ANNOTATION_PREFIX):],
                                           ev.start_ns, ev.duration_ns))
+                    elif ev.name in names:
+                        found.append(Span(ev.name, ev.start_ns,
+                                          ev.duration_ns))
+                if found:
+                    lines.append(sorted(found,
+                                        key=lambda s: (s.start, -s.dur)))
     return Trace(ops, modules, sorted(spans, key=lambda s: s.start),
-                 sorted(devices))
+                 sorted(devices), lines)
 
 
 # ---------------------------------------------------------------- arithmetic
@@ -248,31 +262,32 @@ def select(trace: Trace, scope: Optional[str] = None,
     return [o for o in trace.ops if ok(o)]
 
 
+#: the arguments of `select`, as a metric file names them
+SELECT = ("scope", "name", "program", "opcode", "not_scope", "not_name",
+          "not_program")
+
+
+def select_by(trace: Trace, args: dict) -> List[Op]:
+    """`select` with the selection arguments that a metric's `args` give."""
+    return select(trace, **{k: args[k] for k in SELECT if k in args})
+
+
 def op_seconds(ops: Sequence[Op], n_devices: int) -> float:
     """Device time of the operations, averaged over devices, in seconds:
     the sum of their self times, which counts nothing twice."""
     return sum(o.dur for o in ops) / max(n_devices, 1) / 1e9
 
 
-def idle_gaps(trace: Trace, top: int = 10) -> List[Tuple[str, float]]:
-    """The longest idle gaps of the first device inside the window, each
-    named by the host annotation that covers most of it."""
+def idle_intervals(trace: Trace) -> List[Tuple[float, float]]:
+    """The first device's idle intervals inside the window, in ns.
+    `program_readers.idle_gaps` names the longest by what the host did."""
     if not trace.devices:
         return []
     lo, hi = window_of(trace)
-    b = busy(trace, trace.devices[0])
-    edges = [lo] + [x for iv in b for x in iv] + [hi]
-    gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
+    edges = [lo] + [x for iv in busy(trace, trace.devices[0])
+                    for x in iv] + [hi]
+    return [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
             if edges[i + 1] > edges[i]]
-    out = []
-    for a, z in sorted(gaps, key=lambda g: g[0] - g[1])[:top]:
-        best, cover = "no_annotation", 0.0
-        for s in trace.spans:
-            c = min(z, s.start + s.dur) - max(a, s.start)
-            if c > cover:
-                best, cover = s.name, c
-        out.append((best, (z - a) / 1e9))
-    return out
 
 
 def top_ops(trace: Trace, top: int = 10) -> List[Tuple[str, float]]:

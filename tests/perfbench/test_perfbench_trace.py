@@ -1,17 +1,23 @@
 """The reduction from a trace to metrics: on a hand-made trace whose every
-number can be worked out on paper, and on a small trace recorded on a TPU
-v5e (`fixtures/round.xplane.pb.gz`: two `Booster.update()` rounds of the
-fixture cell `tiny13-l31.train`, 65,536 rows)."""
+number can be worked out on paper, and on the driver's traced run of the
+fixture cell `tiny13-l31.train` (65,536 rows) recorded on a TPU v5e
+(`fixtures/driver_round/`, PR 37: the trace and the job's record of the
+program, through `perfbench.program_run --save`)."""
 import gzip
+import json
 import os
 
 import pytest
 
-from perfbench import manifest, readers, trace as T
+from perfbench import manifest, program_readers as P, program_run, readers
+from perfbench import trace as T
 
 FIXTURE = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                       "fixtures", "round.xplane.pb.gz")
+                       "fixtures", "driver_round")
 S = 1e9     # the trace's clock is in ns
+# the fixture's (PR 37): at 65,536 rows the split scan outweighs the kernel;
+# a tree makes 10.5 passes on average (1 root + 4 waves + its strict tail)
+HIST_PCT, PASSES = 13.46, 10.5
 
 # (name, opcode, program, start s, length s) as the device line holds them:
 # a `while` 1..6 that encloses the grower's operations, as control flow does
@@ -90,7 +96,8 @@ def test_shares_counts_idle_and_roofline(handmade):
 
 
 def test_idle_gaps_are_named_by_what_the_host_was_doing(handmade):
-    gaps = T.idle_gaps(handmade)
+    """Without the program's spans a gap is named by the annotation."""
+    gaps = P.idle_gaps({"trace": handmade})
     assert sorted(round(g[1], 6) for g in gaps) == [1.0, 1.0, 2.0]
     assert gaps[0][1] == pytest.approx(2.0)
     # 6..8 lies 0.5 s under `update` and 1.0 s under `between_rounds`
@@ -121,40 +128,52 @@ def test_instruction_text_is_split_into_name_and_opcode():
 
 @pytest.fixture(scope="module")
 def recorded(tmp_path_factory):
+    """(trace, the job's saved result, `run.per_layer`'s reduction of both
+    for the manifest's first cell)."""
     path = tmp_path_factory.mktemp("trace") / "round.xplane.pb"
-    with gzip.open(FIXTURE, "rb") as f:
+    with gzip.open(os.path.join(FIXTURE, "trace.xplane.pb.gz"), "rb") as f:
         path.write_bytes(f.read())
-    return T.load(str(path))
+    with open(os.path.join(FIXTURE, "program.json")) as f:
+        saved = json.load(f)
+    cell = manifest.benchmark()["workloads"][0]["name"]
+    return T.load(str(path)), saved, program_run.reduce_saved(FIXTURE, cell)
 
 
 def test_recorded_trace_reduces(recorded):
-    tr = recorded
+    """Every metric that lists the manifest's first cell reads on its
+    fixture: those of `BENCHMARK.json`'s `per_layer` and those of
+    `program_metrics/`."""
+    tr, saved, out = recorded
+    rounds = saved["units_in_window"]["rounds"]
     assert tr.devices == [0]
     lo, hi = T.window_of(tr)
     busy = T.busy_seconds(tr)
     assert 0 < busy <= (hi - lo) / 1e9
-    assert sum(s.name == "update" for s in tr.spans) == 2
-    assert sum(s.name == "between_rounds" for s in tr.spans) == 2
-    # every operation found its program, and the grower's is the heaviest
+    assert sum(s.name == "update" for s in tr.spans) == rounds
+    assert sum(s.name == "between_rounds" for s in tr.spans) == rounds
+    # every operation found its program, and the grower's is the heaviest:
+    # at 65,536 rows its split scan's `reduce-window` outweighs the kernel
+    # (`test_the_program_metrics_read_the_recorded_run`)
     assert all(o.program for o in tr.ops)
-    assert T.top_ops(tr)[0][0] == "jit_grow:pallas_histogram_multi_rows"
-    ctx = {"trace": tr, "counters": {"jit.recompiles": 0},
-           "memory": {"peak_bytes": 15809536}, "units": {"trees": 2},
-           "shape": {"rows": 65536, "columns": 13, "max_bin": 255},
-           "peaks": manifest.peaks("TPU v5 lite")}
-    got = {}
-    for m in manifest.layer_metrics(
-            manifest.benchmark()["workloads"][0]["name"]):
-        v = readers.read(m, ctx)
-        if v is not None:
-            got[m["name"]] = v
-    assert set(got) == {m["name"] for m in manifest.benchmark()["per_layer"]}
+    assert T.top_ops(tr)[0][0] == "jit_grow:reduce-window"
+    assert "jit_grow:pallas_histogram_multi_rows_full" in dict(T.top_ops(tr))
+    first = manifest.benchmark()["workloads"][0]["name"]
+    got = {k: v["value"] for k, v in out["metrics"].items()}
+    assert set(got) == {m["name"] for m in manifest.benchmark()["per_layer"]
+                        if first in m.get("workloads", [first])}
+    assert set(out["program_metrics"]) == {
+        m["name"] for m in manifest.layer_metrics(
+            first, folder=manifest.PROGRAM_METRICS)}
     # the parts of device-busy time add up to all of it
     parts = ["round.outside_grower_pct", "grower.other_pct", "hist.time_pct"]
     assert all(0 <= got[k] <= 100 for k in parts), got
     assert sum(got[k] for k in parts) == pytest.approx(100.0, abs=0.01)
-    assert got["hist.time_pct"] == pytest.approx(61.4, abs=0.5)
+    assert got["hist.time_pct"] == pytest.approx(HIST_PCT, abs=0.5)
     assert 0 < got["hist_kernel_roofline"] < 100
-    assert got["grower.passes_per_tree"] == 20      # 4 waves + 16 strict
+    assert got["grower.passes_per_tree"] == PASSES
+    assert got["grower.passes_per_tree"] == pytest.approx(
+        out["program_metrics"]["grower.hist_passes_per_tree"]["value"],
+        abs=0.01)
     assert 0 <= got["device.idle_pct"] < 100
-    assert len(T.idle_gaps(tr)) == 10
+    assert got["entry.compiles_in_window"] == 0
+    assert len(out["breakdown"]["idle_gaps"]) == 10

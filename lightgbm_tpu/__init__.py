@@ -5,6 +5,9 @@ JAX/XLA/Pallas compute path, `jax.sharding` data-parallel tree learning over
 ICI/DCN, with the LightGBM Python API reproduced verbatim
 (`Dataset` / `Booster` / `train` / `cv` / sklearn estimators).
 """
+import time as _time
+_IMPORT_T0 = _time.perf_counter()   # the gauge `setup.import_s`, set last
+
 from .basic import Dataset, LightGBMError, Sequence  # noqa: F401
 from .utils.log import register_logger  # noqa: F401
 
@@ -46,3 +49,8 @@ if _ilu.find_spec(".plotting", __package__) is not None:
     __all__ += ["plot_importance", "plot_metric",
                 "plot_split_value_histogram", "plot_tree",
                 "create_tree_digraph"]
+
+from .telemetry import REGISTRY as _REGISTRY  # noqa: E402
+
+_REGISTRY.gauge("setup.import_s").set(_time.perf_counter() - _IMPORT_T0)
+del _time, _IMPORT_T0, _REGISTRY

@@ -24,7 +24,8 @@ import io
 import json
 import time
 from collections import deque
-from typing import Any, Dict, List, NamedTuple, Optional, Tuple, Union
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
@@ -60,6 +61,17 @@ class _PendingChunk(NamedTuple):
     v_iter: Tuple        # per-valid [C, ...] per-iter scores (device)
     it0: int             # first iteration index of the chunk
     dispatch_t: float    # perf_counter right after dispatch returned
+
+
+def _placed(what: str, make: Callable[[], Any]) -> Any:
+    """`make()`, one upload of training state, under a `setup.place` span
+    (attr `what`).  A recording span waits for the arrays, so it holds the
+    transfer and not only its dispatch."""
+    with telemetry.summed_span("setup.place", what=what) as span:
+        out = make()
+        if span is not telemetry.NOOP:
+            jax.block_until_ready(out)
+    return out
 
 
 class _DeviceData:
@@ -111,8 +123,8 @@ class _DeviceData:
                     from .utils.efb import build_bundled_sparse
                     bd = ds.bundle_data = build_bundled_sparse(
                         ds.sparse_binned, self.efb, ds.bin_mappers)
-            self._bundle_fm = jnp.asarray(
-                np.ascontiguousarray(np.asarray(bd).T))
+            self._bundle_fm = _placed("bundle", lambda: jnp.asarray(
+                np.ascontiguousarray(np.asarray(bd).T)))
         mappers = ds.bin_mappers
         self.feat_nb = jnp.asarray(
             np.array([m.num_bin for m in mappers], dtype=np.int32))
@@ -164,27 +176,35 @@ class _DeviceData:
                                       prefetch_depth=depth,
                                       run_stats=self._pf_stats)
 
-    def _put_rows(self, rows: Optional[np.ndarray]) -> Optional[jax.Array]:
+    def _put_rows(self, what: str,
+                  rows: Optional[np.ndarray]) -> Optional[jax.Array]:
         """A per-row host array as f32 on the device(s) the training rows
-        are on; None stays None."""
+        are on, a `setup.place` span of the training set; None stays
+        None."""
         if rows is None:
             return None
         rows = np.asarray(rows, np.float32)
         if self.row_sharding is None:
-            return jnp.asarray(rows)
-        return jax.device_put(rows, self.row_sharding)
+            return self._placed(what, lambda: jnp.asarray(rows))
+        return self._placed(
+            what, lambda: jax.device_put(rows, self.row_sharding))
+
+    def _placed(self, what: str, make: Callable[[], Any]) -> Any:
+        """`make()`, an upload of this data set's rows: a `setup.place`
+        span where it is the training set."""
+        return _placed(what, make) if self._for_train else make()
 
     @property
     def label(self):
         """[N] f32 on the device, uploaded at first use; None without."""
         if self._label is None:
-            self._label = self._put_rows(self._ds.get_label())
+            self._label = self._put_rows("label", self._ds.get_label())
         return self._label
 
     @property
     def weight(self):
         if self._weight is None:
-            self._weight = self._put_rows(self._ds.get_weight())
+            self._weight = self._put_rows("weight", self._ds.get_weight())
         return self._weight
 
     def bins_host(self) -> Optional[np.ndarray]:
@@ -199,7 +219,8 @@ class _DeviceData:
         if self._bins_fm is None:
             host = self.bins_host()
             if host is not None:
-                self._bins_fm = jnp.asarray(host)
+                self._bins_fm = self._placed("bins",
+                                             lambda: jnp.asarray(host))
             elif self._store is not None:
                 self._bins_fm = self._assemble_from_store("bins")
             else:
@@ -424,6 +445,16 @@ class Booster:
         telemetry.MEMLEDGER.configure(
             enabled=bool(self.config.memory_ledger),
             reconcile_ms=float(self.config.memory_reconcile_ms))
+        with telemetry.span("setup.booster") as span:
+            if span is not telemetry.NOOP:
+                for gauge in ("setup.probe_s", "setup.place_s"):
+                    telemetry.REGISTRY.gauge(gauge)     # reads 0, not absent
+            self._init_training_state(train_set)
+
+    def _init_training_state(self, train_set: Dataset) -> None:
+        """The rest of `_init_train`, under its `setup.booster` span: the
+        data on the device, the objective, the grower (kernel probes
+        included) and the per-row state."""
         self._debug_nans = bool(self.config.tpu_debug_nans)
         if self._debug_nans:
             # numeric-sanitizer mode (ref: cmake/Sanitizer.cmake posture):
@@ -624,12 +655,13 @@ class Booster:
         self._dd.row_sharding = self._row_sharding()
         telemetry.REGISTRY.gauge("mesh.shards").set(
             1 if self._mesh is None else self._mesh.devices.size)
-        self._ones = self._rows_of(self._dd, 1.0)
+        self._ones = _placed("ones", lambda: self._rows_of(self._dd, 1.0))
 
         K = self.num_tree_per_iteration
         self._init_scores = [0.0] * K
         self._boost_from_average_done = False
-        self._train_score = self._zero_score(self._dd)
+        self._train_score = _placed("score",
+                                    lambda: self._zero_score(self._dd))
         self._valid_dd: List[_DeviceData] = []
         self._valid_scores: List[jax.Array] = []
         # pipelined chunk training state: FIFO of dispatched-but-not-yet-
@@ -1324,13 +1356,13 @@ class Booster:
             # assembles the full matrix, peak residency is one device
             # slice + the prefetch window
             from .mesh.placement import place_from_datastore
-            self._train_bins = place_from_datastore(
+            self._train_bins = _placed("bins", lambda: place_from_datastore(
                 self._dd.store, self._mesh, kind,
                 payload="bundle" if bundled else "bins",
                 pad_features=pad_features,
                 prefetch_depth=cfg.datastore_prefetch,
                 collective_timeout_ms=cfg.mesh_collective_timeout_ms,
-                run_stats=self._dd._pf_stats)
+                run_stats=self._dd._pf_stats))
             # placement registered the per-device buffers under
             # `datastore.place` — the round-boundary ledger sweep must
             # not attribute the same bytes again under `train.bins`
@@ -1347,9 +1379,9 @@ class Booster:
                 else self._dd.bins_host()
             if train_src is None:
                 train_src = self._dd.bins_fm
-            self._train_bins = place_training_data(
+            self._train_bins = _placed("bins", lambda: place_training_data(
                 np.asarray(train_src), self._mesh, kind,
-                pad_features=pad_features)
+                pad_features=pad_features))
             self._train_bins_attributed = False
         self._grower = make_distributed_grower(
             self._grower_spec, self._mesh, kind,

@@ -82,6 +82,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..telemetry.spans import summed_span
 from ..utils.log import LightGBMError
 from .histogram import hist_value, limb_add
 
@@ -982,14 +983,17 @@ def probe_cached(max_bin: int = 256, num_feature: int = 28,
                  quantized: bool = None, interpret: bool = False,
                  plan=None) -> ProbeResult:
     """probe(), memoised per (backend platform, shape, multi params,
-    lane plan)."""
+    lane plan); a probe that runs is one `setup.probe` span."""
     key = (jax.devices()[0].platform, max_bin, num_feature, multi,
            width, quantized, interpret, plan)
     if key not in _PROBE_CACHE:
-        _PROBE_CACHE[key] = probe(interpret=interpret, max_bin=max_bin,
-                                  num_feature=num_feature, multi=multi,
-                                  width=width, quantized=quantized,
-                                  plan=plan)
+        with summed_span("setup.probe", max_bin=max_bin,
+                         num_feature=num_feature, multi=multi, width=width,
+                         quantized=quantized):
+            _PROBE_CACHE[key] = probe(interpret=interpret, max_bin=max_bin,
+                                      num_feature=num_feature, multi=multi,
+                                      width=width, quantized=quantized,
+                                      plan=plan)
     return _PROBE_CACHE[key]
 
 

@@ -19,7 +19,7 @@ from .metrics import (Counter, Gauge, Histogram, HISTOGRAM_BOUNDS,
                       MetricsRegistry, REGISTRY, Timing, write_prometheus)
 from .sinks import (JsonlSink, MemorySink, Sink, iso_ts, make_event,
                     read_jsonl, read_jsonl_counted)
-from .spans import NOOP, Span, TRACER, Tracer, event, span
+from .spans import NOOP, Span, TRACER, Tracer, event, span, summed_span
 from .spool import (SpoolSink, aggregate as aggregate_spool, attach_spool,
                     chrome_trace, render_timeline)
 from .report import render, summarize
@@ -41,7 +41,7 @@ __all__ = [
     "REGISTRY", "Timing", "write_prometheus",
     "JsonlSink", "MemorySink", "Sink", "iso_ts", "make_event", "read_jsonl",
     "read_jsonl_counted",
-    "NOOP", "Span", "TRACER", "Tracer", "event", "span",
+    "NOOP", "Span", "TRACER", "Tracer", "event", "span", "summed_span",
     "SpoolSink", "aggregate_spool", "attach_spool", "chrome_trace",
     "render_timeline",
     "render", "summarize",

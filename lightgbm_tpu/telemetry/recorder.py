@@ -34,6 +34,7 @@ import collections
 import math
 import sys
 import threading
+import time
 from typing import Any, Dict, List, Optional, Sequence
 
 from .metrics import REGISTRY
@@ -378,41 +379,99 @@ def throughput_report(rounds: int, seconds: float, num_data: int,
 _compile_listener_installed = False
 _compile_lock = threading.Lock()
 
-#: the jax.monitoring duration that marks one XLA computation compile (or
-#: its retrieval from the persistent cache, which the same event times).
-#: The trace/lowering durations fire alongside but must not double-count.
-_COMPILE_EVENT_MARKER = "backend_compile"
-#: the jax.monitoring event (not a duration) of one persistent-cache miss:
-#: a program compiled and written because no entry had its key.
+#: the jax.monitoring time spans of JAX's compile pipeline, by the span
+#: each becomes.  `backend_compile` marks one XLA computation compile or
+#: its retrieval from the persistent cache, which the same event times.
+_JIT_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jit.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jit.lower",
+    "/jax/core/compile/backend_compile_duration": "jit.compile",
+}
+#: the jax.monitoring events (not durations) of one persistent-cache miss
+#: (a program compiled and written because no entry had its key) and of
+#: one hit (fired inside the `backend_compile` span that loaded it).
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+#: jax.monitoring stamps spans with `time.time()`; spans are on
+#: `perf_counter_ns`.  One offset, taken at install, converts.
+_clock_offset_ns = 0
+#: per thread, the wall-clock time of the last cache hit while recording
+_hits = threading.local()
+#: per recorded span name, the disjoint [start, end) intervals (wall
+#: clock) its spans covered: the union behind `jit.trace_s` / `jit.lower_s`
+_covered: Dict[str, List[List[float]]] = {}
+
+
+def _cover(intervals: List[List[float]], start: float, end: float) -> float:
+    """Add [start, end) to the sorted, disjoint `intervals` in place;
+    returns the seconds it adds to their union.  Spans arrive as they end,
+    so an enclosing span arrives after what it encloses and covers the
+    last few intervals."""
+    j = len(intervals)
+    while j and intervals[j - 1][0] > end:       # wholly after it
+        j -= 1
+    k = j
+    while k and intervals[k - 1][1] >= start:    # overlapping it
+        k -= 1
+    merged = intervals[k:j]
+    lo = min([start] + [a for a, _ in merged])
+    hi = max([end] + [b for _, b in merged])
+    intervals[k:j] = [[lo, hi]]
+    return (hi - lo) - sum(b - a for a, b in merged)
+
+
+def _on_jit_span(name: str, start: float, end: float, **kw) -> None:
+    """One callback for each span of JAX's compile pipeline.  Always: a
+    backend compile counts in `jit.recompiles` and `jit.compile_total_s`.
+    While the tracer is active: the span `jit.trace` / `jit.lower` /
+    `jit.compile` under the span open on this thread, and the union
+    gauges `jit.trace_s` / `jit.lower_s`."""
+    span = _JIT_SPANS.get(name)
+    if span == "jit.compile":
+        REGISTRY.counter("jit.recompiles").inc()
+        g = REGISTRY.gauge("jit.compile_total_s")
+        g.set(g.value + (end - start))
+    if span is None or not TRACER.active:
+        return
+    attrs = {"fun": str(kw.get("fun_name", ""))}
+    if span == "jit.compile":
+        hit = getattr(_hits, "at", None)
+        attrs["cache"] = "hit" if hit is not None and start <= hit <= end \
+            else "miss"
+    else:
+        with _compile_lock:
+            g = REGISTRY.gauge(span + "_s")
+            g.set(g.value + _cover(_covered.setdefault(span, []), start,
+                                   end))
+    TRACER.record(span, round(start * 1e9) + _clock_offset_ns,
+                  round(end * 1e9) + _clock_offset_ns, start, **attrs)
+
+
+def _on_jit_event(name: str, **kw) -> None:
+    if name == _CACHE_MISS_EVENT:
+        REGISTRY.counter("jit.cache_misses").inc()
+    elif name == _CACHE_HIT_EVENT and TRACER.active:
+        _hits.at = time.time()
 
 
 def install_compile_listener() -> bool:
     """Hook `jax.monitoring` so every backend compile increments
     `jit.recompiles` and accumulates `jit.compile_total_s`, and every
-    persistent-cache miss increments `jit.cache_misses`.  Idempotent;
-    returns False only when jax is not loaded in this process (this
-    module never imports it)."""
-    global _compile_listener_installed
+    persistent-cache miss increments `jit.cache_misses`; while a sink is
+    attached, the compile pipeline also becomes spans (`_on_jit_span`).
+    Idempotent; returns False only when jax is not loaded in this process
+    (this module never imports it)."""
+    global _compile_listener_installed, _clock_offset_ns
     with _compile_lock:
         if _compile_listener_installed:
             return True
         jax = sys.modules.get("jax")
         if jax is None:
             return False
-
-        def _on_duration(name: str, secs: float, **kw) -> None:
-            if _COMPILE_EVENT_MARKER in name:
-                REGISTRY.counter("jit.recompiles").inc()
-                g = REGISTRY.gauge("jit.compile_total_s")
-                g.set(g.value + float(secs))
-
-        def _on_event(name: str, **kw) -> None:
-            if name == _CACHE_MISS_EVENT:
-                REGISTRY.counter("jit.cache_misses").inc()
-
-        jax.monitoring.register_event_duration_secs_listener(_on_duration)
-        jax.monitoring.register_event_listener(_on_event)
+        _clock_offset_ns = time.perf_counter_ns() - time.time_ns()
+        jax.monitoring.register_event_time_span_listener(_on_jit_span)
+        jax.monitoring.register_event_listener(_on_jit_event)
         REGISTRY.counter("jit.cache_misses")    # reads 0, not absent
         _compile_listener_installed = True
         return True

@@ -40,6 +40,36 @@ The spans of one boosting round (`Booster.update`, booster.py):
 The fused chunk path keeps `train.chunk` (dispatch) and `train.harvest` >
 `train.decode` (readback, decode), each with the chunk's first `round`.
 
+The spans of set-up, all on the same clock as the round's:
+
+    setup.booster               `Booster(params, train_set)`: everything
+                                after the booster's own sinks attach
+      setup.probe               one kernel probe that ran (a cached
+                                verdict records nothing; attrs: max_bin,
+                                num_feature, multi, width, quantized)
+      setup.place               one upload of training state (attr
+                                `what`: bins, bundle, ones, score, label,
+                                weight); a recording span waits for the
+                                arrays, so it holds the transfer
+        parallel.place_data     the bin matrix placed on a mesh
+
+`setup.probe` and `setup.place` also add their seconds to the gauges
+`setup.probe_s` / `setup.place_s` (`summed_span`), which a recording
+`setup.booster` starts at 0.  The gauge `setup.import_s` is the package's
+import (`lightgbm_tpu/__init__.py`).  JAX's own compile pipeline reaches
+the record as spans too (`recorder.install_compile_listener`), nested in
+whatever span was open on the thread when JAX ran it (`setup.probe`,
+`compile_warmup`, `train.gradients`, ...):
+
+    jit.trace                   Python traced to a jaxpr (attr `fun`)
+    jit.lower                   the jaxpr lowered to an MLIR module
+    jit.compile                 compiled, or loaded from the persistent
+                                cache (attr `cache`: hit / miss)
+
+JAX traces a nested jit inside its caller's trace, so `jit.trace` spans
+of one thread may overlap; the gauges `jit.trace_s` / `jit.lower_s` hold
+the seconds of their UNION while a sink was attached.
+
 Device-side, the phases of a tree are `jax.named_scope`s inside the jitted
 growers; they reach the profiler's trace as the `tf_op` stat of a device
 event's METADATA (`jax.profiler.ProfileData` does not show it;
@@ -55,6 +85,7 @@ would hold the chip its child needs.
 """
 from __future__ import annotations
 
+import contextlib
 import itertools
 import os
 import sys
@@ -89,8 +120,8 @@ NOOP = _NOOP = _NoopSpan()
 class Span:
     """One named wall-clock phase; records itself on exit."""
 
-    __slots__ = ("tracer", "name", "attrs", "t0", "wall0", "depth",
-                 "parent", "id", "parent_id", "round", "_annot")
+    __slots__ = ("tracer", "name", "attrs", "t0", "end_ns", "wall0",
+                 "depth", "parent", "id", "parent_id", "round", "_annot")
 
     _ids = itertools.count(1)
 
@@ -129,8 +160,7 @@ class Span:
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
-        end = time.perf_counter_ns()
-        dur = (end - self.t0) / 1e9
+        end = self.end_ns = time.perf_counter_ns()
         if self._annot is not None:
             try:
                 self._annot.__exit__(exc_type, exc, tb)
@@ -141,22 +171,35 @@ class Span:
             stack.pop()
         elif self in stack:       # unbalanced exit (generator teardown)
             stack.remove(self)
-        REGISTRY.timing(f"span.{self.name}").observe(dur)
-        ev = make_event("span", self.name, dur_s=round(dur, 6),
-                        depth=self.depth, pid=os.getpid(), id=self.id,
-                        start_ns=self.t0, end_ns=end)
-        ev["ts"] = round(self.wall0, 6)  # span events stamp their START
-        if self.parent is not None:
-            ev["parent"] = self.parent
-            ev["parent_id"] = self.parent_id
-        if self.round is not None:
-            ev["round"] = self.round
-        if self.attrs:
-            ev["attrs"] = self.attrs
+        ev = _span_event(self.name, self.id, self.depth, self.t0, end,
+                         self.wall0, self.parent, self.parent_id,
+                         self.round, self.attrs)
         if exc_type is not None:
             ev["error"] = getattr(exc_type, "__name__", str(exc_type))
         self.tracer._emit(ev)
         return False
+
+
+def _span_event(name: str, span_id: int, depth: int, start_ns: int,
+                end_ns: int, wall0: float, parent: Optional[str],
+                parent_id: Optional[int], round_: Optional[int],
+                attrs: Dict[str, Any]) -> Dict[str, Any]:
+    """The event of one ended span; its seconds are observed into the
+    timing registry (`span.<name>`)."""
+    dur = (end_ns - start_ns) / 1e9
+    REGISTRY.timing(f"span.{name}").observe(dur)
+    ev = make_event("span", name, dur_s=round(dur, 6), depth=depth,
+                    pid=os.getpid(), id=span_id, start_ns=start_ns,
+                    end_ns=end_ns)
+    ev["ts"] = round(wall0, 6)  # span events stamp their START
+    if parent is not None:
+        ev["parent"] = parent
+        ev["parent_id"] = parent_id
+    if round_ is not None:
+        ev["round"] = round_
+    if attrs:
+        ev["attrs"] = attrs
+    return ev
 
 
 class Tracer:
@@ -235,6 +278,25 @@ class Tracer:
         st = self._stack()
         return st[-1] if st else None
 
+    def record(self, name: str, start_ns: int, end_ns: int,
+               wall0: float, **attrs: Any) -> None:
+        """Emit a span that has already ended, timed by someone else (on
+        `perf_counter_ns`; `wall0` the wall-clock start), as a child of
+        the span open on this thread.  It never starts before that
+        parent: a start converted from another clock may read a few
+        microseconds early.  No-op when inactive."""
+        if not self.active:
+            return
+        stack = self._stack()
+        above = stack[-1] if stack else None
+        if above is not None:
+            start_ns = max(start_ns, above.t0)
+        end_ns = max(end_ns, start_ns)
+        self._emit(_span_event(
+            name, next(Span._ids), len(stack), start_ns, end_ns, wall0,
+            above.name if above else None, above.id if above else None,
+            above.round if above else None, attrs))
+
     # ------------------------------------------------------------ events
     def _emit(self, event: Dict[str, Any]) -> None:
         for s in list(self._sinks):
@@ -271,6 +333,19 @@ TRACER = Tracer()
 
 def span(name: str, **attrs: Any):
     return TRACER.span(name, **attrs)
+
+
+@contextlib.contextmanager
+def summed_span(name: str, **attrs: Any):
+    """`span(name)` whose seconds, when it records, also add up in the
+    gauge `<name>_s`: a total the registry holds for whoever snapshots it
+    (`setup.probe_s`, `setup.place_s`).  Inactive: the shared no-op, and
+    no gauge."""
+    with TRACER.span(name, **attrs) as s:
+        yield s
+    if s is not _NOOP:
+        g = REGISTRY.gauge(name + "_s")
+        g.set(g.value + (s.end_ns - s.t0) / 1e9)
 
 
 def event(name: str, **fields: Any) -> Dict[str, Any]:

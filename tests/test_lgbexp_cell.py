@@ -74,20 +74,22 @@ def test_the_control_and_both_faults_fail_the_fixtures_limits(tmp_path,
 # ----------------------------------------------------------- the manifest
 def test_two_configurations_two_cells_sixteen_metric_files():
     """The benchmark's first two configurations and cells and their
-    sixteen metric files (later PRs append: tests/test_criteo67_cell.py)."""
+    sixteen metric files: the old cell's eight and the new cell's eight
+    `l255.*` twins, the manifest's first sixteen entries (later PRs
+    append metrics of their own: tests/test_criteo67_cell.py)."""
     assert manifest.problems() == []
     b = manifest.benchmark()
     assert [c["name"] for c in b["configs"]][:2] == [
         "airline13-l31", "airline13-lgbexp-l255"]
     assert [w["name"] for w in b["workloads"]][:2] == [OLD_CELL, CELL]
     mine = [m for m in b["per_layer"][:16]]
-    assert all(m["workloads"] in ([OLD_CELL], [CELL, OLD_CELL])
-               for m in mine)
-    files = [f for f in os.listdir(os.path.join(manifest.HERE,
-                                                "layer_metrics"))
-             if f.endswith(".json")
-             and not f.startswith(("par4.", "cat."))]
-    assert len(files) == 16
+    assert [m["workloads"] for m in mine] == [[OLD_CELL]] * 8 \
+        + [[CELL, OLD_CELL]] * 8
+    assert [m["name"] for m in mine[8:]] == [
+        "l255." + m["name"] for m in mine[:8]]
+    for m in mine:
+        assert os.path.isfile(os.path.join(
+            manifest.HERE, "layer_metrics", m["name"] + ".json"))
     assert b["workloads"][1]["chips"] == 1
 
 
@@ -99,9 +101,10 @@ def test_each_new_metric_is_an_old_readers_twin_and_lists_the_new_cell():
     manifest's FIRST cell reports every `per_layer` metric (PERF.md 7.10
     says which line a `benchmark` PR changes to let the lists part)."""
     d = os.path.join(manifest.HERE, "layer_metrics")
-    new = {m["name"]: m for m in manifest.layer_metrics(CELL)}
+    new = {m["name"]: m for m in manifest.layer_metrics(CELL)
+           if m["name"].startswith("l255.")}
     old = {m["name"]: m for m in manifest.layer_metrics(OLD_CELL)
-           if not m["name"].startswith(("l255.", "par4.", "cat."))}
+           if "l255." + m["name"] in new}
     assert len(new) == len(old) == 8
     assert set(new) == {"l255." + n for n in old}
     for name, m in new.items():

@@ -91,7 +91,8 @@ class TestSpanTree:
                 assert e["parent"] in names, e
                 assert e["depth"] >= 1, e
         # the documented nesting of a 2-round per-iteration run
-        assert by_name["dataset.bin"][0]["parent"] == "train.loop"
+        assert by_name["setup.booster"][0]["parent"] == "train.loop"
+        assert by_name["dataset.bin"][0]["parent"] == "setup.booster"
         assert by_name["train.chunk"][0]["parent"] == "train.loop"
         assert by_name["compile_warmup"][0]["parent"] == "train.chunk"
         assert by_name["train.loop"][0]["depth"] == 0
@@ -516,6 +517,7 @@ class TestCompileListener:
         jax.monitoring.record_event("/jax/compilation_cache/cache_misses")
         jax.monitoring.record_event("/jax/compilation_cache/cache_hits")
         assert (misses.value, compiles.value) == (m0 + 1, c0)
-        jax.monitoring.record_event_duration_secs(
-            "/jax/core/compile/backend_compile_duration", 0.25)
+        jax.monitoring.record_event_time_span(
+            "/jax/core/compile/backend_compile_duration", 100.0, 100.25,
+            fun_name="f")
         assert (misses.value, compiles.value) == (m0 + 1, c0 + 1)

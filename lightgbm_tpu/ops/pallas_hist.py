@@ -32,11 +32,16 @@ multi-hot groups: the airline table contracts 1,920 lanes a row, not
 that is 70 % of the MXU's bf16 peak on a 72-row LHS, so what is left is
 the ROWS: past a tree's first waves most rows of a pass are in no slot
 (73 % / 90 % of all rows contracted in the benchmark's cells).  A pass
-whose every tile holds at most 256 or 512 active rows (`COMPACT_CAPS`)
-lands them in that many columns with one selection matmul — a one-hot
-like any other — and contracts those for the tile's 2048
-(`_hist_kernel_multi_compact`): the same call takes 3.2 ms / 4.9 ms for
-9.4 ms whatever the share of active rows, the ranking fusion included.
+whose every tile holds at most 128, 256 or 512 active rows
+(`COMPACT_CAPS`) lands them in that many columns with one selection
+matmul — a one-hot like any other — and contracts those for the tile's
+2048 (`_hist_kernel_multi_compact`): the same call takes 3.2 ms / 4.9 ms
+for 9.4 ms at 256 / 512 whatever the share of active rows, the ranking
+fusion included, and over 83,886,080 rows a 128 call 0.72x of a 256 one.
+Both the selection one-hot ([cap, N_t]) and the histogram dot (cap
+contracted columns) grow with the capacity, so most of a compacting
+call's price does; below 128 columns the per-tile rest is what is left
+(64 read 0.95x of 128), so 128 is the smallest (PERF.md section 6).
 
 Precision design (replaces the old Precision.HIGHEST formulation, which
 cost 3-6 MXU passes): the one-hot operand is {0,1} — exact in bf16 at any
@@ -433,11 +438,11 @@ def _combine_terms(hi: Array, lo: Array):
 
 
 # Active rows a tile that a compacting body holds, ascending: a pass runs
-# the smallest that holds its fullest tile, else the full body.  From one
-# call alone on the chip (PERF.md section 6, PR 32): kept where a call is
-# at most 0.8x of the full one at the largest share of active rows it
-# admits.
-COMPACT_CAPS = (256, 512)
+# the smallest that holds its fullest tile, else the full body.  A
+# capacity is kept where one call alone on the chip (PERF.md section 6)
+# takes at most 0.8x of the next larger body's at the largest share of
+# active rows it admits (the full body's, above 512).
+COMPACT_CAPS = (128, 256, 512)
 
 
 def hist_bodies(row_tile: int = ROW_TILE):

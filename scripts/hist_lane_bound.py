@@ -19,6 +19,13 @@ compacting capacity that holds the fullest tile (forced: `body=`), and as
 the dispatch picks (`auto`); each line carries the largest |difference|
 of its sums from the full body's, relative to the largest sum.
 
+    python3 scripts/hist_lane_bound.py --rows 83886080 \
+        --active-share 0.01,0.02,0.035,0.06 --capacity 64,128
+
+times the capacities below 256 the same way at the 255-leaf cells' rows
+(40,960 tiles), where the deep waves' fullest tile holds 25-170 active
+rows: `c128` is kept where its call is at most 0.8x of `c256`'s.
+
     chiprun -- python3 scripts/hist_lane_bound.py --num-bins 255x9,12,255x57,1 \
         --blocks 1,2,3,4
 
@@ -211,10 +218,11 @@ def compaction(a):
         for body in ["full"] + [f"c{c}" for c in caps if fullest <= c
                                 and c < row_tile] + [None]:
             med, all_s = _time(call, (lid, body), a.reps)
-            sums, calls = call(lid, body)
-            sums = np.asarray(ph.hist_value(sums), np.float64)
+            limbs, calls = call(lid, body)
+            limbs = np.asarray(limbs)
+            sums = np.asarray(ph.hist_value(jnp.asarray(limbs)), np.float64)
             if ref is None:
-                ref = sums
+                ref, ref_limbs = sums, limbs
             print(json.dumps({
                 "active_share": p, "fullest_tile": fullest,
                 "body": body or "auto", "call_s": med,
@@ -222,6 +230,9 @@ def compaction(a):
                                 np.asarray(calls).tolist())),
                 "rel_diff_from_full": float(
                     np.abs(sums - ref).max() / np.abs(ref).max()),
+                "limbs_bit_equal_to_full": bool(
+                    np.array_equal(limbs.view(np.uint32),
+                                   ref_limbs.view(np.uint32))),
                 "all_s": all_s}), flush=True)
     return 0
 

@@ -7,6 +7,9 @@ integer payloads (every f32 sum exact) both limbs equal the full body's
 bit for bit; with real payloads the sums agree with a float64 count inside
 the two-limb tolerance of `tests/test_limb_sums.py`.
 """
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -16,7 +19,7 @@ import jax.numpy as jnp
 import lightgbm_tpu as lgb
 from lightgbm_tpu import telemetry
 from lightgbm_tpu.ops import pallas_hist as ph
-from perfbench import manifest
+from perfbench import manifest, readers
 from perfbench.generators import tabular_codes
 from perfbench.jobs.train import build_dataset
 
@@ -58,7 +61,8 @@ def _float64_sums(bins, pay, lid, mb=255):
 
 
 # --------------------------------------------------- (a) the compaction alone
-@pytest.mark.parametrize("cap,count", [(256, 0), (256, 97), (256, 256),
+@pytest.mark.parametrize("cap,count", [(128, 0), (128, 97), (128, 128),
+                                       (256, 0), (256, 97), (256, 256),
                                        (512, 257), (512, 512)])
 def test_compaction_lands_the_active_rows_in_order(cap, count):
     rng = np.random.RandomState(cap + count)
@@ -83,7 +87,8 @@ def test_compaction_lands_the_active_rows_in_order(cap, count):
 
 # ------------------------------------- (b) integer payloads: the same bits
 @pytest.mark.parametrize("planned", [False, True])
-@pytest.mark.parametrize("body,share", [("c256", 0.08), ("c512", 0.18)])
+@pytest.mark.parametrize("body,share", [("c128", 0.035), ("c256", 0.08),
+                                        ("c512", 0.18)])
 def test_integer_payload_limbs_equal_the_full_bodys(body, share, planned):
     """More tiles than one fold takes (`FLUSH_TILES`) and a ragged last
     tile; the dispatch picks the same body the test forces."""
@@ -104,8 +109,8 @@ def test_integer_payload_limbs_equal_the_full_bodys(body, share, planned):
 
 
 # ---------------------- (c) real payloads: the same sums, to the two limbs
-@pytest.mark.parametrize("body,share", [("full", 0.08), ("c256", 0.08),
-                                        ("c512", 0.18)])
+@pytest.mark.parametrize("body,share", [("full", 0.08), ("c128", 0.035),
+                                        ("c256", 0.08), ("c512", 0.18)])
 def test_real_payload_sums_against_float64(body, share):
     n = TILE * 3
     rng = np.random.RandomState(5)
@@ -127,7 +132,8 @@ def _one_tile_with(count, n_tiles=3):
 
 
 @pytest.mark.parametrize("case,body", [
-    ("all_rows_active", "full"), ("no_row_active", "c256"),
+    ("all_rows_active", "full"), ("no_row_active", "c128"),
+    ("a_tile_at_128", "c128"), ("a_tile_at_129", "c256"),
     ("a_tile_at_256", "c256"), ("a_tile_at_257", "c512"),
     ("a_tile_at_512", "c512"), ("a_tile_at_513", "full"),
     ("uint16_bins", "full"), ("split_feat_tile", "c256"),
@@ -138,8 +144,8 @@ def test_dispatch_runs_the_body_the_rule_says(case, body):
               "no_row_active": np.zeros(n, bool)}.get(case)
     if case.startswith("a_tile_at_"):
         active = _one_tile_with(int(case.rsplit("_", 1)[1]))
-    if active is None:
-        active = np.random.RandomState(6).rand(n) < 0.05
+    if active is None:      # every tile over 128 active rows, under 256
+        active = np.random.RandomState(6).rand(n) < 0.08
     kw, mb, num_bins, dtype = {}, 255, AIRLINE_NUM_BIN, np.uint8
     if case == "uint16_bins":
         mb, num_bins, dtype = 300, (300, 40, 7), np.uint16
@@ -154,7 +160,7 @@ def test_dispatch_runs_the_body_the_rule_says(case, body):
     got, ran = _rows(args, mb, **kw)
     assert ran[body] == 1 and sum(ran.values()) == 1, ran
     statically_full = case in ("uint16_bins", "row_tile_of_100")
-    assert len(ran) == (1 if case == "row_tile_of_100" else 3)
+    assert len(ran) == (1 if case == "row_tile_of_100" else 4)
     if not statically_full:
         want, _ = _rows(args, mb, body="full", **kw)
         np.testing.assert_array_equal(got, want)
@@ -179,7 +185,8 @@ def test_more_slots_than_a_chunk_count_a_call_a_chunk():
     slots = jnp.arange(ph.MULTI_CHUNK + 3, dtype=jnp.int32)
     got, calls = ph.pallas_histogram_multi_rows(
         bins, pw9, lid, slots, 16, interpret=True, count_bodies=True)
-    assert np.asarray(calls).tolist() == [0, 2, 0]
+    # a full chunk of slots holds ~143 rows a tile, the last three ~31
+    assert np.asarray(calls).tolist() == [0, 1, 1, 0]
     want = ph.pallas_histogram_multi_rows(bins, pw9, lid, slots, 16,
                                           interpret=True, body="full")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -194,7 +201,7 @@ _ONE_BLOCK = {}
 
 def _wide_case():
     """Two row tiles of 640 rows, the second ragged; 12% of the rows in
-    the slots, so a tile holds under 256 of them."""
+    the slots, so a tile holds under 128 of them."""
     if not _ONE_BLOCK:
         n = 640 + 300
         rng = np.random.RandomState(11)
@@ -206,7 +213,7 @@ def _wide_case():
     return _ONE_BLOCK["args"], _ONE_BLOCK["want"]
 
 
-@pytest.mark.parametrize("body", ["full", "c256", "c512"])
+@pytest.mark.parametrize("body", ["full", "c128", "c256", "c512"])
 @pytest.mark.parametrize("planned", [False, True])
 @pytest.mark.parametrize("n_blocks", [1, 2, 4])
 def test_column_blocks_sum_the_same_bits(n_blocks, planned, body):
@@ -227,11 +234,11 @@ def test_column_blocks_sum_the_same_bits(n_blocks, planned, body):
     assert ran[body] == 1 and sum(ran.values()) == 1
     assert got.shape == want.shape == (S, 68, 255, 6)
     np.testing.assert_array_equal(got, want)
-    if body == "c256" and planned and n_blocks == 4:
+    if body == "c128" and planned and n_blocks == 4:
         # and as the dispatch picks: the same body
         picked, ran = _rows(args, row_tile=640, plan=plan,
                             feat_tile=feat_tile)
-        assert ran == {"full": 0, "c256": 1, "c512": 0}
+        assert ran == {"full": 0, "c128": 1, "c256": 0, "c512": 0}
         np.testing.assert_array_equal(picked, want)
 
 
@@ -285,7 +292,7 @@ def _grow(monkeypatch, caps):
 def test_wave_grower_grows_the_same_trees_and_counts_its_bodies(
         monkeypatch):
     today, grown0 = _grow(monkeypatch, ())
-    new, grown = _grow(monkeypatch, (256, 512))
+    new, grown = _grow(monkeypatch, (128, 256, 512))
     jax.clear_caches()
     assert today._grow_policy == new._grow_policy == "wave"
     for a, b in zip(today.trees, new.trees):
@@ -300,16 +307,69 @@ def test_wave_grower_grows_the_same_trees_and_counts_its_bodies(
     passes = grown["grow.wave_passes"] + grown["grow.tail_passes"] + 3
     by_body = {k[len("grow.hist_passes_"):]: v for k, v in grown.items()
                if k.startswith("grow.hist_passes_")}
-    assert set(by_body) == {"full", "c256", "c512"}
+    assert set(by_body) == {"full", "c128", "c256", "c512"}
     assert sum(by_body.values()) == passes
-    assert by_body["full"] >= 3 and by_body["c256"] + by_body["c512"] > 0
+    assert by_body["full"] >= 3 and by_body["c128"] + by_body["c256"] \
+        + by_body["c512"] > 0
     tiles = ROWS // TILE
     assert grown["grow.hist_rows_contracted"] == tiles * (
         by_body["full"] * TILE + by_body["c512"] * 512
-        + by_body["c256"] * 256)
+        + by_body["c256"] * 256 + by_body["c128"] * 128)
     assert grown["grow.hist_rows_needed"] \
         <= grown["grow.hist_rows_contracted"] < passes * ROWS
     # today's bodies: every pass full, every row contracted
     assert grown0["grow.hist_passes_full"] == passes
     assert grown0["grow.hist_rows_contracted"] == passes * ROWS
     assert grown0["grow.hist_rows_needed"] == grown["grow.hist_rows_needed"]
+
+
+# --------------------------------- (f) the benchmark's reading of the bodies
+METRIC = "hist.c128_pass_pct"
+PARENT_RECORD = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                             "perfbench", "fixtures", "driver_round",
+                             "program.json")
+
+
+def _metric():
+    with open(os.path.join(manifest.HERE, "layer_metrics",
+                           METRIC + ".json")) as f:
+        return json.load(f)
+
+
+def test_c128_pass_pct_is_listed_for_the_255_leaf_cells():
+    """In the manifest with its file, over every body's counter in the
+    order `hist_bodies` counts them; listed for the cells whose trees have
+    255 leaves (`.l31`'s fixture record predates the body)."""
+    assert manifest.problems() == []
+    b = manifest.benchmark()
+    entry, = [m for m in b["per_layer"] if m["name"] == METRIC]
+    assert (entry["layer"], entry["moves"], entry["unit"], entry["better"],
+            entry["source"]) == ("histogram kernel", "train_rounds_per_s",
+                                 "%", "higher", "program_counter")
+    deep = [w["name"] for w in b["workloads"] if manifest.config(
+        w["config"])["params"]["num_leaves"] == 255]
+    m = _metric()
+    assert entry["workloads"] == m["workloads"] == deep and len(deep) == 3
+    assert m["reader"] == "counter_ratio"
+    assert m["args"]["num"] == ["grow.hist_passes_c128"]
+    assert m["args"]["den"] == [f"grow.hist_passes_{name}"
+                                for name, _ in ph.hist_bodies()]
+
+
+@pytest.mark.parametrize("record,want", [
+    ("parent_record", 0.0), ("a_255_leaf_window", 100 * 20 / 38),
+    ("no_counters", None)])
+def test_c128_pass_pct_reads_the_programs_counters(record, want):
+    """0 on a record of the program before the body (the recorded traced
+    fixture run), the body's share of the calls on one that has it, and
+    nothing where the program counted no kernel call."""
+    counters = {"no_counters": {"grow.leaves": 255},
+                "a_255_leaf_window": {
+                    "grow.hist_passes_full": 6, "grow.hist_passes_c128": 20,
+                    "grow.hist_passes_c256": 8, "grow.hist_passes_c512": 4}
+                }.get(record)
+    if counters is None:
+        with open(PARENT_RECORD) as f:
+            counters = json.load(f)["counters"]
+    got = readers.read(_metric(), {"program": None, "counters": counters})
+    assert got == (pytest.approx(want) if want else want)

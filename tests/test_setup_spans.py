@@ -322,4 +322,7 @@ def test_the_six_metrics_are_in_the_manifest_and_move_setup():
         # (tests/perfbench/test_perfbench_trace.py::test_recorded_trace_reduces)
         assert m["workloads"] == cells[1:]
         assert m["reader"] in ("counter_at_start", "first_span_s")
-    assert [m["name"] for m in b["per_layer"][-6:]] == list(SETUP_METRICS)
+    # listed together, in this order (later PRs append metrics of their own)
+    names = [m["name"] for m in b["per_layer"]]
+    at = names.index(SETUP_METRICS[0])
+    assert names[at:at + 6] == list(SETUP_METRICS)

@@ -96,11 +96,11 @@ def test_hist_kernel_f32_lane_plan(topo, width):
         plan=plan).compile()
 
 
-@pytest.mark.parametrize("body", [None, "full", "c256", "c512"])
+@pytest.mark.parametrize("body", [None, "full", "c128", "c256", "c512"])
 @pytest.mark.parametrize("width", [1, 8])
 def test_hist_kernel_f32_compacting_bodies(topo, width, body):
     """Each compacting body alone (forced) and the dispatching program
-    (`body=None`: the three kernels under one `lax.switch`), at the cells'
+    (`body=None`: the four kernels under one `lax.switch`), at the cells'
     columns and plan.  Each kernel's custom-call is named
     `pallas_histogram_multi_rows*`: the benchmark's trace readers select
     the histogram kernel by that prefix."""

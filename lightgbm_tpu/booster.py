@@ -690,23 +690,24 @@ class Booster:
                 self._grad_rng_fn = _with_rows(jax.jit(_grad), lbl, wgt)
                 self._grad_fn = lambda s: self._grad_rng_fn(
                     s, jax.random.fold_in(self._grad_key0, self.cur_iter))
-            elif getattr(self.objective_, "has_state", False):
-                # stateful objective (unbiased lambdarank): the propensity
-                # state must be a runtime input — a closed-over array would
-                # be baked into the jit as a constant and never update
-                self._obj_state = self.objective_.init_state()
+            elif hasattr(self.objective_, "device_maps"):
+                # lambdarank: its query maps are arguments as the rows are,
+                # and its program is `jit_lambdarank` in a trace
+                self._grad_fn = _with_rows(
+                    jax.jit(self.objective_.lambdarank), lbl, wgt,
+                    self.objective_.device_maps())
+                if self.objective_.has_state:
+                    # unbiased lambdarank: the propensity state must be a
+                    # runtime input too — closed over, it would be baked
+                    # into the jit as a constant and never update
+                    self._obj_state = self.objective_.init_state()
+                    self._grad_state_fn = self._grad_fn
 
-                def _grad_state(score, lbl, wgt, state):
-                    return self.objective_.grad_hess(score, lbl, wgt,
-                                                     state=state)
-                self._grad_state_fn = _with_rows(jax.jit(_grad_state),
-                                                 lbl, wgt)
-
-                def _grad(s):
-                    g, h, self._obj_state = self._grad_state_fn(
-                        s, self._obj_state)
-                    return g, h
-                self._grad_fn = _grad
+                    def _grad(s):
+                        g, h, self._obj_state = self._grad_state_fn(
+                            s, self._obj_state)
+                        return g, h
+                    self._grad_fn = _grad
             else:
                 def _grad(score, lbl, wgt):
                     return self.objective_.grad_hess(score, lbl, wgt)
@@ -1698,6 +1699,7 @@ class Booster:
                     # the constant base score (ref: rf.hpp RF::Boosting)
                     score = jnp.zeros_like(self._train_score)
                 grad, hess = self._grad_fn(score)
+                self.objective_.count_calls()
             else:
                 preds = np.asarray(self._train_score, dtype=np.float64)
                 if K > 1:
@@ -2259,6 +2261,7 @@ class Booster:
         if spec.n_valid:
             self._valid_scores[:spec.n_valid] = list(vfinal)
         self._pending_iters += spec.chunk
+        self.objective_.count_calls(spec.chunk)
         pend = _PendingChunk(spec, stacked, t_iter, v_iter, it0,
                              time.perf_counter())
         self._inflight.append(pend)
@@ -2456,6 +2459,7 @@ class Booster:
                 hess = hess.reshape((-1, K))
         else:
             grad, hess = self._grad_fn(self._train_score)
+            self.objective_.count_calls()
         finished = self.__boost(grad, hess)
         kdrop = len(dropped)
         if kdrop > 0:

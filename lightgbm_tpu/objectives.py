@@ -77,6 +77,10 @@ class ObjectiveFunction:
         """Raw score -> output (ref: ObjectiveFunction::ConvertOutput)."""
         return score
 
+    def count_calls(self, calls: int = 1) -> None:
+        """Count `calls` gradient calls on the host's counters (lambdarank
+        counts its pairs; nothing by default)."""
+
     # leaf-output refit for L1-family (ref: RenewTreeOutput in
     # regression_objective.hpp); percentile computed per leaf host-side.
     renew_tree_output: Optional[Callable] = None

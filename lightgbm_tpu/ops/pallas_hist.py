@@ -183,13 +183,20 @@ def plan_columns(plan) -> list:
 # 16.00M"): beside three blocks it took 0.5-1.3 MiB at S = 8 (72 rows)
 # and 2.9 MiB at S = 14 (126 rows): 24 KiB a row covers both.  At S = 8 the
 # 68 columns of a four-shard Criteo table (4.7 MiB packed) compile as one
-# block in every body; 86 columns (6.0 MiB) are refused.
+# block in every body; 86 columns (6.0 MiB) are refused.  A call of ONE
+# slot (9 rows, 16 with the sublane padding) takes far more a lane than
+# three 16-row blocks: 21.3 MiB at 40,960 lanes and 29.1 MiB at 56,320
+# (535 B a lane, read off the v5e compiler; the single-leaf probe of 220
+# columns of 255 bins under their plan, 51,712 lanes, was refused at 26.3
+# MiB as one block), so a block is priced at no fewer than
+# `VMEM_MIN_ROWS` rows (576 B a lane).
 VMEM_BYTES = 16 << 20
+VMEM_MIN_ROWS = 48
 
 
 def _vmem_need(sum_rows: int, lanes: int) -> int:
     rows = -(-sum_rows // 8) * 8
-    return 3 * rows * lanes * 4 + rows * (24 << 10)
+    return 3 * max(rows, VMEM_MIN_ROWS) * lanes * 4 + rows * (24 << 10)
 
 
 def column_blocks(num_col: int, sum_rows: int, max_bin: int, plan=None,

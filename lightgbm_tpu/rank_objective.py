@@ -7,15 +7,18 @@ TPU-native re-design of the reference's ranking objectives
 `RankXENDCG`).
 
 The reference loops queries on OpenMP threads with O(trunc·len) serial pair
-scans.  The TPU formulation pads queries to a common bucket length P and
-vmaps one fully-vectorized pair computation over the [Q, P] grid:
- - sort each padded query by score (lax.top_k style argsort),
+scans.  The TPU formulation pads queries to a bucket length P and computes
+the pairs of a BLOCK of queries at once:
+ - sort each padded query by score (stable argsort, descending),
  - enumerate pairs (i, j) with i in the top `truncation_level` ranks only
    (matching the reference's truncation), j over all P slots,
- - ΔNDCG, sigmoid lambda, hessian evaluated on the whole [Q, T, P] block,
+ - ΔNDCG, sigmoid lambda, hessian evaluated on the whole [B, T, P] block,
  - scatter-add back to flat [N] via the padded index map.
 
-Shapes are static: Q × P is fixed at Dataset bind time, masks cover padding.
+Shapes are static: each bucket's queries are padded to P and cut into
+blocks of B queries at Dataset bind time, masks cover padding; a scan over
+the blocks keeps the pair program's temporaries at one block's, whatever
+the number of queries.
 """
 from __future__ import annotations
 
@@ -23,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from . import telemetry
 from .objectives import ObjectiveFunction, _weighted_percentile
 from .utils.config import Config
 from .utils.log import LightGBMError
@@ -77,12 +81,13 @@ def _bucket_queries(sizes: np.ndarray, max_buckets: int = 3,
     return merged
 
 
-def _build_buckets(qb: np.ndarray, sizes: np.ndarray):
+def _build_buckets(qb: np.ndarray, sizes: np.ndarray, device: bool = True):
     """The padded gather maps for each length bucket — ONE builder shared
     by both ranking objectives.  Each bucket dict carries the host map
-    (`idx_np`, -1 padded, for position binding), the device-side
-    pre-clipped gather index (`gather` — padding already clipped to row
-    0; `mask` is the truth about padding), and the pad mask."""
+    (`idx_np`, -1 padded, for position binding) and the queries it holds
+    (`qidx`); with `device`, also the device-side pre-clipped gather
+    index (`gather` — padding already clipped to row 0; `mask` is the
+    truth about padding) and the pad mask."""
     buckets = []
     for qidx in _bucket_queries(sizes):
         Pb = int(sizes[qidx].max())
@@ -90,25 +95,66 @@ def _build_buckets(qb: np.ndarray, sizes: np.ndarray):
         for row, q in enumerate(qidx):
             idx[row, :sizes[q]] = np.arange(qb[q], qb[q + 1],
                                             dtype=np.int32)
-        buckets.append({"idx_np": idx, "qidx": qidx,
-                        "gather": jnp.asarray(np.maximum(idx, 0)),
-                        "mask": jnp.asarray(idx >= 0)})
+        b = {"idx_np": idx, "qidx": qidx}
+        if device:
+            b.update(gather=jnp.asarray(np.maximum(idx, 0)),
+                     mask=jnp.asarray(idx >= 0))
+        buckets.append(b)
     return buckets
 
 
+#: bytes of ONE [B, T, P] float32 pair temporary: a bucket's block of B
+#: queries is the most whose pair tensor stays within it (at least one
+#: query), so the pair program's live temporaries are a block's at any
+#: number of queries (tests/test_lgbrank_aot.py reads them at the
+#: Istella-X cell's shape)
+PAIR_BLOCK_BYTES = 32 << 20
+
+
+def _blocked(a: np.ndarray, n_blocks: int, fill) -> np.ndarray:
+    """`a` [Q, ...] padded with `fill` to a whole number of blocks and
+    reshaped [n_blocks, B, ...]."""
+    per = -(-len(a) // n_blocks)
+    pad = np.full((n_blocks * per - len(a),) + a.shape[1:], fill, a.dtype)
+    return np.concatenate([a, pad]).reshape((n_blocks, per) + a.shape[1:])
+
+
 class LambdarankNDCG(ObjectiveFunction):
-    """ref: rank_objective.hpp `LambdarankNDCG`.
+    """ref: rank_objective.hpp `LambdarankNDCG::GetGradientsForOneQuery`
+    (v3, from memory; the rule the Istella-X cell states,
+    `perfbench/configs/istellax220-lgbrank-l31.json`).  Per query, docs
+    stable-sorted by score descending; for each i in the top
+    min(T, n) ranks and j > i with a different label, `high` the better-
+    labelled doc of the pair:
+
+        Δ = |gain_high - gain_low| · |1/log2(2+r_high) - 1/log2(2+r_low)|
+            · inverse_max_dcg@T
+        norm and best score != worst score:  Δ /= 0.01 + |s_high - s_low|
+        p = 1 / (1 + exp(σ (s_high - s_low)))
+        λ = -σ p Δ,  h = σ² p (1 - p) Δ;  high += λ, low -= λ, both += h
+        norm and S = Σ_pairs 2|λ| > 0:  λ, h *= log2(1 + S) / S
+
+    Upstream reads the sigmoid from a table of 2^20 bins; here it is
+    computed (the cell's `departures`).
 
     Position-bias correction (ref: v4 rank_objective.hpp position handling,
     `lambdarank_position_bias_regularization`; algorithm per Unbiased
     LambdaMART, Hu et al. WSDM'19): when `Dataset.position` is supplied the
-    booster activates the stateful path — each pair's lambda is divided by
+    booster activates the stateful path — each pair's Δ above is divided by
     the learned propensities `t_plus[pos_high] * t_minus[pos_low]`, and the
     propensities are re-estimated each iteration from the aggregated raw
     lambdas (alternating minimization, normalized to position 0, exponent
-    1/(1+reg)).  Formula-level parity with the reference is unverifiable
-    while the reference mount is empty; the contract (positions in, per-
-    position bias factors learned jointly with the model) matches.
+    1/(1+reg)).  Formula-level parity with upstream's position handling
+    is not verified (no reference and no cell for it); the contract
+    (positions in, per-position bias factors learned jointly with the
+    model) matches.
+
+    The query maps are ARGUMENTS of the jitted gradients
+    (`device_maps()`, handed to `lambdarank` by the booster as labels
+    are): closed over, they would be baked into the executable as
+    constants.  Each call counts `rank.pair_slots` (pair slots the blocks
+    evaluate) and `rank.pair_positions` (the (i, j) positions of the rule
+    between real docs) on the host (`count_calls`).
     """
     name = "lambdarank"
     is_ranking = True
@@ -141,8 +187,8 @@ class LambdarankNDCG(ObjectiveFunction):
             raise LightGBMError("Ranking objective requires query "
                                 "information (set group in the Dataset)")
         self._num_data = int(qb[-1])
-        # per-query inverse max DCG over the full query (ref: LambdarankNDCG
-        # Init computes inverse_max_dcgs_ at truncation_level)
+        # per-query inverse max DCG at truncation_level (ref:
+        # LambdarankNDCG Init computes inverse_max_dcgs_)
         gains = self.label_gain[label.astype(np.int64)]
         inv_max = np.zeros(len(sizes), dtype=np.float64)
         T = self.truncation_level
@@ -154,19 +200,48 @@ class LambdarankNDCG(ObjectiveFunction):
         self.gain_table = jnp.asarray(self.label_gain.astype(np.float32))
         # r5: length-bucketed padded layout — each bucket pads to its
         # own max, so median queries stop paying the longest query's
-        # [Q, T, P] pair tensor (see _bucket_queries; single bucket ==
-        # the old flat layout, bit-for-bit)
-        self._buckets = _build_buckets(qb, sizes)
-        for b in self._buckets:
-            b["inv_max"] = jnp.asarray(inv_max[b["qidx"]])
-            b["pos"] = None
+        # pair tensor (see _bucket_queries); each bucket in blocks of
+        # queries (PAIR_BLOCK_BYTES)
+        self._buckets = []
+        slots = 0
+        for b in _build_buckets(qb, sizes, device=False):
+            P = b["idx_np"].shape[1]
+            per = max(1, PAIR_BLOCK_BYTES // (4 * min(T, P) * P))
+            n_blocks = -(-len(b["qidx"]) // per)
+            b["blocks"] = n_blocks
+            b["maps"] = {
+                "gather": jnp.asarray(_blocked(
+                    np.maximum(b["idx_np"], 0), n_blocks, 0)),
+                "mask": jnp.asarray(_blocked(b["idx_np"] >= 0, n_blocks,
+                                             False)),
+                "inv_max": jnp.asarray(_blocked(inv_max[b["qidx"]],
+                                                n_blocks, 0.0)),
+                "pos": None}
+            slots += int(np.prod(b["maps"]["gather"].shape)) * min(T, P)
+            self._buckets.append(b)
+        t = np.minimum(sizes, T)
+        self._pair_counts = (slots, int(np.sum(t * (sizes - 1)
+                                               - t * (t - 1) // 2)))
+        telemetry.REGISTRY.gauge("rank.queries").set(len(sizes))
+        telemetry.REGISTRY.gauge("rank.buckets").set(len(self._buckets))
         # re-binding a dataset invalidates any previously bound positions
         # (the buckets above were just rebuilt with pos=None): without
         # this reset a stale has_state/num_positions pair from an earlier
-        # set_positions would make get_gradients reach for b["pos"] and
-        # crash — set_positions must be called again for the new data
+        # set_positions would make get_gradients reach for the missing
+        # position grids and crash — set_positions must be called again
         self.has_state = False
         self.num_positions = 0
+
+    def device_maps(self):
+        """The query maps of every bucket, `[n_blocks, B, P]` (inverse
+        max DCG `[n_blocks, B]`): the gradients' argument `maps`."""
+        return [b["maps"] for b in self._buckets]
+
+    def count_calls(self, calls: int = 1) -> None:
+        slots, positions = self._pair_counts
+        telemetry.REGISTRY.counter("rank.pair_slots").inc(calls * slots)
+        telemetry.REGISTRY.counter("rank.pair_positions").inc(
+            calls * positions)
 
     # ------------------------------------------------- position debiasing
     def set_positions(self, position: np.ndarray) -> None:
@@ -190,7 +265,7 @@ class LambdarankNDCG(ObjectiveFunction):
         for b in self._buckets:
             grid = pos_ids[np.maximum(b["idx_np"], 0)]
             grid[b["idx_np"] < 0] = 0
-            b["pos"] = jnp.asarray(grid)                # [Qb, Pb]
+            b["maps"]["pos"] = jnp.asarray(_blocked(grid, b["blocks"], 0))
         self.has_state = True
 
     def init_state(self):
@@ -198,63 +273,57 @@ class LambdarankNDCG(ObjectiveFunction):
         k = max(self.num_positions, 1)
         return (jnp.ones((k,), jnp.float32), jnp.ones((k,), jnp.float32))
 
-    def _bucket_lambdas(self, b, score, label, state):
-        """Per-bucket [Qb, T, Pb] pair computation → (lam_q, h_q, lp, lm):
-        padded per-row lambdas/hessians plus this bucket's raw
-        propensity mass (lp/lm are None without position state).  The
-        math is per-query, so bucketing changes nothing (the single-
-        bucket case is the pre-r5 flat layout bit-for-bit)."""
-        mask = b["mask"]
-        P = b["gather"].shape[1]
+    def _block_lambdas(self, blk, score, label, state):
+        """One block's [B, T, P] pair computation → (rows, lam, h, lp,
+        lm): the rows of the block's padded slots in score order, their
+        lambdas and hessians (0 on padding), and the block's raw
+        propensity mass (lp/lm are None without position state)."""
+        idx, mask = blk["gather"], blk["mask"]                 # [B, P]
+        P = idx.shape[1]
         T = min(self.truncation_level, P)
         sig = self.sigmoid
-        idx = b["gather"]
-        s = jnp.where(mask, score[idx], -jnp.inf)              # [Qb, Pb]
-        y = jnp.where(mask, label[idx].astype(jnp.int32), -1)
-        gains = jnp.where(mask, self.gain_table[jnp.maximum(y, 0)], 0.0)
-
-        # rank by score desc (padding sinks to the bottom via -inf)
-        order = jnp.argsort(-s, axis=1)                        # [Qb, Pb]
-        s_sorted = jnp.take_along_axis(s, order, axis=1)
-        g_sorted = jnp.take_along_axis(gains, order, axis=1)
-        m_sorted = jnp.take_along_axis(mask, order, axis=1)
+        s = jnp.where(mask, score[idx], -jnp.inf)
+        # rank by score desc, ties in row order (padding sinks via -inf)
+        order = jnp.argsort(-s, axis=1)
+        rows = jnp.take_along_axis(idx, order, axis=1)
+        m = jnp.take_along_axis(mask, order, axis=1)
+        s = jnp.where(m, jnp.take_along_axis(s, order, axis=1), 0.0)
+        y = jnp.where(m, label[rows], -1.0)
+        gain = jnp.where(m, self.gain_table[jnp.maximum(y, 0).astype(
+            jnp.int32)], 0.0)
         discount = 1.0 / jnp.log2(jnp.arange(P, dtype=jnp.float32) + 2.0)
 
-        # pairs: i over top-T ranks, j over all ranks (i < j by rank)
-        si = s_sorted[:, :T, None]                             # [Qb, T, 1]
-        sj = s_sorted[:, None, :]                              # [Qb, 1, Pb]
-        gi = g_sorted[:, :T, None]
-        gj = g_sorted[:, None, :]
-        di = discount[None, :T, None]
-        dj = discount[None, None, :]
-        rank_i = jnp.arange(T)[None, :, None]
-        rank_j = jnp.arange(P)[None, None, :]
-        valid = (rank_j > rank_i) & m_sorted[:, :T, None] \
-            & m_sorted[:, None, :] & (gi != gj)
-
-        # high = larger gain of the pair (ref: the reference swaps so that
-        # `high` is the better-labeled doc)
-        high_is_i = gi > gj
-        s_high = jnp.where(high_is_i, si, sj)
-        s_low = jnp.where(high_is_i, sj, si)
-        dcg_gap = jnp.abs(gi - gj)
-        paired_discount = jnp.abs(di - dj)
-        delta = dcg_gap * paired_discount * \
-            b["inv_max"][:, None, None]                        # [Qb, T, Pb]
+        # pairs: i over the top-T ranks, j over all ranks below i
+        yi, yj = y[:, :T, None], y[:, None, :]
+        valid = (jnp.arange(P)[None, None, :] > jnp.arange(T)[None, :, None]) \
+            & m[:, :T, None] & m[:, None, :] & (yi != yj)
+        high_is_i = yi > yj
+        ds = jnp.where(high_is_i, 1.0, -1.0) * (s[:, :T, None]
+                                                - s[:, None, :])
+        delta = jnp.abs(gain[:, :T, None] - gain[:, None, :]) \
+            * jnp.abs(discount[None, :T, None] - discount[None, None, :]) \
+            * blk["inv_max"][:, None, None]                    # [B, T, P]
+        if self.norm:
+            # regularised by the pair's score distance where the query's
+            # scores are not all equal
+            best = s[:, 0]
+            worst = jnp.min(jnp.where(m, s, jnp.inf), axis=1)
+            delta = jnp.where((best != worst)[:, None, None],
+                              delta / (0.01 + jnp.abs(ds)), delta)
+        p = jax.nn.sigmoid(-sig * ds)                       # 1/(1+e^{σΔs})
 
         lp = lm = None
-        if state is not None and b["pos"] is not None:
+        if state is not None and blk["pos"] is not None:
             # unbiased-LambdaMART correction: divide each pair's weight by
             # the learned click propensities; the raw lambda mass (lp/lm)
             # is returned for the caller's cross-bucket re-estimate
             t_plus, t_minus = state
-            pos_sorted = jnp.take_along_axis(b["pos"], order, axis=1)
-            p_i = jnp.broadcast_to(pos_sorted[:, :T, None], valid.shape)
-            p_j = jnp.broadcast_to(pos_sorted[:, None, :], valid.shape)
+            pos = jnp.take_along_axis(blk["pos"], order, axis=1)
+            p_i = jnp.broadcast_to(pos[:, :T, None], valid.shape)
+            p_j = jnp.broadcast_to(pos[:, None, :], valid.shape)
             pos_high = jnp.where(high_is_i, p_i, p_j)
             pos_low = jnp.where(high_is_i, p_j, p_i)
-            prob = jax.nn.sigmoid(-sig * (s_high - s_low))
-            lam_mag = jnp.where(valid, sig * prob * delta, 0.0)
+            lam_mag = jnp.where(valid, sig * p * delta, 0.0)
             lp = jnp.zeros((max(self.num_positions, 1),), jnp.float32)\
                 .at[pos_high.reshape(-1)].add(
                 (lam_mag / t_minus[pos_low]).reshape(-1))
@@ -263,57 +332,54 @@ class LambdarankNDCG(ObjectiveFunction):
                 (lam_mag / t_plus[pos_high]).reshape(-1))
             delta = delta / (t_plus[pos_high] * t_minus[pos_low])
 
-        p = jax.nn.sigmoid(-sig * (s_high - s_low))            # 1/(1+e^{σΔ})
-        lam = -sig * p * delta                                 # d/ds_high
-        hess = sig * sig * p * (1.0 - p) * delta
-        lam = jnp.where(valid, lam, 0.0)
-        hess = jnp.where(valid, hess, 0.0)
-
-        # accumulate onto sorted positions: high gets +lam, low gets -lam
-        lam_i = jnp.where(high_is_i, lam, -lam).sum(axis=2)    # [Qb, T]
-        lam_j = jnp.where(high_is_i, -lam, lam).sum(axis=1)    # [Qb, Pb]
-        h_i = hess.sum(axis=2)
-        h_j = hess.sum(axis=1)
-        lam_sorted = jnp.zeros(s.shape, dtype=jnp.float32)\
-            .at[:, :T].add(lam_i) + lam_j
-        h_sorted = jnp.zeros(s.shape, dtype=jnp.float32)\
-            .at[:, :T].add(h_i) + h_j
-
+        lam = jnp.where(valid, -sig * p * delta, 0.0)          # d/ds_high
+        hess = jnp.where(valid, sig * sig * p * (1.0 - p) * delta, 0.0)
+        # high gets +lam, low gets -lam; both get +hess
+        lam_sorted = jnp.where(high_is_i, -lam, lam).sum(axis=1)\
+            .at[:, :T].add(jnp.where(high_is_i, lam, -lam).sum(axis=2))
+        h_sorted = hess.sum(axis=1).at[:, :T].add(hess.sum(axis=2))
         if self.norm:
-            # ref: lambdarank_norm — rescale per query by log2(1+Σ|λ|)/Σ|λ|
-            sum_lam = jnp.sum(jnp.abs(lam_sorted), axis=1, keepdims=True)
-            factor = jnp.where(sum_lam > 0,
-                               jnp.log2(1.0 + sum_lam) / sum_lam, 1.0)
-            lam_sorted = lam_sorted * factor
-            h_sorted = h_sorted * factor
+            # ref: lambdarank_norm — rescale per query by log2(1+S)/S,
+            # S = Σ_pairs 2|λ|
+            total = 2.0 * jnp.sum(jnp.abs(lam), axis=(1, 2))
+            factor = jnp.where(total > 0, jnp.log2(1.0 + total)
+                               / jnp.where(total > 0, total, 1.0), 1.0)
+            lam_sorted = lam_sorted * factor[:, None]
+            h_sorted = h_sorted * factor[:, None]
+        # zero the pad slots so the scatter-add at clipped index 0 adds
+        # nothing
+        return (rows.reshape(-1), jnp.where(m, lam_sorted, 0.0).reshape(-1),
+                jnp.where(m, h_sorted, 0.0).reshape(-1), lp, lm)
 
-        # unsort back to query positions; zero the pad slots so the
-        # caller's scatter-add at clipped index 0 adds nothing
-        inv_order = jnp.argsort(order, axis=1)
-        lam_q = jnp.where(mask, jnp.take_along_axis(lam_sorted, inv_order,
-                                                    axis=1), 0.0)
-        h_q = jnp.where(mask, jnp.take_along_axis(h_sorted, inv_order,
-                                                  axis=1), 0.0)
-        return lam_q, h_q, lp, lm
+    def lambdarank(self, score, label, weight, maps, state=None):
+        """The gradients over the query maps `maps` (`device_maps()`):
+        (grad, hess), and with `state` the re-estimated propensities
+        third.  Each bucket's blocks run one after another (`lax.scan`),
+        each scatter-adding its rows into the carried [N] sums."""
+        debias = state is not None and maps[0]["pos"] is not None
+        k = max(self.num_positions, 1)
+        zero = jnp.zeros((k,), jnp.float32) if debias else None
 
-    def grad_hess(self, score, label, weight, state=None):
-        grad = jnp.zeros_like(score)
-        hessian = jnp.zeros_like(score)
-        lp_acc = lm_acc = None
-        for b in self._buckets:
-            lam_q, h_q, lp, lm = self._bucket_lambdas(b, score, label,
-                                                      state)
-            flat = b["gather"].reshape(-1)
-            grad = grad.at[flat].add(lam_q.reshape(-1))
-            hessian = hessian.at[flat].add(h_q.reshape(-1))
-            if lp is not None:
-                lp_acc = lp if lp_acc is None else lp_acc + lp
-                lm_acc = lm if lm_acc is None else lm_acc + lm
+        def block(carry, blk):
+            grad, hess, lp_acc, lm_acc = carry
+            rows, lam, h, lp, lm = self._block_lambdas(blk, score, label,
+                                                       state)
+            grad = grad.at[rows].add(lam)
+            hess = hess.at[rows].add(h)
+            if debias:
+                lp_acc, lm_acc = lp_acc + lp, lm_acc + lm
+            return (grad, hess, lp_acc, lm_acc), None
+
+        carry = (jnp.zeros_like(score), jnp.zeros_like(score), zero, zero)
+        with jax.named_scope("lambdarank"):
+            for blocks in maps:
+                carry, _ = jax.lax.scan(block, carry, blocks)
+        grad, hessian, lp_acc, lm_acc = carry
         new_state = None
-        if lp_acc is not None:
+        if debias:
             # re-estimate propensities from the GLOBAL raw lambda mass
             # (alternating minimization, normalized to position 0,
-            # exponent 1/(1+reg)) — summed across buckets first so the
+            # exponent 1/(1+reg)) — summed across blocks first so the
             # estimate is identical to the flat layout's
             exponent = 1.0 / (1.0 + self.bias_reg)
             tp_new = jnp.where(
@@ -330,6 +396,10 @@ class LambdarankNDCG(ObjectiveFunction):
         if state is not None:
             return grad, hessian, new_state
         return grad, hessian
+
+    def grad_hess(self, score, label, weight, state=None):
+        return self.lambdarank(score, label, weight, self.device_maps(),
+                               state)
 
 
 class RankXENDCG(ObjectiveFunction):

@@ -264,10 +264,12 @@ def test_the_reference_imports_nothing_of_the_program():
 def test_four_configurations_four_cells_thirtyfour_metric_files():
     assert manifest.problems() == []
     b = manifest.benchmark()
-    assert [c["name"] for c in b["configs"]][-1] == CONFIG
-    assert len(b["configs"]) == 4
-    assert [w["name"] for w in b["workloads"]][-1] == CELL
-    assert [w["chips"] for w in b["workloads"]] == [1, 1, 4, 1]
+    # the fourth configuration and cell (later PRs append their own: the
+    # first four are held, not the count of the whole)
+    assert [c["name"] for c in b["configs"]][3] == CONFIG
+    assert len(b["configs"]) >= 4
+    assert [w["name"] for w in b["workloads"]][3] == CELL
+    assert [w["chips"] for w in b["workloads"]][:4] == [1, 1, 4, 1]
     # this cell's nine `cat.*` metrics, listed together and each with its
     # file (later PRs append metrics of their own: no count of the whole)
     names = [m["name"] for m in b["per_layer"]]

@@ -396,15 +396,23 @@ def test_cell_params_build_without_fallback(name):
     import lightgbm_tpu as lgb
     from lightgbm_tpu import telemetry
     from perfbench import manifest
-    from perfbench.generators import tabular_codes
+    from perfbench.generators import ltr_codes, tabular_codes
     from perfbench.jobs.train import build_dataset
     config = manifest.config(name)
     data = config["data"]
-    codes, label = tabular_codes.generate(7, data, 0, 8192)
+    ranking = config["generator"] == "ltr_codes"
+    if ranking:     # query-grouped rows: four queries of its own law
+        rows = ltr_codes.make(7, dict(data, queries=4), 8192, 1)
+        codes, label = rows["codes"], rows["label"]
+        names = ltr_codes.column_names(data)
+    else:
+        codes, label = tabular_codes.generate(7, data, 0, 8192)
+        names = [c["name"] for c in data["columns"]]
     params = {**config["params"], "hist_impl": "pallas",
               "hist_interpret": True}
-    ds = build_dataset(lgb, codes, label, params,
-                       [c["name"] for c in data["columns"]])
+    ds = build_dataset(lgb, codes, label, params, names)
+    if ranking:
+        ds._query_boundaries = rows["query_boundaries"]
     counter = telemetry.REGISTRY.counter("fallback.events")
     before = counter.value
     sink = telemetry.TRACER.add_sink(telemetry.MemorySink())

@@ -243,11 +243,14 @@ def test_column_blocks_sum_the_same_bits(n_blocks, planned, body):
 
 
 @pytest.mark.parametrize("columns,slots,n_blocks", [
-    (13, 8, 1), (68, 8, 1), (256, 8, 4), (68, 14, 2), (256, 14, 8)])
+    (13, 8, 1), (68, 8, 1), (256, 8, 4), (68, 14, 2), (256, 14, 8),
+    (220, 1, 2)])
 def test_chosen_column_blocks_fit_the_vmem_budget(columns, slots, n_blocks):
     """As few blocks as fit: the 68 columns a shard of the four-chip cell
     sees are ONE block at the cell's eight slots under their plan (without
-    one, all 68 on 256 lanes each, they are two)."""
+    one, all 68 on 256 lanes each, they are two); a single-leaf call over
+    220 columns (the ranking cell's probe) is priced at VMEM_MIN_ROWS rows
+    and cut in two."""
     num_bins = (CRITEO_NUM_BIN * 4)[:columns]
     for plan in (None, ph.lane_plan(num_bins, 255)):
         blocks = ph.column_blocks(columns, slots * 9, 255, plan)
